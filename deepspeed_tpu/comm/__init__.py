@@ -78,6 +78,4 @@ def axis_index(axis_name):
 
 def axis_size(axis_name):
     """Size of the axis (reference: dist.get_world_size(group))."""
-    from ..utils.compat import axis_size as _axis_size
-
-    return _axis_size(axis_name)
+    return lax.axis_size(axis_name)

@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..utils.compat import axis_size as compat_axis_size
-
 _BIT_WEIGHTS = np.asarray([128, 64, 32, 16, 8, 4, 2, 1], np.uint8)  # MSB-first
 
 
@@ -83,7 +81,7 @@ def compressed_allreduce(buf, worker_error, server_error, axis_name):
     the [n] compressed approximation of ``mean(buf)`` — identical on
     all ranks; the error buffers stay padded-size.
     """
-    world = compat_axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     n = buf.shape[0]
     n_pad = padded_size(n, world)
     assert worker_error.shape[0] == n_pad, (

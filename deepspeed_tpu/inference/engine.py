@@ -35,6 +35,8 @@ from ..profiling.comm import CommLedger, SERVE_DECODE_PROGRAM
 from ..profiling.memory import MemoryLedger
 from ..profiling.step_profiler import StepLatencyRing
 from ..runtime import constants as C
+from ..runtime.compilation import (DeepSpeedCompilationConfig,
+                                   configure_persistent_cache)
 from ..telemetry import events as TEL
 from ..telemetry.config import DeepSpeedTelemetryConfig
 from ..telemetry.manager import TelemetryManager
@@ -71,6 +73,12 @@ class InferenceEngine:
         self._validate_config(param_dict)
         self.inference_config = DeepSpeedInferenceConfig(param_dict)
         icfg = self.inference_config
+        # persistent compile cache BEFORE the first jit, by the same rule
+        # as the training engine: the decode program of a 36-layer model
+        # compiles for tens of seconds, and a respawned replica must not
+        # pay it again
+        self._compile_cache_dir = configure_persistent_cache(
+            DeepSpeedCompilationConfig(param_dict))
         self.model = model
         mc = model.config
         assert mc.max_position_embeddings >= icfg.max_seq_len, (

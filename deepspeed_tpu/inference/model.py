@@ -23,6 +23,7 @@ parity test pins this).
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..models.layers import dense, gelu, layer_norm
 from ..ops.transformer.attention import dot_product_attention
@@ -146,17 +147,33 @@ def build_decode(model_config, icfg):
 
 def reference_generate(model, params, prompt, max_new_tokens,
                        eos_token_id=-1):
-    """The naive one-request-at-a-time reference: full forward over the
-    whole growing context per token, greedy argmax.  O(n^2) recompute
-    and one retrace per length — it exists to be the parity oracle the
-    cached engine must match token for token, not to be fast."""
-    ids = list(int(t) for t in prompt)
+    """The naive one-request-at-a-time reference: a full forward over the
+    whole growing context per token, greedy argmax — no cache, no paging,
+    no buckets, no batch.  O(n^2) recompute; it exists to be the parity
+    oracle the cached engine must match token for token, not to be fast.
+
+    The context is right-padded to the request's final length, which a
+    causal model cannot see from the positions before it, so that ONE
+    traced program serves every step of the request (a forward at each
+    new length retraces every op: eight minutes for two requests at
+    GPT-2-large width on the chip)."""
+    n = len(prompt)
+    # one fixed-length buffer: tokens fill it from the left as they come
+    padded = np.zeros((1, n + max_new_tokens), np.int32)
+    padded[0, :n] = [int(t) for t in prompt]
+
+    @jax.jit
+    def next_token(params, padded_ids, n):
+        logits = model.logits(params, padded_ids)
+        return jnp.argmax(jax.lax.dynamic_index_in_dim(
+            logits[0], n - 1, axis=0, keepdims=False))
+
     out = []
     for _ in range(max_new_tokens):
-        logits = model.logits(params, jnp.asarray([ids], jnp.int32))
-        nxt = int(jnp.argmax(logits[0, -1]))
+        nxt = int(next_token(params, padded, n))
         out.append(nxt)
-        ids.append(nxt)
+        padded[0, n] = nxt
+        n += 1
         if eos_token_id >= 0 and nxt == eos_token_id:
             break
     return out

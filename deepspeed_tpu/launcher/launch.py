@@ -53,6 +53,7 @@ fixes.
 """
 
 import argparse
+import glob
 import json
 import os
 import random
@@ -190,6 +191,38 @@ def resolve_node_rank(node_rank, world):
         f"cannot resolve node rank: hostname {hostname!r} not in {hosts}")
 
 
+def local_tpu_chips():
+    """Device files of the TPU chips on this host ([] on a host without
+    any).  Stdlib only: the launcher must not import jax, which would
+    take the chips its children need."""
+    return sorted(glob.glob("/dev/accel[0-9]*")
+                  + glob.glob("/dev/vfio/[0-9]*"))
+
+
+def check_one_process_per_tpu_host(n_local, environ=None):
+    """A TPU chip belongs to one process at a time, and this launcher
+    binds no chips to its children: several children on one TPU host
+    would all open every local chip, and all but the first fail or hang.
+    The supported layout on a host is ONE process driving all its local
+    chips (a ``{"data": n}`` mesh over ``jax.devices()``), so more is
+    refused here with that message.  Children kept off the TPU
+    (``JAX_PLATFORMS`` without ``tpu``, as the CPU test fleets set it)
+    may be as many as asked."""
+    environ = os.environ if environ is None else environ
+    platforms = environ.get("JAX_PLATFORMS", "")
+    if n_local <= 1 or (platforms and "tpu" not in platforms.split(",")):
+        return
+    chips = local_tpu_chips()
+    if chips:
+        raise RuntimeError(
+            f"{n_local} processes asked for on one TPU host "
+            f"({len(chips)} chip(s): {', '.join(chips)}).  A chip belongs "
+            "to one process at a time and this launcher binds no chips "
+            "to its children, so they would fight for them.  Run ONE "
+            "process per host (--num_procs 1) and let it drive all "
+            "local chips through a {'data': n} mesh.")
+
+
 def main(argv=None):
     args = parse_args(argv)
     world = decode_world_info(args.world_info)
@@ -201,6 +234,7 @@ def main(argv=None):
     first_id = sum(len(world[h]) for h in hosts[:node_rank])
     local_slots = world[hosts[node_rank]]
     total = sum(len(v) for v in world.values())
+    check_one_process_per_tpu_host(len(local_slots))
 
     # structured telemetry: restarts and exit codes become queryable
     # events instead of log lines (report CLI merges this stream with the
@@ -267,8 +301,9 @@ def main(argv=None):
         env[ENV_NUM_PROCESSES] = str(n_procs)
         env[ENV_PROCESS_ID] = str(first_id + local_rank)
         # the SLOT id from the (include/exclude-filtered) hostfile, so slot
-        # filtering reaches the process; device binding from it is
-        # platform-specific (e.g. TPU_VISIBLE_CHIPS), left to the script
+        # filtering reaches the process.  No chip is bound from it: on a
+        # TPU host one process drives all local chips
+        # (check_one_process_per_tpu_host refuses more)
         env[ENV_LOCAL_RANK] = str(slot)
         if elastic is not None:
             # the planned world size + normalized schedule travel to the

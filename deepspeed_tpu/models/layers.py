@@ -28,7 +28,9 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.op_common import random_keep
 from ..ops.transformer.attention import (dot_product_attention,
-                                         key_padding_to_additive)
+                                         key_padding_to_additive,
+                                         shard_kernel_over_mesh)
+from ..parallel.mesh import current_platform
 
 
 def _dense_init(rng, in_dim, out_dim, initializer_range=0.02):
@@ -225,7 +227,7 @@ class TransformerLayer:
             # has no effect on already-compiled programs (jit cache).
             blk = s // layout.shape[1]
             use_kernel = (kpm_add is None
-                          and jax.default_backend() == "tpu"
+                          and current_platform() == "tpu"
                           and blk % 128 == 0 and q.shape[-1] % 64 == 0
                           and os.environ.get("DS_SPARSE_FLASH",
                                              "auto") != "never")
@@ -233,8 +235,12 @@ class TransformerLayer:
                 from ..ops.sparse_attention.flash_block_sparse import (
                     flash_block_sparse_attention)
 
-                ctx = flash_block_sparse_attention(q, k, v, layout,
-                                                   causal=causal_sp)
+                # heads stay whole: the layout is per head and static
+                ctx = shard_kernel_over_mesh(
+                    lambda q, k, v, _mask, _seed: (
+                        flash_block_sparse_attention(q, k, v, layout,
+                                                     causal=causal_sp)),
+                    q, k, v, shard_heads=False)
             else:
                 from ..ops.sparse_attention import block_sparse_attention
 

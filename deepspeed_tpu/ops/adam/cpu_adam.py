@@ -21,8 +21,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from ...utils.compat import shard_map
+from jax import shard_map
 
 
 class CPUAdamState(NamedTuple):

@@ -138,10 +138,6 @@ class FlashAttentionBuilder(OpBuilder):
     ENTRY = "flash_attention"
 
     def compatibility(self):
-        try:
-            from jax.experimental.pallas import tpu  # noqa: F401
-        except Exception:
-            return False, "Pallas TPU backend not importable"
         if _backend() != "tpu":
             return False, "compiled Mosaic kernels need a TPU (interpret mode elsewhere)"
         return True, "Pallas kernel; engaged when score memory exceeds budget"
@@ -162,10 +158,6 @@ class SparseFlashAttentionBuilder(OpBuilder):
     ENTRY = "flash_block_sparse_attention"
 
     def compatibility(self):
-        try:
-            from jax.experimental.pallas import tpu  # noqa: F401
-        except Exception:
-            return False, "Pallas TPU backend not importable"
         if _backend() != "tpu":
             return False, "compiled Mosaic kernels need a TPU (gather path elsewhere)"
         return True, "engaged for 128-multiple layout blocks (block >= 512 advised)"

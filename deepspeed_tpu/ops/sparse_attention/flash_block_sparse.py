@@ -437,7 +437,7 @@ def _bwd_dkv_kernel_agg(stlut_ref, stcnt_ref, stmask_ref, q_ref, k_ref,
 
 
 def _grid_params(interpret, ndims=3):
-    if pltpu is None or interpret:
+    if interpret:
         return {}
     sem = ("parallel",) * (ndims - 1) + ("arbitrary",)
     return {"compiler_params": pltpu.CompilerParams(
@@ -776,13 +776,8 @@ def flash_block_sparse_attention(q, k, v, layout, causal=False,
     pairs via a per-tick bitmask; dk/dv aggregates key rows symmetrically.
     ``q_agg``: "auto" (default), "never", or an explicit factor.
 
-    Requires the Mosaic PRNG-free feature set only; on CPU builds without
-    ``jax.experimental.pallas.tpu``, use the gather-based
-    ``block_sparse_attention`` instead.
+    Requires the Mosaic PRNG-free feature set only.
     """
-    assert pltpu is not None, (
-        "flash_block_sparse_attention needs jax.experimental.pallas.tpu; "
-        "use block_sparse_attention (gather-based) on CPU-only builds")
     b, s, h, d = q.shape
     layout = np.asarray(layout)
     nb = layout.shape[1]

@@ -30,34 +30,85 @@ PALLAS_MIN_SCORE_BYTES = 2 * 1024 ** 3
 
 
 def _use_pallas(q, k):
-    try:
-        mode = os.environ.get("DS_FLASH_ATTENTION", "auto")
-        shapes_ok = (jax.default_backend() == "tpu" and q.shape[1] >= 128
-                     and q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0
-                     and q.shape[-1] % 64 == 0)
-        if mode == "never":
-            return False
-        if mode == "always":
-            return shapes_ok
-        if q.shape[1] >= PALLAS_MIN_SEQ and k.shape[1] >= PALLAS_MIN_SEQ:
-            return shapes_ok
-        b, sq, h, _ = q.shape
-        score_bytes = 4 * b * h * sq * k.shape[1]
-        # shapes here are logical/global; under data-parallel GSPMD each
-        # chip materializes 1/dp of the batch — budget the PER-DEVICE size
-        try:
-            from ...parallel.mesh import get_current_mesh
+    from ...parallel.mesh import current_platform, get_current_mesh
 
-            mesh = get_current_mesh()
-            if mesh is not None:
-                dp = dict(zip(mesh.axis_names, mesh.devices.shape)).get(
-                    "data", 1)
-                score_bytes //= max(dp, 1)
-        except Exception:  # dslint: disable=DSE502 -- mesh probe inside a heuristic; undivided score is a safe default
-            pass
-        return shapes_ok and score_bytes > PALLAS_MIN_SCORE_BYTES
-    except Exception:
+    mode = os.environ.get("DS_FLASH_ATTENTION", "auto")
+    if mode == "never":
         return False
+    shapes_ok = (current_platform() == "tpu" and q.shape[1] >= 128
+                 and q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0
+                 and q.shape[-1] % 64 == 0)
+    if mode == "always":
+        return shapes_ok
+    if q.shape[1] >= PALLAS_MIN_SEQ and k.shape[1] >= PALLAS_MIN_SEQ:
+        return shapes_ok
+    b, sq, h, _ = q.shape
+    score_bytes = 4 * b * h * sq * k.shape[1]
+    # shapes here are logical/global; under data-parallel GSPMD each
+    # chip materializes 1/dp of the batch — budget the PER-DEVICE size
+    mesh = get_current_mesh()
+    if mesh is not None:
+        score_bytes //= max(mesh.shape.get("data", 1), 1)
+    return shapes_ok and score_bytes > PALLAS_MIN_SCORE_BYTES
+
+
+def shard_kernel_over_mesh(kernel, q, k, v, kv_mask=None, seed=None,
+                           shard_heads=True):
+    """``kernel(q, k, v, kv_mask, seed)`` on each device's shard of the
+    batch (over ``data``) and of the heads (over ``model``).
+
+    XLA cannot partition a Mosaic kernel call: on a mesh of several
+    devices it refuses to lower one anywhere a mesh axis is still left to
+    GSPMD ("Mosaic kernels cannot be automatically partitioned") — at the
+    top level of the step, and just as much inside the engine's
+    ``shard_map`` bodies, which are manual over ``data`` alone.  So the
+    call is wrapped in a ``shard_map`` over every axis that is not manual
+    yet.  Attention is independent per (sample, head), so each shard's
+    kernel needs no collective.  A batch or head count the axis does not
+    divide stays replicated over it.
+
+    The shards share ``seed``: in-kernel dropout masks repeat across batch
+    shards at equal local (sample, head) index — as the replicated key of
+    the engine's bucketed exchange already makes XLA dropout do.
+    """
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from ...parallel.mesh import DATA_AXIS, MODEL_AXIS, get_current_mesh
+
+    mesh = get_current_mesh()
+    if mesh is None or mesh.size == 1:
+        return kernel(q, k, v, kv_mask, seed)
+    context = jax.sharding.get_abstract_mesh()
+    auto = frozenset(mesh.axis_names) - frozenset(
+        () if context.empty else context.manual_axes)
+    if not auto:
+        return kernel(q, k, v, kv_mask, seed)
+
+    def axis_for(axis, dim):
+        n = mesh.shape.get(axis, 1)
+        return axis if axis in auto and n > 1 and dim % n == 0 else None
+
+    batch_axis = axis_for(DATA_AXIS, q.shape[0])
+    head_axis = axis_for(MODEL_AXIS, q.shape[2]) if shard_heads else None
+    qkv_spec = P(batch_axis, None, head_axis, None)
+    args, specs = [q, k, v], [qkv_spec] * 3
+    if kv_mask is not None:
+        args.append(kv_mask)
+        specs.append(P(batch_axis, None))
+    if seed is not None:
+        args.append(seed)
+        specs.append(P())
+
+    def body(q, k, v, *rest):
+        rest = list(rest)
+        mask = rest.pop(0) if kv_mask is not None else None
+        return kernel(q, k, v, mask, rest.pop(0) if rest else None)
+
+    # nested in one of the engine's shard_maps, the mesh is the context's
+    return shard_map(body, mesh=mesh if context.empty else context,
+                     in_specs=tuple(specs), out_specs=qkv_spec,
+                     axis_names=auto, check_vma=False)(*args)
 
 
 def key_padding_to_additive(key_padding_mask):
@@ -115,9 +166,11 @@ def dot_product_attention(q, k, v, mask=None, key_padding_mask=None,
             seed = jax.lax.bitcast_convert_type(
                 jax.random.bits(dropout_rng, (2,), jnp.uint32), jnp.int32)
             rate = float(dropout_rate)
-        return flash_attention(q, k, v, kv_mask=key_padding_mask,
-                               dropout_seed=seed, causal=causal,
-                               dropout_rate=rate)
+        return shard_kernel_over_mesh(
+            lambda q, k, v, kv_mask, seed: flash_attention(
+                q, k, v, kv_mask=kv_mask, dropout_seed=seed, causal=causal,
+                dropout_rate=rate),
+            q, k, v, kv_mask=key_padding_mask, seed=seed)
     if key_padding_mask is not None:
         mask = key_padding_to_additive(key_padding_mask)[:, None, None, :]
     return reference_attention(q, k, v, mask=mask, causal=causal,

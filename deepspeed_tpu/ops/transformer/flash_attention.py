@@ -39,17 +39,11 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pallas TPU backend is unavailable on CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
+from ...utils.logging import logger
 
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    # Without the pallas TPU package no scratch allocation works (even in
-    # interpret mode), so interpret calls fall back to the pure-jnp path
-    # (_jnp_flash_reference) and compiled calls raise in _flash_fwd.
-    pltpu = None
-    _VMEM = None
+_VMEM = pltpu.VMEM
 
 NEG_INF = -1e30
 # Running-max floor: keeps exp(NEG_INF - m) == 0 even for rows where every
@@ -469,7 +463,6 @@ def _dropout_ops(dropout_rate, dropout_seed):
         return (), (), 0.0
     assert dropout_seed is not None, (
         "flash_attention dropout_rate > 0 requires a dropout_seed")
-    assert pltpu is not None, "in-kernel dropout needs the pallas TPU backend"
     seed = jnp.asarray(dropout_seed, jnp.int32).reshape(-1)
     if seed.size == 1:  # legacy scalar seed: widen with a zero hi word
         seed = jnp.concatenate([seed, jnp.zeros((1,), jnp.int32)])
@@ -478,9 +471,21 @@ def _dropout_ops(dropout_rate, dropout_seed):
             float(dropout_rate))  # dslint: disable=DSH102 -- dropout_rate rides custom_vjp nondiff_argnums: static by construction
 
 
+@functools.lru_cache(maxsize=None)
+def _log_geometry(s, kv_len, d, causal, dropout, block_q, block_k, chosen):
+    """One line per distinct kernel geometry per process (traced calls
+    repeat per layer and per pass): which blocks a shape ran with, and
+    whether the caller, the measured heuristic or the first-use tuner
+    chose them — two cold runs of an un-anchored shape may differ."""
+    logger.info("flash_attention geometry: s=%d kv=%d d=%d causal=%s "
+                "dropout=%s -> block_q=%d block_k=%d (%s)", s, kv_len, d,
+                causal, dropout, block_q, block_k, chosen)
+
+
 def _resolve_blocks(s, kv_len, d, block_q, block_k, causal=False,
                     dropout_rate=0.0):
     auto_q, auto_k = _auto_blocks(s, kv_len, d, causal)
+    chosen = "caller"
     if block_q is None and block_k is None:
         # runtime autotune (reference analog: the GEMM algorithm search
         # baked into kernel setup, csrc/includes/gemm_test.h): shapes the
@@ -489,10 +494,14 @@ def _resolve_blocks(s, kv_len, d, block_q, block_k, causal=False,
         # calls back into flash_attention with EXPLICIT blocks, so the
         # recursion terminates here.
         from .kernel_tuner import tune
+        heuristic = (auto_q, auto_k)
         auto_q, auto_k = tune(s, kv_len, d, causal, dropout_rate,
-                              flash_attention, (auto_q, auto_k))
+                              flash_attention, heuristic)
+        chosen = "heuristic" if (auto_q, auto_k) == heuristic else "tuned"
     block_q = block_q or auto_q
     block_k = block_k or auto_k
+    _log_geometry(s, kv_len, d, causal, dropout_rate, block_q, block_k,
+                  chosen)
     # The kernels index K/V in whole blocks; a ragged tail would silently
     # attend over out-of-block garbage.  Dispatchers (attention.py) only
     # route divisible shapes here; direct callers must pad or shrink blocks.
@@ -504,7 +513,7 @@ def _resolve_blocks(s, kv_len, d, block_q, block_k, causal=False,
 
 
 def _grid_params(interpret):
-    if pltpu is None or interpret:
+    if interpret:
         return {}
     # bh and the outer block dim are parallel; the streamed dim accumulates
     # into VMEM scratch and must run in order.  The raised vmem limit lets
@@ -516,45 +525,8 @@ def _grid_params(interpret):
         vmem_limit_bytes=100 * 1024 * 1024)}
 
 
-def _jnp_flash_reference(q, k, v, kv_mask, causal):
-    """Dense jnp forward with the kernels' exact masking semantics —
-    the scratch-free interpret-mode path for CPU-only jax builds where
-    ``jax.experimental.pallas.tpu`` is unimportable (O(s²) memory, test
-    shapes only).  Returns (out [b,s,h,d], lse [b·h, 1, s])."""
-    b, s, h, d = q.shape
-    kv_len = k.shape[1]
-    scale = 1.0 / math.sqrt(d)
-    sc = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                    k.astype(jnp.float32)) * scale
-    if causal:
-        q_idx = jnp.arange(s)[:, None]
-        k_idx = jnp.arange(kv_len)[None, :]
-        sc = jnp.where((q_idx >= k_idx)[None, None], sc, NEG_INF)
-    if kv_mask is not None:
-        sc = jnp.where(kv_mask.astype(jnp.float32)[:, None, None, :] > 0.0,
-                       sc, NEG_INF)
-    m = jnp.maximum(jnp.max(sc, axis=-1, keepdims=True), MAX_FLOOR)
-    p = jnp.exp(sc - m)
-    l = jnp.sum(p, axis=-1, keepdims=True)
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    out = jnp.einsum("bhqk,bkhd->bqhd", (p / l_safe).astype(v.dtype), v)
-    lse = (m + jnp.log(l_safe))[..., 0].reshape(b * h, 1, s)
-    return out.astype(q.dtype), lse
-
-
 def _flash_fwd(q, k, v, kv_mask, dropout_seed, causal, block_q, block_k,
                interpret, dropout_rate):
-    if pltpu is None:
-        if not interpret:
-            raise RuntimeError(
-                "flash_attention needs jax.experimental.pallas.tpu for "
-                "compiled kernels, which this jax build could not import — "
-                "use attn_impl='auto' on a CPU backend (XLA attention) "
-                "instead")
-        assert not dropout_rate, (
-            "in-kernel dropout needs the pallas TPU backend (hardware PRNG)")
-        out, lse = _jnp_flash_reference(q, k, v, kv_mask, causal)
-        return out, (q, k, v, kv_mask, dropout_seed, out, lse)
     b, s, h, d = q.shape
     kv_len = k.shape[1]
     block_q, block_k = _resolve_blocks(s, kv_len, d, block_q, block_k, causal,
@@ -616,14 +588,6 @@ def _flash_fwd_rule(q, k, v, kv_mask, dropout_seed, causal, block_q, block_k,
 
 def _flash_bwd_rule(causal, block_q, block_k, interpret, dropout_rate, res, g):
     q, k, v, kv_mask, dropout_seed, out, lse = res
-    if pltpu is None:  # interpret fallback (see _flash_fwd); no dropout
-        dq, dk, dv = jax.vjp(
-            lambda q_, k_, v_: _jnp_flash_reference(q_, k_, v_, kv_mask,
-                                                    causal)[0],
-            q, k, v)[1](g)
-        return (dq, dk, dv,
-                jnp.zeros_like(kv_mask) if kv_mask is not None else None,
-                None)
     b, s, h, d = q.shape
     kv_len = k.shape[1]
     block_q, block_k = _resolve_blocks(s, kv_len, d, block_q, block_k, causal,
@@ -649,7 +613,7 @@ def _flash_bwd_rule(causal, block_q, block_k, interpret, dropout_rate, res, g):
 
     if n_qb == 1 and n_kb == 1:
         # single-tile fused backward: one kernel, one score pass
-        grid_1d = ({} if (pltpu is None or interpret) else
+        grid_1d = ({} if interpret else
                    {"compiler_params": pltpu.CompilerParams(
                        dimension_semantics=("parallel",),
                        vmem_limit_bytes=100 * 1024 * 1024)})

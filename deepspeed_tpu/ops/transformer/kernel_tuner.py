@@ -5,21 +5,23 @@ kernel build runs a small search over algorithms and caches the winner
 (``/root/reference/csrc/includes/gemm_test.h``).  This is the TPU analog
 for the Pallas flash kernels: the hand-calibrated ``_auto_blocks``
 heuristic stays authoritative for the shapes it was measured on (the
-"anchored" regimes below — re-tuning those would risk regressing measured
-choices on a noisy attachment), and any OTHER shape gets a cached
+"anchored" regimes below — those never re-tune, so two cold machines
+always run them with the same blocks), and any OTHER shape gets a cached
 first-use micro-search over a small block-geometry candidate set.
 
 Search cost is one kernel compile per candidate (~4-6 candidates) the
 first time a new (seq, kv_len, head_dim, causal, dropout) shape is seen
-on a TPU backend; winners persist to a JSON cache
-(``~/.cache/deepspeed_tpu/flash_blocks.json`` or ``$DS_FLASH_TUNE_CACHE``)
-so every later process skips straight to the tuned geometry.
+on a TPU backend; winners persist to ``flash_blocks.json`` in the compile
+cache directory (``runtime/compilation/cache.py`` has the one rule for
+where that is), beside the compiled programs they shaped, so every later
+process that finds the programs finds the geometry too.  On a machine
+with no such file two cold runs may pick different blocks for an
+un-anchored shape; ``flash_attention`` logs the geometry of every
+distinct kernel call so that a run says which it used.
 
-Measurement discipline (PERF.md "Methodology"): candidates run under one
-``lax.scan`` inside a single jit (per-dispatch latency on remote-attached
-chips is ~70-100 ms and identical across candidates, so it cancels in the
-ranking), with three interleaved repeats and min-aggregation — single
-shots at ms granularity swing +-50% on the bench attachment.
+Measurement discipline: candidates run under one ``lax.scan`` inside a
+single jit (dispatch cost is identical across candidates, so it cancels
+in the ranking), with three interleaved repeats and min-aggregation.
 
 Knobs: ``DS_FLASH_AUTOTUNE=0`` disables the search (pure heuristic),
 ``=1`` forces tuning even for anchored shapes, unset/``auto`` tunes only
@@ -34,9 +36,6 @@ import time
 import jax
 import jax.numpy as jnp
 
-_CACHE_PATH = os.environ.get(
-    "DS_FLASH_TUNE_CACHE",
-    os.path.expanduser("~/.cache/deepspeed_tpu/flash_blocks.json"))
 _memory_cache = {}
 _disk_loaded = False
 
@@ -51,6 +50,12 @@ _disk_loaded = False
 # v2: version-carrying keys; retires v1 entries ranked before the
 # interleaved-repeat/min-aggregation discipline carried its own version.
 TUNER_VERSION = 2
+
+
+def _cache_path():
+    from ...runtime.compilation.cache import active_cache_dir
+
+    return os.path.join(active_cache_dir(), "flash_blocks.json")
 
 
 def _mode():
@@ -85,7 +90,7 @@ def _load_disk():
         return
     _disk_loaded = True
     try:
-        with open(_CACHE_PATH) as f:
+        with open(_cache_path()) as f:
             _memory_cache.update(json.load(f))
     except Exception:  # dslint: disable=DSE502 -- cache file absent/corrupt on first run; tuner just re-measures
         pass
@@ -93,8 +98,9 @@ def _load_disk():
 
 def _save_disk():
     try:
-        os.makedirs(os.path.dirname(_CACHE_PATH), exist_ok=True)
-        with open(_CACHE_PATH, "w") as f:
+        path = _cache_path()
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        with open(path, "w") as f:
             json.dump(_memory_cache, f, indent=1, sort_keys=True)
     except Exception:  # dslint: disable=DSE502 -- read-only FS etc.; in-memory cache still works
         pass
@@ -155,7 +161,7 @@ def tune(s, kv_len, d, causal, dropout, flash_fn, heuristic, bh=8):
         "flash-attention autotune: first use of shape s=%d kv=%d d=%d "
         "causal=%s — compiling and timing %d block geometries (one-time; "
         "cached at %s; DS_FLASH_AUTOTUNE=0 disables)",
-        s, kv_len, d, causal, len(cands), _CACHE_PATH)
+        s, kv_len, d, causal, len(cands), _cache_path())
 
     kq = jax.random.PRNGKey(0)
     q = jax.random.normal(kq, (1, s, bh, d), jnp.bfloat16)
@@ -196,9 +202,8 @@ def tune(s, kv_len, d, causal, dropout, flash_fn, heuristic, bh=8):
     if not runners:
         return heuristic
 
-    # INTERLEAVED repeats with min-aggregation (PERF.md methodology:
-    # single shots swing ±50% on remote attachments, and back-to-back
-    # repeats let one load spike mis-rank a whole candidate)
+    # INTERLEAVED repeats with min-aggregation (back-to-back repeats
+    # let one load spike mis-rank a whole candidate)
     results = {c: [] for c in runners}
     for _ in range(3):
         for c, run in runners.items():

@@ -30,10 +30,10 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ...parallel.mesh import SEQ_AXIS
-from ...utils.compat import shard_map
 
 NEG = -1e9
 
@@ -112,12 +112,8 @@ def ring_attention(q, k, v, mesh=None, axis_name=SEQ_AXIS, causal=False,
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
 
-    from ...utils.compat import PARTIAL_MANUAL_SHARD_MAP
-
-    if nshards == 1 or not PARTIAL_MANUAL_SHARD_MAP:
-        # single seq shard — or old jax, where the partial-manual ring
-        # program cannot compile (see utils/compat.py): same math, dense,
-        # GSPMD-sharded instead of ring-scheduled
+    if nshards == 1:
+        # single seq shard: same math, dense
         from .attention import reference_attention
 
         mask4 = (key_padding_mask[:, None, None, :]

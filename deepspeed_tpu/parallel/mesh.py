@@ -89,27 +89,34 @@ def mesh_axis_sizes(mesh, keep_trivial=False):
     return {ax: n for ax, n in sizes.items() if n > 1}
 
 
-def available_devices(n_devices: Optional[int] = None, platform: Optional[str] = None):
-    """Pick ``n_devices`` devices, preferring the default backend but falling
-    back to the host-platform (virtual CPU) devices when the default backend
-    is too small — this is what lets multi-chip sharding run under
-    ``--xla_force_host_platform_device_count`` on a single-chip/CPU box."""
+def current_platform():
+    """Platform the traced program is built for: the engine-registered
+    mesh's devices when there is one (so a mesh of described TPU devices
+    compiles the TPU path from a CPU-only host, and a CPU test mesh takes
+    the CPU path on a TPU host), else the default backend."""
     import jax
 
-    if platform is not None:
-        devs = jax.devices(platform)
-    else:
-        devs = jax.devices()
-        if n_devices is not None and len(devs) < n_devices:
-            try:
-                cpu = jax.devices("cpu")
-                if len(cpu) >= n_devices:
-                    devs = cpu
-            except RuntimeError:
-                pass
+    if _CURRENT_MESH is not None:
+        return _CURRENT_MESH.devices.flat[0].platform
+    return jax.default_backend()
+
+
+def available_devices(n_devices: Optional[int] = None, platform: Optional[str] = None):
+    """The first ``n_devices`` devices of ``platform`` (default: the
+    default backend).  Too few is an error — never a quiet switch to
+    another platform's devices: a four-chip mesh asked for on a one-chip
+    host must not "pass" on virtual CPU devices.  Multi-chip runs on a CPU
+    box set ``JAX_PLATFORMS=cpu`` and
+    ``--xla_force_host_platform_device_count`` so that the default
+    backend itself has the devices."""
+    import jax
+
+    devs = jax.devices(platform)
     if n_devices is not None:
         if len(devs) < n_devices:
-            raise ValueError(f"need {n_devices} devices, only {len(devs)} available")
+            raise ValueError(
+                f"need {n_devices} {devs[0].platform} devices, only "
+                f"{len(devs)} available")
         devs = devs[:n_devices]
     return devs
 

@@ -12,8 +12,8 @@ from .overlap import analyze_hlo, parse_hlo_transfers, transfer_summary
 from .sharding import analyze_sharding, entry_parameters
 from .step_profiler import (model_scope_breakdown, timed_loop, timed_scan,
                             wall_breakdown)
-from .utilization import (DEFAULT_PEAK_TFLOPS, PEAK_TFLOPS, chip_peak_tflops,
-                          chip_specs, model_flops_utilization)
+from .utilization import (PEAK_TFLOPS, chip_peak_tflops, chip_specs,
+                          model_flops_utilization)
 
 __all__ = ["CommLedger", "collective_summary", "parse_hlo_collectives",
            "predicted_wire_bytes", "publish_rank_latency",
@@ -23,7 +23,7 @@ __all__ = ["CommLedger", "collective_summary", "parse_hlo_collectives",
            "wall_breakdown", "model_scope_breakdown", "timed_loop",
            "timed_scan", "MemoryLedger", "HostBufferRegistry",
            "device_memory_summary", "see_memory_usage", "PEAK_TFLOPS",
-           "DEFAULT_PEAK_TFLOPS", "chip_peak_tflops", "chip_specs",
+           "chip_peak_tflops", "chip_specs",
            "model_flops_utilization", "analyze_hlo",
            "parse_hlo_transfers", "transfer_summary",
            "analyze_sharding", "entry_parameters",

@@ -205,7 +205,8 @@ class FlopsProfile:
     def mfu(self, device=None):
         """Model-FLOPs utilisation against the chip's bf16 peak — the
         SAME peak table bench.py quotes (``profiling/utilization.py``),
-        so profiler and bench utilisation cannot drift."""
+        so profiler and bench utilisation cannot drift.  None without a
+        wall time or on a device that is not a TPU (it has no peak)."""
         if not self.wall_ms:
             return None
         from ..utilization import chip_peak_tflops
@@ -214,7 +215,8 @@ class FlopsProfile:
             import jax
 
             device = jax.devices()[0]
-        return self.achieved_tflops() / chip_peak_tflops(device)
+        peak = chip_peak_tflops(device)
+        return None if peak is None else self.achieved_tflops() / peak
 
     def print(self, top_modules=3, log=None):
         log = log or logger.info

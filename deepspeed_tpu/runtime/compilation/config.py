@@ -17,14 +17,9 @@ class DeepSpeedCompilationConfig:
         comp = param_dict.get(C.COMPILATION, {}) or {}
         self.cache = get_scalar_param(
             comp, C.COMPILATION_CACHE, C.COMPILATION_CACHE_DEFAULT)
-        # identity checks on purpose: 0/1 would pass an `in (True, False)`
-        # equality test but then match NEITHER the `is False` disable nor
-        # the `== "auto"` defer downstream — an explicit 0 (disable)
-        # would silently force-enable
-        if not (self.cache is True or self.cache is False
-                or self.cache == "auto"):
+        if not isinstance(self.cache, bool):
             raise ValueError(
-                f'compilation.cache must be true, false, or "auto", '
+                f"compilation.cache must be true or false, "
                 f"got {self.cache!r}")
         cache_dir = get_scalar_param(
             comp, C.COMPILATION_CACHE_DIR, C.COMPILATION_CACHE_DIR_DEFAULT)

@@ -476,17 +476,14 @@ PROFILING_PROGRAM_DUMP_DEFAULT = "auto"
 # scale, so warm-starting them is a first-class subsystem.)
 #############################################
 COMPILATION = "compilation"
-# persistent XLA compile cache: "auto" enables it unless the process
-# already configured one (e.g. a test harness or an explicit
-# JAX_COMPILATION_CACHE_DIR env), true forces this config's cache over
-# any ambient one, false leaves compilation uncached
+# persistent XLA compile cache (runtime/compilation/cache.py has the one
+# rule for where it lives); false leaves jax's cache settings untouched
 COMPILATION_CACHE = "cache"
-COMPILATION_CACHE_DEFAULT = "auto"
-# where compiled executables persist; empty -> <telemetry run dir>/
-# xla_cache, so warm-start artifacts ride the run directory like every
-# other run artifact.  Fresh processes (bench reruns, --max-restarts
-# respawns, auto-resume restarts) pointing at the same dir skip
-# recompilation entirely.
+COMPILATION_CACHE_DEFAULT = True
+# where compiled executables persist when JAX_COMPILATION_CACHE_DIR is
+# not set; empty -> <checkout>/.jax_cache.  The path is part of every
+# cache key, so fresh processes (bench reruns, --max-restarts respawns,
+# auto-resume restarts) hit only when they resolve the same directory.
 COMPILATION_CACHE_DIR = "cache_dir"
 COMPILATION_CACHE_DIR_DEFAULT = ""
 # skip caching executables smaller than this (bytes): tiny programs

@@ -38,11 +38,11 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ...comm.compression import compressed_allreduce
 from ...parallel.mesh import DATA_AXIS
-from ...utils.compat import shard_map
 
 
 class OnebitAdamState(NamedTuple):

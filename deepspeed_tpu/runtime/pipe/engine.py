@@ -45,6 +45,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ...parallel.mesh import DATA_AXIS, PIPE_AXIS
@@ -52,7 +53,6 @@ from ...utils.logging import log_dist
 from ..engine import DeepSpeedEngine
 from .module import PipelineModule, split_batch
 from .schedule import InferenceSchedule, TrainSchedule
-from ...utils.compat import shard_map
 
 
 class _PipelinedModel:
@@ -249,9 +249,8 @@ class _PipelinedModel:
                     lambda a: jax.lax.ppermute(a, PIPE_AXIS, perm), y)
                 return (x_next, loss_sum + jnp.reshape(loss, (1,))), None
 
-            # loss accumulator kept 1-D: scalar residuals crossing the
-            # shard_map boundary trip a jax-0.4.x transpose bug (mis-named
-            # scalar residual -> _SpecError); see utils/compat.py
+            # the loss accumulator is a 1-element vector; [0] is taken
+            # after the psum below
             (x_state, loss_sum), _ = jax.lax.scan(
                 tick, (zeros_boundary(), jnp.zeros((1,), jnp.float32)),
                 jnp.arange(ticks))

@@ -126,11 +126,10 @@ FIELDS = {
                                "materialized per-device parameter "
                                "bytes (entry-layout ÷shard receipt)"),
     # ZeRO-2 bucketed-collective A/B row (round 14, bench.py
-    # _measure_zero2_overlap via the fresh-subprocess harness):
-    # overlap_comm on (the headline) vs off (the serialized control) on
-    # a dp mesh, with both schedules' static exposed-wire receipts —
-    # dryrun-marked on non-TPU backends (toy geometry on a virtual CPU
-    # mesh proves the plumbing; the bench attachment proves the ms)
+    # _measure_zero2_overlap): overlap_comm on (the headline) vs off
+    # (the serialized control) on a data mesh of every local chip, with
+    # both schedules' static exposed-wire receipts; left out with fewer
+    # than two chips
     "zero2_overlap_ms_per_step": (numbers.Real, "ms, overlap_comm on"),
     "zero2_serial_ms_per_step": (numbers.Real,
                                  "ms, serialized control (info)"),

@@ -18,11 +18,9 @@ from .logging import log_dist, logger
 def device_fence():
     """Block until previously dispatched device computations complete.
 
-    A host round-trip (``device_get`` of a freshly dispatched computation)
-    rather than ``block_until_ready``: on remote-attached platforms the
-    latter has been observed to return before remote execution finishes,
-    while a fetched result cannot exist until everything queued before it
-    (per-device dispatch is in order) has run.
+    A host round-trip (``device_get`` of a freshly dispatched
+    computation): a fetched result cannot exist until everything queued
+    before it (per-device dispatch is in order) has run.
     """
     try:
         import jax
@@ -140,6 +138,11 @@ class ThroughputTimer:
         self.micro_step_count = 0
 
     def _init_timer(self):
+        if not self.initialized:
+            # the fence is a tiny program of its own: compile it with the
+            # first step's programs, not when the first measured window
+            # opens ``start_step`` steps in (a compile in steady state)
+            device_fence()
         self.initialized = True
 
     def start(self):

@@ -11,27 +11,27 @@ Each trial runs in a FRESH SUBPROCESS: compiled executables and buffers
 from a previous trial linger in-process (observed: a config that OOMs
 after prior same-process trials trains fine alone), so isolation is the
 only way to get truthful capacity numbers.  All trials share one
-persistent XLA compile cache (exported via JAX_COMPILATION_CACHE_DIR),
-so a re-run — or a retry of a flaked trial — warm-starts its programs;
+persistent XLA compile cache (each resolves the same directory by the
+rule in ``runtime/compilation/cache.py``), so a re-run — or a retry of a flaked trial — warm-starts its programs;
 each trial prints its cold/warm compile-wall split.
 
 Rows past gpt2-xl ride the round-6 O(1)-compile configuration: the
 uniform-chunk scan update ("offload_uniform_chunks": auto engages past
 24 chunks) keeps program size constant in chunk count — the round-5
-blocker at 2.7B was >30 min of REMOTE-COMPILE wall for the unrolled
-chunk programs, not memory.
+blocker at 2.7B was >30 min of compile wall for the unrolled chunk
+programs, not memory.
 
 Round 12 adds an **overlap mode** (``overlap`` argument): A/B the
 double-buffered chunk pipeline (``offload_overlap`` on vs off) on the
 gpt2-large offload row and emit ONE ``bench_schema``-validated JSON
 record as the last line — ``offload_gpt2_large_ms_per_step`` (the
 serialized control), ``offload_gpt2_large_overlap_ms_per_step`` (the
-headline; target ≤ ~0.5 s/step on the bench attachment), plus both
+headline; target ≤ ~0.5 s/step on the chip, not measured), plus both
 schedules' static exposed-wire receipts so the bench JSON alone shows
 the exposure drop.  On a non-TPU backend the same harness path runs
 end-to-end at toy geometry under ``DS_OFFLOAD_FORCE_INJIT`` and the
-record carries ``note: "dryrun"`` — a CPU box proves the plumbing, the
-bench attachment proves the milliseconds.
+record carries ``note: "dryrun"`` — a CPU box proves the plumbing, only
+the chip gives the milliseconds.
 
 Usage: python examples/bench_offload_capacity.py [quick|overlap [quick]]
 """
@@ -152,10 +152,6 @@ def try_step(offload, hidden, layers, heads, offload_grads=False,
     # no T_GMB default: the coordinator's buffer-count cap derives the
     # round-5 3584 layout (and beyond) automatically; export T_GMB to
     # force a manual group size, T_SDT=bf16 for reduced host state
-    # one shared warm cache across every fresh-subprocess trial
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        ".jax_cache"))
     try:
         proc = subprocess.run([sys.executable, "-u", "-c", _TRIAL], env=env,
                               capture_output=True, text=True,
@@ -224,8 +220,7 @@ def overlap_mode():
         record["offload_gpt2_large_overlap_note"] = (
             "dryrun: non-TPU backend, toy geometry (hidden "
             f"{h}, {L} layers) under DS_OFFLOAD_FORCE_INJIT — harness "
-            "receipt only; the ms/step target needs the bench "
-            "attachment")
+            "receipt only; the ms/step target needs the chip")
     rows = {}
     for tag, ov in (("off", "off"), ("on", "on")):
         ok, info, compile_line, overlap = try_step(

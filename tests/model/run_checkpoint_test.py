@@ -13,7 +13,9 @@ driver is that gate for the TPU framework, per config:
 - ``pipeline``   PipelineModule over a pipe=2 x data=2 mesh
 - ``elastic_dp`` ZeRO-2 saved at dp=4, RESUMED at dp=2 (elastic restore)
 
-Flow per config (all three runs in FRESH subprocesses):
+Flow per config (all three runs in FRESH subprocesses of this jax-free
+driver; pytest's on-chip leg, whose process already holds the chip, runs
+them in that process instead, each on a new engine):
 
 1. uninterrupted run: ``steps`` steps, loss logged every step;
 2. first half: ``steps//2`` steps, ``save_checkpoint``;
@@ -184,7 +186,13 @@ def _child(args):
 
 
 # ----------------------------------------------------------- orchestrate
-def _run_child(config, steps, dp, log, save=None, load=None, force_cpu=True):
+def _run_child(config, steps, dp, log, save=None, load=None, force_cpu=True,
+               in_process=False):
+    if in_process:
+        # the caller's process holds the chip (pytest's ``-m tpu`` tier): a
+        # child could not open it, so the phase runs here on a new engine
+        return _child(argparse.Namespace(config=config, steps=steps, dp=dp,
+                                         log=log, save=save, load=load))
     env = dict(os.environ)
     if force_cpu:
         env["DS_CKPT_FORCE_CPU"] = "1"
@@ -217,7 +225,8 @@ def _grep(path):
     return out
 
 
-def run_config(name, steps, out_dir, force_cpu=True, rtol=1e-4):
+def run_config(name, steps, out_dir, force_cpu=True, rtol=1e-4,
+               in_process=False):
     dp = MULTI_DEVICE[name]
     resume_dp = 2 if name == "elastic_dp" else dp
     half = steps // 2
@@ -226,10 +235,12 @@ def run_config(name, steps, out_dir, force_cpu=True, rtol=1e-4):
     resume_log = os.path.join(out_dir, f"{name}_resume.log")
     ckpt = os.path.join(out_dir, f"{name}_ckpt")
 
-    _run_child(name, steps, dp, full_log, force_cpu=force_cpu)
-    _run_child(name, half, dp, first_log, save=ckpt, force_cpu=force_cpu)
+    _run_child(name, steps, dp, full_log, force_cpu=force_cpu,
+               in_process=in_process)
+    _run_child(name, half, dp, first_log, save=ckpt, force_cpu=force_cpu,
+               in_process=in_process)
     _run_child(name, steps - half, resume_dp, resume_log, load=ckpt,
-               force_cpu=force_cpu)
+               force_cpu=force_cpu, in_process=in_process)
 
     full = _grep(full_log)
     first = _grep(first_log)

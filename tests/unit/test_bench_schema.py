@@ -2,24 +2,34 @@
 
 The standing ROADMAP rule — every README/PERF headline quotes a driver
 artifact — needs the artifact's fields to be stable; this suite pins
-the registry against the real round-5 artifact and the round-6 fields
+the registry against the round-5 record and the round-6 fields
 (reduced-precision ``host_state_dtype`` / ``host_state_bytes_per_step``).
 """
 
-import json
-import os
-
 from deepspeed_tpu.tools.bench_schema import field_type, validate_record
 
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
+# the round-5 bench record, as bench.py printed it on an earlier
+# attachment (the BENCH_r05.json artifact itself is no longer in the tree)
+ROUND5_RECORD = {
+    "metric": "bert_large_seq128_samples_per_sec_per_chip",
+    "value": 477.34, "unit": "samples/s", "vs_baseline": 1.755,
+    "model_tflops_per_sec": 111.6, "mfu": 0.5664, "chip_peak_tflops": 197.0,
+    "loss": 8.5964, "batch": 112, "dropout": 0.1, "device": "TPU v5 lite",
+    "seq512_batch": 32, "seq512_samples_per_sec": 104.13,
+    "seq512_vs_baseline": 2.002, "seq512_mfu": 0.5237,
+    "gpt2_medium_seq1024_samples_per_sec": 38.56,
+    "gpt2_medium_tokens_per_sec": 39487.0, "gpt2_mfu": 0.4554,
+    "gpt2_batch": 8, "sparse_attn_seq": 16384,
+    "sparse_attn_dense_ms": 74.78, "sparse_attn_sparse_ms": 28.24,
+    "sparse_attn_speedup_vs_dense": 2.65,
+    "offload_gpt2_large_ms_per_step": 1292.0,
+    "offload_gpt2_large_params_b": 0.77,
+    "offload_xl_note": "opt-in",
+}
 
 
-def test_round5_artifact_validates():
-    path = os.path.join(REPO, "BENCH_r05.json")
-    with open(path) as f:
-        record = json.load(f)["parsed"]
-    assert validate_record(record) == []
+def test_round5_record_validates():
+    assert validate_record(ROUND5_RECORD) == []
 
 
 def test_round6_reduced_precision_fields():

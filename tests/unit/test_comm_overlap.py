@@ -372,7 +372,7 @@ def test_compressed_allreduce_internal_padding_vs_reference(cpu_devices):
     from deepspeed_tpu.comm.compression import (
         compressed_allreduce, compressed_allreduce_reference,
         padded_size)
-    from deepspeed_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     world, n = 4, 100  # 100 % (8*4) != 0
     n_pad = padded_size(n, world)
@@ -414,7 +414,7 @@ def test_compressed_allreduce_rejects_wrong_error_sizes(cpu_devices):
     from jax.sharding import PartitionSpec as P
 
     from deepspeed_tpu.comm.compression import compressed_allreduce
-    from deepspeed_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     mesh = make_mesh({"data": 4}, devices=cpu_devices[:4])
 

@@ -1,10 +1,10 @@
 """Compilation subsystem: persistent XLA cache + compile telemetry.
 
-The capacity-scale receipts (a ~35-min gpt2-xl compile becoming a warm
-load) are TPU-bound, but every mechanism is backend-agnostic and
-CI-checked here: config parsing/validation, the enable policy
-("auto" defers to an ambient cache; true overrides; false disables),
-the TWO-FRESH-SUBPROCESS warm-start roundtrip, and the
+Every mechanism is backend-agnostic and CI-checked here: config
+parsing/validation, the ONE rule for where the cache lives
+(``JAX_COMPILATION_CACHE_DIR`` where set, and then nothing is set in
+code; else ``<checkout>/.jax_cache``; an explicit ``cache_dir`` the one
+override), the TWO-FRESH-SUBPROCESS warm-start roundtrip, and the
 jax.monitoring -> TelemetryManager bridge.
 """
 
@@ -47,7 +47,7 @@ def cache_knobs():
 # ---------------------------------------------------------------- config
 def test_config_defaults_and_validation():
     cfg = DeepSpeedCompilationConfig({})
-    assert cfg.cache == "auto" and cfg.cache_dir == ""
+    assert cfg.cache is True and cfg.cache_dir == ""
     assert cfg.min_entry_size_bytes == 0 and cfg.min_compile_secs == 0.0
     cfg = DeepSpeedCompilationConfig(
         {"compilation": {"cache": True, "cache_dir": "/x",
@@ -55,15 +55,11 @@ def test_config_defaults_and_validation():
                          "min_compile_secs": 1.5}})
     assert cfg.cache is True and cfg.cache_dir == "/x"
     assert cfg.min_entry_size_bytes == 4096 and cfg.min_compile_secs == 1.5
-    with pytest.raises(ValueError):
-        DeepSpeedCompilationConfig({"compilation": {"cache": "yes"}})
-    # 0/1 are rejected, not bool-coerced: 0 == False passes an equality
-    # check yet matches neither `is False` nor `== "auto"` downstream —
-    # an explicit disable would silently force-ENABLE (reviewed defect)
-    with pytest.raises(ValueError):
-        DeepSpeedCompilationConfig({"compilation": {"cache": 0}})
-    with pytest.raises(ValueError):
-        DeepSpeedCompilationConfig({"compilation": {"cache": 1}})
+    # true or false, nothing else: 0/1 and strings are rejected, not
+    # coerced (an explicit disable must never read as enabled)
+    for bad in ("yes", "auto", 0, 1):
+        with pytest.raises(ValueError):
+            DeepSpeedCompilationConfig({"compilation": {"cache": bad}})
     with pytest.raises(ValueError):
         DeepSpeedCompilationConfig(
             {"compilation": {"min_entry_size_bytes": -1}})
@@ -82,58 +78,126 @@ def test_compilation_block_in_dsc4xx_schema():
     assert issues[0].section == "compilation"
     assert issues[0].suggestion == "cache_dir"
     assert not validate_config_dict(
-        {"compilation": {"cache": "auto", "cache_dir": "/x",
+        {"compilation": {"cache": True, "cache_dir": "/x",
                          "min_entry_size_bytes": 0,
                          "min_compile_secs": 0.5}})
 
 
 # ---------------------------------------------------------------- policy
-def test_configure_auto_defers_to_ambient(cache_knobs, tmp_path):
-    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "ambient"))
-    cfg = DeepSpeedCompilationConfig({})  # auto
-    got = configure_persistent_cache(cfg, run_dir=str(tmp_path / "run"))
-    assert got == str(tmp_path / "ambient")
-    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "ambient")
-    assert not (tmp_path / "run").exists()
+@pytest.fixture
+def cache_dir_updates(monkeypatch):
+    """Every ``jax.config.update("jax_compilation_cache_dir", ...)`` made
+    while the fixture is live (the call still goes through)."""
+    calls = []
+    real = jax.config.update
+
+    def spy(name, value):
+        if name == "jax_compilation_cache_dir":
+            calls.append(value)
+        return real(name, value)
+
+    monkeypatch.setattr(jax.config, "update", spy)
+    return calls
 
 
-def test_configure_disabled_touches_nothing(cache_knobs, tmp_path):
+def _train_engine(config=None):
+    import deepspeed_tpu as deepspeed
+    from deepspeed_tpu.parallel import make_mesh
+
+    from .simple_model import SimpleModel, base_config
+
+    engine, *_ = deepspeed.initialize(
+        model=SimpleModel(hidden_dim=16),
+        config=dict(base_config(train_batch_size=8), **(config or {})),
+        mesh=make_mesh({"data": 1}, devices=jax.devices("cpu")[:1]))
+    return engine
+
+
+def _serve_engine(config=None):
+    from deepspeed_tpu.inference import InferenceEngine
+    from deepspeed_tpu.models import GPT2Config, GPT2LMHeadTPU
+
+    model = GPT2LMHeadTPU(GPT2Config(
+        vocab_size=64, hidden_size=32, num_layers=1, num_heads=2,
+        max_position_embeddings=32, embd_dropout=0.0, attn_dropout=0.0,
+        resid_dropout=0.0))
+    return InferenceEngine(
+        model, model.init(jax.random.PRNGKey(0)),
+        config=dict({"inference": {"kv_block_size": 8, "kv_blocks": 8,
+                                   "max_batch_slots": 2, "max_seq_len": 32,
+                                   "prefill_buckets": [16]}},
+                    **(config or {})))
+
+
+@pytest.mark.parametrize("make_engine", [_train_engine, _serve_engine])
+def test_env_var_set_means_no_directory_set_in_code(
+        cache_knobs, cache_dir_updates, monkeypatch, tmp_path, make_engine):
+    """With JAX_COMPILATION_CACHE_DIR set jax uses it natively: neither
+    engine sets a directory in code, not even for an explicit
+    ``cache_dir`` in its config."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    for config in ({}, {"compilation": {"cache_dir": str(tmp_path / "x")}}):
+        engine = make_engine(config)
+        assert engine._compile_cache_dir == str(tmp_path / "env")
+    assert cache_dir_updates == []
+    assert not (tmp_path / "x").exists()
+
+
+@pytest.mark.parametrize("make_engine", [_train_engine, _serve_engine])
+def test_env_var_unset_means_checkout_jax_cache_from_any_cwd(
+        cache_knobs, monkeypatch, tmp_path, make_engine):
+    """Unset, both engines resolve ``<checkout>/.jax_cache`` — a fixed
+    path next to the package, whatever the working directory (the path is
+    part of every cache key, so one that moves never hits)."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    jax.config.update("jax_compilation_cache_dir", None)
+    monkeypatch.chdir(tmp_path)
+    engine = make_engine()
+    want = os.path.join(REPO, ".jax_cache")
+    assert engine._compile_cache_dir == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert os.listdir(tmp_path) == []  # nothing under the cwd
+
+
+def test_configure_disabled_touches_nothing(cache_knobs, cache_dir_updates,
+                                            monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     cfg = DeepSpeedCompilationConfig({"compilation": {"cache": False}})
-    assert configure_persistent_cache(cfg, run_dir=str(tmp_path)) is None
-    assert not (tmp_path / "xla_cache").exists()
+    assert configure_persistent_cache(cfg) is None
+    assert cache_dir_updates == []
 
 
-def test_configure_forced_overrides_and_exports(cache_knobs, tmp_path):
-    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "ambient"))
+def test_explicit_cache_dir_is_the_one_override(cache_knobs, monkeypatch,
+                                                tmp_path):
+    """With the variable unset an explicit ``compilation.cache_dir`` wins
+    over the default, with the block's thresholds — and nothing is
+    exported: a child resolves its directory by the same rule."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     cfg = DeepSpeedCompilationConfig(
-        {"compilation": {"cache": True, "min_compile_secs": 0.25}})
-    got = configure_persistent_cache(cfg, run_dir=str(tmp_path / "run"))
-    assert got == str(tmp_path / "run" / "xla_cache")
-    assert os.path.isdir(got)
+        {"compilation": {"cache_dir": str(tmp_path / "mine"),
+                         "min_compile_secs": 0.25}})
+    got = configure_persistent_cache(cfg)
+    assert got == str(tmp_path / "mine") and os.path.isdir(got)
     assert jax.config.jax_compilation_cache_dir == got
     assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.25
-    # subprocess inheritance: fresh-process trials read the env var
-    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == got
+    assert "JAX_COMPILATION_CACHE_DIR" not in os.environ
 
 
-def test_configure_auto_with_explicit_dir_wins(cache_knobs, tmp_path):
-    """An explicitly configured cache_dir is intent: under the default
-    "auto" it must override an ambient cache (including the env var a
-    prior engine in this process exported), not be silently ignored."""
-    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "ambient"))
-    cfg = DeepSpeedCompilationConfig(
-        {"compilation": {"cache_dir": str(tmp_path / "mine")}})
-    got = configure_persistent_cache(cfg)
-    assert got == str(tmp_path / "mine")
-    assert jax.config.jax_compilation_cache_dir == got
+def test_configure_leaves_an_already_resolved_cache_alone(
+        cache_knobs, cache_dir_updates, monkeypatch):
+    """The harness (or an earlier engine) already pointed jax at the
+    directory the rule resolves: a later engine changes nothing, so the
+    harness keeps its own thresholds."""
+    from deepspeed_tpu.runtime.compilation.cache import default_cache_dir
 
-
-def test_configure_auto_enables_when_nothing_ambient(cache_knobs, tmp_path):
-    jax.config.update("jax_compilation_cache_dir", None)
-    os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
-    cfg = DeepSpeedCompilationConfig({})
-    got = configure_persistent_cache(cfg, run_dir=str(tmp_path))
-    assert got == str(tmp_path / "xla_cache") and os.path.isdir(got)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    jax.config.update("jax_compilation_cache_dir", default_cache_dir())
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    del cache_dir_updates[:]
+    got = configure_persistent_cache(DeepSpeedCompilationConfig({}))
+    assert got == default_cache_dir()
+    assert cache_dir_updates == []
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.5
 
 
 # ------------------------------------------------- fresh-process roundtrip
@@ -168,8 +232,7 @@ engine, *_ = deepspeed.initialize(
     model=Stack(), mesh=mesh,
     config={"train_batch_size": 8, "steps_per_print": 10 ** 9,
             "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
-            "compilation": {"cache": True, "cache_dir": sys.argv[1],
-                            "min_compile_secs": 0.0}})
+            "compilation": {"cache_dir": sys.argv[1]}})
 rng = np.random.default_rng(0)
 b = (rng.normal(size=(8, 64)).astype(np.float32),
      rng.normal(size=(8, 64)).astype(np.float32))

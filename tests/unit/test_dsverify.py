@@ -21,7 +21,7 @@ from deepspeed_tpu.tools.dslint import failing
 from deepspeed_tpu.tools.dslint import programs as dsp
 from deepspeed_tpu.tools.dslint.cli import main as dslint_main
 from deepspeed_tpu.tools.dslint.core import ParsedFile
-from deepspeed_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .simple_model import SimpleModel, base_config, random_batches

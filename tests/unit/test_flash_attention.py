@@ -191,36 +191,68 @@ def test_flash_dropout_matches_explicit_mask_reference():
     assert not jnp.array_equal(out_f, other)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_interpret_without_pallas_tpu_package(monkeypatch, causal):
-    """CPU-only jax builds (no ``jax.experimental.pallas.tpu``) must still
-    serve interpret-mode flash attention — fwd and grads — via the
-    scratch-free jnp path, and compiled calls must raise the real reason."""
-    from deepspeed_tpu.ops.transformer import flash_attention as fa
 
-    monkeypatch.setattr(fa, "pltpu", None)
-    monkeypatch.setattr(fa, "_VMEM", None)
-    b, s = 2, 256
-    q, k, v = rand_qkv(b, s, 2, 64, seed=11)
-    kvm, additive = padding_masks(b, s, [256, 100])
-    out = fa.flash_attention(q, k, v, kv_mask=kvm, causal=causal,
-                             interpret=True)
-    out_ref = reference_attention(q, k, v, mask=additive, causal=causal)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),
-                               atol=2e-5, rtol=2e-5)
 
-    def loss(fn):
-        return lambda q, k, v: jnp.sum(
-            fn(q, k, v, kv_mask=kvm, causal=causal) ** 2)
+@pytest.mark.parametrize("dims", [{"data": 4}, {"data": 2, "model": 2}],
+                         ids=["data4", "data2-model2"])
+def test_kernel_call_is_sharded_over_the_mesh(dims):
+    """XLA cannot partition a Mosaic kernel call, so the dispatch wraps it
+    in a shard_map over every mesh axis not manual yet: each device runs
+    the kernel on its own batch (and head) shard, forward and backward —
+    at the top level of a GSPMD step and nested inside a shard_map that is
+    manual over ``data`` alone, as the engine's bucketed exchange is."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
 
-    g = jax.grad(loss(lambda *a, **kw: fa.flash_attention(
-        *a, interpret=True, **kw)), argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(loss(lambda *a, **kw: reference_attention(
-        a[0], a[1], a[2], mask=additive, causal=causal)), argnums=(0, 1, 2))(
-            q, k, v)
-    for gf, gr, name in zip(g, g_ref, "qkv"):
-        np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
-                                   atol=5e-4, rtol=5e-4,
-                                   err_msg=f"d{name} mismatch")
-    with pytest.raises(RuntimeError, match="pallas.tpu"):
-        fa.flash_attention(q, k, v, interpret=False)
+    from deepspeed_tpu.ops.transformer.attention import shard_kernel_over_mesh
+    from deepspeed_tpu.parallel import make_mesh
+    from deepspeed_tpu.parallel.mesh import (get_current_mesh,
+                                             set_current_mesh)
+
+    b, s, h = 8, 128, 4
+    q, k, v = rand_qkv(b, s, h, 64, seed=21)
+    kvm, additive = padding_masks(b, s, [128, 100] * (b // 2))
+    seen = []
+
+    def kernel(q, k, v, mask, seed):
+        seen.append((q.shape, mask.shape))
+        return flash_attention(q, k, v, kv_mask=mask, causal=True,
+                               interpret=True)
+
+    def loss(q, k, v, mask):
+        return jnp.sum(shard_kernel_over_mesh(kernel, q, k, v,
+                                              kv_mask=mask) ** 2)
+
+    want, g_want = jax.value_and_grad(
+        lambda q, k, v: jnp.sum(reference_attention(
+            q, k, v, mask=additive, causal=True) ** 2),
+        argnums=(0, 1, 2))(q, k, v)
+    mesh, before = make_mesh(dims), get_current_mesh()
+    set_current_mesh(mesh)
+    try:
+        top = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
+            q, k, v, kvm)
+
+        def nested(q, k, v, mask):
+            def body(q, k, v, mask):
+                val, g = jax.value_and_grad(loss, argnums=(0, 1, 2))(
+                    q, k, v, mask)
+                return jax.lax.psum(val, "data"), g
+
+            rows = P("data")
+            return shard_map(body, mesh=mesh, in_specs=(rows,) * 4,
+                             out_specs=(P(), (rows,) * 3),
+                             axis_names={"data"}, check_vma=False)(
+                                 q, k, v, mask)
+
+        inner = jax.jit(nested)(q, k, v, kvm)
+    finally:
+        set_current_mesh(before)
+    shard = (b // dims["data"], s, h // dims.get("model", 1), 64)
+    assert set(seen) == {(shard, (shard[0], s))}
+    for got, g_got in (top, inner):
+        np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+        for a, r, name in zip(g_got, g_want, "qkv"):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                       atol=5e-4, rtol=5e-4,
+                                       err_msg=f"d{name} mismatch")

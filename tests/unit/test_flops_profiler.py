@@ -5,6 +5,7 @@ handling, per-scope attribution, engine integration (reference
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 import deepspeed_tpu as deepspeed
 from deepspeed_tpu.parallel import make_mesh
@@ -169,11 +170,25 @@ def test_backend_cost_analysis_returns_dict():
 
 def test_flops_profile_wall_and_mfu():
     from deepspeed_tpu.profiling.flops_profiler.profiler import FlopsProfile
-    from deepspeed_tpu.profiling.utilization import chip_peak_tflops
+    from deepspeed_tpu.profiling.utilization import (chip_peak_tflops,
+                                                     chip_specs)
 
     prof = FlopsProfile(flops=2 * 10 ** 12, macs=10 ** 12, params=1000,
                         wall_ms=100.0)
     assert prof.achieved_tflops() == 20.0
-    dev = jax.devices()[0]
-    assert prof.mfu(dev) == 20.0 / chip_peak_tflops(dev)
+
+    class Dev:
+        platform, device_kind = "tpu", "TPU v5 lite"
+
+    assert chip_peak_tflops(Dev) == 197.0
+    assert prof.mfu(Dev) == 20.0 / 197.0
+    # a device that is not a TPU has no peak, so no MFU...
+    assert prof.mfu(jax.devices()[0]) is None
+    # ...and a TPU the table does not know is an error, never a default
+    Dev.device_kind = "TPU v99"
+    with pytest.raises(ValueError, match="TPU v99"):
+        prof.mfu(Dev)
+    with pytest.raises(ValueError, match="TPU v99"):
+        chip_specs("TPU v99")
+    assert chip_specs("cpu")["peak_tflops"] > 0  # static analysers only
     assert FlopsProfile(1, 0, 1).achieved_tflops() is None

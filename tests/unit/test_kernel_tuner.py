@@ -34,7 +34,8 @@ def test_candidates_respect_constraints():
 def test_tune_returns_heuristic_off_tpu(monkeypatch, tmp_path):
     """On non-TPU backends (this CI tier) tune() must fall back to the
     heuristic without touching the kernel."""
-    monkeypatch.setattr(kt, "_CACHE_PATH", str(tmp_path / "cache.json"))
+    monkeypatch.setattr(kt, "_cache_path",
+                        lambda: str(tmp_path / "cache.json"))
     monkeypatch.setattr(kt, "_memory_cache", {})
     monkeypatch.setattr(kt, "_disk_loaded", False)
 
@@ -57,7 +58,7 @@ def test_cache_roundtrip(monkeypatch, tmp_path):
     cache = tmp_path / "cache.json"
     key = kt._key(1536, 1536, 64, False, 0.0, _FakeTpu.device_kind)
     assert "faketpu_v0" in key
-    monkeypatch.setattr(kt, "_CACHE_PATH", str(cache))
+    monkeypatch.setattr(kt, "_cache_path", lambda: str(cache))
     monkeypatch.setattr(kt, "_memory_cache", {key: [256, 512]})
     monkeypatch.setattr(kt, "_disk_loaded", True)
     monkeypatch.setattr(kt.jax, "devices", lambda *a: [_FakeTpu()])
@@ -96,7 +97,7 @@ def test_tuner_version_bump_invalidates_cache(monkeypatch, tmp_path):
         1536, 1536, 64, False, 0.0, _FakeTpu.device_kind)
 
     cache = tmp_path / "cache.json"
-    monkeypatch.setattr(kt, "_CACHE_PATH", str(cache))
+    monkeypatch.setattr(kt, "_cache_path", lambda: str(cache))
     monkeypatch.setattr(kt.jax, "devices", lambda *a: [_FakeTpu()])
     # a winner cached by the CURRENT tuner version...
     key = kt._key(1536, 1536, 64, False, 0.0, _FakeTpu.device_kind)
@@ -134,7 +135,8 @@ def test_tune_searches_on_chip(monkeypatch, tmp_path):
     and a second call is a cache hit (no recompiles)."""
     from deepspeed_tpu.ops.transformer.flash_attention import flash_attention
 
-    monkeypatch.setattr(kt, "_CACHE_PATH", str(tmp_path / "cache.json"))
+    monkeypatch.setattr(kt, "_cache_path",
+                        lambda: str(tmp_path / "cache.json"))
     monkeypatch.setattr(kt, "_memory_cache", {})
     monkeypatch.setattr(kt, "_disk_loaded", False)
 

@@ -394,6 +394,37 @@ def test_launch_exports_compile_cache_dir(tmp_path, monkeypatch):
     assert out.read_text() == os.path.abspath(str(tmp_path / "xla_cache"))
 
 
+def test_several_processes_on_one_tpu_host_are_refused(tmp_path, monkeypatch):
+    """A chip belongs to one process at a time and the launcher binds no
+    chips: > 1 local process on a host with TPU chips fails with the
+    supported layout in the message, before anything is spawned — unless
+    the children are kept off the TPU (the CPU test fleets)."""
+    from deepspeed_tpu.launcher import launch
+    from deepspeed_tpu.launcher.runner import encode_world_info
+
+    monkeypatch.setattr(launch, "local_tpu_chips",
+                        lambda: ["/dev/accel0", "/dev/accel1"])
+    launch.check_one_process_per_tpu_host(1, environ={})
+    launch.check_one_process_per_tpu_host(2, environ={"JAX_PLATFORMS": "cpu"})
+    for env in ({}, {"JAX_PLATFORMS": "tpu"}, {"JAX_PLATFORMS": "tpu,cpu"}):
+        with pytest.raises(RuntimeError, match="ONE process per host"):
+            launch.check_one_process_per_tpu_host(2, environ=env)
+    # and main() makes the check before it spawns
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    script = tmp_path / "child.py"
+    script.write_text("open(%r, 'w').write('spawned')\n"
+                      % str(tmp_path / "spawned"))
+    wi = encode_world_info({socket.gethostname(): [0, 1]})
+    with pytest.raises(RuntimeError, match="2 processes asked for"):
+        launch.main(["--world_info", wi, "--node_rank", "0",
+                     "--master_addr", "127.0.0.1", "--master_port", "29998",
+                     str(script)])
+    assert not (tmp_path / "spawned").exists()
+    # a host without chips launches as many as asked
+    monkeypatch.setattr(launch, "local_tpu_chips", lambda: [])
+    launch.check_one_process_per_tpu_host(8, environ={})
+
+
 def test_map_exit_code_signal_names():
     import signal
 

@@ -91,13 +91,17 @@ def test_checkpoint_resume_continuity_matrix():
 @pytest.mark.tpu
 def test_checkpoint_resume_continuity_on_chip():
     """One continuity leg on the real chip (single-device configs only:
-    the tier has one TPU)."""
+    the tier has one TPU).  In this process: it holds the chip, and a
+    child that needs the chip then fails on libtpu's lock file.  Fresh
+    processes on the chip are the standalone driver's job
+    (``python tests/model/run_checkpoint_test.py --tpu``)."""
     import tempfile
 
     from ..model import run_checkpoint_test as R
 
     with tempfile.TemporaryDirectory() as tmp:
-        R.run_config("zero2_offload", steps=8, out_dir=tmp, force_cpu=False)
+        R.run_config("zero2_offload", steps=8, out_dir=tmp, force_cpu=False,
+                     in_process=True)
 
 
 @pytest.mark.tpu

@@ -34,7 +34,7 @@ def test_compressed_allreduce_vs_host_reference(cpu_devices):
         out, nwe, nse = compressed_allreduce(b[0], we[0], se[0], "data")
         return out[None], nwe[None], nse[None]
 
-    from deepspeed_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     out, nwe, nse = jax.jit(shard_map(
         body, mesh=mesh, in_specs=(P("data"), P("data"), P("data")),

@@ -3,6 +3,7 @@ must be pip-installable with working console entry points and its native
 kernel sources shipped as package data, so the CLI tools work with the
 repo nowhere on ``sys.path``."""
 import os
+import shutil
 import subprocess
 import sys
 
@@ -14,11 +15,21 @@ REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
 @pytest.fixture(scope="module")
 def installed_tree(tmp_path_factory):
     """pip-install the repo into an isolated --target tree (builds the
-    wheel via setuptools, no network: --no-deps --no-build-isolation)."""
+    wheel via setuptools, no network: --no-deps --no-build-isolation).
+    The build runs on a COPY of the files the wheel needs: setuptools
+    builds in-tree, and a ``build/`` + ``*.egg-info`` left in the
+    checkout holds a stale copy of the package that later runs copy
+    around with the repo."""
+    src = tmp_path_factory.mktemp("src")
+    for name in ("pyproject.toml", "README.md"):
+        shutil.copy(os.path.join(REPO_ROOT, name), src / name)
+    shutil.copytree(os.path.join(REPO_ROOT, "deepspeed_tpu"),
+                    src / "deepspeed_tpu",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     target = tmp_path_factory.mktemp("site")
     proc = subprocess.run(
         [sys.executable, "-m", "pip", "install", "--quiet", "--no-deps",
-         "--no-build-isolation", "--target", str(target), REPO_ROOT],
+         "--no-build-isolation", "--target", str(target), str(src)],
         capture_output=True, text=True, timeout=300)
     assert proc.returncode == 0, proc.stderr
     return target

@@ -1,0 +1,120 @@
+"""The main path's kernels compiled for a described TPU v5e, without a chip.
+
+The TPU's compiler is installed beside jax and compiles for a topology
+that is described, not attached (``on-chip-measurement`` guide, section 2).
+Interpret-mode tests cannot see what it refuses — a slice off the tiling,
+too much VMEM, a kernel that will not lower — so the kernels of
+``chip_smoke.py``'s shapes are compiled here at their real widths, about
+two seconds each.  Nothing runs: this says nothing of results or times.
+
+The persistent compile cache is off around them: an executable for a
+described device is written to it but cannot be read back without a chip.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+from jax.experimental.compilation_cache import compilation_cache  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from deepspeed_tpu.ops.sparse_attention import (  # noqa: E402
+    BigBirdSparsityConfig, flash_block_sparse_attention)
+from deepspeed_tpu.ops.transformer.attention import (  # noqa: E402
+    dot_product_attention)
+from deepspeed_tpu.ops.transformer.flash_attention import (  # noqa: E402
+    flash_attention)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e!r:.200}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _qkv(shape, sharding, dtype=jnp.bfloat16):
+    return [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)] * 3
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    return compiled.as_text()
+
+
+@pytest.mark.parametrize("shape,causal,masked,dropout", [
+    ((8, 512, 16, 64), False, True, 0.1),    # BERT-large seq 512
+    ((4, 1024, 16, 64), True, False, 0.0),   # GPT-2-medium seq 1024
+    ((4, 1024, 16, 64), True, False, 0.1),
+    ((1, 512, 20, 64), True, True, 0.0),     # GPT-2-large prefill bucket
+], ids=["bert-s512-mask-dropout", "gpt2-s1024-causal",
+        "gpt2-s1024-causal-dropout", "prefill-s512-causal-mask"])
+def test_flash_kernel_grad_compiles(v5e, shape, causal, masked, dropout):
+    b, s = shape[:2]
+    extra = []
+    if masked:
+        extra.append(jax.ShapeDtypeStruct((b, s), jnp.float32, sharding=v5e))
+    if dropout:
+        extra.append(jax.ShapeDtypeStruct((2,), jnp.int32, sharding=v5e))
+
+    def loss(q, k, v, *rest):
+        rest = list(rest)
+        kv_mask = rest.pop(0) if masked else None
+        seed = rest.pop(0) if dropout else None
+        out = flash_attention(q, k, v, kv_mask=kv_mask, dropout_seed=seed,
+                              causal=causal, dropout_rate=dropout)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(shape, v5e),
+                    *extra)
+    # forward + the single-tile fused backward
+    assert text.count("tpu_custom_call") >= 2
+
+
+def test_block_sparse_flash_kernel_grad_compiles(v5e):
+    s, heads = 4096, 16
+    layout = BigBirdSparsityConfig(
+        num_heads=heads, block=128, num_random_blocks=1,
+        num_sliding_window_blocks=3, num_global_blocks=1).make_layout(s)
+
+    def loss(q, k, v):
+        out = flash_block_sparse_attention(q, k, v, layout)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                    *_qkv((1, s, heads, 64), v5e))
+    assert "tpu_custom_call" in text
+
+
+def test_serving_decode_attention_compiles(v5e):
+    """One decode position per slot against the paged context, GPT-2-large
+    geometry (20 heads of 64, 4 slots, 1024 positions): the dispatch sends
+    a one-row query to XLA attention, not to the kernel."""
+    slots, max_seq, heads, d = 4, 1024, 20, 64
+    q = jax.ShapeDtypeStruct((slots, 1, heads, d), jnp.bfloat16, sharding=v5e)
+    kv = jax.ShapeDtypeStruct((slots, max_seq, heads, d), jnp.bfloat16,
+                              sharding=v5e)
+    visible = jax.ShapeDtypeStruct((slots, max_seq), jnp.float32,
+                                   sharding=v5e)
+    text = _compile(
+        lambda q, k, v, m: dot_product_attention(q, k, v, key_padding_mask=m),
+        q, kv, kv, visible)
+    assert "tpu_custom_call" not in text
