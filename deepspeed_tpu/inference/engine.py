@@ -141,6 +141,8 @@ class InferenceEngine:
 
         self._step_latencies = StepLatencyRing()
         self._driver_latencies = StepLatencyRing()
+        # the clock of every per-token stamp (a test puts its own here)
+        self._clock = time.monotonic
         self.decode_iterations = 0
         # the serving observability plane: lifecycle tracing, occupancy
         # windows, SLO/goodput accounting.  Always constructed — every
@@ -264,23 +266,32 @@ class InferenceEngine:
     # ------------------------------------------------------------------
     def _run_prefill(self, request):
         sched = self.scheduler
-        t_pre = time.monotonic()
-        ids = np.zeros((1, request.bucket), np.int32)
-        ids[0, :len(request.prompt)] = request.prompt
-        table = np.asarray(sched.block_table_row(request), np.int32)
-        first, self._k_cache, self._v_cache = self._prefills[
-            request.bucket](self.params, self._k_cache, self._v_cache,
-                            jnp.asarray(ids),
-                            jnp.int32(len(request.prompt)), table)
-        token = int(jax.device_get(first))
-        now = time.monotonic()
-        request.first_token_at = now
-        request.step_times.append(now - request.submitted)
-        request.generated.append(token)
-        self.generated_tokens += 1
-        # admit + first_token phase records, admission-wait histogram,
-        # TTFT SLO leg, bucket padding-waste accumulators
-        self.observability.note_prefill(request, now, now - t_pre)
+        span = self.telemetry.span
+        with span("prefill", bucket=request.bucket,
+                  prompt_tokens=len(request.prompt)):
+            t_pre = self._clock()
+            with span("prefill.prep"):
+                ids = np.zeros((1, request.bucket), np.int32)
+                ids[0, :len(request.prompt)] = request.prompt
+                table = np.asarray(sched.block_table_row(request), np.int32)
+            with span("prefill.dispatch"):
+                first, self._k_cache, self._v_cache = self._prefills[
+                    request.bucket](self.params, self._k_cache,
+                                    self._v_cache, jnp.asarray(ids),
+                                    jnp.int32(len(request.prompt)), table)
+            with span("prefill.fetch"):
+                token = int(jax.device_get(first))
+            with span("prefill.account"):
+                now = self._clock()
+                # the TTFT is first_token_at - submitted; step_times
+                # holds the gaps BETWEEN a request's tokens only
+                request.first_token_at = request.last_token_at = now
+                request.generated.append(token)
+                self.generated_tokens += 1
+                # admit + first_token phase records, admission-wait
+                # histogram, TTFT SLO leg, bucket padding-waste
+                # accumulators
+                self.observability.note_prefill(request, now, now - t_pre)
 
     def _emit_finish(self, request):
         self.observability.note_finish(request)
@@ -288,63 +299,84 @@ class InferenceEngine:
     def _emit_deadline(self, request):
         self.observability.note_deadline(request)
 
-    def _decode_once(self):
-        """One continuous-batch decode iteration over the active slots.
-        The single ``device_get`` here is the serve loop's OWN next-token
-        fetch — the baseline the zero-added-syncs test measures against.
-        With a health plane attached, the cadence iterations fold the
-        re-computed weight-fingerprint scalar INTO that same fetch (one
-        batched ``device_get``), so the full resilience plane holds the
-        count at baseline."""
+    def _decode_once(self, active):
+        """One continuous-batch decode iteration over the ``active``
+        slots.  The single ``device_get`` here is the serve loop's OWN
+        next-token fetch — the baseline the zero-added-syncs test
+        measures against.  With a health plane attached, the cadence
+        iterations fold the re-computed weight-fingerprint scalar INTO
+        that same fetch (one batched ``device_get``), so the full
+        resilience plane holds the count at baseline."""
         icfg = self.inference_config
         sched = self.scheduler
-        t_prep = time.monotonic()
-        width = icfg.max_blocks_per_seq
-        tables = np.zeros((icfg.max_batch_slots, width), np.int32)
-        ctx_lens = np.zeros((icfg.max_batch_slots,), np.int32)
-        tokens = np.zeros((icfg.max_batch_slots,), np.int32)
-        before = []
-        for request in sched.slots:
-            if request is None:
-                continue
-            tables[request.slot] = sched.block_table_row(request)
-            # position of the token being decoded = current context - 1
-            # (the last generated token is the decode input)
-            ctx_lens[request.slot] = request.context_len - 1
-            tokens[request.slot] = request.generated[-1]
-            before.append(request)
-        fp_dev = None
-        if self._health is not None:
-            # liveness tick for ENTERING this iteration (throttled O(1)
-            # publish; a wedged decode never refreshes it again)
-            self._health.beat(self.decode_iterations + 1)
-            if (self.decode_iterations + 1) % self.steps_per_print == 0:
-                fp_dev = self._health.fingerprint_device()
-        t0 = time.monotonic()
-        self._driver_latencies.record(t0 - t_prep)
-        next_dev, self._k_cache, self._v_cache = self._decode(
-            self.params, self._k_cache, self._v_cache, tables, ctx_lens,
-            tokens)
-        # ONE host sync per decode iteration, cadence or not: the weight
-        # fingerprint (when due) rides the same batched fetch as the
-        # sampled tokens, so arming the resilience plane adds zero
-        # device_get calls (the zero-added-syncs test counts them)
-        fetched = jax.device_get((next_dev,) if fp_dev is None
-                                 else (next_dev, fp_dev))
-        next_tokens = fetched[0]
-        if fp_dev is not None:
-            self._pending_fingerprint = int(fetched[1])
-        now = time.monotonic()
-        self._step_latencies.record(now - t0)
-        self.decode_iterations += 1
-        for request in before:
-            request.generated.append(int(next_tokens[request.slot]))
-            request.step_times.append(now - t0)
-            self.generated_tokens += 1
-        # O(active) host arithmetic on the scalars this loop already
-        # holds (occupancy window sums, P² per-token observations, the
-        # per-token SLO leg) — no device syncs
-        self.observability.note_decode(before, now - t0)
+        span = self.telemetry.span
+        with span("decode", active=active):
+            with span("decode.prep"):
+                t_prep = self._clock()
+                width = icfg.max_blocks_per_seq
+                tables = np.zeros((icfg.max_batch_slots, width), np.int32)
+                ctx_lens = np.zeros((icfg.max_batch_slots,), np.int32)
+                tokens = np.zeros((icfg.max_batch_slots,), np.int32)
+                before = []
+                for request in sched.slots:
+                    if request is None:
+                        continue
+                    tables[request.slot] = sched.block_table_row(request)
+                    # position of the token being decoded = current
+                    # context - 1 (the last generated token is the
+                    # decode input)
+                    ctx_lens[request.slot] = request.context_len - 1
+                    tokens[request.slot] = request.generated[-1]
+                    before.append(request)
+                fp_dev = None
+                if self._health is not None:
+                    # liveness tick for ENTERING this iteration
+                    # (throttled O(1) publish; a wedged decode never
+                    # refreshes it again)
+                    self._health.beat(self.decode_iterations + 1)
+                    if ((self.decode_iterations + 1)
+                            % self.steps_per_print == 0):
+                        fp_dev = self._health.fingerprint_device()
+                t0 = self._clock()
+                self._driver_latencies.record(t0 - t_prep)
+            with span("decode.dispatch"):
+                # returns when the three tables are copied and the
+                # program is enqueued, not when it has run
+                next_dev, self._k_cache, self._v_cache = self._decode(
+                    self.params, self._k_cache, self._v_cache, tables,
+                    ctx_lens, tokens)
+            with span("decode.fetch"):
+                # ONE host sync per decode iteration, cadence or not:
+                # the weight fingerprint (when due) rides the same
+                # batched fetch as the sampled tokens, so arming the
+                # resilience plane adds zero device_get calls (the
+                # zero-added-syncs test counts them)
+                fetched = jax.device_get((next_dev,) if fp_dev is None
+                                         else (next_dev, fp_dev))
+            with span("decode.account"):
+                next_tokens = fetched[0]
+                if fp_dev is not None:
+                    self._pending_fingerprint = int(fetched[1])
+                now = self._clock()
+                self._step_latencies.record(now - t0)
+                self.decode_iterations += 1
+                gaps = []
+                for request in before:
+                    request.generated.append(int(next_tokens[request.slot]))
+                    # the gap since THIS request's previous token: a
+                    # neighbour's prefill between two of its tokens is
+                    # in it, which the decode call's own duration
+                    # (now - t0) would leave out
+                    gap = now - request.last_token_at
+                    gaps.append(gap)
+                    request.step_times.append(gap)
+                    request.last_token_at = now
+                    self.generated_tokens += 1
+                # host arithmetic on the scalars this loop already holds
+                # (occupancy window sums, the per-token SLO leg; with
+                # telemetry on, the P² per-token observations) — no
+                # device syncs
+                self.observability.note_decode(before, gaps)
 
     def _sample_telemetry(self):
         """Print-cadence sampling: queue/occupancy gauges, one
@@ -408,42 +440,53 @@ class InferenceEngine:
         slots, admit from the queue (each admission prefills
         immediately), then advance every active slot one token.
         Returns the requests finished DURING this iteration."""
-        sched = self.scheduler
-        finished = sched.sweep_deadlines()
-        for request in finished:
-            self._emit_deadline(request)
-        for request in sched.sweep_finished(
-                self.inference_config.eos_token_id):
-            self._emit_finish(request)
-            finished.append(request)
-        while not self._draining:
-            request = sched.try_admit()
-            if request is None:
-                break
-            try:
-                self._run_prefill(request)
-            except BaseException:
-                # a prefill that raises after admission must not strand
-                # the slot + block grant it was just handed (the
-                # blocks-conserved invariant): release everything and
-                # surface the fault
-                sched.abort(request)
-                raise
-        # a prefill can already satisfy a request (max_new_tokens=1, or
-        # the prefill token IS eos): sweep before decoding, else the
-        # slot advances one token past its contract — and an eos landed
-        # at prefill would be buried under the extra token and missed
-        for request in sched.sweep_finished(
-                self.inference_config.eos_token_id):
-            self._emit_finish(request)
-            finished.append(request)
-        if sched.active_count:
-            self._decode_once()
-        if (self.decode_iterations
-                and self.decode_iterations % self.steps_per_print == 0):
-            self._sample_telemetry()
-            self._sample_integrity()
-        return finished
+        span = self.telemetry.span
+        with span("step"):
+            sched = self.scheduler
+            eos = self.inference_config.eos_token_id
+            with span("step.sweep"):
+                finished = sched.sweep_deadlines()
+                for request in finished:
+                    self._emit_deadline(request)
+                for request in sched.sweep_finished(eos):
+                    self._emit_finish(request)
+                    finished.append(request)
+            while not self._draining:
+                # one span per try_admit call; the call that admits nothing
+                # closes the loop
+                with span("step.admit"):
+                    request = sched.try_admit()
+                if request is None:
+                    break
+                try:
+                    self._run_prefill(request)
+                except BaseException:
+                    # a prefill that raises after admission must not strand
+                    # the slot + block grant it was just handed (the
+                    # blocks-conserved invariant): release everything and
+                    # surface the fault
+                    sched.abort(request)
+                    raise
+            # a prefill can already satisfy a request (max_new_tokens=1, or
+            # the prefill token IS eos): sweep before decoding, else the
+            # slot advances one token past its contract — and an eos landed
+            # at prefill would be buried under the extra token and missed
+            with span("step.sweep"):
+                for request in sched.sweep_finished(eos):
+                    self._emit_finish(request)
+                    finished.append(request)
+            active = sched.active_count
+            if active:
+                self._decode_once(active)
+            if (self.decode_iterations
+                    and self.decode_iterations % self.steps_per_print == 0):
+                with span("step.sample"):
+                    self._sample_telemetry()
+                    self._sample_integrity()
+                    # the operator's on-demand device trace (touch
+                    # <run_dir>/device_trace.trigger), as train_batch polls it
+                    self.telemetry.poll_device_trace(self.decode_iterations)
+            return finished
 
     def run(self):
         """Drain the queue: iterate until every submitted request has

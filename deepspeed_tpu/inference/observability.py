@@ -93,7 +93,8 @@ class ServingObservability:
 
     - :meth:`note_prefill` — after the prefill's first-token fetch;
     - :meth:`note_decode` — after the decode iteration's batched fetch
-      (O(active) arithmetic on scalars the loop already holds);
+      (sums on scalars the loop already holds; O(active) only with
+      telemetry on);
     - :meth:`export_serving_window` — ONLY from the steps_per_print
       cadence block (DSH205-registered).
     """
@@ -144,9 +145,10 @@ class ServingObservability:
 
     def note_prefill(self, request, now, prefill_seconds):
         """Post-prefill accounting: the admit + first_token phase
-        records, the admission-wait histogram, the per-token quantile
-        observation for the TTFT token, the bucket padding-waste
-        accumulators, and the TTFT leg of the SLO."""
+        records, the admission-wait histogram, the bucket padding-waste
+        accumulators, and the TTFT leg of the SLO (the TTFT is not a
+        per-token observation: ``serving/per_token_seconds`` holds the
+        gaps between a request's tokens alone)."""
         sched = self.engine.scheduler
         wait = (request.admitted_at - request.submitted
                 if request.admitted_at is not None else 0.0)
@@ -173,13 +175,14 @@ class ServingObservability:
         self.telemetry.counter("serving/admitted").inc()
         self.telemetry.histogram(
             "serving/admission_wait_seconds").observe(wait)
-        self.telemetry.quantiles(
-            "serving/per_token_seconds").observe(ttft)
 
-    def note_decode(self, before, latency):
+    def note_decode(self, before, gaps):
         """Per-iteration accounting on already-fetched scalars: window
-        occupancy/budget sums, the per-token P² observations, and the
-        per-token SLO leg.  O(active) host arithmetic, zero syncs."""
+        occupancy/budget sums and the per-token SLO leg, judged on
+        ``gaps`` — for each request of ``before`` the seconds since ITS
+        previous token.  With telemetry on, also the per-token P²
+        observations: O(active) host arithmetic, zero syncs; with it
+        off nothing walks the requests."""
         n = len(before)
         self._win_iterations += 1
         self._cum_iterations += 1
@@ -190,13 +193,16 @@ class ServingObservability:
         reserved = self.engine.scheduler.reserved_tokens()
         self._win_reserved_sum += reserved
         self._cum_reserved_sum += reserved
-        if not self._slo_tok or latency <= self._slo_tok:
-            self._win_good_tokens += n
-            self._cum_good_tokens += n
+        good = (sum(gap <= self._slo_tok for gap in gaps)
+                if self._slo_tok else n)
+        self._win_good_tokens += good
+        self._cum_good_tokens += good
+        if not self.telemetry.enabled:
+            return
         q = self.telemetry.quantiles("serving/per_token_seconds")
-        for request in before:
+        for request, gap in zip(before, gaps):
             self._win_traces.add(request.trace_id)
-            q.observe(latency)
+            q.observe(gap)
 
     def note_finish(self, request):
         self._emit(

@@ -41,17 +41,19 @@ class Request:
 
     Timing fields are host wall-clock (``time.monotonic``): ``submitted``
     at entry, ``first_token_at`` when prefill emits (TTFT), ``step_times``
-    one per generated token (the per-token latency record the serving
-    bench quotes p50/p99 from).  ``deadline_at`` is an absolute
-    monotonic expiry (None = no deadline): the scheduler's deadline
-    sweep finishes an expired request with ``reason="deadline"`` and
-    the partial tokens it generated so far."""
+    one per generated token AFTER the first: the gap since this request's
+    previous token (``last_token_at``), so an iteration spent behind a
+    neighbour's prefill shows (the per-token latency record the serving
+    bench quotes p50/p99 from; the TTFT is not in it).  ``deadline_at``
+    is an absolute monotonic expiry (None = no deadline): the
+    scheduler's deadline sweep finishes an expired request with
+    ``reason="deadline"`` and the partial tokens it generated so far."""
 
     __slots__ = ("request_id", "prompt", "max_new_tokens", "state",
                  "generated", "blocks", "slot", "bucket", "submitted",
-                 "first_token_at", "finished_at", "finish_reason",
-                 "step_times", "deadline_at", "requeues", "trace_id",
-                 "admitted_at", "_cached_summary")
+                 "first_token_at", "last_token_at", "finished_at",
+                 "finish_reason", "step_times", "deadline_at", "requeues",
+                 "trace_id", "admitted_at", "_cached_summary")
 
     def __init__(self, request_id, prompt, max_new_tokens,
                  deadline_at=None, trace_id=None):
@@ -66,6 +68,7 @@ class Request:
         self.bucket = None
         self.submitted = time.monotonic()
         self.first_token_at = None
+        self.last_token_at = None
         self.finished_at = None
         self.finish_reason = None
         self.step_times = []
@@ -98,6 +101,7 @@ class Request:
         self.slot = None
         self.bucket = None
         self.first_token_at = None
+        self.last_token_at = None
         self.finished_at = None
         self.finish_reason = None
         self.step_times = []

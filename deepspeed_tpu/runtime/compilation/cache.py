@@ -25,6 +25,7 @@ variable for its children from the jax-free side.
 
 import os
 import threading
+import time
 
 from ...utils.logging import logger
 
@@ -94,6 +95,8 @@ def configure_persistent_cache(config):
 EVENT_CACHE_HIT = "/jax/compilation_cache/cache_hits"
 EVENT_CACHE_MISS = "/jax/compilation_cache/cache_misses"
 DURATION_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+DURATION_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+DURATION_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 DURATION_CACHE_RETRIEVAL = (
     "/jax/compilation_cache/cache_retrieval_time_sec")
 
@@ -134,6 +137,17 @@ class CompileStats:
     — the cold/warm receipt the bench JSON records.  ``by_program`` splits
     ``cold_secs`` by the jitted function's name (``train_step``,
     ``decode``, ``prefill``, ...), which jax passes with the event.
+
+    What a compile request costs BEFORE the backend — and all that a
+    retrace which then hits the persistent cache costs — is tracing the
+    Python function to a jaxpr and lowering that to an MLIR module:
+    ``trace_secs`` and ``lower_secs``.  A jitted function called inside
+    another is traced inside its caller's trace and reports its own
+    duration too; ``trace_secs`` counts such seconds once (the outermost
+    trace's), ``trace_secs_by_program`` gives each function's own
+    seconds, callees included, under its bare name (``train_step``) and
+    ``traces_by_program`` how often it was traced: more than once for
+    one program is a retrace.
     """
 
     def __init__(self):
@@ -144,6 +158,11 @@ class CompileStats:
         self.warm_secs = 0.0
         self.programs = 0
         self.by_program = {}
+        self.trace_secs = 0.0
+        self.lower_secs = 0.0
+        self.traces_by_program = {}
+        self.trace_secs_by_program = {}
+        self._open_traces = []   # (ended at, seconds) of outermost traces
         import jax.monitoring as monitoring
 
         with _stats_lock:
@@ -168,6 +187,24 @@ class CompileStats:
                 self.by_program.get(fun_name, 0.0) + float(duration))
         elif event == DURATION_CACHE_RETRIEVAL:
             self.warm_secs += float(duration)
+        elif event == DURATION_LOWER:
+            self.lower_secs += float(duration)
+        elif event == DURATION_TRACE:
+            self._on_trace(float(duration), fun_name)
+
+    def _on_trace(self, duration, fun_name):
+        self.traces_by_program[fun_name] = (
+            self.traces_by_program.get(fun_name, 0) + 1)
+        self.trace_secs_by_program[fun_name] = (
+            self.trace_secs_by_program.get(fun_name, 0.0) + duration)
+        # the event comes as a trace ends: traces that ended after this
+        # one began ran inside it, and their seconds are in its own
+        now = time.perf_counter()
+        began = now - duration
+        while self._open_traces and self._open_traces[-1][0] > began:
+            self.trace_secs -= self._open_traces.pop()[1]
+        self._open_traces.append((now, duration))
+        self.trace_secs += duration
 
     def close(self):
         with _stats_lock:

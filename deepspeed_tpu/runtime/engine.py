@@ -3813,7 +3813,15 @@ class DeepSpeedEngine:
         optimizer step (micro-batch scan + update + param cast), with the
         master/optimizer/param buffers donated.  The step-wise
         ``forward()``/``backward()``/``step()`` API remains for clients that
-        drive micro-batches themselves."""
+        drive micro-batches themselves.
+
+        Host phases are program spans (``ds:train_batch`` ⊃
+        ``batch_fetch``, ``pack``, ``device_put``, ``dispatch``,
+        ``device_get``, ``cadence`` in any ``jax.profiler`` capture)."""
+        with self.telemetry.span("train_batch", step=self.global_steps + 1):
+            return self._train_batch(data_iter)
+
+    def _train_batch(self, data_iter):
         if data_iter is None:
             assert self.training_dataloader is not None
             if not hasattr(self, "_train_iter"):
@@ -3832,7 +3840,8 @@ class DeepSpeedEngine:
             micro_batches = [next(data_iter) for _ in range(acc)]
         self._integrity_step_enter()
         try:
-            packed_host, spec = _pack_batches(micro_batches)
+            with self.telemetry.span("pack"):
+                packed_host, spec = _pack_batches(micro_batches)
         except (ValueError, AssertionError):
             # ragged micro-batches (e.g. a short final batch) cannot be
             # stacked into the fused program; fall back to the step-wise
@@ -3841,13 +3850,15 @@ class DeepSpeedEngine:
                 self.timers("train_batch").stop(sync=False)
             return self._train_batch_stepwise(micro_batches,
                                               t_host0=t_host0)
-        sharding = NamedSharding(self.mesh, P(None, DATA_AXIS, None))
-        if jax.process_count() > 1:
-            packed = {k: jax.make_array_from_process_local_data(sharding, v)
-                      for k, v in packed_host.items()}
-        else:
-            packed = {k: jax.device_put(v, sharding)
-                      for k, v in packed_host.items()}
+        with self.telemetry.span("device_put"):
+            sharding = NamedSharding(self.mesh, P(None, DATA_AXIS, None))
+            if jax.process_count() > 1:
+                packed = {
+                    k: jax.make_array_from_process_local_data(sharding, v)
+                    for k, v in packed_host.items()}
+            else:
+                packed = {k: jax.device_put(v, sharding)
+                          for k, v in packed_host.items()}
 
         hp = self._device_hyperparams()
         step_fn = self._train_step_fn
@@ -3949,44 +3960,45 @@ class DeepSpeedEngine:
                 top_modules=self._config.flops_profiler_config.top_modules)
 
         if self.global_steps % self.steps_per_print() == 0:
-            # monitor scalars share the steps_per_print cadence: fetching
-            # them is a host sync, so it must stay off the per-step
-            # critical path — and cost ONE transfer, not three (loss,
-            # scale and skipped fetched separately each paid a full wire
-            # round-trip; dslint DSH203)
-            self._check_sparse_overflow()
-            lr = self.get_lr()[0] if self.optimizer.param_groups else 0.0
-            # the integrity fingerprint (a dispatched device scalar)
-            # rides the same batched transfer: zero added host syncs
-            fetch = {"loss": loss,
-                     "scale": self.state["scale"].cur_scale,
-                     "skipped": self.state["skipped"]}
-            fp_dev = self._integrity_fingerprint_device()
-            if fp_dev is not None:
-                fetch["fingerprint"] = fp_dev
-            # dslint: disable=DSH203 -- print cadence; cannot batch with the per-step fp16 overflow fetch above
-            stats = jax.device_get(fetch)
-            loss_val = float(stats["loss"])
-            scale = (float(stats["scale"]) if self._config.fp16_enabled
-                     else 1.0)
-            if self._config.fp16_enabled:
-                self.telemetry.note_scale(scale, step=self.global_steps)
-            log_dist(
-                f"step={self.global_steps}, skipped={int(stats['skipped'])}, "
-                f"lr={lr:.6g}, loss={loss_val:.5f}, loss_scale={scale}",
-                ranks=[0])
-            # reference tensorboard tags (engine.py:1014-1067); the event
-            # stream + registry ride the same already-fetched scalars
-            self.telemetry.step_metrics(self.global_steps,
-                                        self.global_samples, {
-                "Train/Samples/train_loss": loss_val,
-                "Train/Samples/lr": lr,
-                "Train/Samples/loss_scale": scale,
-            }, skipped=int(stats["skipped"]))
-            self._sample_memory_watermarks()
-            self._sample_comm_skew()
-            self._sample_attribution()
-            self._sample_integrity(stats.get("fingerprint"))
+            with self.telemetry.span("cadence", step=self.global_steps):
+                # monitor scalars share the steps_per_print cadence: fetching
+                # them is a host sync, so it must stay off the per-step
+                # critical path — and cost ONE transfer, not three (loss,
+                # scale and skipped fetched separately each paid a full wire
+                # round-trip; dslint DSH203)
+                self._check_sparse_overflow()
+                lr = self.get_lr()[0] if self.optimizer.param_groups else 0.0
+                # the integrity fingerprint (a dispatched device scalar)
+                # rides the same batched transfer: zero added host syncs
+                fetch = {"loss": loss,
+                         "scale": self.state["scale"].cur_scale,
+                         "skipped": self.state["skipped"]}
+                fp_dev = self._integrity_fingerprint_device()
+                if fp_dev is not None:
+                    fetch["fingerprint"] = fp_dev
+                # dslint: disable=DSH203 -- print cadence; cannot batch with the per-step fp16 overflow fetch above
+                stats = jax.device_get(fetch)
+                loss_val = float(stats["loss"])
+                scale = (float(stats["scale"]) if self._config.fp16_enabled
+                         else 1.0)
+                if self._config.fp16_enabled:
+                    self.telemetry.note_scale(scale, step=self.global_steps)
+                log_dist(
+                    f"step={self.global_steps}, skipped={int(stats['skipped'])}, "
+                    f"lr={lr:.6g}, loss={loss_val:.5f}, loss_scale={scale}",
+                    ranks=[0])
+                # reference tensorboard tags (engine.py:1014-1067); the event
+                # stream + registry ride the same already-fetched scalars
+                self.telemetry.step_metrics(self.global_steps,
+                                            self.global_samples, {
+                    "Train/Samples/train_loss": loss_val,
+                    "Train/Samples/lr": lr,
+                    "Train/Samples/loss_scale": scale,
+                }, skipped=int(stats["skipped"]))
+                self._sample_memory_watermarks()
+                self._sample_comm_skew()
+                self._sample_attribution()
+                self._sample_integrity(stats.get("fingerprint"))
         if self.wall_clock_breakdown():
             # the fused program has no forward/step boundary to time
             # separately; report the whole fused step

@@ -21,7 +21,6 @@ die without atexit, and the tail events are the post-mortem.
 """
 
 import atexit
-import contextlib
 import os
 import threading
 
@@ -29,12 +28,10 @@ from ..utils.logging import logger
 from . import events as ev
 from .events import EventLog
 from .registry import MetricsRegistry
-from .trace import DeviceTraceTrigger, StepTracer
+from .trace import DeviceTraceTrigger, StepTracer, program_span
 
 METRICS_FILE_PREFIX = "metrics-"
 METRICS_FILE_SUFFIX = ".json"
-
-_NULL_SPAN = contextlib.nullcontext()
 
 
 def metrics_filename(rank):
@@ -137,9 +134,12 @@ class TelemetryManager:
 
     # ------------------------------------------------------------ spans
     def span(self, name, **args):
-        if self.tracer is None:
-            return _NULL_SPAN
-        return self.tracer.span(name, **args)
+        """``with telemetry.span("dispatch", step=n): ...`` — the one
+        span call of the program.  Always a ``ds:<name>`` annotation in
+        any running ``jax.profiler`` session (jax's own "is a session
+        active" is the switch, telemetry on or off); with
+        ``telemetry.trace`` on, also an event in the Chrome-trace file."""
+        return program_span(self.tracer, name, args)
 
     def poll_device_trace(self, step=None):
         if self.device_trace is not None:

@@ -1,6 +1,11 @@
-"""Step tracing: Chrome-trace-event host spans + on-demand device traces.
+"""Step tracing: program spans + on-demand device traces.
 
-Two complementary tools:
+:func:`program_span` is what ``TelemetryManager.span`` returns: a
+``jax.profiler.TraceAnnotation`` named ``ds:<name>`` — the program's host
+phases on the timeline of ANY profiler session, beside the device's
+operations — and, with a :class:`StepTracer`, the same span in its file.
+
+Two complementary tools behind it:
 
 - :class:`StepTracer` — host-side phase spans (batch fetch, dispatch,
   the one batched ``device_get``, checkpoint snapshot/commit, rollback
@@ -26,36 +31,58 @@ import os
 import threading
 import time
 
+import jax
+
 from ..utils.logging import logger
 
 TRACE_FILE_PREFIX = "trace-"
 TRACE_FILE_SUFFIX = ".json"
 DEVICE_TRACE_TRIGGER_FILE = "device_trace.trigger"
 DEVICE_TRACE_DIR = "device_trace"
+# every program span's name in a jax.profiler capture starts with this
+PROGRAM_SPAN_PREFIX = "ds:"
 
 
 def trace_filename(rank):
     return f"{TRACE_FILE_PREFIX}rank{rank}{TRACE_FILE_SUFFIX}"
 
 
+def program_span(tracer, name, args):
+    """One program span, two sinks.  Always a
+    ``jax.profiler.TraceAnnotation`` named ``ds:<name>``: it lands on the
+    host plane of whatever profiler session is running (the benchmark's
+    ``--trace 1``, an on-demand device trace, a TensorBoard capture), on
+    the clock the device planes share, and costs under a microsecond
+    when no session runs.  With a ``tracer`` (``telemetry.trace`` on),
+    also one complete event in its Chrome-trace file."""
+    annotation = jax.profiler.TraceAnnotation(PROGRAM_SPAN_PREFIX + name,
+                                              **args)
+    if tracer is None:
+        return annotation
+    return _Span(tracer, name, args, annotation)
+
+
 class _Span:
-    """Context manager recording one complete ("ph": "X") event."""
+    """The annotation and one complete ("ph": "X") StepTracer event."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_annotation")
 
-    def __init__(self, tracer, name, args):
+    def __init__(self, tracer, name, args, annotation):
         self._tracer = tracer
         self._name = name
         self._args = args
         self._t0 = 0.0
+        self._annotation = annotation
 
     def __enter__(self):
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self._tracer._record(self._name, self._t0, time.perf_counter(),
                              self._args)
+        self._annotation.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -120,7 +147,7 @@ class StepTracer:
 
     def span(self, name, **args):
         """``with tracer.span("dispatch", step=n): ...``"""
-        return _Span(self, name, args)
+        return program_span(self, name, args)
 
     def instant(self, name, **args):
         """Zero-duration marker (anomalies, rollbacks, commits)."""
@@ -223,8 +250,6 @@ class DeviceTraceTrigger:
 
     def _start(self, step):
         try:
-            import jax
-
             os.makedirs(self.out_dir, exist_ok=True)
             jax.profiler.start_trace(self.out_dir)
         except Exception as e:  # noqa: BLE001 — profiling is best-effort
@@ -236,8 +261,6 @@ class DeviceTraceTrigger:
 
     def _stop(self, step):
         try:
-            import jax
-
             jax.profiler.stop_trace()
             logger.info("device trace stopped at step %s; load %s in "
                         "Perfetto/TensorBoard", step, self.out_dir)

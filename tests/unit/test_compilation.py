@@ -337,3 +337,35 @@ def test_compile_stats_collector(cache_knobs, tmp_path):
     assert d["compile_cache_misses"] >= 1
     assert d["compile_seconds_cold"] > 0
     assert d["compile_programs"] >= 1
+
+
+def test_compile_stats_count_traces_and_lowerings_by_program():
+    """A function jitted at two static arguments is traced twice (what a
+    retrace costs even when the persistent cache then hits): the count
+    and the seconds are the program's, and a jitted callee's seconds are
+    in ``trace_secs`` once."""
+    import functools
+
+    import jax.numpy as jnp
+
+    @functools.partial(jax.jit, static_argnums=1)
+    def twice_traced_program(x, n):
+        for _ in range(4):
+            x = jnp.tanh(x) * n + jnp.sum(x)
+        return x
+
+    stats = CompileStats()
+    for n in (3, 5, 3):                    # the third call is a cache hit
+        twice_traced_program(jnp.ones((7, 5)), n).block_until_ready()
+    stats.close()
+    assert stats.traces_by_program["twice_traced_program"] == 2
+    own = stats.trace_secs_by_program["twice_traced_program"]
+    assert 0 < own <= stats.trace_secs
+    # jnp.tanh and jnp.sum are jitted themselves and traced inside
+    assert stats.traces_by_program["tanh"] >= 2
+    assert stats.trace_secs < sum(stats.trace_secs_by_program.values())
+    assert stats.lower_secs > 0
+    # closed: a later trace moves nothing
+    before = stats.trace_secs
+    twice_traced_program(jnp.ones((7, 5)), 7).block_until_ready()
+    assert stats.trace_secs == before

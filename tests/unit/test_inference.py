@@ -323,6 +323,49 @@ class TestInferenceEngine:
         assert tel == base, (f"serving observability added host syncs: "
                              f"{tel} device_get calls vs {base} baseline")
 
+    def test_per_token_latency_is_the_gap_a_request_sees(
+            self, model_and_params):
+        """With an injected clock (a prefill program costs 1 s, a decode
+        0.1 s): a request whose neighbour is prefilled between two of its
+        tokens reports the 1.1 s gap, not the decode call's 0.1 s; the
+        TTFT is not among the per-token latencies; and the per-token SLO
+        leg judges each token by its own gap."""
+        model, params = model_and_params
+        engine = InferenceEngine(model, params, config=serve_config(
+            slo={"ttft_ms": 0, "per_token_ms": 500}))
+        now = [50.0]
+        engine._clock = lambda: now[0]
+
+        def costing(program, seconds):
+            def run(*args):
+                now[0] += seconds
+                return program(*args)
+            return run
+
+        engine._decode = costing(engine._decode, 0.1)
+        for bucket in list(engine._prefills):
+            engine._prefills[bucket] = costing(engine._prefills[bucket],
+                                               1.0)
+        first = engine.request(engine.submit([3, 4, 5], max_new_tokens=6))
+        engine.step()
+        engine.step()
+        second = engine.request(engine.submit([6, 7, 8, 9],
+                                              max_new_tokens=3))
+        engine.run()
+        assert first.step_times == pytest.approx([0.1, 0.1, 1.1, 0.1, 0.1])
+        assert second.step_times == pytest.approx([0.1, 0.1])
+        for request in (first, second):
+            assert len(request.step_times) == len(request.generated) - 1
+        assert first.result()["per_token_p99_seconds"] == pytest.approx(1.1)
+        assert first.result()["per_token_p50_seconds"] == pytest.approx(0.1)
+        receipt = engine.serving_receipt()
+        assert receipt["per_token_p99_seconds"] == pytest.approx(1.1)
+        # nine tokens in all; the one that waited behind the prefill
+        # misses the 500 ms per-token SLO, its neighbour's does not
+        assert receipt["goodput_tokens"] == 8
+        assert receipt["slo_attainment"] == pytest.approx(8 / 9)
+        engine.close()
+
     def test_serving_events_and_receipt(self, model_and_params, tmp_path):
         model, params = model_and_params
         config = serve_config()

@@ -311,6 +311,185 @@ def test_disabled_manager_is_cheap_noop(tmp_path):
     assert not os.listdir(tmp_path)   # nothing written anywhere
 
 
+# ------------------------------------- program spans in a profiler trace
+def profiled_spans(trace_dir):
+    """(name, start ns, end ns, args) of every ``ds:`` span on the host
+    plane of the jax.profiler trace under ``trace_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("ds:"):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return sorted(out, key=lambda s: s[1])
+
+
+def profile(trace_dir, work):
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+    try:
+        work()
+    finally:
+        jax.profiler.stop_trace()
+    return profiled_spans(trace_dir)
+
+
+@pytest.mark.parametrize("telemetry_on", [True, False])
+def test_span_lands_in_the_profiler_trace_and_the_chrome_file(
+        tmp_path, telemetry_on):
+    """One ``with``, two sinks: ``ds:<name>`` in a jax.profiler trace of a
+    tiny jitted loop whether telemetry is on or off, and — on, with
+    ``trace`` — the same span in the Chrome-trace file."""
+    import jax
+    import jax.numpy as jnp
+
+    tel = TelemetryManager(DeepSpeedTelemetryConfig(
+        {"telemetry": {"enabled": telemetry_on, "trace": True,
+                       "run_dir": str(tmp_path / "run")}}), rank=0)
+    step = jax.jit(lambda x: jnp.tanh(x) + 1.0)
+
+    def loop():
+        x = jnp.ones((8, 8))
+        for n in range(3):
+            with tel.span("dispatch", step=n):
+                x = step(x)
+            with tel.span("device_get"):
+                jax.device_get(x)
+
+    spans = profile(tmp_path / "profile", loop)
+    tel.close()
+    assert [s[0] for s in spans] == ["ds:dispatch", "ds:device_get"] * 3
+    assert [s[3].get("step") for s in spans[::2]] == [0, 1, 2]
+    chrome = tmp_path / "run" / "trace-rank0.json"
+    if telemetry_on:
+        complete = [e for e in json.load(open(chrome))
+                    if e.get("ph") == "X" and e["name"] in
+                    ("dispatch", "device_get")]
+        assert [e["name"] for e in complete] == ["dispatch",
+                                                 "device_get"] * 3
+        assert complete[2]["args"] == {"step": 1}
+    else:
+        assert not chrome.exists()
+
+
+def test_training_spans_nest_in_the_profiler_trace(cpu_devices, tmp_path):
+    """``ds:train_batch`` holds the host phases of a step in order, with
+    telemetry off; the cadence block's span comes only on its steps."""
+    config = base_config(steps_per_print=2)
+    engine = make_engine(config, cpu_devices, dp=2)
+    batches = random_batches(5, config["train_batch_size"], HIDDEN)
+    run_steps(engine, batches[:1])                   # compile outside
+    spans = profile(tmp_path, lambda: run_steps(engine, batches[1:]))
+    engine.close()
+    outer = [s for s in spans if s[0] == "ds:train_batch"]
+    assert [s[3]["step"] for s in outer] == [2, 3, 4, 5]
+    inner = [[s[0] for s in spans if s is not o
+              and o[1] <= s[1] and s[2] <= o[2]] for o in outer]
+    phases = ["ds:batch_fetch", "ds:pack", "ds:device_put", "ds:dispatch"]
+    assert inner == [phases + ["ds:cadence"], phases] * 2
+
+
+def tiny_serving_engine(**config):
+    import jax
+
+    from deepspeed_tpu.inference import InferenceEngine
+
+    from .test_inference import serve_config, tiny_model
+
+    model = tiny_model()
+    return InferenceEngine(
+        model, model.init(jax.random.PRNGKey(0)),
+        config=dict(serve_config(max_batch_slots=2, max_new_tokens=4),
+                    **config))
+
+
+def test_serving_engine_polls_the_device_trace_trigger(tmp_path):
+    """A serving operator takes a device trace as a training one does:
+    touch the trigger file, and the cadence block of ``step()`` starts a
+    bounded jax.profiler trace that holds the program's spans."""
+    run_dir = tmp_path / "run"
+    engine = tiny_serving_engine(
+        steps_per_print=1,
+        telemetry={"enabled": True, "run_dir": str(run_dir)})
+    (run_dir / "device_trace.trigger").touch()
+    for n in range(5):      # the trigger is looked for every 10th poll
+        engine.submit([1 + n, 2, 3])
+        engine.run()
+    assert engine.telemetry.device_trace.active
+    assert not (run_dir / "device_trace.trigger").exists()
+    engine.submit([4, 5, 6, 7])
+    engine.run()
+    engine.close()          # stops the trace
+    spans = profiled_spans(run_dir / "device_trace")
+    assert {"ds:step", "ds:prefill", "ds:decode.fetch"} <= {
+        s[0] for s in spans}
+
+
+def test_serving_spans_nest_in_the_profiler_trace(tmp_path):
+    """``ds:step`` holds ``ds:prefill`` and ``ds:decode``, which hold their
+    phases, in a profiler trace of a tiny InferenceEngine with telemetry
+    off."""
+    engine = tiny_serving_engine(steps_per_print=2)
+    engine.submit([1, 2, 3], request_id="warm")
+    engine.run()                                     # compile outside
+
+    def serve():
+        engine.submit([5, 6, 7, 8, 9], request_id="a")
+        engine.submit(list(range(1, 12)), request_id="b")
+        engine.run()
+
+    spans = profile(tmp_path, serve)
+    engine.close()
+
+    def inside(inner, outer):
+        return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+    def parent(span, name):
+        return [o for o in spans if o[0] == name and inside(span, o)]
+
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s[0], []).append(s)
+    steps = by_name["ds:step"]
+    assert len(steps) >= 4
+    assert len(by_name["ds:prefill"]) == 2
+    assert sorted(s[3]["bucket"] for s in by_name["ds:prefill"]) == [8, 16]
+    assert sorted(s[3]["prompt_tokens"]
+                  for s in by_name["ds:prefill"]) == [5, 11]
+    assert len(by_name["ds:decode"]) == engine.decode_iterations - 3
+    assert {s[3]["active"] for s in by_name["ds:decode"]} == {2}
+    for phase in ("prep", "dispatch", "fetch", "account"):
+        for kind in ("prefill", "decode"):
+            found = by_name[f"ds:{kind}.{phase}"]
+            assert len(found) == len(by_name[f"ds:{kind}"])
+            for s in found:
+                (outer,) = parent(s, f"ds:{kind}")
+                assert len(parent(outer, "ds:step")) == 1
+    for name in ("ds:step.sweep", "ds:step.admit", "ds:step.sample"):
+        assert all(len(parent(s, "ds:step")) == 1 for s in by_name[name])
+    # the phases of one decode follow one another and fill it
+    one = by_name["ds:decode"][0]
+    phases = [s for s in spans if s[0].startswith("ds:decode.")
+              and inside(s, one)]
+    assert [s[0] for s in phases] == [
+        "ds:decode.prep", "ds:decode.dispatch", "ds:decode.fetch",
+        "ds:decode.account"]
+    assert all(a[2] <= b[1] for a, b in zip(phases, phases[1:]))
+
+
 # -------------------------------------------------------- engine wiring
 def test_engine_zero_added_host_syncs(cpu_devices, tmp_path, monkeypatch):
     """The acceptance guarantee: telemetry adds ZERO host syncs — the
