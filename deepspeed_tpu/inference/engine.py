@@ -6,8 +6,9 @@ Program split (all shapes static, all programs ledgered):
 - ``serve_prefill_<bucket>`` — one per declared prefill bucket, compiled
   on first use; cache buffers donated.
 - ``serve_decode`` — ONE fixed-width program for the whole serve; cache
-  buffers donated, so the per-token K/V append is an in-place
-  ``dynamic_update_slice`` that XLA aliases onto the input allocation
+  buffers donated, so the per-token K/V append is one in-place scatter
+  a layer that XLA aliases onto the input allocation, and the paged
+  attention kernel reads the live blocks of that same buffer
   (``engine.verify_programs()`` proves the ``input_output_alias``
   materialized — DSP601; a silently-copied cache is the classic decode
   perf bug).
@@ -31,6 +32,8 @@ import numpy as np
 
 from ..models.gpt2 import GPT2LMHeadTPU
 from ..module_inject.replace_module import cast_weights
+from ..ops.transformer.paged_attention import check_tpu_geometry
+from ..parallel.mesh import current_platform
 from ..profiling.comm import CommLedger, SERVE_DECODE_PROGRAM
 from ..profiling.memory import MemoryLedger
 from ..profiling.step_profiler import StepLatencyRing
@@ -92,6 +95,10 @@ class InferenceEngine:
         self.params = jax.device_put(params)
         cache_dtype = (jnp.bfloat16 if icfg.weights_dtype == "bfloat16"
                        else jnp.float32)
+        if current_platform() == "tpu":
+            # a geometry the decode kernel cannot tile fails here, at
+            # construction, never by a silent second path
+            check_tpu_geometry(mc.hidden_size, icfg.kv_block_size)
         self._k_cache, self._v_cache = init_kv_cache(
             mc.num_layers, icfg.kv_blocks, icfg.kv_block_size,
             mc.num_heads, mc.hidden_size // mc.num_heads,
@@ -310,7 +317,10 @@ class InferenceEngine:
         icfg = self.inference_config
         sched = self.scheduler
         span = self.telemetry.span
-        with span("decode", active=active):
+        # counted before the span opens: an annotation's arguments are
+        # fixed at its start
+        live_blocks = sched.live_blocks()
+        with span("decode", active=active, live_blocks=live_blocks):
             with span("decode.prep"):
                 t_prep = self._clock()
                 width = icfg.max_blocks_per_seq
@@ -376,7 +386,7 @@ class InferenceEngine:
                 # (occupancy window sums, the per-token SLO leg; with
                 # telemetry on, the P² per-token observations) — no
                 # device syncs
-                self.observability.note_decode(before, gaps)
+                self.observability.note_decode(before, gaps, live_blocks)
 
     def _sample_telemetry(self):
         """Print-cadence sampling: queue/occupancy gauges, one

@@ -3,11 +3,19 @@ allocator (vLLM-style block tables, adapted to XLA static shapes).
 
 The cache is two device arrays of fixed shape
 
-    ``[layers, kv_blocks, kv_block_size, heads, head_dim]``
+    ``[layers, kv_blocks, kv_block_size, heads * head_dim]``
 
-allocated ONCE at engine construction.  Sequences never own contiguous
-cache memory: each holds a *block table* (host list of block ids) and
-the prefill/decode programs scatter/gather through it.  Both programs
+allocated ONCE at engine construction.  The layout is the decode
+kernel's (``ops/transformer/paged_attention.py``): a page is a dense
+``[kv_block_size, hidden]`` tile whatever the head width (with
+``head_dim`` 64 as the minor dimension every page would be padded to
+128 lanes: the GPT-2-large cache measured 1.5x its logical bytes on a
+v5e in the old ``[..., heads, head_dim]`` layout, 1.0x in this one), and
+a token's K or V is one row — what the fused-QKV projection emits, so
+the append is a row write.  Sequences never own contiguous cache
+memory: each holds a *block table* (host list of block ids); prefill
+scatters whole pages through it, decode scatters one row a slot and the
+paged kernel fetches the live pages by id.  Both programs
 take the cache arrays as donated arguments and return the updated
 arrays, so XLA aliases the output buffer onto the input allocation —
 an in-place update, verified as a materialized ``input_output_alias``
@@ -17,7 +25,8 @@ perf bug this subsystem exists to never ship).
 Block 0 is reserved as the *null block*: inactive decode slots point
 their whole table at it and park their write offset there, so the
 fixed-width decode program needs no masking on the write path — dead
-slots harmlessly overwrite scratch.
+slots harmlessly overwrite scratch (two dead slots write the same row
+in one scatter: any winner will do).
 """
 
 import jax.numpy as jnp
@@ -77,8 +86,9 @@ class BlockAllocator:
 
 def init_kv_cache(num_layers, num_blocks, block_size, heads, head_dim,
                   dtype=jnp.float32):
-    """The (k, v) cache device buffers, zero-initialized."""
-    shape = (num_layers, num_blocks, block_size, heads, head_dim)
+    """The (k, v) cache device buffers, zero-initialized, in the layout
+    the decode kernel reads (see the module docstring)."""
+    shape = (num_layers, num_blocks, block_size, heads * head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 

@@ -5,13 +5,24 @@ so every shape in the traced graph is fixed:
 
 - ``prefill``: one request, padded to a declared bucket length — full
   causal self-attention over the padded prompt, per-layer K/V written
-  into the request's cache blocks, next token read at the true last
-  position.  One compiled program per bucket.
-- ``decode``: the fixed-width continuous batch — one token per slot,
-  K/V appended in place through the block table
-  (``lax.dynamic_update_slice`` into the DONATED cache buffers), paged
-  gather of each slot's context, one-position attention.  Exactly one
-  compiled program for the whole serve, regardless of batch occupancy.
+  into the request's cache blocks as whole pages (one scatter a layer
+  and cache), next token read at the true last position.  One compiled
+  program per bucket.
+- ``decode``: the fixed-width continuous batch — one token per slot.
+  Per layer, every slot's new K row (and V row) is appended through the
+  block table in ONE scatter into the DONATED cache buffer, and the
+  paged-attention kernel
+  (:mod:`~deepspeed_tpu.ops.transformer.paged_attention`) reads, for
+  each slot, only the blocks that hold live context — by the ids in the
+  table, straight out of that buffer.  Nothing in the program has a
+  ``max_seq_len`` dimension: HBM traffic follows the tokens cached.
+  Exactly one compiled program for the whole serve, regardless of batch
+  occupancy.  On a non-TPU platform the same kernel runs through
+  Pallas' interpreter.
+
+The cache layout is ``[layers, blocks, block_size, heads * head_dim]``
+(:mod:`.kv_cache`): a token's K or V is one row, exactly what the
+fused-QKV projection emits.
 
 The math mirrors :class:`~deepspeed_tpu.models.layers.TransformerLayer`
 (pre-LN path) and :meth:`~deepspeed_tpu.models.gpt2.GPT2LMHeadTPU.hidden`
@@ -27,21 +38,20 @@ import numpy as np
 
 from ..models.layers import dense, gelu, layer_norm
 from ..ops.transformer.attention import dot_product_attention
+from ..ops.transformer.paged_attention import paged_decode_attention
+from ..parallel.mesh import current_platform
 
 
 def _write_prefill_blocks(cache, layer_idx, seq_kv, block_table, block_size):
-    """Scatter one layer's [S, h, d] K-or-V rows into ``cache`` through
-    ``block_table`` (whole blocks: S is a bucket, a multiple of the
-    block size).  Returns the updated cache (aliased via donation)."""
-    s = seq_kv.shape[0]
-    blocks = seq_kv.reshape(s // block_size, block_size,
-                            *seq_kv.shape[1:])
-    for j in range(s // block_size):
-        update = blocks[j][None, None]          # [1, 1, bs, h, d]
-        cache = jax.lax.dynamic_update_slice(
-            cache, update.astype(cache.dtype),
-            (layer_idx, block_table[j], 0, 0, 0))
-    return cache
+    """Write one layer's ``[S, hidden]`` K-or-V rows into ``cache``
+    through ``block_table`` as whole pages (S is a bucket, a multiple of
+    the block size): ONE scatter over the bucket's blocks, which the
+    allocator hands out distinct.  Returns the updated cache (aliased
+    via donation)."""
+    n = seq_kv.shape[0] // block_size
+    pages = seq_kv.reshape(n, block_size, seq_kv.shape[-1])
+    return cache.at[layer_idx, block_table[:n]].set(
+        pages.astype(cache.dtype), unique_indices=True)
 
 
 def build_prefill(model_config, icfg, bucket_len):
@@ -65,12 +75,13 @@ def build_prefill(model_config, icfg, bucket_len):
         for i in range(c.num_layers):
             lp = params["blocks"][f"layer_{i}"]
             y = layer_norm(lp["ln_attn"], x, c.layer_norm_eps)
-            qkv = dense(lp["qkv"], y).reshape(1, s, 3, heads, head_dim)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            k_cache = _write_prefill_blocks(k_cache, i, k[0], block_table,
-                                            bs)
-            v_cache = _write_prefill_blocks(v_cache, i, v[0], block_table,
-                                            bs)
+            qkv = dense(lp["qkv"], y).reshape(1, s, 3, c.hidden_size)
+            k_cache = _write_prefill_blocks(k_cache, i, qkv[0, :, 1],
+                                            block_table, bs)
+            v_cache = _write_prefill_blocks(v_cache, i, qkv[0, :, 2],
+                                            block_table, bs)
+            q, k, v = (qkv[:, :, j].reshape(1, s, heads, head_dim)
+                       for j in range(3))
             ctx = dot_product_attention(q, k, v, key_padding_mask=visible,
                                         causal=True)
             x = x + dense(lp["attn_out"], ctx.reshape(1, s, c.hidden_size))
@@ -93,52 +104,40 @@ def build_decode(model_config, icfg):
 
     ``ctx_lens[b]`` is the context length BEFORE this token, i.e. the
     new token's position; inactive slots park at position 0 of the null
-    block and their output is discarded on the host."""
+    block and their output is discarded on the host.  Two dead slots
+    write the same scratch row, so the append scatter promises no
+    unique indices."""
     c = model_config
     bs = icfg.kv_block_size
     n_slots = icfg.max_batch_slots
-    max_seq = icfg.max_seq_len
-    heads, head_dim = c.num_heads, c.hidden_size // c.num_heads
+    heads = c.num_heads
+    interpret = current_platform() != "tpu"
 
     def decode(params, k_cache, v_cache, block_tables, ctx_lens, tokens):
         x = jnp.take(params["wte"], tokens, axis=0) \
             + jnp.take(params["wpe"], ctx_lens, axis=0)       # [B, h]
-        x = x[:, None, :]                                     # [B, 1, h]
         block_ids = jnp.take_along_axis(
             block_tables, (ctx_lens // bs)[:, None], axis=1)[:, 0]
         offsets = ctx_lens % bs
-        # after the write, each slot's valid context includes its own
-        # new token at position ctx_len
-        visible = (jnp.arange(max_seq)[None, :]
-                   <= ctx_lens[:, None]).astype(jnp.float32)
         for i in range(c.num_layers):
             lp = params["blocks"][f"layer_{i}"]
             y = layer_norm(lp["ln_attn"], x, c.layer_norm_eps)
-            qkv = dense(lp["qkv"], y).reshape(n_slots, 3, heads, head_dim)
-            q, k_new, v_new = qkv[:, 0], qkv[:, 1], qkv[:, 2]
-            for b in range(n_slots):
-                upd_k = k_new[b][None, None, None].astype(k_cache.dtype)
-                upd_v = v_new[b][None, None, None].astype(v_cache.dtype)
-                start = (i, block_ids[b], offsets[b], 0, 0)
-                k_cache = jax.lax.dynamic_update_slice(k_cache, upd_k,
-                                                       start)
-                v_cache = jax.lax.dynamic_update_slice(v_cache, upd_v,
-                                                       start)
-            # paged gather: [B, blocks_per_seq, bs, h, d] -> [B, S, h, d]
-            k_ctx = jnp.take(k_cache[i], block_tables, axis=0).reshape(
-                n_slots, max_seq, heads, head_dim)
-            v_ctx = jnp.take(v_cache[i], block_tables, axis=0).reshape(
-                n_slots, max_seq, heads, head_dim)
-            ctx = dot_product_attention(q[:, None].astype(x.dtype),
-                                        k_ctx.astype(x.dtype),
-                                        v_ctx.astype(x.dtype),
-                                        key_padding_mask=visible)
-            x = x + dense(lp["attn_out"], ctx.reshape(n_slots, 1,
-                                                      c.hidden_size))
+            qkv = dense(lp["qkv"], y).reshape(n_slots, 3, c.hidden_size)
+            # the append: every slot's new row in one scatter a cache
+            k_cache = k_cache.at[i, block_ids, offsets].set(
+                qkv[:, 1].astype(k_cache.dtype))
+            v_cache = v_cache.at[i, block_ids, offsets].set(
+                qkv[:, 2].astype(v_cache.dtype))
+            # each slot's context now includes its own new token at
+            # position ctx_len; the kernel reads the live pages only
+            ctx = paged_decode_attention(
+                qkv[:, 0], k_cache, v_cache, block_tables, ctx_lens,
+                layer=i, num_heads=heads, interpret=interpret)
+            x = x + dense(lp["attn_out"], ctx)
             z = layer_norm(lp["ln_mlp"], x, c.layer_norm_eps)
             x = x + dense(lp["fc2"], gelu(dense(lp["fc1"], z)))
         x = layer_norm(params["ln_f"], x, c.layer_norm_eps)
-        logits = x[:, 0] @ params["wte"].T.astype(x.dtype)
+        logits = x @ params["wte"].T.astype(x.dtype)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), \
             k_cache, v_cache
 

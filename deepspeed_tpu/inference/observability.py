@@ -117,6 +117,7 @@ class ServingObservability:
         self._win_good_tokens = 0
         self._win_active_sum = 0
         self._win_reserved_sum = 0
+        self._win_live_blocks = 0
         self._win_traces = set()
         # run-cumulative accumulators (the bench receipt)
         self._run_start = self._win_start
@@ -176,9 +177,10 @@ class ServingObservability:
         self.telemetry.histogram(
             "serving/admission_wait_seconds").observe(wait)
 
-    def note_decode(self, before, gaps):
+    def note_decode(self, before, gaps, live_blocks):
         """Per-iteration accounting on already-fetched scalars: window
-        occupancy/budget sums and the per-token SLO leg, judged on
+        occupancy/budget sums, the KV blocks the iteration read
+        (``live_blocks``) and the per-token SLO leg, judged on
         ``gaps`` — for each request of ``before`` the seconds since ITS
         previous token.  With telemetry on, also the per-token P²
         observations: O(active) host arithmetic, zero syncs; with it
@@ -190,6 +192,7 @@ class ServingObservability:
         self._cum_tokens += n
         self._win_active_sum += n
         self._cum_active_sum += n
+        self._win_live_blocks += live_blocks
         reserved = self.engine.scheduler.reserved_tokens()
         self._win_reserved_sum += reserved
         self._cum_reserved_sum += reserved
@@ -279,6 +282,13 @@ class ServingObservability:
         gauge("serving/token_budget_utilization").set(budget_util)
         gauge("serving/kv_used_blocks").set(float(allocator.used_blocks))
         gauge("serving/kv_used_peak").set(float(allocator.used_peak))
+        # blocks the paged decode kernel walked over the blocks a
+        # full-table gather reads (slots x max_blocks_per_seq): the
+        # share of the reservation that is live context
+        gauge("serving/kv_live_block_share").set(
+            self._win_live_blocks
+            / (iters * icfg.max_batch_slots * icfg.max_blocks_per_seq)
+            if iters else 0.0)
         gauge("serving/slo_attainment").set(attainment)
         gauge("serving/goodput_tokens_per_second").set(
             self._win_good_tokens / window)
@@ -294,6 +304,7 @@ class ServingObservability:
         self._win_good_tokens = 0
         self._win_active_sum = 0
         self._win_reserved_sum = 0
+        self._win_live_blocks = 0
         self._win_traces = set()
 
     # -- the bench receipt ----------------------------------------------

@@ -181,6 +181,16 @@ class ContinuousBatchScheduler:
         return sum(r.worst_case_tokens() for r in self.slots
                    if r is not None)
 
+    def live_blocks(self):
+        """KV blocks the next decode iteration reads: for each active
+        slot the blocks holding its context and the token being
+        decoded, ``ceil(context_len / block_size)`` — what the paged
+        kernel walks, of the ``slots * max_blocks_per_seq`` a
+        full-table gather would."""
+        bs = self.icfg.kv_block_size
+        return sum(-(-r.context_len // bs) for r in self.slots
+                   if r is not None)
+
     def idle(self):
         return not self.waiting and self.active_count == 0
 

@@ -490,6 +490,69 @@ def test_serving_spans_nest_in_the_profiler_trace(tmp_path):
     assert all(a[2] <= b[1] for a, b in zip(phases, phases[1:]))
 
 
+@pytest.mark.parametrize("telemetry_on", [False, True],
+                         ids=["telemetry-off", "telemetry-on"])
+def test_decode_span_and_gauge_count_the_live_blocks(tmp_path, telemetry_on):
+    """``ds:decode`` carries ``live_blocks`` beside ``active`` — the KV
+    blocks the paged kernel walks that iteration, ``ceil(context_len /
+    kv_block_size)`` a slot — telemetry on or off; the gauge
+    ``serving/kv_live_block_share`` (their share of the
+    ``max_batch_slots x max_blocks_per_seq`` a full-table gather read)
+    exists only with telemetry on, at the print cadence."""
+    config = {"steps_per_print": 1}
+    if telemetry_on:
+        config["telemetry"] = {"enabled": True,
+                               "run_dir": str(tmp_path / "run")}
+    engine = tiny_serving_engine(**config)   # 2 slots, blocks of 8, 64 max
+    engine.submit([1, 2, 3], request_id="warm")
+    engine.run()                             # compile outside
+    shares = []
+
+    def serve():
+        engine.submit(list(range(1, 7)), request_id="a")     # 6 tokens
+        engine.submit(list(range(1, 12)), request_id="b")    # 11 tokens
+        while not engine.scheduler.idle():
+            engine.step()
+            shares.append(engine.telemetry.registry.gauge(
+                "serving/kv_live_block_share").value
+                if telemetry_on else None)
+
+    spans = profile(tmp_path / "profile", serve)
+    decodes = [s[3] for s in spans if s[0] == "ds:decode"]
+    # contexts at the three decode iterations: a 7, 8, 9 -> 1, 1, 2 blocks
+    # of 8; b 12, 13, 14 -> 2 blocks each
+    assert [d["live_blocks"] for d in decodes] == [3, 3, 4]
+    assert [d["active"] for d in decodes] == [2, 2, 2]
+    if telemetry_on:
+        # 2 slots x 8 blocks a sequence; one iteration a window
+        assert shares[:3] == [3 / 16, 3 / 16, 4 / 16]
+    else:
+        # no registry at all: the count is one integer sum an iteration
+        assert not engine.telemetry.enabled
+        assert engine.telemetry.registry is None
+    engine.close()
+
+
+def test_scheduler_counts_live_blocks_of_a_hand_built_slot_state():
+    from deepspeed_tpu.inference import (BlockAllocator,
+                                         ContinuousBatchScheduler,
+                                         DeepSpeedInferenceConfig, Request)
+
+    icfg = DeepSpeedInferenceConfig({"inference": {
+        "kv_block_size": 8, "kv_blocks": 64, "max_batch_slots": 4,
+        "max_seq_len": 64, "prefill_buckets": [8, 16, 32],
+        "token_budget": 256}})
+    sched = ContinuousBatchScheduler(icfg, BlockAllocator(icfg.kv_blocks))
+    assert sched.live_blocks() == 0
+    # context_len (prompt + generated) 1, 8, 9 and an empty slot:
+    # ceil(./8) = 1, 1, 2 — the new token's own position is in the count
+    for slot, (n_prompt, n_generated) in enumerate([(1, 0), (5, 3), (8, 1)]):
+        request = Request(f"r{slot}", list(range(n_prompt)), 16)
+        request.generated = list(range(n_generated))
+        sched.slots[slot] = request
+    assert sched.live_blocks() == 1 + 1 + 2
+
+
 # -------------------------------------------------------- engine wiring
 def test_engine_zero_added_host_syncs(cpu_devices, tmp_path, monkeypatch):
     """The acceptance guarantee: telemetry adds ZERO host syncs — the

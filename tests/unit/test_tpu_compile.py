@@ -27,6 +27,8 @@ from deepspeed_tpu.ops.transformer.attention import (  # noqa: E402
     dot_product_attention)
 from deepspeed_tpu.ops.transformer.flash_attention import (  # noqa: E402
     flash_attention)
+from deepspeed_tpu.ops.transformer.paged_attention import (  # noqa: E402
+    check_tpu_geometry, paged_decode_attention)
 
 
 @pytest.fixture(scope="module")
@@ -118,3 +120,45 @@ def test_serving_decode_attention_compiles(v5e):
         lambda q, k, v, m: dot_product_attention(q, k, v, key_padding_mask=m),
         q, kv, kv, visible)
     assert "tpu_custom_call" not in text
+
+
+@pytest.mark.parametrize("hidden,heads,block_size,dtype,slots,ambient", [
+    (1280, 20, 64, jnp.bfloat16, 16, None),  # gpt2_large.backlog, chip_smoke
+    (1280, 20, 64, jnp.float32, 16, None),   # weights_dtype unset
+    (1280, 20, 64, jnp.bfloat16, 16, "float32"),  # as test_tpu_kernels sets
+    (1280, 20, 8, jnp.bfloat16, 2, None),    # half a bf16 tile of rows a page
+    (768, 12, 16, jnp.bfloat16, 1, None),    # GPT-2-small, one slot
+    (1600, 25, 64, jnp.bfloat16, 16, None),  # GPT-2-xl: refused, and said so
+], ids=["large-bf16", "large-fp32", "large-bf16-ambient-fp32", "large-bs8",
+        "small-1slot", "xl"])
+def test_paged_decode_kernel_compiles(v5e, hidden, heads, block_size, dtype,
+                                      slots, ambient):
+    """The decode kernel over the whole cache in place: scalar-prefetched
+    layer and tables, page DMAs out of an ``ANY``-space operand, the
+    block-diagonal matmuls — also under an ambient fp32 matmul precision,
+    which Mosaic refuses for bf16 operands unless the kernel pins its
+    own.  ``check_tpu_geometry`` agrees with the compiler on what cannot
+    be tiled."""
+    layers, blocks, per_seq = 4, 40, 16
+
+    def shape(dims, dt):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=v5e)
+
+    args = (shape((slots, hidden), dtype),
+            shape((layers, blocks, block_size, hidden), dtype),
+            shape((layers, blocks, block_size, hidden), dtype),
+            shape((slots, per_seq), jnp.int32), shape((slots,), jnp.int32))
+
+    def attend(q, k_cache, v_cache, tables, ctx_lens):
+        return paged_decode_attention(q, k_cache, v_cache, tables, ctx_lens,
+                                      layer=3, num_heads=heads)
+
+    try:
+        check_tpu_geometry(hidden, block_size)
+    except ValueError:
+        with pytest.raises(Exception, match="aligned to tiling"):
+            _compile(attend, *args)
+        return
+    with jax.default_matmul_precision(ambient or "default"):
+        text = _compile(attend, *args)
+    assert "tpu_custom_call" in text

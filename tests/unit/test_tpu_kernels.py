@@ -14,6 +14,11 @@ import pytest
 
 from deepspeed_tpu.ops.transformer.attention import reference_attention
 from deepspeed_tpu.ops.transformer.flash_attention import flash_attention
+from deepspeed_tpu.ops.transformer.paged_attention import (
+    paged_decode_attention)
+
+from .test_paged_attention import oracle as paged_oracle
+from .test_paged_attention import slot_state
 
 pytestmark = pytest.mark.tpu
 
@@ -154,3 +159,28 @@ def test_compiled_flash_exp2_matches_exp(monkeypatch):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-4, rtol=2e-4,
                                    err_msg=f"d{name} mismatch")
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-4),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("slots,block_size", [(16, 64), (2, 16)])
+def test_compiled_paged_decode_attention(slots, block_size, dtype, tol):
+    """The decode kernel at GPT-2-large width (20 heads of 64), compiled:
+    mixed context lengths, shuffled tables, two dead slots on the null
+    block, against the full-table gather."""
+    heads, hidden, layers, per_seq = 20, 1280, 2, 16
+    dead = (1, 3) if slots > 3 else ()
+    n_blocks, tables, ctx = slot_state(slots, block_size, dead, seed=2,
+                                       max_blocks=per_seq)
+    keys = jax.random.split(jax.random.PRNGKey(slots), 3)
+    shape = (layers, n_blocks, block_size, hidden)
+    k_cache = jax.random.normal(keys[0], shape, dtype)
+    v_cache = jax.random.normal(keys[1], shape, dtype)
+    q = jax.random.normal(keys[2], (slots, hidden), dtype)
+    got = paged_decode_attention(q, k_cache, v_cache, tables, ctx, layer=1,
+                                 num_heads=heads)
+    want = paged_oracle(q, k_cache, v_cache, 1, tables, ctx, heads=heads)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
