@@ -6,8 +6,8 @@ program dumper, DSP6xx verifier, attribution doctor, EVENT telemetry).
 from .config import DeepSpeedInferenceConfig
 from .engine import DECODE_PROGRAM, InferenceEngine, prefill_program_name
 from .frontend import ServingFrontend, ServingOverloadError
-from .kv_cache import (NULL_BLOCK, BlockAllocator, init_kv_cache,
-                       kv_cache_bytes)
+from .kv_cache import (NULL_BLOCK, BlockAllocator, cache_bytes,
+                       init_cache_buffers, init_kv_cache, kv_cache_bytes)
 from .model import build_decode, build_prefill, reference_generate
 from .observability import (SERVING_PHASE_KEYS,
                             SERVING_TRACE_SCHEMA_VERSION,
@@ -20,7 +20,8 @@ from .scheduler import (ContinuousBatchScheduler, Request, REASON_DEADLINE,
 __all__ = ["DeepSpeedInferenceConfig", "DECODE_PROGRAM", "InferenceEngine",
            "prefill_program_name", "ServingFrontend",
            "ServingOverloadError", "NULL_BLOCK", "BlockAllocator",
-           "init_kv_cache", "kv_cache_bytes", "build_decode",
+           "init_kv_cache", "kv_cache_bytes", "init_cache_buffers",
+           "cache_bytes", "build_decode",
            "build_prefill", "reference_generate", "ServingHealth",
            "arm_serving_preemption", "serving_hang_quorum",
            "ContinuousBatchScheduler", "Request", "REASON_DEADLINE",

@@ -1,13 +1,17 @@
-"""InferenceEngine: continuous-batching serving over a paged KV cache,
+"""InferenceEngine: continuous-batching serving over a paged cache,
 priced and verified by the training-side toolchain.
+
+The engine owns scheduling, blocks, donation and the ``ds:`` spans; the
+served model (``inference/model.py`` has the interface) says what cache
+buffers a layer keeps and builds the programs over them.
 
 Program split (all shapes static, all programs ledgered):
 
 - ``serve_prefill_<bucket>`` — one per declared prefill bucket, compiled
   on first use; cache buffers donated.
 - ``serve_decode`` — ONE fixed-width program for the whole serve; cache
-  buffers donated, so the per-token K/V append is one in-place scatter
-  a layer that XLA aliases onto the input allocation, and the paged
+  buffers donated, so the per-token append is one in-place scatter a
+  layer and buffer that XLA aliases onto the input allocation, and the paged
   attention kernel reads the live blocks of that same buffer
   (``engine.verify_programs()`` proves the ``input_output_alias``
   materialized — DSP601; a silently-copied cache is the classic decode
@@ -30,9 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.gpt2 import GPT2LMHeadTPU
 from ..module_inject.replace_module import cast_weights
-from ..ops.transformer.paged_attention import check_tpu_geometry
 from ..parallel.mesh import current_platform
 from ..profiling.comm import CommLedger, SERVE_DECODE_PROGRAM
 from ..profiling.memory import MemoryLedger
@@ -45,8 +47,7 @@ from ..telemetry.config import DeepSpeedTelemetryConfig
 from ..telemetry.manager import TelemetryManager
 from ..utils.logging import logger
 from .config import DeepSpeedInferenceConfig
-from .kv_cache import BlockAllocator, init_kv_cache
-from .model import build_decode, build_prefill
+from .kv_cache import NULL_BLOCK, BlockAllocator, init_cache_buffers
 from .observability import ServingObservability, mint_trace_id
 from .scheduler import ContinuousBatchScheduler, Request
 
@@ -60,15 +61,17 @@ def prefill_program_name(bucket):
 
 
 class InferenceEngine:
-    """Serve a GPT-2 family model with continuous batching.
+    """Serve a model with continuous batching.
 
-    ``model`` is a :class:`~deepspeed_tpu.models.gpt2.GPT2LMHeadTPU`
-    (or anything exposing ``.config`` with the same geometry fields);
-    ``params`` its parameter pytree (use
-    :func:`~deepspeed_tpu.module_inject.ingest_gpt2_model` to convert an
-    HF Flax checkpoint).  ``config`` is the usual DeepSpeed config dict;
-    the ``inference`` block is DSC4xx-schema-validated like every other
-    section.
+    ``model`` exposes ``.config`` (``max_position_embeddings``) and
+    ``.serving()``, its side of the served-model interface
+    (``inference/model.py``): :class:`~deepspeed_tpu.models.gpt2.
+    GPT2LMHeadTPU`, :class:`~deepspeed_tpu.models.deepseek_v2.
+    DeepseekV2ForServing`.  ``params`` is its parameter pytree (for an HF
+    Flax GPT-2 checkpoint see :meth:`from_hf_gpt2`); leaves already in
+    the serving dtype are taken as they are, not copied.  ``config`` is
+    the usual DeepSpeed config dict; the ``inference`` block is
+    DSC4xx-schema-validated like every other section.
     """
 
     def __init__(self, model, params, config=None):
@@ -95,14 +98,20 @@ class InferenceEngine:
         self.params = jax.device_put(params)
         cache_dtype = (jnp.bfloat16 if icfg.weights_dtype == "bfloat16"
                        else jnp.float32)
+        serving = self.serving = model.serving()
         if current_platform() == "tpu":
             # a geometry the decode kernel cannot tile fails here, at
             # construction, never by a silent second path
-            check_tpu_geometry(mc.hidden_size, icfg.kv_block_size)
-        self._k_cache, self._v_cache = init_kv_cache(
-            mc.num_layers, icfg.kv_blocks, icfg.kv_block_size,
-            mc.num_heads, mc.hidden_size // mc.num_heads,
-            dtype=cache_dtype)
+            serving.check_tpu_geometry(icfg)
+        buffers = serving.cache_buffers(icfg)
+        self._caches = init_cache_buffers(
+            serving.num_layers, icfg.kv_blocks, icfg.kv_block_size,
+            tuple(buffers.values()), dtype=cache_dtype)
+        # bytes one live block holds in each buffer (the live-bytes gauges)
+        self.cache_block_bytes = {
+            name: serving.num_layers * icfg.kv_block_size * row
+            * jnp.dtype(cache_dtype).itemsize
+            for name, row in buffers.items()}
         self.allocator = BlockAllocator(icfg.kv_blocks)
         self.scheduler = ContinuousBatchScheduler(icfg, self.allocator)
 
@@ -133,18 +142,27 @@ class InferenceEngine:
                 context_fn=self.program_verify_context,
                 donation_fn=lambda name: self._donation_specs.get(name))
 
-        # -- compiled programs (cache args 1/2 donated everywhere) -------
-        self._donation_specs = {DECODE_PROGRAM: (1, 2)}
+        # -- compiled programs (argument 1, the tuple of cache buffers,
+        # donated everywhere) ---------------------------------------------
+        self._donation_specs = {DECODE_PROGRAM: (1,)}
         self._decode = self.memory_ledger.wrap(
             DECODE_PROGRAM,
-            jax.jit(build_decode(mc, icfg), donate_argnums=(1, 2)))
+            jax.jit(serving.build_decode(icfg), donate_argnums=(1,)))
         self._prefills = {}
         for bucket in icfg.prefill_buckets:
             name = prefill_program_name(bucket)
-            self._donation_specs[name] = (1, 2)
+            self._donation_specs[name] = (1,)
             self._prefills[bucket] = self.memory_ledger.wrap(
-                name, jax.jit(build_prefill(mc, icfg, bucket),
-                              donate_argnums=(1, 2)))
+                name, jax.jit(serving.build_prefill(icfg, bucket),
+                              donate_argnums=(1,)))
+        # the decode program's host tables, kept between iterations: a
+        # slot's row changes only when its request does
+        self._tables = np.full(
+            (icfg.max_batch_slots, icfg.max_blocks_per_seq), NULL_BLOCK,
+            np.int32)
+        self._table_owner = [None] * icfg.max_batch_slots
+        # the scalar counters the model's decode program last reported
+        self.model_counters = {}
 
         self._step_latencies = StepLatencyRing()
         self._driver_latencies = StepLatencyRing()
@@ -171,11 +189,12 @@ class InferenceEngine:
                                     "prefill_buckets": list(
                                         icfg.prefill_buckets)})
         logger.info(
-            "InferenceEngine: %d layers, %d slots, %d KV blocks x %d "
-            "tokens, prefill buckets %s, weights %s",
-            mc.num_layers, icfg.max_batch_slots, icfg.kv_blocks,
-            icfg.kv_block_size, list(icfg.prefill_buckets),
-            icfg.weights_dtype)
+            "InferenceEngine: %s, %d layers, %d slots, %d blocks x %d "
+            "tokens of %s, prefill buckets %s, weights %s",
+            type(model).__name__, serving.num_layers, icfg.max_batch_slots,
+            icfg.kv_blocks, icfg.kv_block_size,
+            " + ".join(f"{n}[{w}]" for n, w in buffers.items()),
+            list(icfg.prefill_buckets), icfg.weights_dtype)
 
     @staticmethod
     def _validate_config(param_dict):
@@ -197,6 +216,7 @@ class InferenceEngine:
         ``module_inject`` (fused-layer injection + embedding remap),
         then the standard constructor (which applies the configured
         serve dtype)."""
+        from ..models.gpt2 import GPT2LMHeadTPU
         from ..module_inject import ingest_gpt2_model
 
         params = ingest_gpt2_model(hf_params)
@@ -282,12 +302,11 @@ class InferenceEngine:
                 ids[0, :len(request.prompt)] = request.prompt
                 table = np.asarray(sched.block_table_row(request), np.int32)
             with span("prefill.dispatch"):
-                first, self._k_cache, self._v_cache = self._prefills[
-                    request.bucket](self.params, self._k_cache,
-                                    self._v_cache, jnp.asarray(ids),
-                                    jnp.int32(len(request.prompt)), table)
+                out, self._caches = self._prefills[request.bucket](
+                    self.params, self._caches, jnp.asarray(ids),
+                    jnp.int32(len(request.prompt)), table)
             with span("prefill.fetch"):
-                token = int(jax.device_get(first))
+                token = int(jax.device_get(out)["tokens"])
             with span("prefill.account"):
                 now = self._clock()
                 # the TTFT is first_token_at - submitted; step_times
@@ -323,15 +342,22 @@ class InferenceEngine:
         with span("decode", active=active, live_blocks=live_blocks):
             with span("decode.prep"):
                 t_prep = self._clock()
-                width = icfg.max_blocks_per_seq
-                tables = np.zeros((icfg.max_batch_slots, width), np.int32)
+                tables, owner = self._tables, self._table_owner
                 ctx_lens = np.zeros((icfg.max_batch_slots,), np.int32)
                 tokens = np.zeros((icfg.max_batch_slots,), np.int32)
                 before = []
-                for request in sched.slots:
+                for slot, request in enumerate(sched.slots):
+                    grant = None if request is None else request.blocks
+                    if grant is not owner[slot]:
+                        # a block grant (a list made at admission) stays
+                        # as it is until the request finishes, so a row is
+                        # rewritten only when the slot's grant changes (a
+                        # freed slot goes back to the null block)
+                        tables[slot] = (NULL_BLOCK if request is None else
+                                        sched.block_table_row(request))
+                        owner[slot] = grant
                     if request is None:
                         continue
-                    tables[request.slot] = sched.block_table_row(request)
                     # position of the token being decoded = current
                     # context - 1 (the last generated token is the
                     # decode input)
@@ -352,19 +378,21 @@ class InferenceEngine:
             with span("decode.dispatch"):
                 # returns when the three tables are copied and the
                 # program is enqueued, not when it has run
-                next_dev, self._k_cache, self._v_cache = self._decode(
-                    self.params, self._k_cache, self._v_cache, tables,
-                    ctx_lens, tokens)
+                out_dev, self._caches = self._decode(
+                    self.params, self._caches, tables, ctx_lens, tokens)
             with span("decode.fetch"):
                 # ONE host sync per decode iteration, cadence or not:
                 # the weight fingerprint (when due) rides the same
                 # batched fetch as the sampled tokens, so arming the
                 # resilience plane adds zero device_get calls (the
                 # zero-added-syncs test counts them)
-                fetched = jax.device_get((next_dev,) if fp_dev is None
-                                         else (next_dev, fp_dev))
+                fetched = jax.device_get((out_dev,) if fp_dev is None
+                                         else (out_dev, fp_dev))
             with span("decode.account"):
-                next_tokens = fetched[0]
+                # the tokens, and whatever scalar counters the model's
+                # program reported in the same fetch
+                self.model_counters = dict(fetched[0])
+                next_tokens = self.model_counters.pop("tokens")
                 if fp_dev is not None:
                     self._pending_fingerprint = int(fetched[1])
                 now = self._clock()
@@ -403,6 +431,8 @@ class InferenceEngine:
             float(self.allocator.free_blocks))
         self.telemetry.gauge("serving/generated_tokens").set(
             float(self.generated_tokens))
+        for key, value in self.model_counters.items():
+            self.telemetry.gauge(f"serving/{key}").set(float(value))
         self.telemetry.emit(
             TEL.EVENT_SERVING, step=self.decode_iterations, kind="queue",
             queue_depth=sched.queue_depth, active=sched.active_count,
@@ -600,8 +630,8 @@ class InferenceEngine:
             "device_kind": getattr(jax.devices()[0], "device_kind", ""),
             # declared sharding (profiling/sharding, DSS8xx): single-
             # replica serving declares everything replicated on a
-            # 1-wide data axis — weights as the params family, the two
-            # paged KV buffers as kv_cache — so the decode program's
+            # 1-wide data axis — weights as the params family, the
+            # paged cache buffers as kv_cache — so the decode program's
             # residency still gets a priced receipt
             "declared_sharding": self._declared_sharding(leaves),
         }
@@ -616,7 +646,7 @@ class InferenceEngine:
                     for l in param_leaves),
                 "kv_cache": sharding_prof.build_declared_family(
                     (int(np.prod(c.shape)) * c.dtype.itemsize, [], 1)
-                    for c in (self._k_cache, self._v_cache)),
+                    for c in self._caches),
             }
             return {"tag": "serve|data1", "mesh_axes": mesh_axes,
                     "families": families}

@@ -1,18 +1,22 @@
-"""Paged KV cache: preallocated device buffers + a host-side block
+"""Paged cache: preallocated device buffers + a host-side block
 allocator (vLLM-style block tables, adapted to XLA static shapes).
 
-The cache is two device arrays of fixed shape
+The cache is one device array per buffer the served model names
+(``inference/model.py``: GPT-2 keeps ``k_cache`` and ``v_cache`` with
+rows of ``heads * head_dim``, a latent-attention model ONE buffer whose
+row is its compressed key/value and shared rotary key), each of shape
 
-    ``[layers, kv_blocks, kv_block_size, heads * head_dim]``
+    ``[layers, kv_blocks, kv_block_size, row width]``
 
 allocated ONCE at engine construction.  The layout is the decode
-kernel's (``ops/transformer/paged_attention.py``): a page is a dense
-``[kv_block_size, hidden]`` tile whatever the head width (with
-``head_dim`` 64 as the minor dimension every page would be padded to
-128 lanes: the GPT-2-large cache measured 1.5x its logical bytes on a
-v5e in the old ``[..., heads, head_dim]`` layout, 1.0x in this one), and
-a token's K or V is one row — what the fused-QKV projection emits, so
-the append is a row write.  Sequences never own contiguous cache
+kernels' (``ops/transformer/paged_attention.py``,
+``mla_paged_attention.py``): a page is a dense ``[kv_block_size, row]``
+tile whatever the head width (with ``head_dim`` 64 as the minor
+dimension every page would be padded to 128 lanes: the GPT-2-large
+cache measured 1.5x its logical bytes on a v5e in the old ``[..., heads,
+head_dim]`` layout, 1.0x in this one), and a token's entry is one row —
+what the projection emits, so the append is a row write.  The same block
+table serves every buffer.  Sequences never own contiguous cache
 memory: each holds a *block table* (host list of block ids); prefill
 scatters whole pages through it, decode scatters one row a slot and the
 paged kernel fetches the live pages by id.  Both programs
@@ -84,17 +88,34 @@ class BlockAllocator:
             self._free.append(int(b))
 
 
+def init_cache_buffers(num_layers, num_blocks, block_size, row_widths,
+                       dtype=jnp.float32):
+    """One zero-initialized device buffer ``[layers, blocks, block, row]``
+    per entry of ``row_widths``, in the layout the decode kernels read
+    (see the module docstring)."""
+    return tuple(jnp.zeros((num_layers, num_blocks, block_size, row), dtype)
+                 for row in row_widths)
+
+
 def init_kv_cache(num_layers, num_blocks, block_size, heads, head_dim,
                   dtype=jnp.float32):
-    """The (k, v) cache device buffers, zero-initialized, in the layout
-    the decode kernel reads (see the module docstring)."""
-    shape = (num_layers, num_blocks, block_size, heads * head_dim)
-    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+    """The (k, v) buffers of a model that caches per-head keys and
+    values: two buffers with rows of ``heads * head_dim``."""
+    return init_cache_buffers(num_layers, num_blocks, block_size,
+                              (heads * head_dim,) * 2, dtype)
+
+
+def cache_bytes(num_layers, num_blocks, block_size, row_widths,
+                dtype=jnp.float32):
+    """Footprint of one engine's cache buffers, whatever their rows
+    (capacity-planning aid)."""
+    return num_layers * num_blocks * block_size * sum(row_widths) \
+        * jnp.dtype(dtype).itemsize
 
 
 def kv_cache_bytes(num_layers, num_blocks, block_size, heads, head_dim,
                    dtype=jnp.float32):
-    """Footprint of one engine's K+V buffers (capacity-planning aid)."""
-    itemsize = jnp.dtype(dtype).itemsize
-    return 2 * num_layers * num_blocks * block_size * heads * head_dim \
-        * itemsize
+    """:func:`cache_bytes` of K+V buffers with rows of ``heads *
+    head_dim``."""
+    return cache_bytes(num_layers, num_blocks, block_size,
+                       (heads * head_dim,) * 2, dtype)

@@ -1,7 +1,30 @@
-"""Prefill/decode forwards over a GPT-2 param tree with a paged KV cache.
+"""The served-model interface, and GPT-2's side of it.
 
-Two program families, both closed over the static model/cache geometry
-so every shape in the traced graph is fixed:
+:class:`~deepspeed_tpu.inference.engine.InferenceEngine` owns scheduling,
+blocks, donation and the ``ds:`` spans; it does not know a layer.  A model
+it can serve has a ``serving()`` method that returns an object with:
+
+- ``num_layers``;
+- ``cache_buffers(icfg) -> {name: row width}``: the paged buffers a layer
+  keeps, each ``[layers, kv_blocks, kv_block_size, row width]`` in the
+  serving dtype, allocated once by the engine and DONATED to every program
+  (GPT-2: ``k_cache`` and ``v_cache`` rows of ``hidden``; DeepSeek-V2: one
+  ``latent_cache`` row of 512 + 64, ``models/deepseek_v2.py``);
+- ``check_tpu_geometry(icfg)``: raise for a cache its decode kernel cannot
+  tile on a TPU (called at construction there, never a second path);
+- ``build_prefill(icfg, bucket) -> prefill(params, caches, input_ids[1, S],
+  true_len, block_table) -> (out, caches)``, one program a bucket, and
+  ``build_decode(icfg) -> decode(params, caches, block_tables, ctx_lens,
+  tokens) -> (out, caches)``, one program for the serve; ``caches`` is the
+  tuple of buffers in ``cache_buffers`` order, ``out`` a dict the engine
+  fetches whole in its one sync: ``"tokens"`` (prefill: the first token;
+  decode: ``[slots]``) and any scalar counters the model reports
+  (published as ``serving/<key>`` gauges on the print cadence).  The
+  functions are NAMED ``prefill`` and ``decode``: a device trace names a
+  compiled program after its function.
+
+The rest of this file is GPT-2: two program families, both closed over the
+static model/cache geometry so every shape in the traced graph is fixed:
 
 - ``prefill``: one request, padded to a declared bucket length — full
   causal self-attention over the padded prompt, per-layer K/V written
@@ -20,9 +43,8 @@ so every shape in the traced graph is fixed:
   occupancy.  On a non-TPU platform the same kernel runs through
   Pallas' interpreter.
 
-The cache layout is ``[layers, blocks, block_size, heads * head_dim]``
-(:mod:`.kv_cache`): a token's K or V is one row, exactly what the
-fused-QKV projection emits.
+GPT-2's cache rows are ``heads * head_dim`` wide (:mod:`.kv_cache`): a
+token's K or V is one row, exactly what the fused-QKV projection emits.
 
 The math mirrors :class:`~deepspeed_tpu.models.layers.TransformerLayer`
 (pre-LN path) and :meth:`~deepspeed_tpu.models.gpt2.GPT2LMHeadTPU.hidden`
@@ -38,7 +60,8 @@ import numpy as np
 
 from ..models.layers import dense, gelu, layer_norm
 from ..ops.transformer.attention import dot_product_attention
-from ..ops.transformer.paged_attention import paged_decode_attention
+from ..ops.transformer.paged_attention import (check_tpu_geometry,
+                                               paged_decode_attention)
 from ..parallel.mesh import current_platform
 
 
@@ -142,6 +165,45 @@ def build_decode(model_config, icfg):
             k_cache, v_cache
 
     return decode
+
+
+class GPT2Serving:
+    """GPT-2's side of the served-model interface (the module docstring):
+    K and V buffers with rows of ``hidden``, :func:`build_prefill` and
+    :func:`build_decode` under the interface's calling convention — the
+    same operations in the same order, so the compiled programs are the
+    ones the two functions always gave."""
+
+    def __init__(self, model_config):
+        self.config = model_config
+        self.num_layers = model_config.num_layers
+
+    def cache_buffers(self, icfg):
+        return {"k_cache": self.config.hidden_size,
+                "v_cache": self.config.hidden_size}
+
+    def check_tpu_geometry(self, icfg):
+        check_tpu_geometry(self.config.hidden_size, icfg.kv_block_size)
+
+    def build_prefill(self, icfg, bucket_len):
+        inner = build_prefill(self.config, icfg, bucket_len)
+
+        def prefill(params, caches, input_ids, true_len, block_table):
+            token, k_cache, v_cache = inner(params, *caches, input_ids,
+                                            true_len, block_table)
+            return {"tokens": token}, (k_cache, v_cache)
+
+        return prefill
+
+    def build_decode(self, icfg):
+        inner = build_decode(self.config, icfg)
+
+        def decode(params, caches, block_tables, ctx_lens, tokens):
+            next_tokens, k_cache, v_cache = inner(
+                params, *caches, block_tables, ctx_lens, tokens)
+            return {"tokens": next_tokens}, (k_cache, v_cache)
+
+        return decode
 
 
 def reference_generate(model, params, prompt, max_new_tokens,

@@ -289,6 +289,13 @@ class ServingObservability:
             self._win_live_blocks
             / (iters * icfg.max_batch_slots * icfg.max_blocks_per_seq)
             if iters else 0.0)
+        # the same live blocks as bytes of each of the model's buffers
+        # (GPT-2: serving/k_cache_live_bytes and v_cache's; a latent-
+        # attention model: serving/latent_cache_live_bytes)
+        for name, block_bytes in self.engine.cache_block_bytes.items():
+            gauge(f"serving/{name}_live_bytes").set(
+                block_bytes * self._win_live_blocks / iters
+                if iters else 0.0)
         gauge("serving/slo_attainment").set(attainment)
         gauge("serving/goodput_tokens_per_second").set(
             self._win_good_tokens / window)

@@ -110,6 +110,12 @@ class GPT2LMHeadTPU:
                 attn_dropout_checkpoint=config.attn_dropout_checkpoint,
                 normalize_invertible=config.normalize_invertible)
 
+    def serving(self):
+        """This model's side of ``InferenceEngine``'s model interface."""
+        from ..inference.model import GPT2Serving
+
+        return GPT2Serving(self.config)
+
     def _is_moe_layer(self, i):
         c = self.config
         return bool(c.moe_experts) and i % c.moe_every == c.moe_every - 1

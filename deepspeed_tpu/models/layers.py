@@ -54,6 +54,34 @@ def layer_norm(params, x, eps=1e-12):
     return (y * params["scale"] + params["bias"]).astype(x.dtype)
 
 
+def rms_norm(params, x, eps=1e-6):
+    """RMSNorm (no mean, no bias) in fp32, cast back to ``x``'s dtype."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1,
+                                     keepdims=True) + eps)
+    return (y * params["scale"].astype(jnp.float32)).astype(x.dtype)
+
+
+def gated_silu_mlp(params, x, out_dtype=None):
+    """``down(silu(gate x) * up x)`` with gate and up fused in one
+    ``gate_up`` kernel ``[in, 2 * width]`` (gate first), no biases; the
+    last product's accumulator comes back as ``out_dtype`` (``x``'s by
+    default)."""
+    width = params["down"]["kernel"].shape[0]
+    gu = x @ params["gate_up"]["kernel"].astype(x.dtype)
+    return jnp.matmul(gated_silu(gu, width),
+                      params["down"]["kernel"].astype(x.dtype),
+                      preferred_element_type=out_dtype or x.dtype)
+
+
+def gated_silu(gate_up, width):
+    """``silu(gate) * up`` of a fused ``[..., 2 * width]`` product, the
+    activation in fp32."""
+    gate = gate_up[..., :width].astype(jnp.float32)
+    return (jax.nn.silu(gate) * gate_up[..., width:].astype(
+        jnp.float32)).astype(gate_up.dtype)
+
+
 def gelu(x):
     # tanh approximation: matches the reference kernel (gelu_kernels.cu) and
     # keeps everything elementwise-fusable.

@@ -185,12 +185,13 @@ def ingest_gpt2_model(hf_params):
 def cast_weights(params, dtype):
     """Cast every floating-point leaf of a param tree to ``dtype``
     (serving-time bf16 ingestion; integer leaves — e.g. token tables —
-    pass through untouched)."""
+    pass through untouched, and so does a leaf that already has the
+    dtype: no second copy of weights handed over ready)."""
     import jax
 
     def cast(leaf):
         arr = jnp.asarray(leaf)
-        if jnp.issubdtype(arr.dtype, jnp.floating):
+        if jnp.issubdtype(arr.dtype, jnp.floating) and arr.dtype != dtype:
             return arr.astype(dtype)
         return arr
 

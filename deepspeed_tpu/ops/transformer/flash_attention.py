@@ -450,6 +450,18 @@ def flash_attention(q, k, v, kv_mask=None, dropout_seed=None, causal=False,
     return out
 
 
+def flash_attention_forward(q, k, v, *, causal, block_q, block_k,
+                            interpret=False, name=None):
+    """The forward kernel alone, for serving: ``q``, ``k`` [b, s, h, d] and
+    ``v`` [b, s, h, dv] with a value width of its own, blocks given by the
+    caller (no first-use tuning inside a served program), softmax scale
+    1/sqrt(d) (fold any other factor into ``q``).  ``name`` is the kernel's
+    name in a device trace."""
+    out, _ = _flash_fwd(q, k, v, None, None, causal, block_q, block_k,
+                        interpret, 0.0, name=name)
+    return out
+
+
 def _mask_spec(h, block_k):
     # one [1, 1, block_k] mask slice per (batch·head, k block) program:
     # batch = i // h.  The singleton middle axis keeps the block's
@@ -526,9 +538,13 @@ def _grid_params(interpret):
 
 
 def _flash_fwd(q, k, v, kv_mask, dropout_seed, causal, block_q, block_k,
-               interpret, dropout_rate):
+               interpret, dropout_rate, name=None):
     b, s, h, d = q.shape
     kv_len = k.shape[1]
+    # the values may be narrower or wider than the keys (latent attention
+    # expands keys of 192 beside values of 128): the score tile is q.k over
+    # d, the accumulator and the output are dv wide
+    dv = v.shape[-1]
     block_q, block_k = _resolve_blocks(s, kv_len, d, block_q, block_k, causal,
                                        dropout_rate)
     masked = kv_mask is not None
@@ -555,24 +571,25 @@ def _flash_fwd(q, k, v, kv_mask, dropout_seed, causal, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda i, j, kb: (i, kb, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j, kb: (i, kb, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda i, j, kb: (i, kb, 0)),
             *seed_specs,
             *mask_specs,
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda i, j, kb: (i, j, 0)),
             pl.BlockSpec((1, 1, block_q), lambda i, j, kb: (i, 0, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, s, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, s), jnp.float32),
         ],
         scratch_shapes=[
             _VMEM((block_q, 1), jnp.float32),   # running max m
             _VMEM((block_q, 1), jnp.float32),   # running sum l
-            _VMEM((block_q, d), jnp.float32),   # output accumulator
+            _VMEM((block_q, dv), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
+        name=name,
         **_grid_params(interpret),
     )(qf, kf, vf, *seed_ops, *mask_ops)
     outh = _unflatten_heads(out, b, h)
@@ -587,6 +604,9 @@ def _flash_fwd_rule(q, k, v, kv_mask, dropout_seed, causal, block_q, block_k,
 
 
 def _flash_bwd_rule(causal, block_q, block_k, interpret, dropout_rate, res, g):
+    assert res[2].shape[-1] == res[0].shape[-1], (
+        "the flash backward kernels assume values as wide as keys; a "
+        "value width of its own is forward-only (flash_attention_forward)")
     q, k, v, kv_mask, dropout_seed, out, lse = res
     b, s, h, d = q.shape
     kv_len = k.shape[1]

@@ -1,0 +1,173 @@
+"""The pieces DeepSeek-V2's serving path is made of, each against a closed
+form or a case worked by hand (CPU, small widths)."""
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
+from deepspeed_tpu.inference.kv_cache import cache_bytes, kv_cache_bytes
+from deepspeed_tpu.models import GPT2Config, GPT2LMHeadTPU
+from deepspeed_tpu.models import expert_shard
+from deepspeed_tpu.models.deepseek_v2 import (DeepseekV2Config,
+                                              DeepseekV2ForServing, rotate,
+                                              yarn_inv_freq,
+                                              yarn_softmax_scale)
+from deepspeed_tpu.models.layers import gated_silu_mlp, rms_norm
+from deepspeed_tpu.module_inject.replace_module import cast_weights
+
+
+def test_yarn_frequencies_against_the_closed_form():
+    c = DeepseekV2Config()          # the published rope_scaling
+    inv = yarn_inv_freq(c)
+    assert inv.shape == (32,)
+    j = np.arange(32)
+    plain = 10000.0 ** (-2.0 * j / 64)
+    # correction dimensions of 32 and 1 rotations over 4096 positions
+    def dim(r):
+        return 64 * math.log(4096 / (r * 2 * math.pi)) / (2 * math.log(1e4))
+    low, high = math.floor(dim(32)), math.ceil(dim(1))
+    assert (low, high) == (10, 23)
+    ramp = np.clip((j - low) / (high - low), 0, 1)
+    np.testing.assert_allclose(inv, plain / 40 * ramp + plain * (1 - ramp),
+                               rtol=1e-6)
+    # fast dimensions are left alone, slow ones divided by the factor
+    np.testing.assert_allclose(inv[:11], plain[:11], rtol=1e-6)
+    np.testing.assert_allclose(inv[23:], plain[23:] / 40, rtol=1e-6)
+    m = 0.1 * 0.707 * math.log(40) + 1
+    assert m == pytest.approx(1.2608, abs=1e-4)
+    assert yarn_softmax_scale(c) == pytest.approx(m * m / math.sqrt(192))
+
+
+def test_rotation_turns_interleaved_pairs_and_keeps_dot_products():
+    c = DeepseekV2Config(qk_rope_head_dim=8, rope_scaling={
+        "type": "yarn", "factor": 1, "beta_fast": 32, "beta_slow": 1,
+        "mscale": 1.0, "mscale_all_dim": 1.0,
+        "original_max_position_embeddings": 64})
+    x = jnp.arange(8, dtype=jnp.float32)[None] + 1.0
+    pos = jnp.asarray([3])
+    got = np.asarray(rotate(x, pos, c))[0]
+    inv = 10000.0 ** (-np.arange(0, 8, 2) / 8)
+    cos, sin = np.cos(3 * inv), np.sin(3 * inv)
+    even, odd = np.arange(1, 9, 2.0), np.arange(2, 10, 2.0)
+    # pairs (x0, x1), (x2, x3), ... turn; first halves then second halves
+    np.testing.assert_allclose(got[:4], even * cos - odd * sin, rtol=1e-5)
+    np.testing.assert_allclose(got[4:], odd * cos + even * sin, rtol=1e-5)
+    # q.k depends on the distance alone
+    q = jax.random.normal(jax.random.PRNGKey(0), (1, 8))
+    k = jax.random.normal(jax.random.PRNGKey(1), (1, 8))
+    def dot(pq, pk):
+        return float(jnp.sum(rotate(q, jnp.asarray([pq]), c)
+                             * rotate(k, jnp.asarray([pk]), c)))
+    assert dot(5, 2) == pytest.approx(dot(40, 37), rel=1e-4)
+    # one rotation serves every head of a token
+    per_head = rotate(jnp.stack([x, 2 * x], axis=1), pos, c)
+    np.testing.assert_allclose(np.asarray(per_head[0, 1]), 2 * got,
+                               rtol=1e-5)
+
+
+def test_group_limited_routing_worked_by_hand():
+    # 8 experts in 4 groups of 2, keep 2 groups, choose 3
+    scores = jnp.asarray([
+        # g0        g1        g2        g3
+        [.30, .01, .02, .25, .20, .19, .02, .01],
+        [.05, .05, .40, .01, .10, .30, .05, .04]])
+    weights, ids = expert_shard.group_limited_topk(scores, 4, 2, 3)
+    # token 0: groups by best expert .30 .25 .20 .02 -> g0, g1; of their
+    # experts (.30 .01 .02 .25) the best three — .20 and .19 of g2 are
+    # larger than .02 but their group is out
+    assert ids[0].tolist() == [0, 3, 2]
+    np.testing.assert_allclose(weights[0], [.30, .25, .02])
+    # token 1: groups .05 .40 .30 .05 -> g1, g2: .40, .30, .10
+    assert ids[1].tolist() == [2, 5, 4]
+    np.testing.assert_allclose(weights[1], [.40, .30, .10])
+
+
+def _experts(held, hidden, width, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 2)
+    return {"gate_up": 0.3 * jax.random.normal(
+                keys[0], (held, hidden, 2 * width)),
+            "down": 0.3 * jax.random.normal(keys[1], (held, width, hidden))}
+
+
+def _expert_ffn(experts, e, x):
+    return gated_silu_mlp({"gate_up": {"kernel": experts["gate_up"][e]},
+                           "down": {"kernel": experts["down"][e]}}, x)
+
+
+@pytest.mark.parametrize("tokens", [5, 40])
+def test_no_token_is_dropped_when_every_token_picks_one_expert(tokens):
+    """GShard capacity routing would drop all but ``capacity`` of them."""
+    held, hidden, width, top_k = 4, 16, 8, 2
+    experts = _experts(held, hidden, width)
+    x = jax.random.normal(jax.random.PRNGKey(3), (tokens, hidden))
+    ids = jnp.broadcast_to(jnp.asarray([2, 9]), (tokens, top_k))  # 9: away
+    weights = jnp.full((tokens, top_k), 0.5)
+    y, counts = expert_shard.held_experts_ffn(
+        x, weights, ids, jnp.ones((tokens,), bool), experts,
+        first_expert=0, interpret=True, tiling=(16, 128, 128))
+    np.testing.assert_allclose(np.asarray(y),
+                               0.5 * np.asarray(_expert_ffn(experts, 2, x)),
+                               rtol=1e-4, atol=1e-5)
+    assert counts.tolist() == [0, 0, tokens, 0, tokens]
+    share, peak = expert_shard.load_counters(counts)
+    assert float(share) == pytest.approx(0.5)
+    assert float(peak) == pytest.approx(4.0)
+
+
+def test_padding_rows_are_routed_nowhere_and_counted_nowhere():
+    experts = _experts(2, 16, 8)
+    x = jax.random.normal(jax.random.PRNGKey(4), (6, 16))
+    ids = jnp.asarray([[0, 1]] * 6)
+    valid = jnp.arange(6) < 4
+    y, counts = expert_shard.held_experts_ffn(
+        x, jnp.ones((6, 2)), ids, valid, experts, first_expert=0,
+        interpret=True, tiling=(16, 128, 128))
+    assert counts.tolist() == [4, 4, 0]
+    assert not np.asarray(y[4:]).any()
+    want = _expert_ffn(experts, 0, x) + _expert_ffn(experts, 1, x)
+    np.testing.assert_allclose(np.asarray(y[:4]), np.asarray(want[:4]),
+                               rtol=1e-4, atol=1e-5)
+
+
+def test_rms_norm_is_fp32_inside():
+    x = jnp.asarray([[3.0, 4.0]], jnp.bfloat16)
+    y = rms_norm({"scale": jnp.asarray([1.0, 2.0])}, x, eps=0.0)
+    rms = math.sqrt(12.5)
+    assert y.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(y, np.float32),
+                               [[3 / rms, 8 / rms]], rtol=1e-2)
+
+
+def test_weights_already_in_the_serving_dtype_are_not_copied():
+    tree = {"a": jnp.ones((4, 4), jnp.bfloat16),
+            "b": jnp.ones((4,), jnp.float32), "ids": jnp.arange(3)}
+    out = cast_weights(tree, jnp.bfloat16)
+    assert out["a"] is tree["a"] and out["ids"] is tree["ids"]
+    assert out["b"].dtype == jnp.bfloat16
+
+
+def test_both_models_describe_their_cache_through_one_interface():
+    icfg = DeepSpeedInferenceConfig({"inference": {
+        "kv_block_size": 8, "kv_blocks": 9, "max_seq_len": 32,
+        "prefill_buckets": [8]}})
+    gpt2 = GPT2LMHeadTPU(GPT2Config(vocab_size=64, hidden_size=32,
+                                    num_layers=2, num_heads=4)).serving()
+    assert gpt2.cache_buffers(icfg) == {"k_cache": 32, "v_cache": 32}
+    mla = DeepseekV2ForServing(DeepseekV2Config()).serving()
+    # 512 + 64 stored in whole lane tiles
+    assert mla.cache_buffers(icfg) == {"latent_cache": 640}
+    assert mla.num_layers == 60 and gpt2.num_layers == 2
+    for serving in (gpt2, mla):
+        assert serving.build_decode(icfg).__name__ == "decode"
+        assert serving.build_prefill(icfg, 8).__name__ == "prefill"
+    # the footprint of any set of buffers; K+V is a case of it
+    assert cache_bytes(5, 12289, 64, (640,), jnp.bfloat16) == 5033574400
+    assert kv_cache_bytes(2, 9, 8, 4, 8) == cache_bytes(2, 9, 8, (32, 32))
+    with pytest.raises(ValueError, match="cannot tile"):
+        DeepseekV2ForServing(DeepseekV2Config(
+            kv_lora_rank=48)).serving().check_tpu_geometry(icfg)
+    mla.check_tpu_geometry(icfg)

@@ -420,7 +420,7 @@ class TestInferenceEngine:
             model, params, config=serve_config(weights_dtype="bfloat16"))
         leaves = jax.tree_util.tree_leaves(engine.params)
         assert all(l.dtype == jnp.bfloat16 for l in leaves)
-        assert engine._k_cache.dtype == jnp.bfloat16
+        assert engine._caches[0].dtype == jnp.bfloat16
         rid = engine.submit(seeded_prompts(1, seed=2)[0],
                             max_new_tokens=4)
         out = engine.run()[rid]

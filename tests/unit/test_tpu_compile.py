@@ -162,3 +162,61 @@ def test_paged_decode_kernel_compiles(v5e, hidden, heads, block_size, dtype,
     with jax.default_matmul_precision(ambient or "default"):
         text = _compile(attend, *args)
     assert "tpu_custom_call" in text
+
+
+# -- the latent-attention serving path at its published widths ---------------
+
+@pytest.mark.parametrize("pages", [1, 16])
+def test_latent_paged_decode_kernel_compiles(v5e, pages):
+    """128 query heads over one 576-wide latent row stored 640 wide, 64
+    slots of 192 pages: what the decode program calls once a layer."""
+    from deepspeed_tpu.ops.transformer.mla_paged_attention import (
+        mla_paged_decode_attention, padded_row_width)
+
+    row = padded_row_width(512 + 64)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    text = _compile(
+        lambda q, c, t, l: mla_paged_decode_attention(
+            q, c, t, l, layer=3, value_width=512, scale=0.1,
+            pages_per_step=pages),
+        s((64, 128, row), jnp.bfloat16), s((5, 1025, 64, row), jnp.bfloat16),
+        s((64, 192), jnp.int32), s((64,), jnp.int32))
+    assert "tpu_custom_call" in text
+    assert "mla_paged_decode_attention" in text
+
+
+def test_flash_forward_compiles_at_key_width_192_value_width_128(v5e):
+    from deepspeed_tpu.ops.transformer.flash_attention import (
+        flash_attention_forward)
+
+    def s(d):
+        return jax.ShapeDtypeStruct((1, 4096, 128, d), jnp.bfloat16,
+                                    sharding=v5e)
+
+    text = _compile(
+        lambda q, k, v: flash_attention_forward(
+            q, k, v, causal=True, block_q=1024, block_k=1024,
+            name="mla_prefill_attention"), s(192), s(192), s(128))
+    assert "tpu_custom_call" in text and "mla_prefill_attention" in text
+
+
+@pytest.mark.parametrize("rows,tiling", [(384, (128, 5120, 512)),
+                                         (49152, (256, 2560, 1024))])
+def test_grouped_matmul_compiles_for_decode_and_prefill(v5e, rows, tiling):
+    """20 held experts of 5120 x 3072 (gate and up fused) and 1536 x 5120,
+    21 groups: the last is the pairs held elsewhere."""
+    from deepspeed_tpu.ops.transformer.grouped_matmul import (
+        moe_grouped_matmul)
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    for k, n in ((5120, 3072), (1536, 5120)):
+        text = _compile(
+            lambda lhs, rhs, sizes: moe_grouped_matmul(lhs, rhs, sizes,
+                                                       tiling=tiling),
+            s((rows, k)), s((20, k, n)), s((21,), jnp.int32))
+        assert "moe_grouped_matmul" in text
