@@ -23,9 +23,18 @@ analysis + HLO walk at compile time, the ProgramDumper lands
 ``<run_dir>/programs/serve_*.{hlo,json}`` sidecars for the offline
 verifier, decode iterations feed a StepLatencyRing for the attribution
 doctor, and EVENT-stream telemetry narrates admissions / finishes /
-queue depth.  The ONLY per-iteration host sync is the next-token fetch
-the serve loop needs anyway — telemetry adds zero (the device_get-
-counting test pins this).
+queue depth.
+
+The serve loop keeps ONE program in flight.  A program's sampled tokens
+stay on the device as the next decode's input (the decode's output
+array, with each prefill's first token put into its lane by the prefill
+program itself), the block tables live there too and are sent again only
+when a slot's grant changes, and the host reads a program's outputs only
+after the NEXT decode has been enqueued: the device runs iteration k
+while the host reads iteration k-1, books it and prepares k+1.  The one
+batched ``device_get`` a ``step()`` makes is its ONLY host sync —
+telemetry and the health plane add zero (the device_get-counting tests
+pin this).  What follows for a caller is in :meth:`InferenceEngine.step`.
 """
 
 import time
@@ -58,6 +67,25 @@ DECODE_PROGRAM = SERVE_DECODE_PROGRAM
 
 def prefill_program_name(bucket):
     return f"serve_prefill_{int(bucket)}"
+
+
+class _Enqueued:
+    """One enqueued program whose outputs the host has not read: the
+    device ``out`` dict, the ``(request, block grant)`` each lane was
+    dispatched for, the clock at its enqueue and, for a decode, the KV
+    blocks it reads (None marks a prefill)."""
+
+    __slots__ = ("out", "lanes", "enqueued_at", "live_blocks")
+
+    def __init__(self, out, lanes, enqueued_at, live_blocks=None):
+        self.out = out
+        self.lanes = lanes
+        self.enqueued_at = enqueued_at
+        self.live_blocks = live_blocks
+
+    @property
+    def is_decode(self):
+        return self.live_blocks is not None
 
 
 class InferenceEngine:
@@ -161,6 +189,18 @@ class InferenceEngine:
             (icfg.max_batch_slots, icfg.max_blocks_per_seq), NULL_BLOCK,
             np.int32)
         self._table_owner = [None] * icfg.max_batch_slots
+        # ...and their copy on the device, made anew when a row changed
+        self._tables_dev = None
+        # the next decode's input tokens, on the device: the last
+        # decode's output with the prefills' first tokens put into their
+        # lanes by the prefill programs themselves
+        self._next_tokens = jnp.zeros((icfg.max_batch_slots,), jnp.int32)
+        # programs enqueued whose outputs the host has not read (one
+        # program deep: a step reads everything enqueued before its own
+        # decode), and the weight fingerprint enqueued with them
+        self._unread = []
+        self._fingerprint_dev = None
+        self._last_read_at = 0.0
         # the scalar counters the model's decode program last reported
         self.model_counters = {}
 
@@ -169,6 +209,10 @@ class InferenceEngine:
         # the clock of every per-token stamp (a test puts its own here)
         self._clock = time.monotonic
         self.decode_iterations = 0
+        # of those, the iterations enqueued while the previous one's
+        # outputs were still unread (serving/enqueued_ahead_share)
+        self.decodes_enqueued_ahead = 0
+        self._sampled_decodes = self._sampled_ahead = 0
         # the serving observability plane: lifecycle tracing, occupancy
         # windows, SLO/goodput accounting.  Always constructed — every
         # hook is host arithmetic that no-ops emission when telemetry
@@ -291,33 +335,37 @@ class InferenceEngine:
     # ------------------------------------------------------------------
     # the serve loop
     # ------------------------------------------------------------------
-    def _run_prefill(self, request):
-        sched = self.scheduler
+    def _enqueue_prefill(self, request):
+        """Enqueue the admitted request's prefill.  Nothing is read here:
+        the program also puts its first token into the request's lane of
+        the next decode's input, on the device, and the host reads it in
+        the step's one fetch."""
         span = self.telemetry.span
         with span("prefill", bucket=request.bucket,
                   prompt_tokens=len(request.prompt)):
             t_pre = self._clock()
             with span("prefill.prep"):
+                # fresh arrays: a program that has not run yet may still
+                # read its host arguments (the CPU backend aliases them)
                 ids = np.zeros((1, request.bucket), np.int32)
                 ids[0, :len(request.prompt)] = request.prompt
-                table = np.asarray(sched.block_table_row(request), np.int32)
+                table = np.asarray(
+                    self.scheduler.block_table_row(request), np.int32)
             with span("prefill.dispatch"):
-                out, self._caches = self._prefills[request.bucket](
-                    self.params, self._caches, jnp.asarray(ids),
-                    jnp.int32(len(request.prompt)), table)
+                out, self._caches, self._next_tokens = self._prefills[
+                    request.bucket](
+                        self.params, self._caches, ids,
+                        np.int32(len(request.prompt)), table,
+                        self._next_tokens, np.int32(request.slot))
             with span("prefill.fetch"):
-                token = int(jax.device_get(out)["tokens"])
+                # kept so that a `prefill` still holds its four phases
+                # (trace readers pair them by count); the token comes
+                # with the step's one fetch
+                pass
             with span("prefill.account"):
-                now = self._clock()
-                # the TTFT is first_token_at - submitted; step_times
-                # holds the gaps BETWEEN a request's tokens only
-                request.first_token_at = request.last_token_at = now
-                request.generated.append(token)
-                self.generated_tokens += 1
-                # admit + first_token phase records, admission-wait
-                # histogram, TTFT SLO leg, bucket padding-waste
-                # accumulators
-                self.observability.note_prefill(request, now, now - t_pre)
+                request.dispatched = 1
+                self._unread.append(_Enqueued(
+                    out, [(request, request.blocks)], t_pre))
 
     def _emit_finish(self, request):
         self.observability.note_finish(request)
@@ -326,45 +374,55 @@ class InferenceEngine:
         self.observability.note_deadline(request)
 
     def _decode_once(self, active):
-        """One continuous-batch decode iteration over the ``active``
-        slots.  The single ``device_get`` here is the serve loop's OWN
-        next-token fetch — the baseline the zero-added-syncs test
-        measures against.  With a health plane attached, the cadence
-        iterations fold the re-computed weight-fingerprint scalar INTO
-        that same fetch (one batched ``device_get``), so the full
-        resilience plane holds the count at baseline."""
+        """Enqueue one continuous-batch decode iteration over the
+        ``active`` slots the scheduler advances, THEN read what was
+        enqueued before it: the previous decode's tokens and counters,
+        the first tokens of this step's prefills and, on the cadence
+        iterations with a health plane attached, the re-computed
+        weight-fingerprint scalar — ONE batched ``device_get``, the
+        step's only host sync (the zero-added-syncs tests count them).  The device runs this
+        iteration while the host reads, accounts and prepares the next:
+        its input tokens are the previous programs' outputs and never
+        leave the device."""
         icfg = self.inference_config
         sched = self.scheduler
         span = self.telemetry.span
         # counted before the span opens: an annotation's arguments are
         # fixed at its start
         live_blocks = sched.live_blocks()
-        with span("decode", active=active, live_blocks=live_blocks):
+        in_flight = int(any(p.is_decode for p in self._unread))
+        with span("decode", active=active, live_blocks=live_blocks,
+                  in_flight=in_flight):
             with span("decode.prep"):
                 t_prep = self._clock()
                 tables, owner = self._tables, self._table_owner
                 ctx_lens = np.zeros((icfg.max_batch_slots,), np.int32)
-                tokens = np.zeros((icfg.max_batch_slots,), np.int32)
-                before = []
+                lanes = []
                 for slot, request in enumerate(sched.slots):
-                    grant = None if request is None else request.blocks
+                    grant = (request.blocks if sched.decodes_next(request)
+                             else None)
                     if grant is not owner[slot]:
                         # a block grant (a list made at admission) stays
                         # as it is until the request finishes, so a row is
-                        # rewritten only when the slot's grant changes (a
-                        # freed slot goes back to the null block)
-                        tables[slot] = (NULL_BLOCK if request is None else
+                        # rewritten only when the slot's grant changes; a
+                        # freed slot, and one whose request has all its
+                        # tokens dispatched, goes back to the null block
+                        tables[slot] = (NULL_BLOCK if grant is None else
                                         sched.block_table_row(request))
                         owner[slot] = grant
-                    if request is None:
+                        self._tables_dev = None
+                    if grant is None:
                         continue
-                    # position of the token being decoded = current
-                    # context - 1 (the last generated token is the
-                    # decode input)
-                    ctx_lens[request.slot] = request.context_len - 1
-                    tokens[request.slot] = request.generated[-1]
-                    before.append(request)
-                fp_dev = None
+                    # position of the token being decoded: the context
+                    # dispatched so far - 1 (the last dispatched token is
+                    # the decode input, on the device already)
+                    ctx_lens[slot] = (len(request.prompt)
+                                      + request.dispatched - 1)
+                    lanes.append((request, grant))
+                if self._tables_dev is None:
+                    # a COPY goes to the device: the host rows are written
+                    # again while programs that read the old ones wait
+                    self._tables_dev = jax.device_put(tables.copy())
                 if self._health is not None:
                     # liveness tick for ENTERING this iteration
                     # (throttled O(1) publish; a wedged decode never
@@ -372,39 +430,75 @@ class InferenceEngine:
                     self._health.beat(self.decode_iterations + 1)
                     if ((self.decode_iterations + 1)
                             % self.steps_per_print == 0):
-                        fp_dev = self._health.fingerprint_device()
+                        self._fingerprint_dev = \
+                            self._health.fingerprint_device()
                 t0 = self._clock()
                 self._driver_latencies.record(t0 - t_prep)
             with span("decode.dispatch"):
-                # returns when the three tables are copied and the
-                # program is enqueued, not when it has run
+                # returns when the positions are copied and the program
+                # is enqueued, not when it has run
                 out_dev, self._caches = self._decode(
-                    self.params, self._caches, tables, ctx_lens, tokens)
-            with span("decode.fetch"):
-                # ONE host sync per decode iteration, cadence or not:
-                # the weight fingerprint (when due) rides the same
-                # batched fetch as the sampled tokens, so arming the
-                # resilience plane adds zero device_get calls (the
-                # zero-added-syncs test counts them)
-                fetched = jax.device_get((out_dev,) if fp_dev is None
-                                         else (out_dev, fp_dev))
-            with span("decode.account"):
+                    self.params, self._caches, self._tables_dev, ctx_lens,
+                    self._next_tokens)
+            self._next_tokens = out_dev["tokens"]
+            for request, _ in lanes:
+                request.dispatched += 1
+            self.decode_iterations += 1
+            self.decodes_enqueued_ahead += in_flight
+            before, self._unread = self._unread, [
+                _Enqueued(out_dev, lanes, t0, live_blocks)]
+            self._read(before, "decode.fetch", "decode.account")
+
+    def _read(self, unread, fetch_span, account_span):
+        """Read the outputs of the ``unread`` programs (and the weight
+        fingerprint, when one was enqueued) in ONE ``device_get`` and
+        book them.  A lane is booked only while its request still holds
+        the block grant it was dispatched under: one that was finished
+        (the token decoded past an EOS), expired, aborted or requeued
+        while its program was in flight is dropped, never booked to the
+        slot's next owner."""
+        span = self.telemetry.span
+        fp_dev, self._fingerprint_dev = self._fingerprint_dev, None
+        outs = ()
+        with span(fetch_span):
+            if unread or fp_dev is not None:
+                outs, fingerprint = jax.device_get(
+                    ([program.out for program in unread], fp_dev))
+        with span(account_span):
+            if fp_dev is not None:
+                self._pending_fingerprint = int(fingerprint)
+            now = self._clock()
+            for program, out in zip(unread, outs):
+                lanes = [request for request, grant in program.lanes
+                         if request.blocks is grant]
+                if not program.is_decode:
+                    for request in lanes:
+                        # the TTFT is first_token_at - submitted;
+                        # step_times holds the gaps BETWEEN a request's
+                        # tokens only
+                        request.first_token_at = request.last_token_at = now
+                        request.generated.append(int(out["tokens"]))
+                        self.generated_tokens += 1
+                        # admit + first_token phase records,
+                        # admission-wait histogram, TTFT SLO leg, bucket
+                        # padding-waste accumulators
+                        self.observability.note_prefill(
+                            request, now, now - program.enqueued_at)
+                    continue
                 # the tokens, and whatever scalar counters the model's
                 # program reported in the same fetch
-                self.model_counters = dict(fetched[0])
+                self.model_counters = dict(out)
                 next_tokens = self.model_counters.pop("tokens")
-                if fp_dev is not None:
-                    self._pending_fingerprint = int(fetched[1])
-                now = self._clock()
-                self._step_latencies.record(now - t0)
-                self.decode_iterations += 1
+                # an iteration's time: read to read while the pipe is
+                # full, enqueue to read when it was empty
+                self._step_latencies.record(
+                    now - max(program.enqueued_at, self._last_read_at))
                 gaps = []
-                for request in before:
+                for request in lanes:
                     request.generated.append(int(next_tokens[request.slot]))
                     # the gap since THIS request's previous token: a
                     # neighbour's prefill between two of its tokens is
-                    # in it, which the decode call's own duration
-                    # (now - t0) would leave out
+                    # in it
                     gap = now - request.last_token_at
                     gaps.append(gap)
                     request.step_times.append(gap)
@@ -414,7 +508,16 @@ class InferenceEngine:
                 # (occupancy window sums, the per-token SLO leg; with
                 # telemetry on, the P² per-token observations) — no
                 # device syncs
-                self.observability.note_decode(before, gaps, live_blocks)
+                self.observability.note_decode(lanes, gaps,
+                                               program.live_blocks)
+            self._last_read_at = now
+
+    def _flush(self):
+        """Read the program in flight, if any: the pipe drains (no slot
+        is left to advance, ``run()``, ``drain()``, ``close()``)."""
+        if self._unread:
+            unread, self._unread = self._unread, []
+            self._read(unread, "step.fetch", "step.account")
 
     def _sample_telemetry(self):
         """Print-cadence sampling: queue/occupancy gauges, one
@@ -433,6 +536,16 @@ class InferenceEngine:
             float(self.generated_tokens))
         for key, value in self.model_counters.items():
             self.telemetry.gauge(f"serving/{key}").set(float(value))
+        decodes = self.decode_iterations - self._sampled_decodes
+        if decodes:
+            # the share of this window's decode iterations that were
+            # enqueued while the previous one's outputs were still
+            # unread: 1.0 with the pipe full, lower where it drained
+            self.telemetry.gauge("serving/enqueued_ahead_share").set(
+                (self.decodes_enqueued_ahead - self._sampled_ahead)
+                / decodes)
+            self._sampled_decodes = self.decode_iterations
+            self._sampled_ahead = self.decodes_enqueued_ahead
         self.telemetry.emit(
             TEL.EVENT_SERVING, step=self.decode_iterations, kind="queue",
             queue_depth=sched.queue_depth, active=sched.active_count,
@@ -475,22 +588,37 @@ class InferenceEngine:
             self._pending_fingerprint, None
         self._health.note_weight_fingerprint(fingerprint)
 
+    def _sweep_finished(self, finished):
+        for request in self.scheduler.sweep_finished(
+                self.inference_config.eos_token_id):
+            self._emit_finish(request)
+            finished.append(request)
+        return finished
+
     def step(self):
-        """One engine iteration: expire deadlines, recycle finished
-        slots, admit from the queue (each admission prefills
-        immediately), then advance every active slot one token.
-        Returns the requests finished DURING this iteration."""
+        """One engine iteration, one program ahead of the host: expire
+        deadlines, admit from the queue (each admission's prefill is
+        enqueued at once), enqueue the decode that advances every slot
+        with tokens left to ask for, and only then read what was
+        enqueued BEFORE that decode — the previous decode's tokens and
+        this step's first tokens — in the step's one host sync; last,
+        recycle the slots those tokens finished.  Returns the requests
+        finished during this iteration, each with its whole
+        ``generated``.
+
+        So a decode's tokens reach the host one enqueue late; a request
+        is returned by the ``step()`` that READ its last token, the one
+        after the step that enqueued it; and an EOS is seen one
+        iteration late: the token decoded past it is dropped here, its
+        cache row inside the request's own block grant."""
         span = self.telemetry.span
         with span("step"):
             sched = self.scheduler
-            eos = self.inference_config.eos_token_id
             with span("step.sweep"):
                 finished = sched.sweep_deadlines()
                 for request in finished:
                     self._emit_deadline(request)
-                for request in sched.sweep_finished(eos):
-                    self._emit_finish(request)
-                    finished.append(request)
+                self._sweep_finished(finished)
             while not self._draining:
                 # one span per try_admit call; the call that admits nothing
                 # closes the loop
@@ -499,7 +627,7 @@ class InferenceEngine:
                 if request is None:
                     break
                 try:
-                    self._run_prefill(request)
+                    self._enqueue_prefill(request)
                 except BaseException:
                     # a prefill that raises after admission must not strand
                     # the slot + block grant it was just handed (the
@@ -507,17 +635,16 @@ class InferenceEngine:
                     # surface the fault
                     sched.abort(request)
                     raise
-            # a prefill can already satisfy a request (max_new_tokens=1, or
-            # the prefill token IS eos): sweep before decoding, else the
-            # slot advances one token past its contract — and an eos landed
-            # at prefill would be buried under the extra token and missed
-            with span("step.sweep"):
-                for request in sched.sweep_finished(eos):
-                    self._emit_finish(request)
-                    finished.append(request)
-            active = sched.active_count
+            # a request whose tokens are all dispatched (max_new_tokens=1:
+            # by its prefill alone) is not advanced again: its slot parks
+            # until the read below, or the next step's, finishes it
+            active = sched.decoding_count
             if active:
                 self._decode_once(active)
+            else:
+                self._flush()
+            with span("step.sweep"):
+                self._sweep_finished(finished)
             if (self.decode_iterations
                     and self.decode_iterations % self.steps_per_print == 0):
                 with span("step.sample"):
@@ -534,10 +661,10 @@ class InferenceEngine:
         reason, TTFT, per-token p50/p99)."""
         while not self.scheduler.idle():
             self.step()
-        # final sweep: the last decode's tokens may have finished slots
-        for request in self.scheduler.sweep_finished(
-                self.inference_config.eos_token_id):
-            self._emit_finish(request)
+        # nothing stays unread (requests aborted from outside can leave
+        # the scheduler idle over a program in flight)
+        self._flush()
+        self._sweep_finished([])
         self._sample_telemetry()
         return {rid: r.result() for rid, r in self._results.items()}
 
@@ -707,11 +834,10 @@ class InferenceEngine:
                     float(deadline_secs), self.scheduler.active_count)
                 break
             drained.extend(self.step())
-        for request in self.scheduler.sweep_finished(
-                self.inference_config.eos_token_id):
-            self._emit_finish(request)
-            drained.append(request)
-        return drained
+        # the program in flight is read before the drain returns, at the
+        # deadline too
+        self._flush()
+        return self._sweep_finished(drained)
 
     def close(self, reason="serve_done"):
         """Shut the engine down respawnably: stop admission, drain the
@@ -724,6 +850,7 @@ class InferenceEngine:
         if self.scheduler.active_count:
             self.drain()
         self._draining = True
+        self._flush()
         if self._health is not None:
             self._health.stop()
         # TelemetryManager.close emits the EVENT_RUN_END itself

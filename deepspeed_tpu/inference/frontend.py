@@ -5,7 +5,11 @@ The front-end owns request-level robustness; the per-replica
 :class:`~deepspeed_tpu.inference.engine.InferenceEngine` owns decode.
 One router, N engines (in-process replicas — the real-launcher fleet
 runs one engine per process and gets the same guarantees from the
-shared-run-dir ledger protocol the serving chaos e2e drives):
+shared-run-dir ledger protocol the serving chaos e2e drives).  Each
+engine keeps one program in flight (``InferenceEngine.step``), so a
+replica's chip works on its next iteration while the router steps the
+others; a finished request is harvested in the front-end step in which
+its replica read its last token:
 
 - **admission** — round-robin over live replicas.  With
   ``inference.max_queue_depth`` set, a submit arriving at a full fleet

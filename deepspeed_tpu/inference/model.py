@@ -13,15 +13,27 @@ it can serve has a ``serving()`` method that returns an object with:
 - ``check_tpu_geometry(icfg)``: raise for a cache its decode kernel cannot
   tile on a TPU (called at construction there, never a second path);
 - ``build_prefill(icfg, bucket) -> prefill(params, caches, input_ids[1, S],
-  true_len, block_table) -> (out, caches)``, one program a bucket, and
-  ``build_decode(icfg) -> decode(params, caches, block_tables, ctx_lens,
-  tokens) -> (out, caches)``, one program for the serve; ``caches`` is the
-  tuple of buffers in ``cache_buffers`` order, ``out`` a dict the engine
-  fetches whole in its one sync: ``"tokens"`` (prefill: the first token;
-  decode: ``[slots]``) and any scalar counters the model reports
-  (published as ``serving/<key>`` gauges on the print cadence).  The
-  functions are NAMED ``prefill`` and ``decode``: a device trace names a
-  compiled program after its function.
+  true_len, block_table, next_tokens, slot) -> (out, caches,
+  next_tokens)``, one program a bucket, and ``build_decode(icfg) ->
+  decode(params, caches, block_tables, ctx_lens, tokens) -> (out,
+  caches)``, one program for the serve; ``caches`` is the tuple of
+  buffers in ``cache_buffers`` order, ``out`` a dict the engine fetches
+  whole: ``"tokens"`` (prefill: the first token; decode: ``[slots]``) and
+  any scalar counters the model reports (published as ``serving/<key>``
+  gauges on the print cadence).  The functions are NAMED ``prefill`` and
+  ``decode``: a device trace names a compiled program after its function.
+
+The engine keeps one program in flight, so a program's tokens never
+travel device -> host -> device: ``decode``'s ``tokens`` argument IS the
+last decode's ``out["tokens"]`` array, and a ``prefill`` puts its first
+token into lane ``slot`` of that array itself (``next_tokens``, returned
+beside the caches) — lanes of free or finished slots carry whatever was
+decoded there last and are parked by ``ctx_lens`` 0 over a null table
+row.  ``block_tables`` is a device array the engine sends again only when
+a slot's grant changes; ``ctx_lens`` comes from the host's own counts
+each iteration.  The host reads ``out`` one enqueue late
+(``InferenceEngine.step`` has the contract: a decode's tokens one
+``step()`` after its enqueue, the token decoded past an EOS dropped).
 
 The rest of this file is GPT-2: two program families, both closed over the
 static model/cache geometry so every shape in the traced graph is fixed:
@@ -188,10 +200,12 @@ class GPT2Serving:
     def build_prefill(self, icfg, bucket_len):
         inner = build_prefill(self.config, icfg, bucket_len)
 
-        def prefill(params, caches, input_ids, true_len, block_table):
+        def prefill(params, caches, input_ids, true_len, block_table,
+                    next_tokens, slot):
             token, k_cache, v_cache = inner(params, *caches, input_ids,
                                             true_len, block_table)
-            return {"tokens": token}, (k_cache, v_cache)
+            return ({"tokens": token}, (k_cache, v_cache),
+                    next_tokens.at[slot].set(token))
 
         return prefill
 

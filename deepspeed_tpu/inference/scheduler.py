@@ -11,8 +11,15 @@ dispatches the compiled programs.  Per engine iteration:
    worst-case block span (prefill bucket ∪ prompt+generation cap) —
    allocation is all-at-admission, so decode can never hit
    out-of-blocks;
-3. every active slot advances one token through the fixed-shape decode
-   program.
+3. every active slot whose DISPATCHED tokens are short of its cap
+   advances one token through the fixed-shape decode program.  The
+   engine keeps one program in flight, so the host's ``generated`` lags
+   what the device was asked for by one enqueue: ``Request.dispatched``
+   counts the tokens asked for, and a request that has reached
+   ``max_new_tokens`` by that count is not dispatched again — its slot
+   parks at the null block, as a free slot does, until its last token
+   has been read and the sweep recycles it — so no write ever leaves
+   the block grant.
 
 The token budget is the Orca admission knob: the sum of each active
 request's worst case (prompt + remaining generation) stays under
@@ -47,13 +54,17 @@ class Request:
     bench quotes p50/p99 from; the TTFT is not in it).  ``deadline_at``
     is an absolute monotonic expiry (None = no deadline): the
     scheduler's deadline sweep finishes an expired request with
-    ``reason="deadline"`` and the partial tokens it generated so far."""
+    ``reason="deadline"`` and the partial tokens it generated so far.
+    ``dispatched`` counts the tokens whose programs have been enqueued
+    (the prefill's and one a decode), ``generated`` holds those the host
+    has read: ``dispatched - len(generated)`` are in flight."""
 
     __slots__ = ("request_id", "prompt", "max_new_tokens", "state",
-                 "generated", "blocks", "slot", "bucket", "submitted",
-                 "first_token_at", "last_token_at", "finished_at",
-                 "finish_reason", "step_times", "deadline_at", "requeues",
-                 "trace_id", "admitted_at", "_cached_summary")
+                 "generated", "dispatched", "blocks", "slot", "bucket",
+                 "submitted", "first_token_at", "last_token_at",
+                 "finished_at", "finish_reason", "step_times",
+                 "deadline_at", "requeues", "trace_id", "admitted_at",
+                 "_cached_summary")
 
     def __init__(self, request_id, prompt, max_new_tokens,
                  deadline_at=None, trace_id=None):
@@ -63,6 +74,7 @@ class Request:
         self.max_new_tokens = int(max_new_tokens)
         self.state = QUEUED
         self.generated = []
+        self.dispatched = 0
         self.blocks = []
         self.slot = None
         self.bucket = None
@@ -97,6 +109,7 @@ class Request:
             "result is never re-served (exactly-once)")
         self.state = QUEUED
         self.generated = []
+        self.dispatched = 0
         self.blocks = []
         self.slot = None
         self.bucket = None
@@ -181,15 +194,28 @@ class ContinuousBatchScheduler:
         return sum(r.worst_case_tokens() for r in self.slots
                    if r is not None)
 
+    @staticmethod
+    def decodes_next(request):
+        """Whether the next decode advances this slot: it holds a
+        request whose dispatched tokens (in flight included) are short
+        of its cap."""
+        return (request is not None
+                and request.dispatched < request.max_new_tokens)
+
+    @property
+    def decoding_count(self):
+        """Slots the next decode advances."""
+        return sum(1 for r in self.slots if self.decodes_next(r))
+
     def live_blocks(self):
-        """KV blocks the next decode iteration reads: for each active
-        slot the blocks holding its context and the token being
-        decoded, ``ceil(context_len / block_size)`` — what the paged
-        kernel walks, of the ``slots * max_blocks_per_seq`` a
+        """KV blocks the next decode iteration reads: for each slot it
+        advances, the blocks holding its context and the token being
+        decoded, ``ceil((prompt + dispatched) / block_size)`` — what
+        the paged kernel walks, of the ``slots * max_blocks_per_seq`` a
         full-table gather would."""
         bs = self.icfg.kv_block_size
-        return sum(-(-r.context_len // bs) for r in self.slots
-                   if r is not None)
+        return sum(-(-(len(r.prompt) + r.dispatched) // bs)
+                   for r in self.slots if self.decodes_next(r))
 
     def idle(self):
         return not self.waiting and self.active_count == 0
@@ -323,6 +349,7 @@ class ContinuousBatchScheduler:
         request.slot = None
         request.bucket = None
         request.state = QUEUED
+        request.dispatched = 0
         request.admitted_at = None
 
     def sweep_finished(self, eos_token_id):

@@ -306,16 +306,18 @@ class DeepseekV2Serving:
 
     # -- the two programs --------------------------------------------------
     def build_prefill(self, icfg, bucket_len):
-        """``(params, caches, input_ids[1, S], true_len, block_table) ->
-        (out, caches)``: the expanded path over one request padded to the
-        bucket."""
+        """``(params, caches, input_ids[1, S], true_len, block_table,
+        next_tokens, slot) -> (out, caches, next_tokens)``: the expanded
+        path over one request padded to the bucket; its first token is
+        also put into lane ``slot`` of the next decode's input."""
         c = self.config
         bs = icfg.kv_block_size
         assert bucket_len % bs == 0
         block = math.gcd(bucket_len, self.PREFILL_BLOCK)
         n_pages = bucket_len // bs
 
-        def prefill(params, caches, input_ids, true_len, block_table):
+        def prefill(params, caches, input_ids, true_len, block_table,
+                    next_tokens, slot):
             (cache,) = caches
             s = input_ids.shape[1]
             positions = jnp.arange(s)
@@ -356,7 +358,9 @@ class DeepseekV2Serving:
                 x = x + y
             last = jax.lax.dynamic_slice(
                 x, (true_len - 1, 0), (1, c.hidden_size))
-            return {"tokens": self._next_token(params, last)[0]}, (cache,)
+            token = self._next_token(params, last)[0]
+            return ({"tokens": token}, (cache,),
+                    next_tokens.at[slot].set(token))
 
         return prefill
 

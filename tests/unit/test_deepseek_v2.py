@@ -171,3 +171,104 @@ def test_both_models_describe_their_cache_through_one_interface():
         DeepseekV2ForServing(DeepseekV2Config(
             kv_lora_rank=48)).serving().check_tpu_geometry(icfg)
     mla.check_tpu_geometry(icfg)
+
+
+# ------------------------------- the interface's tokens stay on the device
+def _tiny_served(family):
+    """A tiny model of ``family`` with seeded weights and the engine
+    config it is served under."""
+    if family == "gpt2":
+        model = GPT2LMHeadTPU(GPT2Config(
+            vocab_size=256, hidden_size=64, num_layers=2, num_heads=4,
+            max_position_embeddings=64, embd_dropout=0.0, attn_dropout=0.0,
+            resid_dropout=0.0))
+        params = model.init(jax.random.PRNGKey(0))
+    else:
+        model = DeepseekV2ForServing(DeepseekV2Config(
+            vocab_size=256, hidden_size=64, num_hidden_layers=3,
+            num_attention_heads=4, q_lora_rank=32, kv_lora_rank=48,
+            qk_nope_head_dim=16, qk_rope_head_dim=16, v_head_dim=16,
+            intermediate_size=128, moe_intermediate_size=32,
+            n_routed_experts=16, experts_held=4, n_shared_experts=1,
+            num_experts_per_tok=3, n_group=4, topk_group=2,
+            routed_scaling_factor=4.0, max_position_embeddings=2560,
+            rope_scaling={"type": "yarn", "factor": 40, "beta_fast": 32,
+                          "beta_slow": 1, "mscale": 0.707,
+                          "mscale_all_dim": 0.707,
+                          "original_max_position_embeddings": 64}))
+        leaves, tree = jax.tree_util.tree_flatten(
+            model.param_shapes(), is_leaf=lambda x: isinstance(x, tuple))
+        keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
+        params = jax.tree_util.tree_unflatten(tree, [
+            jnp.ones(shape) if len(shape) == 1
+            else 0.2 * jax.random.normal(key, shape)
+            for key, shape in zip(keys, leaves)])
+    config = {"steps_per_print": 10 ** 9, "inference": {
+        "kv_block_size": 8, "kv_blocks": 33, "max_batch_slots": 3,
+        "max_seq_len": 64, "prefill_buckets": [16, 32], "token_budget": 192,
+        "max_new_tokens": 16, "weights_dtype": "float32"}}
+    return model, params, config
+
+
+@pytest.mark.parametrize("family", ["gpt2", "deepseek_v2"])
+def test_tokens_reach_the_next_decode_without_leaving_the_device(
+        family, monkeypatch):
+    """Both models' side of the interface: a prefill puts its first token
+    into lane ``slot`` of the next decode's input and leaves the other
+    lanes alone; a decode's ``tokens`` argument IS the last program's
+    output array, never a host copy; positions and tables are what the
+    host sends.  And the engine, whatever the model: one ``device_get`` a
+    step, admissions included, with the model's counters in it."""
+    from deepspeed_tpu.inference import InferenceEngine
+    model, params, config = _tiny_served(family)
+    engine = InferenceEngine(model, params, config=config)
+    produced, gets = [], []
+
+    def watched(program, decode):
+        def run(*args):
+            tokens = args[4] if decode else args[5]
+            assert isinstance(tokens, jax.Array)
+            if produced:
+                assert tokens is produced[-1]
+            result = program(*args)
+            if decode:
+                assert isinstance(args[2], jax.Array)       # tables
+                assert isinstance(args[3], np.ndarray)      # positions
+                produced.append(result[0]["tokens"])
+            else:
+                out, _, merged = result
+                slot, before = int(args[6]), np.asarray(tokens)
+                want = before.copy()
+                want[slot] = int(out["tokens"])
+                np.testing.assert_array_equal(np.asarray(merged), want)
+                produced.append(merged)
+            return result
+        return run
+
+    engine._decode = watched(engine._decode, True)
+    for bucket in list(engine._prefills):
+        engine._prefills[bucket] = watched(engine._prefills[bucket], False)
+    real_get = jax.device_get
+    monkeypatch.setattr(jax, "device_get",
+                        lambda x: gets.append(1) or real_get(x))
+    rng = np.random.default_rng(3)
+    for n, cap in [(5, 6), (12, 4), (20, 7), (9, 5), (3, 6)]:
+        engine.submit(rng.integers(0, 256, size=n), max_new_tokens=cap)
+    steps = 0
+    while not engine.scheduler.idle():
+        before = len(gets)
+        engine.step()
+        steps += 1
+        assert len(gets) - before <= 1
+        if family == "deepseek_v2" and engine.decode_iterations >= 2:
+            share = float(engine.model_counters[
+                "moe_local_assignment_share"])
+            assert 0.0 <= share <= 1.0
+            assert float(engine.model_counters[
+                "moe_expert_load_max_over_mean"]) >= 1.0
+    monkeypatch.setattr(jax, "device_get", real_get)
+    assert engine.scheduler.admitted_total == 5 and len(gets) <= steps
+    if family == "gpt2":
+        assert engine.model_counters == {}
+    assert engine.generated_tokens == 6 + 4 + 7 + 5 + 6
+    engine.close()

@@ -58,6 +58,36 @@ def model_and_params():
     return model, model.init(jax.random.PRNGKey(0))
 
 
+def count_device_gets(engine, prompts, monkeypatch, max_new_tokens):
+    """Serve ``prompts`` step by step and return the number of
+    ``jax.device_get`` calls made, after asserting that no ``step()`` —
+    one that admits and prefills included — made more than one."""
+    counts = {"n": 0}
+    real_get = jax.device_get
+
+    def counting_get(x):
+        counts["n"] += 1
+        return real_get(x)
+
+    monkeypatch.setattr(jax, "device_get", counting_get)
+    try:
+        for i, p in enumerate(prompts):
+            engine.submit(p, max_new_tokens=max_new_tokens,
+                          request_id=f"r{i}")
+        admitting_steps = 0
+        while not engine.scheduler.idle():
+            gets, admitted = counts["n"], engine.scheduler.admitted_total
+            engine.step()
+            assert counts["n"] - gets <= 1, "a step synced twice"
+            admitting_steps += engine.scheduler.admitted_total > admitted
+        assert admitting_steps >= 2     # prefills mid-run, not only first
+        engine.run()
+    finally:
+        monkeypatch.setattr(jax, "device_get", real_get)
+    engine.close()
+    return counts["n"]
+
+
 # ---------------------------------------------------------------- config
 class TestInferenceConfig:
     def test_defaults(self):
@@ -283,29 +313,17 @@ class TestInferenceEngine:
         """Serving observability (telemetry + both ledgers + program
         dumper, print cadence every iteration) rides the serve loop's
         own next-token fetches: the jax.device_get count is IDENTICAL
-        with it all on and all off."""
+        with it all on and all off — and no ``step()`` makes more than
+        ONE, the steps that admit (two slots for six requests: prefills
+        all along the run) included."""
         model, params = model_and_params
-        prompts = seeded_prompts(4, seed=5)
+        prompts = seeded_prompts(6, seed=5)
 
         def count_gets(config):
+            config["inference"]["max_batch_slots"] = 2
             engine = InferenceEngine(model, params, config=config)
-            counts = {"n": 0}
-            real_get = jax.device_get
-
-            def counting_get(x):
-                counts["n"] += 1
-                return real_get(x)
-
-            monkeypatch.setattr(jax, "device_get", counting_get)
-            try:
-                for i, p in enumerate(prompts):
-                    engine.submit(p, max_new_tokens=4,
-                                  request_id=f"r{i}")
-                engine.run()
-            finally:
-                monkeypatch.setattr(jax, "device_get", real_get)
-            engine.close()
-            return counts["n"]
+            return count_device_gets(
+                engine, prompts, monkeypatch, max_new_tokens=4)
 
         base_cfg = serve_config()
         base_cfg["steps_per_print"] = 1
@@ -326,10 +344,14 @@ class TestInferenceEngine:
     def test_per_token_latency_is_the_gap_a_request_sees(
             self, model_and_params):
         """With an injected clock (a prefill program costs 1 s, a decode
-        0.1 s): a request whose neighbour is prefilled between two of its
-        tokens reports the 1.1 s gap, not the decode call's 0.1 s; the
-        TTFT is not among the per-token latencies; and the per-token SLO
-        leg judges each token by its own gap."""
+        0.1 s, charged where the program is enqueued): a request whose
+        neighbour is prefilled between two of its tokens reports the
+        1.1 s gap, not the decode call's 0.1 s; the TTFT is not among the
+        per-token latencies; and the per-token SLO leg judges each token
+        by its own gap.  A token is stamped when the host reads it, one
+        enqueue after its own: the neighbour's prefill lies between the
+        reads of ``first``'s second and third tokens, and the last token
+        is read by a step that enqueues nothing (the clock stands)."""
         model, params = model_and_params
         engine = InferenceEngine(model, params, config=serve_config(
             slo={"ttft_ms": 0, "per_token_ms": 500}))
@@ -352,7 +374,7 @@ class TestInferenceEngine:
         second = engine.request(engine.submit([6, 7, 8, 9],
                                               max_new_tokens=3))
         engine.run()
-        assert first.step_times == pytest.approx([0.1, 0.1, 1.1, 0.1, 0.1])
+        assert first.step_times == pytest.approx([0.1, 1.1, 0.1, 0.1, 0.0])
         assert second.step_times == pytest.approx([0.1, 0.1])
         for request in (first, second):
             assert len(request.step_times) == len(request.generated) - 1
@@ -435,3 +457,316 @@ class TestInferenceEngine:
         config["strict_config"] = True
         with pytest.raises(ValueError, match="kv_block_sise"):
             InferenceEngine(model, params, config=config)
+
+
+# ------------------------------------------------- one program in flight
+def recording(program, calls):
+    """``program`` with the positional arguments of every call kept."""
+    def run(*args):
+        calls.append(args)
+        return program(*args)
+    return run
+
+
+def all_blocks_free(engine):
+    return engine.allocator.free_blocks == engine.allocator.capacity
+
+
+class TestOneProgramInFlight:
+    """The pipelined serve loop: a program's tokens stay on the device as
+    the next decode's input and are read one enqueue late."""
+
+    @pytest.mark.parametrize("slots,caps", [
+        (2, [1, 2, 5, 8, 3, 4, 8, 1]),      # recycled; caps of 1 and 2
+        (3, [8, 7, 6, 5, 4, 3, 2]),
+        (1, [3, 1, 4]),                     # one slot: every edge in turn
+    ], ids=["2-slots", "3-slots", "1-slot"])
+    def test_tokens_match_the_reference_with_slots_recycled(
+            self, model_and_params, slots, caps):
+        model, params = model_and_params
+        engine = InferenceEngine(model, params, config=serve_config(
+            max_batch_slots=slots))
+        prompts = seeded_prompts(len(caps), seed=17 + slots)
+        for i, (p, cap) in enumerate(zip(prompts, caps)):
+            engine.submit(p, max_new_tokens=cap, request_id=f"r{i}")
+        results = engine.run()
+        for i, (p, cap) in enumerate(zip(prompts, caps)):
+            assert results[f"r{i}"]["tokens"] == reference_generate(
+                model, params, p, cap), f"r{i}"
+            assert results[f"r{i}"]["finish_reason"] == REASON_LENGTH
+        assert engine.generated_tokens == sum(caps)
+        assert all_blocks_free(engine) and engine._unread == []
+        engine.close()
+
+    @pytest.mark.parametrize("eos_at", [0, 2], ids=["prefill-token",
+                                                    "third-token"])
+    def test_eos_is_seen_one_iteration_late_and_the_overshoot_dropped(
+            self, model_and_params, eos_at):
+        """The decode after the program that produced EOS (a decode, or
+        the prefill itself) is already enqueued when the host sees it:
+        its token is dropped, the request ends AT the EOS, and the blocks
+        come back."""
+        model, params = model_and_params
+        prompts = seeded_prompts(2, seed=7)
+        refs = [reference_generate(model, params, p, 8) for p in prompts]
+        eos = refs[0][eos_at]
+        engine = InferenceEngine(model, params,
+                                 config=serve_config(eos_token_id=eos))
+        calls = []
+        engine._decode = recording(engine._decode, calls)
+        rids = [engine.submit(p, max_new_tokens=8) for p in prompts]
+        first = engine.request(rids[0])
+        out = engine.run()
+        for rid, p in zip(rids, prompts):
+            want = reference_generate(model, params, p, 8, eos_token_id=eos)
+            assert out[rid]["tokens"] == want
+        assert out[rids[0]]["finish_reason"] == REASON_EOS
+        assert out[rids[0]]["tokens"][-1] == eos
+        n = len(out[rids[0]]["tokens"])
+        assert n < 8
+        # one decode went past the EOS (its position was handed to the
+        # program), inside the grant; its token was booked to nobody
+        positions = [int(np.asarray(c[3])[0]) for c in calls]
+        assert len(prompts[0]) + n - 1 in positions
+        assert first.dispatched == n + 1
+        assert engine.generated_tokens == sum(
+            len(r["tokens"]) for r in out.values())
+        assert all_blocks_free(engine)
+        engine.close()
+
+    def test_a_request_at_its_cap_is_never_dispatched_again(
+            self, model_and_params):
+        """The positions handed to ``decode``: each request's run from
+        its prompt's length to one short of its worst case, then 0 over a
+        null table row (the slot parks) while its last token is read."""
+        model, params = model_and_params
+        engine = InferenceEngine(model, params, config=serve_config(
+            max_batch_slots=2))
+        calls = []
+        engine._decode = recording(engine._decode, calls)
+        prompts = seeded_prompts(2, seed=23, lo=4, hi=12)
+        caps = [3, 6]
+        for i, (p, cap) in enumerate(zip(prompts, caps)):
+            engine.submit(p, max_new_tokens=cap, request_id=f"r{i}")
+        engine.run()
+        for slot, (p, cap) in enumerate(zip(prompts, caps)):
+            handed = [int(np.asarray(c[3])[slot]) for c in calls]
+            live = [x for x in handed if x]
+            assert live == list(range(len(p), len(p) + cap - 1))
+            assert max(live) < len(p) + cap - 1 < 64
+            # parked: position 0 AND the null block in every table entry
+            parked = [c for c, x in zip(calls, handed) if not x]
+            assert parked or cap == max(caps)
+            for c in parked:
+                assert (np.asarray(c[2])[slot] == NULL_BLOCK).all()
+        assert len(calls) == max(caps) - 1
+        engine.close()
+
+    @pytest.mark.parametrize("how", ["deadline", "abort", "requeue"])
+    def test_a_lane_in_flight_is_never_booked_to_the_slots_next_owner(
+            self, model_and_params, how):
+        """One slot: ``a`` leaves it with a program in flight and ``b``
+        (or ``a`` itself, requeued) takes the slot — and with it the
+        freed blocks — in the very next step."""
+        model, params = model_and_params
+        engine = InferenceEngine(model, params, config=serve_config(
+            max_batch_slots=1))
+        pa, pb = seeded_prompts(2, seed=29)
+        a = engine.request(engine.submit(pa, max_new_tokens=8,
+                                         request_id="a"))
+        b = engine.request(engine.submit(pb, max_new_tokens=5,
+                                         request_id="b"))
+        for _ in range(3):
+            engine.step()
+        assert engine._unread and a.dispatched == len(a.generated) + 1
+        held = list(a.generated)
+        if how == "deadline":
+            a.deadline_at = 0.0                 # long past
+        else:
+            engine.scheduler.abort(a)
+            if how == "requeue":
+                a.reset_for_requeue()
+                engine.resubmit(a)
+        engine.step()
+        assert engine.scheduler.slots[0] is b and b.dispatched == 2
+        if how == "deadline":
+            assert a.finish_reason == "deadline" and a.generated == held
+        engine.run()
+        assert b.generated == reference_generate(model, params, pb, 5)
+        if how == "requeue":
+            assert a.generated == reference_generate(model, params, pa, 8)
+            assert a.requeues == 1
+        served = [r for r in (a, b) if r.state == "finished"]
+        assert engine.generated_tokens >= sum(
+            len(r.generated) for r in served)
+        assert all_blocks_free(engine) and engine._unread == []
+        engine.close()
+
+    @pytest.mark.parametrize("leave", ["run", "drain", "drain_deadline",
+                                       "close", "idle_then_resume"])
+    def test_nothing_is_left_unread(self, model_and_params, leave):
+        model, params = model_and_params
+        engine = InferenceEngine(model, params, config=serve_config())
+        prompts = seeded_prompts(3, seed=37)
+        requests = [engine.request(engine.submit(p, max_new_tokens=6))
+                    for p in prompts]
+        for _ in range(2):
+            engine.step()
+        assert engine._unread                   # a program in flight
+        if leave == "drain":
+            done = engine.drain(deadline_secs=0)        # unbounded
+            assert sorted(r.request_id for r in done) == sorted(
+                r.request_id for r in requests)
+        elif leave == "drain_deadline":
+            engine.drain(deadline_secs=1e-9)    # abandons them at once
+            # what was in flight was read all the same
+            assert all(r.dispatched == len(r.generated) for r in requests)
+        elif leave == "close":
+            engine.close()
+        else:
+            engine.run()
+        assert engine._unread == []
+        if leave != "drain_deadline":
+            for p, r in zip(prompts, requests):
+                assert r.generated == reference_generate(model, params, p, 6)
+            assert all_blocks_free(engine)
+        if leave == "idle_then_resume":
+            for _ in range(3):
+                assert engine.step() == []      # idle steps: nothing to do
+            ahead, iterations = (engine.decodes_enqueued_ahead,
+                                 engine.decode_iterations)
+            late = seeded_prompts(1, seed=41)[0]
+            rid = engine.submit(late, max_new_tokens=4)
+            assert engine.run()[rid]["tokens"] == reference_generate(
+                model, params, late, 4)
+            # the pipe was empty: the first decode had nothing ahead of it
+            assert engine.decode_iterations - iterations == 3
+            assert engine.decodes_enqueued_ahead - ahead == 2
+        engine.close()
+        assert engine._unread == []
+
+    def test_host_tables_are_not_written_under_an_unread_program(
+            self, model_and_params):
+        """What a program was handed is what it reads, whenever it runs:
+        the tables on the device share no memory with the host rows the
+        loop keeps writing, and every array handed over still holds what
+        it held at its enqueue after the serve has moved on."""
+        model, params = model_and_params
+        engine = InferenceEngine(model, params, config=serve_config(
+            max_batch_slots=2))
+        handed = []
+
+        def keeping(program, which):
+            def run(*args):
+                for i in which:
+                    assert not np.shares_memory(np.asarray(args[i]),
+                                                engine._tables)
+                    handed.append((args[i], np.array(args[i], copy=True)))
+                return program(*args)
+            return run
+
+        engine._decode = keeping(engine._decode, (2, 3))
+        for bucket in list(engine._prefills):
+            engine._prefills[bucket] = keeping(engine._prefills[bucket],
+                                               (2, 4))
+        for i, p in enumerate(seeded_prompts(5, seed=43)):
+            engine.submit(p, max_new_tokens=3 + i)
+        engine.run()
+        assert len(handed) > 20
+        for array, then in handed:
+            np.testing.assert_array_equal(np.asarray(array), then)
+        engine.close()
+
+    def test_decode_is_enqueued_before_the_last_ones_tokens_are_read(
+            self, model_and_params, monkeypatch):
+        """On a clock that only the programs move: ``decode`` k is
+        entered before the read that returns decode k-1's tokens, every
+        step reads once, and the host's ``generated`` runs one token
+        behind what was dispatched while the pipe is full."""
+        model, params = model_and_params
+        engine = InferenceEngine(model, params, config=serve_config())
+        clock, events = [100.0], []
+        engine._clock = lambda: clock[0]
+        decode, real_get = engine._decode, jax.device_get
+
+        def entered(*args):
+            clock[0] += 0.01
+            events.append("decode")
+            return decode(*args)
+
+        def reading(x):
+            events.append("read")
+            return real_get(x)
+
+        engine._decode = entered
+        monkeypatch.setattr(jax, "device_get", reading)
+        request = engine.request(engine.submit([5, 6, 7], max_new_tokens=6))
+        for k in range(1, 6):
+            engine.step()
+            assert events == ["decode", "read"] * k
+            assert request.dispatched == k + 1
+            assert len(request.generated) == k
+        assert engine.step() == [request]       # the pipe drains: a read
+        assert events == ["decode", "read"] * 5 + ["read"]
+        monkeypatch.setattr(jax, "device_get", real_get)
+        assert request.generated == reference_generate(
+            model, params, [5, 6, 7], 6)
+        # stamped when read: the first token one decode call after the
+        # prefill's enqueue, the others a decode call apart, the last by
+        # the step that enqueued nothing
+        assert request.first_token_at == pytest.approx(100.01)
+        assert request.step_times == pytest.approx(
+            [0.01, 0.01, 0.01, 0.01, 0.0])
+        engine.close()
+
+    def test_a_prefill_that_raises_releases_slot_and_grant(
+            self, model_and_params):
+        model, params = model_and_params
+        engine = InferenceEngine(model, params, config=serve_config(
+            max_batch_slots=2))
+        ok = engine.request(engine.submit([1, 2, 3], max_new_tokens=5))
+        engine.step()
+        engine.step()
+        real = dict(engine._prefills)
+
+        def exploding(*args):
+            raise RuntimeError("injected prefill fault")
+
+        engine._prefills = {b: exploding for b in real}
+        engine.submit([4, 5, 6, 7], max_new_tokens=5, request_id="bad")
+        with pytest.raises(RuntimeError, match="injected"):
+            engine.step()
+        assert engine.scheduler.active_count == 1
+        engine._prefills = real
+        engine.scheduler.waiting.clear()
+        engine.run()
+        # the neighbour's program in flight was not lost to the fault
+        assert ok.generated == reference_generate(model, params,
+                                                  [1, 2, 3], 5)
+        assert all_blocks_free(engine)
+        engine.close()
+
+    def test_enqueued_ahead_share_gauge_reads_one_over_a_busy_run(
+            self, model_and_params, tmp_path):
+        model, params = model_and_params
+        config = serve_config(max_batch_slots=2)
+        config["steps_per_print"] = 3
+        config["telemetry"] = {"enabled": True, "run_dir": str(tmp_path)}
+        engine = InferenceEngine(model, params, config=config)
+        # caps staggered so that the two slots never finish together
+        # (that would drain the pipe, as an idle engine does)
+        for p, cap in zip(seeded_prompts(6, seed=47), [9, 4, 6, 7, 5, 8]):
+            engine.submit(p, max_new_tokens=cap)
+        gauge = engine.telemetry.registry.gauge(
+            "serving/enqueued_ahead_share")
+        seen = []
+        while not engine.scheduler.idle():
+            engine.step()
+            if engine.decode_iterations % 3 == 0:
+                seen.append(gauge.value)
+        # the first window holds the first decode, which had nothing
+        # ahead of it; every later one is full
+        assert seen[0] == pytest.approx(2 / 3)
+        assert len(seen) > 4 and set(seen[1:]) == {1.0}, seen
+        assert engine.decodes_enqueued_ahead == engine.decode_iterations - 1
+        engine.close()

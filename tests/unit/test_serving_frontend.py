@@ -344,6 +344,28 @@ class TestServingFrontend:
         for e in replicas:
             e.close()
 
+    def test_dead_replicas_program_in_flight_is_read_and_dropped(
+            self, model_and_params):
+        """A replica dies with a decode enqueued and unread: its
+        requests move on, and when the dead engine is closed the
+        program is read (nothing stays unread) and its tokens are
+        booked to nobody — the requests live on another replica now."""
+        replicas = _fleet(model_and_params)
+        fe = ServingFrontend(replicas)
+        for p in seeded_prompts(4, seed=57):
+            fe.submit(p, max_new_tokens=6)
+        for _ in range(2):
+            fe.step()
+        dead = replicas[0]
+        assert dead._unread, "replica 0 should have a program in flight"
+        booked = dead.generated_tokens
+        assert fe.mark_dead(0)
+        dead.close()
+        assert dead._unread == [] and dead.generated_tokens == booked
+        assert dead.allocator.free_blocks == dead.allocator.capacity
+        assert len(fe.run()) == 4
+        replicas[1].close()
+
     def test_replica_that_raises_mid_step_is_evicted(self,
                                                      model_and_params):
         model, params = model_and_params

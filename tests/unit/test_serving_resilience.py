@@ -24,7 +24,8 @@ from deepspeed_tpu.resilience.constants import (EXIT_INTEGRITY_EVICT,
                                                 FleetIntegrityError,
                                                 TrainingDivergedError)
 
-from .test_inference import (seeded_prompts, serve_config, tiny_model,
+from .test_inference import (count_device_gets, seeded_prompts,
+                             serve_config, tiny_model,
                              model_and_params)  # noqa: F401 — fixture
 
 
@@ -377,31 +378,18 @@ def test_zero_added_host_syncs_with_health_armed(model_and_params,
     ZERO jax.device_get calls over the bare serve loop — the
     fingerprint scalar rides the next-token fetch."""
     model, params = model_and_params
-    prompts = seeded_prompts(4, seed=31)
+    prompts = seeded_prompts(6, seed=31)
 
     def count_gets(health_run_dir):
-        config = serve_config()
+        config = serve_config(max_batch_slots=2)
         config["steps_per_print"] = 1
         engine = InferenceEngine(model, params, config=config)
         if health_run_dir is not None:
             engine.attach_health(sres.ServingHealth(
                 engine, health_run_dir, 0, 1, peer_timeout_secs=60.0))
-        counts = {"n": 0}
-        real_get = jax.device_get
-
-        def counting_get(x):
-            counts["n"] += 1
-            return real_get(x)
-
-        monkeypatch.setattr(jax, "device_get", counting_get)
-        try:
-            for i, p in enumerate(prompts):
-                engine.submit(p, max_new_tokens=4, request_id=f"r{i}")
-            engine.run()
-        finally:
-            monkeypatch.setattr(jax, "device_get", real_get)
-        engine.close()
-        return counts["n"]
+        # no step() syncs twice, admissions and the fingerprint included
+        return count_device_gets(engine, prompts, monkeypatch,
+                                 max_new_tokens=4)
 
     base = count_gets(None)
     armed = count_gets(tmp_path)

@@ -492,6 +492,39 @@ def test_serving_spans_nest_in_the_profiler_trace(tmp_path):
 
 @pytest.mark.parametrize("telemetry_on", [False, True],
                          ids=["telemetry-off", "telemetry-on"])
+def test_decode_spans_say_whether_a_program_was_in_flight(tmp_path,
+                                                          telemetry_on):
+    """``ds:decode`` carries ``in_flight``: 1 where the decode was
+    enqueued while the previous one's outputs were still unread, 0 where
+    the pipe was empty (the first decode after the engine stood idle).
+    The read that drains the pipe is ``ds:step.fetch`` /
+    ``ds:step.account``, outside any ``ds:decode``."""
+    config = {"steps_per_print": 100}
+    if telemetry_on:
+        config["telemetry"] = {"enabled": True,
+                               "run_dir": str(tmp_path / "run")}
+    engine = tiny_serving_engine(**config)
+    engine.submit([1, 2, 3], request_id="warm")
+    engine.run()                             # compile outside
+
+    def serve():
+        for prompt in ([5, 6, 7, 8, 9], [1, 2]):
+            engine.submit(prompt)            # 4 tokens each: 3 decodes
+            engine.run()                     # idle in between
+
+    spans = profile(tmp_path / "profile", serve)
+    engine.close()
+    decodes = [s for s in spans if s[0] == "ds:decode"]
+    assert [int(s[3]["in_flight"]) for s in decodes] == [0, 1, 1, 0, 1, 1]
+    drains = [s for s in spans if s[0] == "ds:step.fetch"]
+    assert len(drains) == 2 == len(
+        [s for s in spans if s[0] == "ds:step.account"])
+    assert not any(d[1] <= s[1] and s[2] <= d[2]
+                   for s in drains for d in decodes)
+
+
+@pytest.mark.parametrize("telemetry_on", [False, True],
+                         ids=["telemetry-off", "telemetry-on"])
 def test_decode_span_and_gauge_count_the_live_blocks(tmp_path, telemetry_on):
     """``ds:decode`` carries ``live_blocks`` beside ``active`` — the KV
     blocks the paged kernel walks that iteration, ``ceil(context_len /
@@ -524,8 +557,9 @@ def test_decode_span_and_gauge_count_the_live_blocks(tmp_path, telemetry_on):
     assert [d["live_blocks"] for d in decodes] == [3, 3, 4]
     assert [d["active"] for d in decodes] == [2, 2, 2]
     if telemetry_on:
-        # 2 slots x 8 blocks a sequence; one iteration a window
-        assert shares[:3] == [3 / 16, 3 / 16, 4 / 16]
+        # 2 slots x 8 blocks a sequence; one iteration a window, booked
+        # when its tokens are read: one step after the one that enqueued it
+        assert shares[:4] == [0.0, 3 / 16, 3 / 16, 4 / 16]
     else:
         # no registry at all: the count is one integer sum an iteration
         assert not engine.telemetry.enabled
@@ -544,13 +578,19 @@ def test_scheduler_counts_live_blocks_of_a_hand_built_slot_state():
         "token_budget": 256}})
     sched = ContinuousBatchScheduler(icfg, BlockAllocator(icfg.kv_blocks))
     assert sched.live_blocks() == 0
-    # context_len (prompt + generated) 1, 8, 9 and an empty slot:
-    # ceil(./8) = 1, 1, 2 — the new token's own position is in the count
-    for slot, (n_prompt, n_generated) in enumerate([(1, 0), (5, 3), (8, 1)]):
+    # prompt + dispatched tokens (read or still in flight) 1, 8, 9 and an
+    # empty slot: ceil(./8) = 1, 1, 2 — the new token's own position is
+    # in the count
+    for slot, (n_prompt, n_dispatched) in enumerate([(1, 0), (5, 3),
+                                                     (8, 1)]):
         request = Request(f"r{slot}", list(range(n_prompt)), 16)
-        request.generated = list(range(n_generated))
+        request.dispatched = n_dispatched
         sched.slots[slot] = request
     assert sched.live_blocks() == 1 + 1 + 2
+    # a request with every token dispatched is not advanced again: its
+    # slot parks, and the next decode reads none of its blocks
+    sched.slots[2].dispatched = 16
+    assert sched.live_blocks() == 1 + 1 and sched.decoding_count == 2
 
 
 # -------------------------------------------------------- engine wiring
