@@ -197,7 +197,7 @@ def _train(name, model, config, mesh, batches, order, stats, geometry,
 
 def train_bert(mesh, seed, stats, geometry, cfg=None, batch=8, seq=512,
                n_pred=80, steps=5, expect_kernel=True):
-    """BERT-large pretraining steps as ``bench.py`` builds them: seq 512,
+    """BERT-large pretraining steps in bing_bert's phase-2 shape: seq 512,
     dropout 0.1, ``max_predictions_per_seq``, bf16, Adam."""
     from deepspeed_tpu.models import BertConfig, BertForPreTrainingTPU
 
@@ -222,7 +222,7 @@ def train_bert(mesh, seed, stats, geometry, cfg=None, batch=8, seq=512,
                 "next_sentence_labels": rng.integers(
                     0, 2, size=(batch,)).astype(np.int32)}
 
-    # lr: with no warm-up the post-LN stack overshoots at bench.py's 1e-4
+    # lr: with no warm-up the post-LN stack overshoots at lr 1e-4
     # (on the chip the loss went 11.34, 11.45, 13.53 before it fell);
     # 1e-5 descends from the first step, ~0.1 a step, far above the
     # dropout noise of 640 predicted tokens

@@ -672,9 +672,7 @@ class InferenceEngine:
     # receipts (the training engine's surface, serving programs)
     # ------------------------------------------------------------------
     def serving_receipt(self):
-        """Aggregate serve metrics over every finished request —
-        the record ``examples/bench_serving.py`` registers under
-        ``bench_schema``."""
+        """Aggregate serve metrics over every finished request."""
         finished = [r for r in self._results.values()
                     if r.state == "finished"]
         lats = sorted(t for r in finished for t in r.step_times)

@@ -281,8 +281,8 @@ class ServingFrontend:
 
     # -- receipts -------------------------------------------------------
     def resilience_receipt(self):
-        """The requeue/shed/deadline/recovery counters the serving
-        bench and the chaos dryrun leg quote."""
+        """The requeue/shed/deadline/recovery counters the chaos
+        dryrun leg quotes."""
         latencies = [rec[2] for rec in self._recoveries
                      if rec[2] is not None]
         return {

@@ -314,11 +314,10 @@ class ServingObservability:
         self._win_live_blocks = 0
         self._win_traces = set()
 
-    # -- the bench receipt ----------------------------------------------
+    # -- the receipt ----------------------------------------------------
     def receipt(self):
         """Run-cumulative occupancy/SLO receipt — merged into
-        ``engine.serving_receipt()`` so the serving bench and the
-        dryrun leg quote schema-registered fields."""
+        ``engine.serving_receipt()``, which the dryrun leg quotes."""
         icfg = self.icfg
         iters = self._cum_iterations
         wall = max(time.monotonic() - self._run_start, 1e-9)

@@ -19,7 +19,6 @@ CUDA ``DeepSpeedTransformerLayer`` plays in the reference
   ``ops/transformer/transformer.py:39-154``).
 """
 
-import os
 from typing import Any, Dict, Optional
 
 import jax
@@ -249,16 +248,10 @@ class TransformerLayer:
             # Pallas LUT-driven kernel on TPU when the layout blocks are
             # MXU-shaped and no key-padding mask is needed; the gather
             # implementation stays as the general/CPU path.
-            # DS_SPARSE_FLASH=never forces the gather path.  Read at TRACE
-            # time (like DS_FLASH_ATTENTION, ops/transformer/attention.py):
-            # set it before the first jitted call — flipping it afterwards
-            # has no effect on already-compiled programs (jit cache).
             blk = s // layout.shape[1]
             use_kernel = (kpm_add is None
                           and current_platform() == "tpu"
-                          and blk % 128 == 0 and q.shape[-1] % 64 == 0
-                          and os.environ.get("DS_SPARSE_FLASH",
-                                             "auto") != "never")
+                          and blk % 128 == 0 and q.shape[-1] % 64 == 0)
             if use_kernel:
                 from ..ops.sparse_attention.flash_block_sparse import (
                     flash_block_sparse_attention)

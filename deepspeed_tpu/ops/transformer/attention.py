@@ -10,7 +10,6 @@ bandwidth story on TPU.
 """
 
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -24,7 +23,7 @@ from ..op_common import random_keep
 #   fwd+bwd; seq 2048: 7.3 vs 15.8 ms — see flash_attention._auto_blocks,
 #   the authoritative tuning record) AND never materializes the [s, s]
 #   score tensor, which is also what lifts the memory ceiling for long
-#   sequences.  DS_FLASH_ATTENTION=always|never|auto overrides.
+#   sequences.
 PALLAS_MIN_SEQ = 512
 PALLAS_MIN_SCORE_BYTES = 2 * 1024 ** 3
 
@@ -32,14 +31,9 @@ PALLAS_MIN_SCORE_BYTES = 2 * 1024 ** 3
 def _use_pallas(q, k):
     from ...parallel.mesh import current_platform, get_current_mesh
 
-    mode = os.environ.get("DS_FLASH_ATTENTION", "auto")
-    if mode == "never":
-        return False
     shapes_ok = (current_platform() == "tpu" and q.shape[1] >= 128
                  and q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0
                  and q.shape[-1] % 64 == 0)
-    if mode == "always":
-        return shapes_ok
     if q.shape[1] >= PALLAS_MIN_SEQ and k.shape[1] >= PALLAS_MIN_SEQ:
         return shapes_ok
     b, sq, h, _ = q.shape

@@ -34,7 +34,6 @@ saved mask.
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -49,20 +48,6 @@ NEG_INF = -1e30
 # Running-max floor: keeps exp(NEG_INF - m) == 0 even for rows where every
 # key is masked out (m would otherwise be NEG_INF and exp(0) = 1).
 MAX_FLOOR = -1e20
-
-# Base-2 softmax: fold log2(e) into the score scale so the per-element
-# transcendental is exp2 instead of exp (one fewer VPU multiply per score
-# entry; the probabilities are bit-comparable — exp2(x·log2e) == exp(x) up
-# to fp rounding).  The logsumexp residual is stored in base 2 so forward
-# and backward agree; the natural-scale 1/√d still lands on dq/dk.  Read
-# once at import: the choice bakes into the jit cache (same contract as
-# DS_FLASH_ATTENTION — see ADVICE round 3).
-EXP2 = os.environ.get("DS_FLASH_EXP2", "0") != "0"
-LOG2E = 1.4426950408889634
-
-
-def _ex(x, exp2):
-    return jnp.exp2(x) if exp2 else jnp.exp(x)
 
 
 def _auto_blocks(s, kv_len, d=64, causal=False):
@@ -94,9 +79,9 @@ def _auto_blocks(s, kv_len, d=64, causal=False):
     the 2x causal MXU waste at this size.  So for causal shapes up to
     s=1024 the auto policy now prefers one full tile; past that the
     streamed q512 geometry still wins (the waste grows quadratically).
-    (Also measured, negative: base-2 softmax (DS_FLASH_EXP2) is a wash —
-    Mosaic's exp already costs the same as exp2 — and a masked/unmasked
-    tile split gains zero; both knobs documented, not defaulted.)
+    (Also measured, negative: base-2 softmax is a wash — Mosaic's exp
+    already costs the same as exp2 — and a masked/unmasked tile split
+    gains zero.)
     """
     def pick(n, candidates):
         for c in candidates:
@@ -174,7 +159,7 @@ def _scores(q_blk, k_blk, scale, causal, masked, kvm_ref, j, kb, block_q,
     return s
 
 
-def _fwd_kernel(*refs, scale, causal, masked, dropout, single, exp2):
+def _fwd_kernel(*refs, scale, causal, masked, dropout, single):
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[:3]
     rest = refs[3:]
@@ -186,16 +171,14 @@ def _fwd_kernel(*refs, scale, causal, masked, dropout, single, exp2):
     block_k = k_ref.shape[1]
     i, j, kb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     n_kb = pl.num_programs(2)
-    score_scale = scale * LOG2E if exp2 else scale
-    log = jnp.log2 if exp2 else jnp.log
 
     if single:
         # one k block: straight-line softmax, no scratch round-trips (the
         # common short-sequence case; ~25% faster than the streamed form)
-        s = _scores(q_ref[0], k_ref[0], score_scale, causal, masked, kvm_ref,
+        s = _scores(q_ref[0], k_ref[0], scale, causal, masked, kvm_ref,
                     j, kb, block_q, block_k)
         m = jnp.maximum(jnp.max(s, axis=1, keepdims=True), MAX_FLOOR)
-        p = _ex(s - m, exp2)
+        p = jnp.exp(s - m)
         l = jnp.sum(p, axis=1, keepdims=True)
         if dropout:
             thresh, inv_keep = _dropout_thresh(dropout)
@@ -206,7 +189,7 @@ def _fwd_kernel(*refs, scale, causal, masked, dropout, single, exp2):
                                   preferred_element_type=jnp.float32)
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m + log(l_safe))[:, 0]
+        lse_ref[0, 0] = (m + jnp.log(l_safe))[:, 0]
         return
 
     @pl.when(kb == 0)
@@ -227,13 +210,13 @@ def _fwd_kernel(*refs, scale, causal, masked, dropout, single, exp2):
     # VPU work with the dots already; reverted to the single body)
     @pl.when(needed)
     def _step():
-        s = _scores(q_ref[0], k_ref[0], score_scale, causal, masked, kvm_ref,
+        s = _scores(q_ref[0], k_ref[0], scale, causal, masked, kvm_ref,
                     j, kb, block_q, block_k)
         m, l = m_sc[...], l_sc[...]
         m_new = jnp.maximum(jnp.maximum(m, jnp.max(s, axis=1, keepdims=True)),
                             MAX_FLOOR)
-        p = _ex(s - m_new, exp2)
-        corr = _ex(m - m_new, exp2)
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
         # l accumulates the UNdropped sum (softmax normalizer); dropout hits
         # only the value accumulation, so out == dropout(softmax(s)) @ v.
         l_sc[...] = l * corr + jnp.sum(p, axis=1, keepdims=True)
@@ -251,10 +234,10 @@ def _fwd_kernel(*refs, scale, causal, masked, dropout, single, exp2):
         l = l_sc[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc_sc[...] / l_safe).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_sc[...] + log(l_safe))[:, 0]
+        lse_ref[0, 0] = (m_sc[...] + jnp.log(l_safe))[:, 0]
 
 
-def _bwd_dq_kernel(*refs, scale, causal, masked, dropout, single, exp2):
+def _bwd_dq_kernel(*refs, scale, causal, masked, dropout, single):
     refs = list(refs)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     rest = refs[6:]
@@ -267,12 +250,10 @@ def _bwd_dq_kernel(*refs, scale, causal, masked, dropout, single, exp2):
     i, j, kb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     n_kb = pl.num_programs(2)
 
-    score_scale = scale * LOG2E if exp2 else scale
-
     def tile_dq():
-        s = _scores(q_ref[0], k_ref[0], score_scale, causal, masked, kvm_ref,
+        s = _scores(q_ref[0], k_ref[0], scale, causal, masked, kvm_ref,
                     j, kb, block_q, block_k)
-        p = _ex(s - lse_ref[0, 0][:, None], exp2)
+        p = jnp.exp(s - lse_ref[0, 0][:, None])
         dp = jax.lax.dot_general(do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         if dropout:
@@ -302,7 +283,7 @@ def _bwd_dq_kernel(*refs, scale, causal, masked, dropout, single, exp2):
         dq_ref[0] = (dq_sc[...] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(*refs, scale, causal, masked, dropout, single, exp2):
+def _bwd_dkv_kernel(*refs, scale, causal, masked, dropout, single):
     refs = list(refs)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     rest = refs[6:]
@@ -316,12 +297,10 @@ def _bwd_dkv_kernel(*refs, scale, causal, masked, dropout, single, exp2):
     i, kb, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     n_qb = pl.num_programs(2)
 
-    score_scale = scale * LOG2E if exp2 else scale
-
     def tile_dkdv():
-        s = _scores(q_ref[0], k_ref[0], score_scale, causal, masked, kvm_ref,
+        s = _scores(q_ref[0], k_ref[0], scale, causal, masked, kvm_ref,
                     j, kb, block_q, block_k)
-        p = _ex(s - lse_ref[0, 0][:, None], exp2)  # [Bq, Bk] fp32
+        p = jnp.exp(s - lse_ref[0, 0][:, None])  # [Bq, Bk] fp32
         dp = jax.lax.dot_general(do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         if dropout:
@@ -369,7 +348,7 @@ def _bwd_dkv_kernel(*refs, scale, causal, masked, dropout, single, exp2):
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
 
 
-def _bwd_fused_kernel(*refs, scale, causal, masked, dropout, exp2):
+def _bwd_fused_kernel(*refs, scale, causal, masked, dropout):
     """Single-tile fused backward: dq, dk, dv from ONE score
     materialization.  The streamed pair (_bwd_dq_kernel + _bwd_dkv_kernel)
     each recompute the q·kᵀ scores, the softmax exp, the dᵒ·vᵀ dot and —
@@ -388,11 +367,10 @@ def _bwd_fused_kernel(*refs, scale, causal, masked, dropout, exp2):
     block_q, d = q_ref.shape[1], q_ref.shape[2]
     block_k = k_ref.shape[1]
     i = pl.program_id(0)
-    score_scale = scale * LOG2E if exp2 else scale
 
-    s = _scores(q_ref[0], k_ref[0], score_scale, causal, masked, kvm_ref,
+    s = _scores(q_ref[0], k_ref[0], scale, causal, masked, kvm_ref,
                 0, 0, block_q, block_k)
-    p = _ex(s - lse_ref[0, 0][:, None], exp2)  # [Bq, Bk] fp32
+    p = jnp.exp(s - lse_ref[0, 0][:, None])  # [Bq, Bk] fp32
     dp = jax.lax.dot_general(do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
     if dropout:
@@ -564,7 +542,7 @@ def _flash_fwd(q, k, v, kv_mask, dropout_seed, causal, block_q, block_k,
 
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                masked=masked, dropout=drop,
-                               single=(n_kb == 1), exp2=EXP2)
+                               single=(n_kb == 1))
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, n_qb, n_kb),
@@ -643,7 +621,7 @@ def _flash_bwd_rule(causal, block_q, block_k, interpret, dropout_rate, res, g):
                             if masked else ())
         dq, dk, dv = pl.pallas_call(
             functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
-                              masked=masked, dropout=drop, exp2=EXP2),
+                              masked=masked, dropout=drop),
             grid=(bh,),
             in_specs=[
                 pl.BlockSpec((1, block_q, d), lambda i: (i, 0, 0)),
@@ -674,8 +652,7 @@ def _flash_bwd_rule(causal, block_q, block_k, interpret, dropout_rate, res, g):
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          masked=masked, dropout=drop, single=(n_kb == 1),
-                          exp2=EXP2),
+                          masked=masked, dropout=drop, single=(n_kb == 1)),
         grid=(bh, n_qb, n_kb),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
@@ -701,8 +678,7 @@ def _flash_bwd_rule(causal, block_q, block_k, interpret, dropout_rate, res, g):
                       if masked else ())
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          masked=masked, dropout=drop, single=(n_qb == 1),
-                          exp2=EXP2),
+                          masked=masked, dropout=drop, single=(n_qb == 1)),
         grid=(bh, n_kb, n_qb),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, kb, j: (i, j, 0)),

@@ -203,9 +203,8 @@ class FlopsProfile:
         return self.flops / (self.wall_ms / 1e3) / 1e12
 
     def mfu(self, device=None):
-        """Model-FLOPs utilisation against the chip's bf16 peak — the
-        SAME peak table bench.py quotes (``profiling/utilization.py``),
-        so profiler and bench utilisation cannot drift.  None without a
+        """Model-FLOPs utilisation against the chip's bf16 peak (the
+        table in ``profiling/utilization.py``).  None without a
         wall time or on a device that is not a TPU (it has no peak)."""
         if not self.wall_ms:
             return None

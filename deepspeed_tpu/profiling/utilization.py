@@ -1,8 +1,8 @@
 """Chip peak FLOP/s table + model-FLOPs-utilisation (MFU) math.
 
-The ONE implementation shared by ``bench.py`` (three reporting sites),
-the flops profiler, and the capacity planner — utilisation numbers must
-not drift between reporters because each carried its own peak table.
+The ONE implementation shared by the flops profiler and the capacity
+planner — utilisation numbers must not drift between reporters because
+each carried its own peak table.
 
 A TPU whose ``device_kind`` is not in the tables is an ERROR, not a
 default: a utilisation against a guessed peak is a wrong number under a
@@ -88,6 +88,5 @@ def achieved_tflops(samples_per_sec, flops_per_sample):
 
 def model_flops_utilization(samples_per_sec, flops_per_sample,
                             peak_tflops):
-    """MFU in [0, 1] (values > 1 mean the harness measured nothing —
-    callers hard-fail on that, see ``bench.py``)."""
+    """MFU in [0, 1] (values > 1 mean the caller measured nothing)."""
     return achieved_tflops(samples_per_sec, flops_per_sample) / peak_tflops

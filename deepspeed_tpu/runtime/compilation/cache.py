@@ -8,7 +8,7 @@ module + compile options (and on the cache directory's own path, so a
 directory that moves never hits); this module decides where it lives.
 
 One rule, for every process of the framework (training and serving
-engines, ``bench.py``, ``chip_smoke.py``, the test harness):
+engines, ``benchmarks.run``, ``chip_smoke.py``, the test harness):
 
 - where ``JAX_COMPILATION_CACHE_DIR`` is set, jax uses it natively and
   this module sets no directory in code;

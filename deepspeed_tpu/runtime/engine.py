@@ -1085,7 +1085,7 @@ class DeepSpeedEngine:
         if self._module_params is not None:
             return self._module_params
         with self.mesh:
-            return self._cast_params_fn(self.state["master"])
+            return self._cast_params_fn(self._device_master())
 
     def get_master_params(self):
         return self.state["master"]
@@ -2316,7 +2316,7 @@ class DeepSpeedEngine:
             Each chunk's (p, m, v[, g]) slices load from pinned host,
             update on device, and write back in place via
             ``dynamic_update_slice`` (concatenated fresh outputs defeat
-            host donation aliasing — examples/exp_host_stream.py).
+            host donation aliasing).
             Within one group the SSA chain serializes chunk k's loads
             behind chunk k-1's write-back — that preserves in-place
             aliasing (reading the ORIGINAL buffer instead measured
@@ -3485,21 +3485,23 @@ class DeepSpeedEngine:
         self.state["master"] = jax.device_put(self.state["master"], target_m)
         self.state["opt"] = jax.device_put(self.state["opt"], target_o)
 
+    def _device_master(self):
+        """The flat master where a compiled program can read it: eager
+        offload parks it in pinned host memory between steps."""
+        m = self.state["master"]
+        if self._offload_eager and m.sharding.memory_kind == "pinned_host":
+            m = jax.device_put(m, self.flat.master_device_sharding)
+        return m
+
     def _refresh_module_params(self):
         if self.zero_stage >= 3:
             self._module_params = None
         else:
-            m = self.state["master"]
-            if self._offload_eager and m.sharding.memory_kind == "pinned_host":
-                m = jax.device_put(m, self.flat.master_device_sharding)
-            self._module_params = self._cast_params_fn(m)
+            self._module_params = self._cast_params_fn(self._device_master())
 
     def _forward_params(self):
         if self.zero_stage >= 3:
-            m = self.state["master"]
-            if self._offload_eager and m.sharding.memory_kind == "pinned_host":
-                m = jax.device_put(m, self.flat.master_device_sharding)
-            return m
+            return self._device_master()
         return self._module_params
 
     def _shard_batch(self, batch):

@@ -44,7 +44,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ...ops.op_common import LANES, build_segments
 from .stream import UNIFORM_MIN_CHUNKS
 
-# Measured on the round-4 bench attachment (examples/exp_host_stream.py):
+# Measured on the round-4 chip attachment:
 # compiling a program that touches a single host-memory-space buffer larger
 # than ~5 GB SIGABRTs the AOT toolchain (wall bisected to between 4.92 and
 # 5.53 GB), while the total pinned pool is fine to >= 20 GB.  Offloaded
@@ -222,16 +222,20 @@ class FlatParamCoordinator:
         # CPU suite execute the chunk-streamed update end-to-end
         # (tests/unit/test_offload_stream.py) instead of leaving its
         # numerics TPU-only.
+        platform = mesh.devices.flat[0].platform
         self.injit_placement = (
-            mesh.devices.flat[0].platform == "tpu"
+            platform == "tpu"
             or os.environ.get("DS_OFFLOAD_FORCE_INJIT") == "1")
         self._host_memory_kind = None
-        if cpu_offload:
+        # the CPU backend names a pinned_host space but cannot lower an
+        # in-jit placement into it: the forced in-jit form takes the CPU
+        # as the single space it is
+        if cpu_offload and not (platform == "cpu" and self.injit_placement):
             try:
                 mesh.devices.flat[0].memory("pinned_host")
                 self._host_memory_kind = "pinned_host"
             except Exception as e:
-                if mesh.devices.flat[0].platform != "cpu":
+                if platform != "cpu":
                     # loud by design: a silent on-device fallback would
                     # claim the reference's "10x bigger models" capability
                     # (ZeRO-Offload, stage2.py:326-342) without delivering

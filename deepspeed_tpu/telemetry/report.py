@@ -28,11 +28,8 @@ per-rank event streams (``events-rank*.jsonl``) and metric snapshots
   (``profiling/doctor.py`` — needs the run's ``programs/`` sidecars);
 - with ``--json``, a machine-readable report document — summary, comm,
   elastic, and (with ``--doctor``) doctor sections, plus the merged
-  event list under ``events`` — so CI and the bench harness consume
-  verdicts without scraping text;
-- with ``--diff OLD NEW``, a threshold-gated diff of two
-  ``BENCH_r*.json`` driver artifacts (``tools/bench_diff.py`` — the
-  bench regression gate; ``run_dir`` is optional in this mode).
+  event list under ``events`` — so CI consumes verdicts without
+  scraping text.
 
 Stdlib-only: runs anywhere the artifacts are mounted, no jax required.
 """
@@ -673,8 +670,8 @@ REPORT_JSON_SCHEMA_VERSION = 1
 def report_json(run_dir, strict=False, doctor=False,
                 grad_accumulation_steps=1):
     """Machine-readable report document: summary / comm / elastic
-    sections (+ the doctor verdict with ``doctor=True``) so CI and the
-    bench harness consume verdicts without scraping text.  The merged
+    sections (+ the doctor verdict with ``doctor=True``) so CI consumes
+    verdicts without scraping text.  The merged
     event list rides under ``events``."""
     records = ev.read_events(run_dir, strict=strict)
     streams = sorted({str(r.get("_stream")) for r in records})
@@ -752,10 +749,9 @@ def main(argv=None):
     sub = parser.add_subparsers(dest="command", required=True)
     rep = sub.add_parser("report",
                          help="timeline + metric summary for one run dir")
-    rep.add_argument("run_dir", nargs="?", default=None,
+    rep.add_argument("run_dir",
                      help="telemetry run directory "
-                          "(holds events-rank*.jsonl); optional with "
-                          "--diff")
+                          "(holds events-rank*.jsonl)")
     rep.add_argument("--prometheus", action="store_true",
                      help="emit a Prometheus text dump instead of the "
                           "human report")
@@ -785,55 +781,24 @@ def main(argv=None):
                      help="micro-batch multiplicity for the doctor's "
                           "step-wise program weighting (fused step "
                           "programs ignore it)")
-    rep.add_argument("--diff", nargs=2, metavar=("OLD", "NEW"),
-                     help="diff two BENCH_r*.json driver artifacts with "
-                          "the bench_schema regression thresholds")
     args = parser.parse_args(argv)
 
-    diff_regressed = False
-    if args.diff:
-        from ..tools.bench_diff import (diff_records, format_diff,
-                                        load_bench_record, regressions)
-
-        old_path, new_path = args.diff
-        try:
-            diffs = diff_records(load_bench_record(old_path),
-                                 load_bench_record(new_path))
-        except (OSError, ValueError) as e:
-            print(f"error: {e}", file=sys.stderr)
-            return 2
-        diff_regressed = bool(regressions(diffs))
-        if args.as_json:
-            # one JSON document only: --json + --diff emits the diff
-            # rows and skips the run report even when run_dir is given
-            json.dump(diffs, sys.stdout, indent=1)
-            sys.stdout.write("\n")
-            return 1 if diff_regressed else 0
-        print(format_diff(diffs, old_path, new_path))
-        if args.run_dir is None:
-            return 1 if diff_regressed else 0
-        print()
-
-    if args.run_dir is None:
-        print("error: run_dir is required without --diff", file=sys.stderr)
-        return 2
     if not os.path.isdir(args.run_dir):
         print(f"error: {args.run_dir} is not a directory", file=sys.stderr)
         return 2
     if args.prometheus:
         sys.stdout.write(prometheus_dump(args.run_dir))
-        return 1 if diff_regressed else 0
+        return 0
     if args.as_json:
         doc = report_json(args.run_dir, strict=args.strict,
                           doctor=args.doctor,
                           grad_accumulation_steps=args.grad_accum)
         json.dump(doc, sys.stdout, indent=1)
         sys.stdout.write("\n")
-        return 1 if diff_regressed else 0
+        return 0
     text, records = generate_report(args.run_dir, strict=args.strict,
                                     comm=args.comm, doctor=args.doctor,
                                     grad_accumulation_steps=args.grad_accum,
                                     serving=args.serving)
     sys.stdout.write(text)
-    # a regressed --diff gates the combined form too (CI relies on it)
-    return 1 if (diff_regressed or not records) else 0
+    return 0 if records else 1

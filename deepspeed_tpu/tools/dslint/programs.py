@@ -938,8 +938,7 @@ def program_overlap(artifact: ProgramArtifact):
 
 # relative growth of a program's exposed_wire_seconds beyond its
 # baseline-recorded metric that trips DSO704 (generous: the figure is
-# model-derived and roofline-table sensitive, same rationale as the
-# bench_diff exposed_wire_seconds gate)
+# model-derived and roofline-table sensitive)
 EXPOSED_WIRE_RATCHET_TOL = 0.25
 # absolute floor on the ratchet ceiling: a recorded metric at (or
 # rounding to) 0.0 must not make every epsilon of cost-model noise a

@@ -94,6 +94,18 @@ def main():
         training_data=dataset, auto_resume=True)
     fresh = engine.global_steps == 0
 
+    # start line: the seeded faults land on a rank's FIRST life, told by
+    # ``fresh``.  Without it a rank that comes up after rank 0 committed
+    # a step auto-resumes there, looks respawned and never takes its
+    # fault.  Later lives find every first life's mark and pass at once.
+    os.makedirs(out_dir, exist_ok=True)
+    open(os.path.join(out_dir, f"ready-rank{rank}"), "w").close()
+    deadline = time.monotonic() + 120.0
+    while (sum(n.startswith("ready-rank") for n in os.listdir(out_dir))
+           < _env_int("DS_NUM_PROCESSES", 1)
+           and time.monotonic() < deadline):
+        time.sleep(0.02)
+
     target = _env_int("DS_CHAOS_TARGET_RANK", -1)
     flip_step = _env_int("DS_CHAOS_BITFLIP_STEP")
     hang_step = _env_int("DS_CHAOS_HANG_STEP")
@@ -112,7 +124,6 @@ def main():
         hang_secs=600.0,
         rank=rank, target_rank=target)
 
-    os.makedirs(out_dir, exist_ok=True)
     life = "fresh" if fresh else f"resumed@{engine.global_steps}"
     log_path = os.path.join(out_dir, f"steps-rank{rank}-{life}.jsonl")
     loss = None          # a resumed-complete life never enters the loop

@@ -181,40 +181,7 @@ def test_engine_attribution_receipt_reconciles(cpu_devices, tmp_path):
     assert rec["predicted_step_seconds"] == pytest.approx(
         sum(v for p, v in rec["phases"].items()
             if p != attr.PHASE_UNEXPLAINED))
-    # bench receipt fields are schema-registered and gate-covered
-    from deepspeed_tpu.tools.bench_schema import (threshold_for,
-                                                  validate_record)
-
-    row = {"predicted_step_seconds": rec["predicted_step_seconds"],
-           "step_unexplained_fraction": rec["step_unexplained_fraction"],
-           "leg_zero2_predicted_step_seconds": 0.001,
-           "leg_zero2_step_unexplained_fraction": 0.9,
-           "offload_gpt2_large_predicted_step_seconds": 0.001,
-           "offload_gpt2_large_step_unexplained_fraction": 0.9}
-    assert validate_record(row) == []
-    assert threshold_for("predicted_step_seconds") == ("lower", 0.25)
-    assert threshold_for("leg_zero2_step_unexplained_fraction") \
-        == ("zero", 0.25)
     engine.close()
-
-
-def test_unexplained_fraction_gates_on_magnitude():
-    """The fraction is SIGNED with optimum 0: bench_diff's 'zero'
-    direction gates |new| vs |old| with an absolute band — moving
-    toward 0 is an improvement even across the sign flip, and a worse
-    over-prediction regresses despite being 'lower'."""
-    from deepspeed_tpu.tools.bench_diff import diff_records
-
-    def status(old, new):
-        rows = diff_records({"step_unexplained_fraction": old},
-                            {"step_unexplained_fraction": new})
-        return rows[0]["status"]
-
-    assert status(-0.10, 0.0) == "ok"        # toward 0: never regressed
-    assert status(0.80, 0.30) == "improved"
-    assert status(-0.10, -0.50) == "regressed"  # worse over-prediction
-    assert status(0.30, 0.80) == "regressed"
-    assert status(0.80, 0.85) == "ok"        # within the absolute band
 
 
 def test_engine_flops_cross_check_rides_the_receipt(cpu_devices,

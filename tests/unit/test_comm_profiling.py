@@ -3,11 +3,9 @@ the HLO collective parser and wire-bytes model, the CommLedger riding the
 MemoryLedger AOT hook on a real ZeRO-2 multi-device program (exactness
 against the analytic formulas), per-rank latency/skew export + the
 straggler resilience hook, the report CLI's ``--comm`` section and
-cross-rank clock alignment, the structured MULTICHIP record path through
-``bench_diff``, and the multichip dp=1 loss-parity assert tripping on a
-deliberately broken psum-for-pmean."""
+cross-rank clock alignment, and the multichip dp=1 loss-parity assert
+tripping on a deliberately broken psum-for-pmean."""
 
-import glob
 import json
 import os
 import sys
@@ -443,82 +441,6 @@ def test_comm_summary_measured_uses_median_of_last_window(tmp_path):
     assert abs(measured["rank0"] - 0.002) < 1e-9
     lines = "\n".join(report_mod.comm_summary(records))
     assert "2.00ms" in lines and "30000" not in lines
-
-
-# ------------------------------------- MULTICHIP record + bench_diff CI
-def test_load_bench_record_extracts_multichip_tail(tmp_path):
-    from deepspeed_tpu.tools.bench_diff import load_bench_record
-
-    rec = {"metric": "dryrun_multichip", "multichip_schema_version": 1,
-           "n_devices": 8, "leg_zero2_status": "ok",
-           "leg_zero2_loss": 5.54, "leg_zero2_comm_wire_bytes": 3007634,
-           "legs_ok": 9, "legs_failed": 0, "legs_skipped": 0,
-           "axes": "pipe,data,seq,model,expert"}
-    wrapper = {"n_devices": 8, "rc": 0, "ok": True, "skipped": False,
-               "tail": "log line\n" + json.dumps(rec)
-                       + "\nRuntimeError: trailing noise"}
-    path = tmp_path / "MULTICHIP_new.json"
-    path.write_text(json.dumps(wrapper))
-    loaded = load_bench_record(str(path))
-    assert loaded["legs_ok"] == 9
-    assert loaded["leg_zero2_comm_wire_bytes"] == 3007634
-
-    # legacy blob (rounds <= 7): scalar fields survive, prose dropped
-    legacy = tmp_path / "MULTICHIP_old.json"
-    legacy.write_text(json.dumps({"n_devices": 8, "rc": 0, "ok": True,
-                                  "skipped": False, "tail": "just logs"}))
-    loaded = load_bench_record(str(legacy))
-    assert loaded == {"n_devices": 8, "rc": 0, "ok": True,
-                      "skipped": False}
-
-
-def test_multichip_record_fields_are_schema_registered():
-    from deepspeed_tpu.tools.bench_schema import (threshold_for,
-                                                  validate_record)
-
-    rec = {"metric": "dryrun_multichip", "multichip_schema_version": 1,
-           "n_devices": 8, "axes": "data,model",
-           "legs_ok": 9, "legs_failed": 0, "legs_skipped": 0,
-           "leg_pipe_3d_status": "ok", "leg_pipe_3d_loss": 2.2,
-           "leg_pipe_3d_loss2": 1.8, "leg_pipe_3d_parity_ref_loss": 2.2,
-           "leg_pipe_3d_comm_collectives": 22,
-           "leg_pipe_3d_comm_payload_bytes": 68616,
-           "leg_pipe_3d_comm_wire_bytes": 74760,
-           "leg_moe_status": "skipped", "leg_moe_note": "odd devices",
-           "leg_zero3_status": "failed", "leg_zero3_error": "boom",
-           "ok": True, "rc": 0, "skipped": False}
-    assert validate_record(rec) == []
-    assert threshold_for("leg_pipe_3d_comm_wire_bytes") == ("lower", 0.25)
-    assert threshold_for("legs_ok") == ("higher", 0.0)
-    assert threshold_for("leg_pipe_3d_loss") == (None, None)
-    # type drift is caught
-    assert validate_record({"leg_pipe_3d_loss": "high"})
-    assert validate_record({"legs_ok": True})          # bool smuggled
-
-
-def test_bench_comm_receipt_fields_registered():
-    from deepspeed_tpu.tools.bench_schema import (threshold_for,
-                                                  validate_record)
-
-    rec = {"comm_collectives_per_step": 0, "comm_wire_bytes_per_step": 0,
-           "offload_gpt2_xl_comm_wire_bytes_per_step": 123,
-           "offload_gpt2_xl_comm_collectives_per_step": 9}
-    assert validate_record(rec) == []
-    assert threshold_for("comm_wire_bytes_per_step") == ("lower", 0.25)
-    assert threshold_for(
-        "offload_gpt2_xl_comm_wire_bytes_per_step") == ("lower", 0.25)
-
-
-def test_bench_diff_self_check_covers_multichip_history(capsys):
-    """CI satellite: the checked-in MULTICHIP_r0*.json sequence runs
-    through the regression gate's --self-check (report-only, exit 0)."""
-    from deepspeed_tpu.tools import bench_diff
-
-    artifacts = sorted(glob.glob(os.path.join(REPO, "MULTICHIP_r*.json")))
-    assert len(artifacts) >= 2
-    assert bench_diff.main(["--self-check", *artifacts]) == 0
-    out = capsys.readouterr().out
-    assert "regression(s)" in out
 
 
 # --------------------------------------------- dp=1 loss-parity asserts

@@ -412,7 +412,7 @@ def test_serving_decode_programs_verify_clean(cpu_devices, tmp_path):
     decode/prefill programs carry a declared spec (``serve|data1`` —
     replicated serve weights + KV cache) and verify clean on BOTH
     surfaces, with the decode program's residency receipt priced (the
-    ``serving_param_bytes_per_device`` field bench_serving quotes)."""
+    ``serving_param_bytes_per_device`` figure)."""
     import json
 
     import jax

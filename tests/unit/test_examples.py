@@ -34,24 +34,3 @@ def test_gpt2_pipeline_example():
     _run("gpt2_pipeline.py", "--steps", "2", "--pipe", "2", "--data", "2",
          "--layers", "4", "--micro_batch", "2", "--grad_acc", "2",
          "--seq", "32", "--vocab", "256")
-
-
-@pytest.mark.slow
-def test_bench_serving_example():
-    import json
-
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "examples",
-                                      "bench_serving.py"), "8", "0"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=280)
-    assert proc.returncode == 0, (
-        f"bench_serving.py failed\nstdout:{proc.stdout[-2000:]}\n"
-        f"stderr:{proc.stderr[-2000:]}")
-    assert "bench-serving-schema" not in proc.stderr
-    record = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert record["serving_requests"] == 8
-    assert record["serving_dsp_violations"] == 0
-    assert record["serving_programs_compiled"] <= 3
-    assert record["serving_per_token_p50_seconds"] > 0

@@ -27,6 +27,25 @@ def padding_masks(b, s, lengths):
     return kvm, additive
 
 
+@pytest.mark.parametrize("platform,batch,seq,kernel", [
+    ("tpu", 112, 128, False),   # bert_large.seq128: XLA attention
+    ("tpu", 32, 512, True),     # bert_large.seq512: the Pallas kernel
+    ("cpu", 32, 512, False),    # off the TPU: never the kernel
+])
+def test_dispatch_follows_platform_and_shape(monkeypatch, platform, batch,
+                                             seq, kernel):
+    """The one decision ``dot_product_attention`` takes from what it
+    observes, at the benchmark cells' shapes (BERT-large: 16 heads of
+    64)."""
+    from deepspeed_tpu.ops.transformer.attention import _use_pallas
+    from deepspeed_tpu.parallel import mesh
+
+    monkeypatch.setattr(mesh, "current_platform", lambda: platform)
+    monkeypatch.setattr(mesh, "get_current_mesh", lambda: None)
+    q = jax.ShapeDtypeStruct((batch, seq, 16, 64), jnp.bfloat16)
+    assert _use_pallas(q, q) is kernel
+
+
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("s", [256, 384])
 def test_flash_forward_matches_reference(causal, s):

@@ -224,10 +224,10 @@ def test_collect_exports(tmp_path):
     from deepspeed_tpu.launcher.runner import collect_exports
 
     environ = {"LIBTPU_INIT_ARGS": "--mega", "JAX_PLATFORMS": "tpu",
-               "DS_FLASH_ATTENTION": "1", "HOME": "/root", "PATH": "/bin"}
+               "DS_ANY_FORWARDED_VAR": "1", "HOME": "/root", "PATH": "/bin"}
     assert collect_exports(environ, paths=()) == {
         "LIBTPU_INIT_ARGS": "--mega", "JAX_PLATFORMS": "tpu",
-        "DS_FLASH_ATTENTION": "1"}
+        "DS_ANY_FORWARDED_VAR": "1"}
     d1, d2 = tmp_path / "a", tmp_path / "b"
     d1.mkdir(), d2.mkdir()
     (d1 / ".deepspeed_env").write_text(

@@ -1,11 +1,10 @@
 """Memory observability suite (``deepspeed_tpu/profiling/memory`` +
-``capacity`` + ``tools/bench_diff``): the compiled-program HBM ledger
+``capacity``): the compiled-program HBM ledger
 (records every engine jit entry point's ``memory_analysis`` with zero
 step-path cost and bit-identical training), live watermark events at the
 steps_per_print cadence, the offload host-buffer registry, the AOT
 capacity planner's fit/no-fit verdict on CPU (fail-soft when capacity is
-unknowable), and the bench regression gate over a sequence of bench
-records."""
+unknowable)."""
 
 import json
 import logging
@@ -20,9 +19,6 @@ from deepspeed_tpu.parallel import make_mesh
 from deepspeed_tpu.profiling import capacity
 from deepspeed_tpu.profiling import memory as mem
 from deepspeed_tpu.telemetry import read_events, validate_event
-from deepspeed_tpu.tools import bench_diff
-from deepspeed_tpu.tools.bench_schema import (field_type, threshold_for,
-                                              validate_record)
 
 from .simple_model import SimpleModel, base_config, random_batches
 
@@ -410,105 +406,6 @@ def test_predicted_peak_accounting():
     assert mem.predicted_peak_bytes(entry) == 100 + 90 - 80 + 50 + 7
     assert mem.predicted_host_bytes(entry) == 30 + 30 - 30 + 5
     assert mem.predicted_peak_bytes(None) is None
-
-
-# ------------------------------------------------------ bench regression
-def test_bench_diff_classification():
-    old = {"value": 100.0, "offload_gpt2_large_ms_per_step": 1000.0,
-           "loss": 8.0, "device": "TPU v5 lite", "mfu": 0.5}
-    new = {"value": 80.0, "offload_gpt2_large_ms_per_step": 850.0,
-           "loss": 9.5, "device": "TPU v5 lite", "mfu": 0.51,
-           "peak_hbm_bytes": 7}
-    by_field = {d["field"]: d for d in bench_diff.diff_records(old, new)}
-    assert by_field["value"]["status"] == "regressed"          # -20% tput
-    assert by_field["offload_gpt2_large_ms_per_step"]["status"] \
-        == "improved"                                          # -15% time
-    assert by_field["loss"]["status"] == "info"                # no gate
-    assert by_field["device"]["status"] == "ok"
-    assert by_field["mfu"]["status"] == "ok"                   # +2% < tol
-    assert by_field["peak_hbm_bytes"]["status"] == "added"
-    assert len(bench_diff.regressions(by_field.values())) == 1
-
-
-def test_bench_diff_cli_gate(tmp_path, capsys):
-    a, b = tmp_path / "a.json", tmp_path / "b.json"
-    a.write_text(json.dumps({"value": 100.0}))
-    b.write_text(json.dumps({"parsed": {"value": 50.0}}))  # driver wrapper
-    assert bench_diff.main([str(a), str(b)]) == 1          # gate trips
-    assert "REGRESSED" in capsys.readouterr().out
-    assert bench_diff.main([str(a), str(b), "--no-fail"]) == 0
-    assert bench_diff.main([str(b), str(a)]) == 0          # improvement
-
-
-def test_bench_diff_self_check_over_a_record_sequence(tmp_path, capsys):
-    """CI mode over a sequence of bench records (three rounds of an
-    earlier attachment, in the driver's wrapper): violations are
-    REPORTED, historical rows never hard-fail (exit 0 by contract)."""
-    rounds = [
-        {"value": 466.32, "mfu": 0.5533, "seq512_samples_per_sec": 91.09,
-         "sparse_attn_speedup_vs_dense": 1.96},
-        {"value": 468.6, "mfu": 0.556, "seq512_samples_per_sec": 97.84,
-         "sparse_attn_speedup_vs_dense": 1.95,
-         "offload_gpt2_large_ms_per_step": 1534.0},
-        {"value": 477.34, "mfu": 0.5664, "seq512_samples_per_sec": 104.13,
-         "sparse_attn_speedup_vs_dense": 2.65,
-         "offload_gpt2_large_ms_per_step": 1292.0},
-    ]
-    artifacts = []
-    for i, parsed in enumerate(rounds):
-        path = tmp_path / f"round{i}.json"
-        path.write_text(json.dumps({"n": i, "rc": 0, "parsed": parsed}))
-        artifacts.append(str(path))
-    rc = bench_diff.main(["--self-check", *artifacts])
-    out = capsys.readouterr().out
-    assert rc == 0
-    assert out.count("bench diff:") == len(artifacts) - 1
-    assert "field(s) compared" in out
-
-
-def test_report_cli_diff_mode(tmp_path, capsys):
-    from deepspeed_tpu.telemetry import report as report_mod
-
-    a, b = tmp_path / "a.json", tmp_path / "b.json"
-    a.write_text(json.dumps({"value": 100.0}))
-    b.write_text(json.dumps({"value": 101.0}))
-    assert report_mod.main(["report", "--diff", str(a), str(b)]) == 0
-    assert "bench diff" in capsys.readouterr().out
-    # without --diff, run_dir stays required
-    assert report_mod.main(["report"]) == 2
-    # the regression gate survives the combined run_dir + --diff form
-    from deepspeed_tpu.telemetry import EventLog
-
-    run_dir = tmp_path / "run"
-    log = EventLog(run_dir, rank=0)
-    log.emit("run_start", step=0, world_size=1)
-    log.close()
-    bad = tmp_path / "bad.json"
-    bad.write_text(json.dumps({"value": 50.0}))
-    assert report_mod.main(["report", str(run_dir),
-                            "--diff", str(a), str(bad)]) == 1
-    out = capsys.readouterr().out
-    assert "REGRESSED" in out and "timeline" in out
-    # --json + --diff emits ONE JSON document (the diff), gate intact
-    assert report_mod.main(["report", str(run_dir), "--json",
-                            "--diff", str(a), str(bad)]) == 1
-    json.loads(capsys.readouterr().out)  # parseable as a single doc
-
-
-def test_bench_schema_memory_receipt_fields():
-    record = {
-        "peak_hbm_bytes": 12884901888,
-        "predicted_temp_bytes": 7516192768,
-        "offload_gpt2_xl_peak_hbm_bytes": 15032385536,
-        "offload_gpt2_xl_predicted_temp_bytes": 9663676416,
-        "offload_gpt2_xl_host_buffer_bytes": 18677760000,
-    }
-    assert validate_record(record) == []
-    assert field_type("offload_gpt2_27b_host_buffer_bytes")
-    assert threshold_for("value") == ("higher", 0.05)
-    assert threshold_for("offload_gpt2_xl_ms_per_step") == ("lower", 0.10)
-    assert threshold_for("loss") == (None, None)
-    assert threshold_for("offload_gpt2_xl_host_groups") == (None, None)
 
 
 # ------------------------------------------------------------ env report

@@ -428,23 +428,7 @@ def test_program_baseline_key_covers_dso7(tmp_path):
     assert baseline_key(diags[0]) == "<programs>|DSO701|train_step"
 
 
-# --------------------------------------------------- receipts/schema
-def test_overlap_fields_are_schema_registered():
-    from deepspeed_tpu.tools.bench_schema import (threshold_for,
-                                                  validate_record)
-
-    rec = {"exposed_wire_seconds": 0.0012, "overlap_fraction": 0.0,
-           "leg_zero2_exposed_wire_seconds": 0.0,
-           "leg_zero2_overlap_fraction": 1.0,
-           "offload_gpt2_large_exposed_wire_seconds": 0.08,
-           "offload_gpt2_large_overlap_fraction": 0.1}
-    assert validate_record(rec) == []
-    assert threshold_for("exposed_wire_seconds") == ("lower", 0.25)
-    assert threshold_for("overlap_fraction") == ("higher", 0.10)
-    assert threshold_for("leg_pipe_exposed_wire_seconds") == \
-        ("lower", 0.25)
-    assert threshold_for("offload_gpt2_xl_overlap_fraction") == \
-        ("higher", 0.10)
+# ---------------------------------------------------------- receipts
 
 
 class _FakeCompiled:

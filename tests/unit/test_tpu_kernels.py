@@ -135,32 +135,6 @@ def test_compiled_block_sparse_kernel():
                                    err_msg=f"d{name} mismatch")
 
 
-def test_compiled_flash_exp2_matches_exp(monkeypatch):
-    """Base-2 softmax (DS_FLASH_EXP2) is numerically interchangeable with
-    the natural-base kernel: exp2(x*log2e) == exp(x) up to fp rounding,
-    forward and grads."""
-    import deepspeed_tpu.ops.transformer.flash_attention as fa
-
-    q, k, v = rand_qkv(1, 1024, 2, 64, seed=7)
-
-    def loss(q_, k_, v_):
-        return jnp.sum(fa.flash_attention(q_, k_, v_, causal=True) ** 2)
-
-    monkeypatch.setattr(fa, "EXP2", False)
-    out_e = fa.flash_attention(q, k, v, causal=True)
-    g_e = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    monkeypatch.setattr(fa, "EXP2", True)
-    out_2 = fa.flash_attention(q, k, v, causal=True)
-    g_2 = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-
-    np.testing.assert_allclose(np.asarray(out_2), np.asarray(out_e),
-                               atol=2e-5, rtol=2e-5)
-    for a, b, name in zip(g_2, g_e, "qkv"):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=2e-4, rtol=2e-4,
-                                   err_msg=f"d{name} mismatch")
-
-
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-4),
                                        (jnp.bfloat16, 2e-2)],
                          ids=["fp32", "bf16"])
