@@ -28,6 +28,7 @@ from jax.sharding import PartitionSpec as P
 from ..ops.op_common import random_keep
 from ..ops.transformer.attention import (dot_product_attention,
                                          key_padding_to_additive,
+                                         self_attention,
                                          shard_kernel_over_mesh)
 from ..parallel.mesh import current_platform
 
@@ -269,8 +270,8 @@ class TransformerLayer:
                     q, k, v, layout, causal=causal_sp,
                     key_padding_mask=kpm_add, attn_mask=None)
         else:
-            ctx = dot_product_attention(
-                q, k, v, mask=mask, key_padding_mask=key_padding_mask,
+            ctx = self_attention(
+                qkv, mask=mask, key_padding_mask=key_padding_mask,
                 causal=self.causal,
                 dropout_rate=self.attn_dropout_ratio, dropout_rng=r1,
                 deterministic=deterministic)

@@ -46,10 +46,11 @@ def _use_pallas(q, k):
     return shapes_ok and score_bytes > PALLAS_MIN_SCORE_BYTES
 
 
-def shard_kernel_over_mesh(kernel, q, k, v, kv_mask=None, seed=None,
+def shard_kernel_over_mesh(kernel, q, k=None, v=None, kv_mask=None, seed=None,
                            shard_heads=True):
     """``kernel(q, k, v, kv_mask, seed)`` on each device's shard of the
-    batch (over ``data``) and of the heads (over ``model``).
+    batch (over ``data``) and of the heads (over ``model``).  ``q`` alone
+    (``k`` and ``v`` None) is a fused projection [b, s, 3, h, d].
 
     XLA cannot partition a Mosaic kernel call: on a mesh of several
     devices it refuses to lower one anywhere a mesh axis is still left to
@@ -84,9 +85,13 @@ def shard_kernel_over_mesh(kernel, q, k, v, kv_mask=None, seed=None,
         return axis if axis in auto and n > 1 and dim % n == 0 else None
 
     batch_axis = axis_for(DATA_AXIS, q.shape[0])
-    head_axis = axis_for(MODEL_AXIS, q.shape[2]) if shard_heads else None
+    head_axis = axis_for(MODEL_AXIS, q.shape[-2]) if shard_heads else None
     qkv_spec = P(batch_axis, None, head_axis, None)
-    args, specs = [q, k, v], [qkv_spec] * 3
+    if k is None:
+        args, specs = [q], [P(batch_axis, None, None, head_axis, None)]
+    else:
+        args, specs = [q, k, v], [qkv_spec] * 3
+    n_qkv = len(args)
     if kv_mask is not None:
         args.append(kv_mask)
         specs.append(P(batch_axis, None))
@@ -94,10 +99,10 @@ def shard_kernel_over_mesh(kernel, q, k, v, kv_mask=None, seed=None,
         args.append(seed)
         specs.append(P())
 
-    def body(q, k, v, *rest):
-        rest = list(rest)
+    def body(*args):
+        qkv, rest = args[:n_qkv] + (None,) * (3 - n_qkv), list(args[n_qkv:])
         mask = rest.pop(0) if kv_mask is not None else None
-        return kernel(q, k, v, mask, rest.pop(0) if rest else None)
+        return kernel(*qkv, mask, rest.pop(0) if rest else None)
 
     # nested in one of the engine's shard_maps, the mesh is the context's
     return shard_map(body, mesh=mesh if context.empty else context,
@@ -133,6 +138,40 @@ def reference_attention(q, k, v, mask=None, causal=False, dropout_rate=0.0,
     return ctx
 
 
+def _kernel_dropout(dropout_rate, dropout_rng, deterministic):
+    """(seed, rate) of the flash kernels' in-kernel probs dropout."""
+    if (deterministic or dropout_rate < 1.0 / 512.0 or dropout_rng is None):
+        return None, 0.0
+    # hand the kernel 64 bits of seed material from this call's rng stream
+    # (32 bits would birthday-collide across steps after ~65k draws)
+    seed = jax.lax.bitcast_convert_type(
+        jax.random.bits(dropout_rng, (2,), jnp.uint32), jnp.int32)
+    return seed, float(dropout_rate)
+
+
+def self_attention(qkv, mask=None, key_padding_mask=None, causal=False,
+                   dropout_rate=0.0, dropout_rng=None, deterministic=True):
+    """``dot_product_attention`` over q, k, v = ``qkv[:, :, 0..2]`` of a
+    fused projection [batch, seq, 3, heads, head_dim].  Where the flash
+    kernel runs it is handed the one array (no slice is materialised for
+    it: ``flash_attention.flash_self_attention``); the result is the same
+    either way."""
+    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+    if not (_use_pallas(q, k) and mask is None):
+        return dot_product_attention(
+            q, k, v, mask=mask, key_padding_mask=key_padding_mask,
+            causal=causal, dropout_rate=dropout_rate, dropout_rng=dropout_rng,
+            deterministic=deterministic)
+    from .flash_attention import flash_self_attention
+
+    seed, rate = _kernel_dropout(dropout_rate, dropout_rng, deterministic)
+    return shard_kernel_over_mesh(
+        lambda qkv, _k, _v, kv_mask, seed: flash_self_attention(
+            qkv, kv_mask=kv_mask, dropout_seed=seed, causal=causal,
+            dropout_rate=rate),
+        qkv, kv_mask=key_padding_mask, seed=seed)
+
+
 def dot_product_attention(q, k, v, mask=None, key_padding_mask=None,
                           causal=False, dropout_rate=0.0,
                           dropout_rng=None, deterministic=True):
@@ -151,15 +190,7 @@ def dot_product_attention(q, k, v, mask=None, key_padding_mask=None,
     if _use_pallas(q, k) and mask is None:
         from .flash_attention import flash_attention
 
-        seed, rate = None, 0.0
-        if (not deterministic and dropout_rate >= 1.0 / 512.0
-                and dropout_rng is not None):
-            # in-kernel probs dropout: hand the kernel 64 bits of seed
-            # material from this call's rng stream (32 bits would
-            # birthday-collide across steps after ~65k draws)
-            seed = jax.lax.bitcast_convert_type(
-                jax.random.bits(dropout_rng, (2,), jnp.uint32), jnp.int32)
-            rate = float(dropout_rate)
+        seed, rate = _kernel_dropout(dropout_rate, dropout_rng, deterministic)
         return shard_kernel_over_mesh(
             lambda q, k, v, kv_mask, seed: flash_attention(
                 q, k, v, kv_mask=kv_mask, dropout_seed=seed, causal=causal,

@@ -10,9 +10,19 @@ pass over Q/K/V — this is what buys the "10x longer sequences" capability
 the reference got from block-sparse attention (SURVEY §5.7), but for the
 dense case.
 
-Layout: inputs are [batch, seq, heads, head_dim]; kernels run on
-[batch·heads, seq, head_dim] with a 3-D grid (bh, outer blocks, inner
-blocks).  K/V stream through VMEM one block per grid step — VMEM usage is
+Layout: inputs are [batch, seq, heads, head_dim].  The kernels index them
+as [batch, seq, heads·head_dim] — a free reshape of what the QKV projection
+wrote — in blocks whose last dimension is whole 128-lane tiles: two 64-wide
+heads a block, one head a block where head_dim is a multiple of 128
+(``_Operands``), and write their outputs the same way, lane-dense, so no
+transpose stands between the GEMMs and the kernels.  A self-attention
+layer hands over its fused [b, s, 3, h, d] projection whole
+(``flash_self_attention``): q, k and v are the same array at three block
+indices, and the fused backward returns the one gradient.  Shapes whose
+heads do not fill lane tiles (an odd number of 64-wide heads, key width
+192) run on [batch·heads, seq, head_dim] through a transpose each way.  The
+grid is (batch · blocks a row, outer blocks, inner blocks).  K/V stream
+through VMEM one block per grid step — VMEM usage is
 O(block), not O(seq), so sequence length is bounded by HBM alone — measured
 on one v5e chip: BERT-large trains at seq 8192 (1.1 samples/s), 16384, and
 32768 (batch 1, per-layer remat), vs the reference's 16x-over-512 best with
@@ -34,6 +44,7 @@ saved mask.
 
 import functools
 import math
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +54,9 @@ from jax.experimental.pallas import tpu as pltpu
 from ...utils.logging import logger
 
 _VMEM = pltpu.VMEM
+# largest [s, 3·h·d] output block the fused backward keeps resident (it is
+# double-buffered): 16M holds s 1024 at a hidden size of 2,730 in bf16
+_ROW_BLOCK_BYTES = 16 * 1024 * 1024
 
 NEG_INF = -1e30
 # Running-max floor: keeps exp(NEG_INF - m) == 0 even for rows where every
@@ -56,6 +70,13 @@ def _auto_blocks(s, kv_len, d=64, causal=False):
     Measured on v5e (B·S = 8k tokens, h16 d64): (512, 512) wins at s=512
     (5.4 ms fwd+bwd vs XLA's ~6.8), (512, 2048) at s=2048 (7.3 vs 15.8) —
     128² blocks leave ~2x on the table (pipeline bubbles + sub-MXU dots).
+    Those and the records below were read while every grid step held ONE
+    head of [b·h, s, d] operands behind a transpose each way; since the
+    kernels index the projection's layout (two d=64 heads a step) the same
+    blocks stand — at b32 s512 h16 d64 with mask and dropout, one layer's
+    QKV GEMM + attention + output GEMM, forward and backward, fell from
+    5.77 to 4.56 ms at (512, 512), the kernels' share of it from 3.19 to
+    2.73 (chip runs of PR 30; the geometry was not searched again).
     Bigger k blocks win until the double-buffered K/V block footprint
     presses on scoped VMEM, so block_k·d caps at 128K elements.
 
@@ -159,43 +180,120 @@ def _scores(q_blk, k_blk, scale, causal, masked, kvm_ref, j, kb, block_q,
     return s
 
 
-def _fwd_kernel(*refs, scale, causal, masked, dropout, single):
+# -- several heads in one 128-lane block --------------------------------------
+#
+# An operand block is [rows, G·d]: the lanes of G heads side by side, as the
+# QKV projection wrote them (G = 1 where a head fills whole lane tiles, and
+# where the operands are flattened).  The per-head [Bq, Bk] tile is computed
+# once per head, and nothing is sliced out of a lane tile: an operand with
+# the OTHER heads' lanes zeroed (``_own_lanes``) stands for the head.  A
+# product that contracts over the lanes (q·kᵀ, dO·vᵀ) then adds exact zeros
+# to its sums; a product whose output is lane-wide (p·v, ds·k, dsᵀ·q,
+# pᵀ·dO) is zero outside the head's lanes, so the heads' products ADD to
+# the output block (the backward kernels), or is taken over all G·d lanes
+# of the unmasked operand and selected by lane (``_by_head``: the forward,
+# where each head's accumulator has its own normaliser).  On a 128 x 128 MXU
+# a contraction of 64 and an output of 64 columns each half-fill a pass, so
+# the full-width products cost what the 64-wide ones did.  Measured at the
+# seq-512 cell's shape, a layer's forward / fused backward (chip runs of
+# PR 30): one head a step on [b·h, s, 64] 0.893 / 1.150 ms; two heads a
+# step with q and dO zeroed and every output selected 0.803 / 1.281; with
+# k, v (and q, dO for dk, dv) zeroed and the outputs added 0.815 / 1.222;
+# static 64-lane slices were slower than either (a layer's attention
+# sandwich 4.60 ms against 4.56).  So the forward selects and the backward
+# adds.
+
+def _own_lanes(x, g, heads):
+    """``x`` [rows, heads·d] with every lane outside head ``g`` zeroed."""
+    if heads == 1:
+        return x
+    d = x.shape[1] // heads
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    own = (lane >= g * d) & (lane < (g + 1) * d)
+    return jnp.where(own, x, jnp.zeros_like(x))
+
+
+def _by_head(parts, shape):
+    """[rows, G·d] that holds ``parts[g]`` in head g's lanes; a part is
+    lane-wide already or one column, spread over its head's lanes."""
+    if len(parts) == 1:
+        return parts[0]
+    d = shape[1] // len(parts)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    out = parts[-1]
+    for g in range(len(parts) - 2, -1, -1):
+        out = jnp.where(lane < (g + 1) * d, parts[g], out)
+    return out
+
+
+def _step_id(lead):
+    """The step's number: the steps are the grid's ``lead`` leading
+    dimensions — ``(b·h,)`` flattened, ``(batch rows, blocks a row)`` in
+    the projection's layout — counted in row-major order."""
+    i = pl.program_id(0)
+    if lead == 2:
+        i = i * pl.num_programs(1) + pl.program_id(1)
+    return i
+
+
+def _grid_ids(lead):
+    """``(i, j, kb)`` of a grid ``(*steps, outer blocks, inner blocks)``."""
+    return _step_id(lead), pl.program_id(lead), pl.program_id(lead + 1)
+
+
+def _head_index(i, g, heads):
+    """batch·h + head, the index ``_keep_mask`` has always been seeded by:
+    step ``i`` holds heads ``i·G .. i·G + G − 1`` in either layout."""
+    return i if heads == 1 else i * heads + g
+
+
+def _fwd_kernel(*refs, scale, causal, masked, dropout, single, heads, lead):
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[:3]
     rest = refs[3:]
     seed_ref = rest.pop(0) if dropout else None
     kvm_ref = rest.pop(0) if masked else None
-    o_ref, lse_ref, m_sc, l_sc, acc_sc = rest
+    o_ref, lse_ref = rest[:2]
+    # per head a running max m and sum l; one lane-wide output accumulator
+    m_scs, l_scs = rest[2:2 + heads], rest[2 + heads:2 + 2 * heads]
+    acc_sc = rest[2 + 2 * heads]
 
-    block_q, d = q_ref.shape[1], q_ref.shape[2]
-    block_k = k_ref.shape[1]
-    i, j, kb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    n_kb = pl.num_programs(2)
+    block_q, block_k = q_ref.shape[1], k_ref.shape[1]
+    i, j, kb = _grid_ids(lead)
+    n_kb = pl.num_programs(lead + 1)
+    wide = o_ref.shape[1:]
 
     if single:
         # one k block: straight-line softmax, no scratch round-trips (the
         # common short-sequence case; ~25% faster than the streamed form)
-        s = _scores(q_ref[0], k_ref[0], scale, causal, masked, kvm_ref,
-                    j, kb, block_q, block_k)
-        m = jnp.maximum(jnp.max(s, axis=1, keepdims=True), MAX_FLOOR)
-        p = jnp.exp(s - m)
-        l = jnp.sum(p, axis=1, keepdims=True)
-        if dropout:
-            thresh, inv_keep = _dropout_thresh(dropout)
-            keep = _keep_mask(seed_ref, i, j, kb, (block_q, block_k), thresh)
-            p = jnp.where(keep, p * inv_keep, 0.0)
-        acc = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0],
-                                  (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m + jnp.log(l_safe))[:, 0]
+        outs, stats = [], []
+        for g in range(heads):
+            s = _scores(_own_lanes(q_ref[0], g, heads), k_ref[0], scale,
+                        causal, masked, kvm_ref, j, kb, block_q, block_k)
+            m = jnp.maximum(jnp.max(s, axis=1, keepdims=True), MAX_FLOOR)
+            p = jnp.exp(s - m)
+            l = jnp.sum(p, axis=1, keepdims=True)
+            if dropout:
+                thresh, inv_keep = _dropout_thresh(dropout)
+                keep = _keep_mask(seed_ref, _head_index(i, g, heads), j, kb,
+                                  (block_q, block_k), thresh)
+                p = jnp.where(keep, p * inv_keep, 0.0)
+            acc = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0],
+                                      (((1,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            outs.append(acc / l_safe)
+            stats.append((m, l_safe))
+        o_ref[0] = _by_head(outs, wide).astype(o_ref.dtype)
+        for g, (m, l_safe) in enumerate(stats):
+            lse_ref[g, 0] = (m + jnp.log(l_safe))[:, 0]
         return
 
     @pl.when(kb == 0)
     def _init():
-        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[...] = jnp.zeros_like(l_sc)
+        for m_sc, l_sc in zip(m_scs, l_scs):
+            m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+            l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
     # causal: q rows of block j end at (j+1)·Bq − 1; skip k blocks past
@@ -210,34 +308,81 @@ def _fwd_kernel(*refs, scale, causal, masked, dropout, single):
     # VPU work with the dots already; reverted to the single body)
     @pl.when(needed)
     def _step():
-        s = _scores(q_ref[0], k_ref[0], scale, causal, masked, kvm_ref,
-                    j, kb, block_q, block_k)
-        m, l = m_sc[...], l_sc[...]
-        m_new = jnp.maximum(jnp.maximum(m, jnp.max(s, axis=1, keepdims=True)),
-                            MAX_FLOOR)
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m - m_new)
-        # l accumulates the UNdropped sum (softmax normalizer); dropout hits
-        # only the value accumulation, so out == dropout(softmax(s)) @ v.
-        l_sc[...] = l * corr + jnp.sum(p, axis=1, keepdims=True)
-        m_sc[...] = m_new
-        if dropout:
-            thresh, inv_keep = _dropout_thresh(dropout)
-            keep = _keep_mask(seed_ref, i, j, kb, (block_q, block_k), thresh)
-            p = jnp.where(keep, p * inv_keep, 0.0)
-        acc_sc[...] = acc_sc[...] * corr + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        corrs, ps = [], []
+        for g, (m_sc, l_sc) in enumerate(zip(m_scs, l_scs)):
+            s = _scores(_own_lanes(q_ref[0], g, heads), k_ref[0], scale,
+                        causal, masked, kvm_ref, j, kb, block_q, block_k)
+            m, l = m_sc[...], l_sc[...]
+            m_new = jnp.maximum(
+                jnp.maximum(m, jnp.max(s, axis=1, keepdims=True)), MAX_FLOOR)
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m - m_new)
+            # l accumulates the UNdropped sum (softmax normalizer); dropout
+            # hits only the value accumulation, so out ==
+            # dropout(softmax(s)) @ v.
+            l_sc[...] = l * corr + jnp.sum(p, axis=1, keepdims=True)
+            m_sc[...] = m_new
+            if dropout:
+                thresh, inv_keep = _dropout_thresh(dropout)
+                keep = _keep_mask(seed_ref, _head_index(i, g, heads), j, kb,
+                                  (block_q, block_k), thresh)
+                p = jnp.where(keep, p * inv_keep, 0.0)
+            corrs.append(corr)
+            ps.append(p)
+        rescaled = acc_sc[...] * _by_head(corrs, wide)
+        acc_sc[...] = rescaled + _by_head(
+            [jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0],
+                                 (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+             for p in ps], wide)
 
     @pl.when(kb == n_kb - 1)
     def _finalize():
-        l = l_sc[...]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_sc[...] / l_safe).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_sc[...] + jnp.log(l_safe))[:, 0]
+        l_safes = []
+        for l_sc in l_scs:
+            l = l_sc[...]
+            l_safes.append(jnp.where(l == 0.0, 1.0, l))
+        o_ref[0] = (acc_sc[...] / _by_head(l_safes, wide)).astype(o_ref.dtype)
+        for g, (m_sc, l_safe) in enumerate(zip(m_scs, l_safes)):
+            lse_ref[g, 0] = (m_sc[...] + jnp.log(l_safe))[:, 0]
 
 
-def _bwd_dq_kernel(*refs, scale, causal, masked, dropout, single):
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _sum(parts):
+    return functools.reduce(operator.add, parts)
+
+
+def _bwd_tile(q, k_g, v_g, do, lse, delta, seed_ref, kvm_ref, head, j, kb, *,
+              scale, causal, masked, dropout, want_pv):
+    """One head's [Bq, Bk] tile of the backward recurrence, recomputed from
+    the saved logsumexp: ``ds = p ∘ (dp − Δ)`` and, where the caller forms
+    dv, the (dropped) probabilities ``p_v`` that met the values — both in
+    the operands' dtype, ready for the gradient products.  ``k_g`` and
+    ``v_g`` hold the head's lanes alone; ``lse`` and ``delta`` are its
+    [Bq, 1] columns."""
+    block_q, block_k = q.shape[0], k_g.shape[0]
+    s = _scores(q, k_g, scale, causal, masked, kvm_ref, j, kb, block_q,
+                block_k)
+    p = jnp.exp(s - lse)  # [Bq, Bk] fp32
+    dp = _dot(do, v_g, ((1,), (1,)))
+    p_v = p
+    if dropout:
+        thresh, inv_keep = _dropout_thresh(dropout)
+        # the forward's tile (head, j, kb): same seed words, same mask
+        keep = _keep_mask(seed_ref, head, j, kb, (block_q, block_k), thresh)
+        if want_pv:
+            p_v = jnp.where(keep, p * inv_keep, 0.0)
+        dp = jnp.where(keep, dp * inv_keep, 0.0)
+    ds = (p * (dp - delta)).astype(q.dtype)
+    return (p_v.astype(do.dtype) if want_pv else None), ds
+
+
+def _bwd_dq_kernel(*refs, scale, causal, masked, dropout, single, heads,
+                   lead):
     refs = list(refs)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     rest = refs[6:]
@@ -245,24 +390,21 @@ def _bwd_dq_kernel(*refs, scale, causal, masked, dropout, single):
     kvm_ref = rest.pop(0) if masked else None
     dq_ref, dq_sc = rest
 
-    block_q, d = q_ref.shape[1], q_ref.shape[2]
-    block_k = k_ref.shape[1]
-    i, j, kb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    n_kb = pl.num_programs(2)
+    block_q, block_k = q_ref.shape[1], k_ref.shape[1]
+    i, j, kb = _grid_ids(lead)
+    n_kb = pl.num_programs(lead + 1)
 
     def tile_dq():
-        s = _scores(q_ref[0], k_ref[0], scale, causal, masked, kvm_ref,
-                    j, kb, block_q, block_k)
-        p = jnp.exp(s - lse_ref[0, 0][:, None])
-        dp = jax.lax.dot_general(do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        if dropout:
-            thresh, inv_keep = _dropout_thresh(dropout)
-            keep = _keep_mask(seed_ref, i, j, kb, (block_q, block_k), thresh)
-            dp = jnp.where(keep, dp * inv_keep, 0.0)
-        ds = (p * (dp - delta_ref[0, 0][:, None])).astype(k_ref.dtype)
-        return jax.lax.dot_general(ds, k_ref[0], (((1,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
+        parts = []
+        for g in range(heads):
+            k_g = _own_lanes(k_ref[0], g, heads)
+            _, ds = _bwd_tile(
+                q_ref[0], k_g, _own_lanes(v_ref[0], g, heads), do_ref[0],
+                lse_ref[g, 0][:, None], delta_ref[g, 0][:, None], seed_ref,
+                kvm_ref, _head_index(i, g, heads), j, kb, scale=scale,
+                causal=causal, masked=masked, dropout=dropout, want_pv=False)
+            parts.append(_dot(ds, k_g, ((1,), (0,))))
+        return _sum(parts)
 
     if single:
         dq_ref[0] = (tile_dq() * scale).astype(dq_ref.dtype)
@@ -283,7 +425,8 @@ def _bwd_dq_kernel(*refs, scale, causal, masked, dropout, single):
         dq_ref[0] = (dq_sc[...] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(*refs, scale, causal, masked, dropout, single):
+def _bwd_dkv_kernel(*refs, scale, causal, masked, dropout, single, heads,
+                    lead):
     refs = list(refs)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     rest = refs[6:]
@@ -291,33 +434,24 @@ def _bwd_dkv_kernel(*refs, scale, causal, masked, dropout, single):
     kvm_ref = rest.pop(0) if masked else None
     dk_ref, dv_ref, dk_sc, dv_sc = rest
 
-    block_k, d = k_ref.shape[1], k_ref.shape[2]
-    block_q = q_ref.shape[1]
-    # grid is (bh, k blocks, q blocks): q streams in the inner dimension
-    i, kb, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    n_qb = pl.num_programs(2)
+    block_q, block_k = q_ref.shape[1], k_ref.shape[1]
+    # grid is (steps, k blocks, q blocks): q streams in the inner dimension
+    i, kb, j = _grid_ids(lead)
+    n_qb = pl.num_programs(lead + 1)
 
     def tile_dkdv():
-        s = _scores(q_ref[0], k_ref[0], scale, causal, masked, kvm_ref,
-                    j, kb, block_q, block_k)
-        p = jnp.exp(s - lse_ref[0, 0][:, None])  # [Bq, Bk] fp32
-        dp = jax.lax.dot_general(do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        if dropout:
-            thresh, inv_keep = _dropout_thresh(dropout)
-            # fwd tile (j, kb) — same seed hash, same mask
-            keep = _keep_mask(seed_ref, i, j, kb, (block_q, block_k), thresh)
-            p_v = jnp.where(keep, p * inv_keep, 0.0)
-            dp_m = jnp.where(keep, dp * inv_keep, 0.0)
-        else:
-            p_v, dp_m = p, dp
-        dv_t = jax.lax.dot_general(
-            p_v.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp_m - delta_ref[0, 0][:, None])).astype(q_ref.dtype)
-        dk_t = jax.lax.dot_general(ds, q_ref[0], (((0,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-        return dk_t, dv_t
+        dks, dvs = [], []
+        for g in range(heads):
+            p_v, ds = _bwd_tile(
+                q_ref[0], _own_lanes(k_ref[0], g, heads),
+                _own_lanes(v_ref[0], g, heads), do_ref[0],
+                lse_ref[g, 0][:, None], delta_ref[g, 0][:, None], seed_ref,
+                kvm_ref, _head_index(i, g, heads), j, kb, scale=scale,
+                causal=causal, masked=masked, dropout=dropout, want_pv=True)
+            dvs.append(_dot(p_v, _own_lanes(do_ref[0], g, heads),
+                            ((0,), (0,))))
+            dks.append(_dot(ds, _own_lanes(q_ref[0], g, heads), ((0,), (0,))))
+        return _sum(dks), _sum(dvs)
 
     if single:
         dk_t, dv_t = tile_dkdv()
@@ -348,7 +482,8 @@ def _bwd_dkv_kernel(*refs, scale, causal, masked, dropout, single):
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
 
 
-def _bwd_fused_kernel(*refs, scale, causal, masked, dropout):
+def _bwd_fused_kernel(*refs, scale, causal, masked, dropout, heads, lead,
+                      fused_out):
     """Single-tile fused backward: dq, dk, dv from ONE score
     materialization.  The streamed pair (_bwd_dq_kernel + _bwd_dkv_kernel)
     each recompute the q·kᵀ scores, the softmax exp, the dᵒ·vᵀ dot and —
@@ -356,41 +491,47 @@ def _bwd_fused_kernel(*refs, scale, causal, masked, dropout):
     policy picks for s ≤ 1024 (GPT-2 s=1024 causal, BERT s=512) the whole
     tile fits VMEM, so one straight-line kernel computes p and ds once
     and feeds all three gradient dots (round-5 follow-up to the round-4b
-    single-tile forward: the same win applied to the backward)."""
+    single-tile forward: the same win applied to the backward).  It holds
+    every row of dO and O, so Δ = rowsum(dO ∘ O) is formed here, as the
+    column the tile subtracts, and no pass of XLA's reads the two again."""
     refs = list(refs)
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
+    q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref = refs[:6]
     rest = refs[6:]
     seed_ref = rest.pop(0) if dropout else None
     kvm_ref = rest.pop(0) if masked else None
-    dq_ref, dk_ref, dv_ref = rest
+    i = _step_id(lead)
 
-    block_q, d = q_ref.shape[1], q_ref.shape[2]
-    block_k = k_ref.shape[1]
-    i = pl.program_id(0)
-
-    s = _scores(q_ref[0], k_ref[0], scale, causal, masked, kvm_ref,
-                0, 0, block_q, block_k)
-    p = jnp.exp(s - lse_ref[0, 0][:, None])  # [Bq, Bk] fp32
-    dp = jax.lax.dot_general(do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    if dropout:
-        thresh, inv_keep = _dropout_thresh(dropout)
-        keep = _keep_mask(seed_ref, i, 0, 0, (block_q, block_k), thresh)
-        p_v = jnp.where(keep, p * inv_keep, 0.0)
-        dp = jnp.where(keep, dp * inv_keep, 0.0)
-    else:
-        p_v = p
-    ds = (p * (dp - delta_ref[0, 0][:, None])).astype(q_ref.dtype)
-    dq_ref[0] = (jax.lax.dot_general(
-        ds, k_ref[0], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale).astype(dq_ref.dtype)
+    dqs, dks, dvs = [], [], []
+    for g in range(heads):
+        k_g = _own_lanes(k_ref[0], g, heads)
+        do_g = _own_lanes(do_ref[0], g, heads)
+        delta = jnp.sum(do_g.astype(jnp.float32)
+                        * o_ref[0].astype(jnp.float32), axis=1, keepdims=True)
+        p_v, ds = _bwd_tile(
+            q_ref[0], k_g, _own_lanes(v_ref[0], g, heads), do_ref[0],
+            lse_ref[g, 0][:, None], delta, seed_ref, kvm_ref,
+            _head_index(i, g, heads), 0, 0, scale=scale, causal=causal,
+            masked=masked, dropout=dropout, want_pv=True)
+        dqs.append(_dot(ds, k_g, ((1,), (0,))))
+        dks.append(_dot(ds, _own_lanes(q_ref[0], g, heads), ((0,), (0,))))
+        dvs.append(_dot(p_v, do_g, ((0,), (0,))))
     # s was scaled after the q·kᵀ dot, so the 1/√d factor lands on dk too
-    dk_ref[0] = (jax.lax.dot_general(
-        ds, q_ref[0], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale).astype(dk_ref.dtype)
-    dv_ref[0] = jax.lax.dot_general(
-        p_v.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dv_ref.dtype)
+    grads = (_sum(dqs) * scale, _sum(dks) * scale, _sum(dvs))
+    if not fused_out:
+        for ref, grad in zip(rest, grads):
+            ref[0] = grad.astype(ref.dtype)
+        return
+    # ONE gradient [b, s, 3·h·d] for a fused projection: the output block
+    # is the batch row's whole [s, 3·h·d], resident in VMEM while the
+    # row's steps each store their three [s, G·d] pieces at their lanes
+    # (whole lane tiles, so the stores are aligned), and written to HBM
+    # once a row, in full lines.
+    dqkv_ref, = rest
+    width = q_ref.shape[2]
+    for third, grad in enumerate(grads):
+        at = pl.multiple_of(
+            third * (dqkv_ref.shape[2] // 3) + pl.program_id(1) * width, 128)
+        dqkv_ref[0, :, pl.ds(at, width)] = grad.astype(dqkv_ref.dtype)
 
 
 def _flatten_heads(x):
@@ -401,6 +542,116 @@ def _flatten_heads(x):
 def _unflatten_heads(x, b, h):
     bh, s, d = x.shape
     return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+
+
+class _Operands:
+    """How the kernels see the [b, s, h, d] operands; follows from ``h``,
+    ``d`` and ``dv`` alone.
+
+    *Projection layout*: ``[b, s, h·d]``, a free reshape of what the QKV
+    GEMM wrote, indexed in blocks whose last dimension is whole 128-lane
+    tiles — two 64-wide heads a block (grid ``b · h/2``), one head a block
+    where the widths are multiples of 128.  Outputs leave in the same
+    layout, lane-dense: nothing is transposed around the kernels.  With
+    ``fused``, q, k and v are the three thirds of ONE ``[b, s, 3·h·d]``
+    array (``flash_self_attention``), told apart by the block index alone.
+
+    *Flattened*: ``[b·h, s, d]`` through a transpose each way, one head a
+    block, for shapes whose heads do not fill lane tiles (an odd number of
+    64-wide heads, key width 192) — a block narrower than 128 lanes of the
+    projection's layout cannot be indexed.
+    """
+
+    def __init__(self, b, h, d, dv, fused=False):
+        self.b, self.h, self.fused = b, h, fused
+        if d % 128 == 0 and dv % 128 == 0:
+            self.heads, self.packed = 1, True
+        elif d == dv == 64 and h % 2 == 0:
+            self.heads, self.packed = 2, True
+        else:
+            self.heads, self.packed = 1, False
+        assert self.packed or not fused
+        self.blocks = h // self.heads  # blocks a batch row
+        # the grid's leading dimensions, one step a block of heads
+        self.steps = (b, self.blocks) if self.packed else (b * h,)
+        self.name = "flattened" if not self.packed else (
+            f"heads/block={self.heads}, projection layout"
+            + (", fused qkv" if fused else ""))
+
+    def to_kernel(self, x):
+        if not self.packed:
+            return _flatten_heads(x)
+        return x.reshape(x.shape[0], x.shape[1], -1)
+
+    def from_kernel(self, x):
+        if not self.packed:
+            return _unflatten_heads(x, self.b, self.h)
+        return x.reshape(self.b, x.shape[1], self.h, -1)
+
+    def shape(self, s, width, dtype):
+        """Of a q/k/v-like output, ``width`` lanes a head."""
+        if self.packed:
+            return jax.ShapeDtypeStruct((self.b, s, self.h * width), dtype)
+        return jax.ShapeDtypeStruct((self.b * self.h, s, width), dtype)
+
+    def spec(self, rows, width, seq_block, third=0):
+        """Block of a q/k/v-like operand; ``seq_block`` maps the grid ids
+        to the block's index along the sequence, ``third`` says which of
+        q, k, v a fused array is read as."""
+        if self.packed:
+            first = third * self.blocks if self.fused else 0
+            return pl.BlockSpec(
+                (1, rows, self.heads * width),
+                lambda *ids: (ids[0], seq_block(*ids), first + ids[1]))
+        return pl.BlockSpec((1, rows, width),
+                            lambda *ids: (ids[0], seq_block(*ids), 0))
+
+    def qkv_specs(self, block_q, block_k, d, dv, at_q, at_k):
+        return [self.spec(block_q, d, at_q, 0), self.spec(block_k, d, at_k, 1),
+                self.spec(block_k, dv, at_k, 2)]
+
+    def row_spec(self, rows, seq_block):
+        """Block of a per-row statistic (logsumexp, Δ), kept [b·h, 1, s] in
+        either layout: the step's heads are consecutive there."""
+        if self.packed:
+            return pl.BlockSpec(
+                (self.heads, 1, rows),
+                lambda *ids: (ids[0] * self.blocks + ids[1], 0,
+                              seq_block(*ids)))
+        return pl.BlockSpec((self.heads, 1, rows),
+                            lambda *ids: (ids[0], 0, seq_block(*ids)))
+
+    def mask_spec(self, rows, seq_block):
+        # one [1, 1, rows] slice of the [b, 1, kv_len] key mask per step,
+        # the step's batch row's.  The singleton middle axis keeps the
+        # block's trailing-two dims Mosaic-tileable.
+        if self.packed:
+            return pl.BlockSpec((1, 1, rows),
+                                lambda *ids: (ids[0], 0, seq_block(*ids)))
+        return pl.BlockSpec((1, 1, rows),
+                            lambda *ids: (ids[0] // self.h, 0,
+                                          seq_block(*ids)))
+
+    def gradient(self, grads):
+        """(dq, dk, dv) as the kernels wrote them, or the one gradient of a
+        fused projection."""
+        return jnp.concatenate(grads, axis=-1) if self.fused else tuple(grads)
+
+    def grid_params(self, interpret, *blocks, rows_in_order=False):
+        """Compiler parameters of a grid ``(*steps, *blocks)``, ``blocks``
+        the semantics of its trailing dimensions; the steps are parallel
+        unless the blocks of a batch row have to run in order.  The raised
+        vmem limit lets XLA keep large kernel outputs in VMEM when it
+        judges that profitable (v5e has 128M; the default 16M scoped limit
+        rejects long-sequence outputs it would otherwise promote)."""
+        if interpret:
+            return {}
+        steps = ("parallel",) * len(self.steps)
+        if rows_in_order:
+            steps = ("parallel", "arbitrary")
+        return {"compiler_params": pltpu.CompilerParams(
+            dimension_semantics=steps + blocks,
+            vmem_limit_bytes=100 * 1024 * 1024)}
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
@@ -440,13 +691,6 @@ def flash_attention_forward(q, k, v, *, causal, block_q, block_k,
     return out
 
 
-def _mask_spec(h, block_k):
-    # one [1, 1, block_k] mask slice per (batch·head, k block) program:
-    # batch = i // h.  The singleton middle axis keeps the block's
-    # trailing-two dims Mosaic-tileable.
-    return pl.BlockSpec((1, 1, block_k), lambda i, j, kb: (i // h, 0, kb))
-
-
 def _dropout_ops(dropout_rate, dropout_seed):
     """(operands, specs, active_rate) for the in-kernel dropout seed."""
     if not dropout_rate:
@@ -462,18 +706,20 @@ def _dropout_ops(dropout_rate, dropout_seed):
 
 
 @functools.lru_cache(maxsize=None)
-def _log_geometry(s, kv_len, d, causal, dropout, block_q, block_k, chosen):
+def _log_geometry(s, kv_len, d, causal, dropout, block_q, block_k, chosen,
+                  layout):
     """One line per distinct kernel geometry per process (traced calls
-    repeat per layer and per pass): which blocks a shape ran with, and
-    whether the caller, the measured heuristic or the first-use tuner
-    chose them — two cold runs of an un-anchored shape may differ."""
+    repeat per layer and per pass): which blocks a shape ran with, whether
+    the caller, the measured heuristic or the first-use tuner chose them —
+    two cold runs of an un-anchored shape may differ — and which operand
+    layout the kernels index (``_Operands``)."""
     logger.info("flash_attention geometry: s=%d kv=%d d=%d causal=%s "
-                "dropout=%s -> block_q=%d block_k=%d (%s)", s, kv_len, d,
-                causal, dropout, block_q, block_k, chosen)
+                "dropout=%s -> block_q=%d block_k=%d (%s; %s)", s, kv_len, d,
+                causal, dropout, block_q, block_k, chosen, layout)
 
 
 def _resolve_blocks(s, kv_len, d, block_q, block_k, causal=False,
-                    dropout_rate=0.0):
+                    dropout_rate=0.0, layout=""):
     auto_q, auto_k = _auto_blocks(s, kv_len, d, causal)
     chosen = "caller"
     if block_q is None and block_k is None:
@@ -491,7 +737,7 @@ def _resolve_blocks(s, kv_len, d, block_q, block_k, causal=False,
     block_q = block_q or auto_q
     block_k = block_k or auto_k
     _log_geometry(s, kv_len, d, causal, dropout_rate, block_q, block_k,
-                  chosen)
+                  chosen, layout)
     # The kernels index K/V in whole blocks; a ragged tail would silently
     # attend over out-of-block garbage.  Dispatchers (attention.py) only
     # route divisible shapes here; direct callers must pad or shrink blocks.
@@ -502,83 +748,199 @@ def _resolve_blocks(s, kv_len, d, block_q, block_k, causal=False,
     return block_q, block_k
 
 
-def _grid_params(interpret):
-    if interpret:
-        return {}
-    # bh and the outer block dim are parallel; the streamed dim accumulates
-    # into VMEM scratch and must run in order.  The raised vmem limit lets
-    # XLA keep large kernel outputs in VMEM when it judges that profitable
-    # (v5e has 128M; the default 16M scoped limit rejects long-sequence
-    # outputs it would otherwise promote).
-    return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=100 * 1024 * 1024)}
+# which block along the sequence a grid step reads, by the grid's ids: the
+# grids are (*steps, outer blocks, inner blocks), or the steps alone
+def _outer(*ids):
+    return ids[-2]
+
+
+def _inner(*ids):
+    return ids[-1]
+
+
+def _first(*ids):
+    return 0
+
+
+def _mask_ops(kv_mask, ops, rows, seq_block):
+    """(operands, specs) of the optional [b, kv_len] key mask."""
+    if kv_mask is None:
+        return (), ()
+    return ((kv_mask.astype(jnp.float32)[:, None, :],),
+            (ops.mask_spec(rows, seq_block),))
+
+
+def _fwd_call(ops, q, k, v, dims, kv_mask, dropout_seed, causal, block_q,
+              block_k, interpret, dropout_rate, name=None):
+    """The forward kernel over operands already in ``ops``' layout (q, k
+    and v one array where it is fused); ``dims`` = (s, kv_len, d, dv).
+    Returns the output in that layout and the logsumexp [b·h, 1, s]."""
+    s, kv_len, d, dv = dims
+    block_q, block_k = _resolve_blocks(s, kv_len, d, block_q, block_k, causal,
+                                       dropout_rate, ops.name)
+    masked = kv_mask is not None
+    n_qb = pl.cdiv(s, block_q)
+    n_kb = pl.cdiv(kv_len, block_k)
+
+    at_q, at_k = _outer, _inner  # grid (*steps, q blocks, k blocks)
+    seed_ops, seed_specs, drop = _dropout_ops(dropout_rate, dropout_seed)
+    if masked:
+        assert kv_mask.shape == (ops.b, kv_len), (
+            f"kv_mask must be [batch, kv_len]={ops.b, kv_len}, "
+            f"got {kv_mask.shape}")
+    mask_ops, mask_specs = _mask_ops(kv_mask, ops, block_k, at_k)
+
+    kernel = functools.partial(_fwd_kernel, scale=1.0 / math.sqrt(d),
+                               causal=causal, masked=masked, dropout=drop,
+                               single=(n_kb == 1), heads=ops.heads,
+                               lead=len(ops.steps))
+    return pl.pallas_call(
+        kernel,
+        grid=(*ops.steps, n_qb, n_kb),
+        in_specs=[
+            *ops.qkv_specs(block_q, block_k, d, dv, at_q, at_k),
+            *seed_specs,
+            *mask_specs,
+        ],
+        out_specs=[
+            ops.spec(block_q, dv, at_q),
+            ops.row_spec(block_q, at_q),
+        ],
+        out_shape=[
+            ops.shape(s, dv, q.dtype),
+            jax.ShapeDtypeStruct((ops.b * ops.h, 1, s), jnp.float32),
+        ],
+        scratch_shapes=[
+            *[_VMEM((block_q, 1), jnp.float32)] * ops.heads,  # running max m
+            *[_VMEM((block_q, 1), jnp.float32)] * ops.heads,  # running sum l
+            _VMEM((block_q, ops.heads * dv), jnp.float32),  # output accumulator
+        ],
+        interpret=interpret,
+        name=name,
+        # steps and the outer block dim are parallel; the streamed dim
+        # accumulates into VMEM scratch and must run in order
+        **ops.grid_params(interpret, "parallel", "arbitrary"),
+    )(q, k, v, *seed_ops, *mask_ops)
+
+
+def _bwd_call(ops, q, k, v, g, out, lse, dims, kv_mask, dropout_seed, causal,
+              block_q, block_k, interpret, dropout_rate):
+    """(dq, dk, dv) in ``ops``' layout from operands, the output and its
+    cotangent ``g`` in that layout; ``dims`` = (s, kv_len, d).  Where q,
+    k, v are one fused array, its one gradient."""
+    s, kv_len, d = dims
+    block_q, block_k = _resolve_blocks(s, kv_len, d, block_q, block_k, causal,
+                                       dropout_rate, ops.name)
+    masked = kv_mask is not None
+    n_qb = pl.cdiv(s, block_q)
+    n_kb = pl.cdiv(kv_len, block_k)
+
+    seed_ops, seed_specs, drop = _dropout_ops(dropout_rate, dropout_seed)
+    static = dict(scale=1.0 / math.sqrt(d), causal=causal, masked=masked,
+                  dropout=drop, heads=ops.heads, lead=len(ops.steps))
+
+    def in_specs(at_q, at_k, sixth):
+        mask_ops, mask_specs = _mask_ops(kv_mask, ops, block_k, at_k)
+        return mask_ops, [
+            *ops.qkv_specs(block_q, block_k, d, d, at_q, at_k),
+            ops.spec(block_q, d, at_q),
+            ops.row_spec(block_q, at_q),
+            sixth,
+            *seed_specs,
+            *mask_specs,
+        ]
+
+    if n_qb == 1 and n_kb == 1:
+        # single-tile fused backward: one kernel, one score pass, Δ formed
+        # inside.  A fused projection gets its ONE gradient from it
+        # (_bwd_fused_kernel) while a batch row's whole [s, 3·h·d] fits
+        # VMEM twice over beside the operands; a row's blocks share that
+        # output block, so they run in order
+        one_out = ops.fused and q[0].size * q.dtype.itemsize <= _ROW_BLOCK_BYTES
+        if one_out:
+            out_specs = pl.BlockSpec((1, s, q.shape[2]),
+                                     lambda row, block: (row, 0, 0))
+            out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
+        else:
+            out_specs = [ops.spec(block_q, d, _first),
+                         ops.spec(block_k, d, _first),
+                         ops.spec(block_k, d, _first)]
+            out_shape = [ops.shape(s, d, q.dtype), ops.shape(kv_len, d, k.dtype),
+                         ops.shape(kv_len, d, v.dtype)]
+        mask_ops, specs = in_specs(_first, _first,
+                                   ops.spec(block_q, d, _first))
+        grads = pl.pallas_call(
+            functools.partial(_bwd_fused_kernel, fused_out=one_out, **static),
+            grid=ops.steps,
+            in_specs=specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            interpret=interpret,
+            **ops.grid_params(interpret, rows_in_order=one_out),
+        )(q, k, v, g, lse, out, *seed_ops, *mask_ops)
+        return grads if one_out else ops.gradient(grads)
+
+    # Δ = rowsum(dO ∘ O) per head, [b·h, 1, s] like the logsumexp
+    if ops.packed:
+        g4, out4 = ops.from_kernel(g), ops.from_kernel(out)
+        delta = jnp.sum(g4.astype(jnp.float32) * out4.astype(jnp.float32),
+                        axis=-1).transpose(0, 2, 1).reshape(-1, 1, s)
+    else:
+        delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1, keepdims=True).transpose(0, 2, 1)
+
+    at_q, at_k = _outer, _inner  # grid (*steps, q blocks, k blocks)
+    mask_ops, specs = in_specs(at_q, at_k, ops.row_spec(block_q, at_q))
+    dq = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, single=(n_kb == 1), **static),
+        grid=(*ops.steps, n_qb, n_kb),
+        in_specs=specs,
+        out_specs=ops.spec(block_q, d, at_q),
+        out_shape=ops.shape(s, d, q.dtype),
+        scratch_shapes=[_VMEM((block_q, ops.heads * d), jnp.float32)],
+        interpret=interpret,
+        **ops.grid_params(interpret, "parallel", "arbitrary"),
+    )(q, k, v, g, lse, delta, *seed_ops, *mask_ops)
+
+    # grid (*steps, k blocks, q blocks): q streams in the inner dimension
+    at_q, at_k = _inner, _outer
+    mask_ops, specs = in_specs(at_q, at_k, ops.row_spec(block_q, at_q))
+    dk, dv = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, single=(n_qb == 1), **static),
+        grid=(*ops.steps, n_kb, n_qb),
+        in_specs=specs,
+        out_specs=[
+            ops.spec(block_k, d, at_k),
+            ops.spec(block_k, d, at_k),
+        ],
+        out_shape=[
+            ops.shape(kv_len, d, k.dtype),
+            ops.shape(kv_len, d, v.dtype),
+        ],
+        scratch_shapes=[
+            _VMEM((block_k, ops.heads * d), jnp.float32),
+            _VMEM((block_k, ops.heads * d), jnp.float32),
+        ],
+        interpret=interpret,
+        **ops.grid_params(interpret, "parallel", "arbitrary"),
+    )(q, k, v, g, lse, delta, *seed_ops, *mask_ops)
+    return ops.gradient((dq, dk, dv))
 
 
 def _flash_fwd(q, k, v, kv_mask, dropout_seed, causal, block_q, block_k,
                interpret, dropout_rate, name=None):
     b, s, h, d = q.shape
-    kv_len = k.shape[1]
     # the values may be narrower or wider than the keys (latent attention
     # expands keys of 192 beside values of 128): the score tile is q.k over
     # d, the accumulator and the output are dv wide
     dv = v.shape[-1]
-    block_q, block_k = _resolve_blocks(s, kv_len, d, block_q, block_k, causal,
-                                       dropout_rate)
-    masked = kv_mask is not None
-    scale = 1.0 / math.sqrt(d)
-    qf, kf, vf = _flatten_heads(q), _flatten_heads(k), _flatten_heads(v)
-    bh = b * h
-    n_qb = pl.cdiv(s, block_q)
-    n_kb = pl.cdiv(kv_len, block_k)
-
-    seed_ops, seed_specs, drop = _dropout_ops(dropout_rate, dropout_seed)
-    mask_ops, mask_specs = (), ()
-    if masked:
-        assert kv_mask.shape == (b, kv_len), (
-            f"kv_mask must be [batch, kv_len]={b, kv_len}, got {kv_mask.shape}")
-        mask_ops = (kv_mask.astype(jnp.float32)[:, None, :],)
-        mask_specs = (_mask_spec(h, block_k),)
-
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               masked=masked, dropout=drop,
-                               single=(n_kb == 1))
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(bh, n_qb, n_kb),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j, kb: (i, kb, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda i, j, kb: (i, kb, 0)),
-            *seed_specs,
-            *mask_specs,
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, dv), lambda i, j, kb: (i, j, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda i, j, kb: (i, 0, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, s, dv), q.dtype),
-            jax.ShapeDtypeStruct((bh, 1, s), jnp.float32),
-        ],
-        scratch_shapes=[
-            _VMEM((block_q, 1), jnp.float32),   # running max m
-            _VMEM((block_q, 1), jnp.float32),   # running sum l
-            _VMEM((block_q, dv), jnp.float32),  # output accumulator
-        ],
-        interpret=interpret,
-        name=name,
-        **_grid_params(interpret),
-    )(qf, kf, vf, *seed_ops, *mask_ops)
-    outh = _unflatten_heads(out, b, h)
-    return outh, (q, k, v, kv_mask, dropout_seed, outh, lse)
-
-
-def _flash_fwd_rule(q, k, v, kv_mask, dropout_seed, causal, block_q, block_k,
-                    interpret, dropout_rate):
-    out, res = _flash_fwd(q, k, v, kv_mask, dropout_seed, causal, block_q,
-                          block_k, interpret, dropout_rate)
-    return out, res
+    ops = _Operands(b, h, d, dv)
+    out, lse = _fwd_call(
+        ops, ops.to_kernel(q), ops.to_kernel(k), ops.to_kernel(v),
+        (s, k.shape[1], d, dv), kv_mask, dropout_seed, causal, block_q,
+        block_k, interpret, dropout_rate, name)
+    out = ops.from_kernel(out)
+    return out, (q, k, v, kv_mask, dropout_seed, out, lse)
 
 
 def _flash_bwd_rule(causal, block_q, block_k, interpret, dropout_rate, res, g):
@@ -587,128 +949,67 @@ def _flash_bwd_rule(causal, block_q, block_k, interpret, dropout_rate, res, g):
         "value width of its own is forward-only (flash_attention_forward)")
     q, k, v, kv_mask, dropout_seed, out, lse = res
     b, s, h, d = q.shape
-    kv_len = k.shape[1]
-    block_q, block_k = _resolve_blocks(s, kv_len, d, block_q, block_k, causal,
-                                       dropout_rate)
-    masked = kv_mask is not None
-    scale = 1.0 / math.sqrt(d)
-    bh = b * h
-
-    qf, kf, vf = _flatten_heads(q), _flatten_heads(k), _flatten_heads(v)
-    dof = _flatten_heads(g)
-    of = _flatten_heads(out)
-    delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32), axis=-1,
-                    keepdims=True).transpose(0, 2, 1)  # [bh, 1, s]
-
-    n_qb = pl.cdiv(s, block_q)
-    n_kb = pl.cdiv(kv_len, block_k)
-
-    seed_ops, seed_specs, drop = _dropout_ops(dropout_rate, dropout_seed)
-    mask_ops, mask_specs = (), ()
-    if masked:
-        mask_ops = (kv_mask.astype(jnp.float32)[:, None, :],)
-        mask_specs = (_mask_spec(h, block_k),)
-
-    if n_qb == 1 and n_kb == 1:
-        # single-tile fused backward: one kernel, one score pass
-        grid_1d = ({} if interpret else
-                   {"compiler_params": pltpu.CompilerParams(
-                       dimension_semantics=("parallel",),
-                       vmem_limit_bytes=100 * 1024 * 1024)})
-        fused_seed_specs = seed_specs
-        fused_mask_specs = ((pl.BlockSpec((1, 1, block_k),
-                                          lambda i: (i // h, 0, 0)),)
-                            if masked else ())
-        dq, dk, dv = pl.pallas_call(
-            functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
-                              masked=masked, dropout=drop),
-            grid=(bh,),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), lambda i: (i, 0, 0)),
-                pl.BlockSpec((1, block_k, d), lambda i: (i, 0, 0)),
-                pl.BlockSpec((1, block_k, d), lambda i: (i, 0, 0)),
-                pl.BlockSpec((1, block_q, d), lambda i: (i, 0, 0)),
-                pl.BlockSpec((1, 1, block_q), lambda i: (i, 0, 0)),
-                pl.BlockSpec((1, 1, block_q), lambda i: (i, 0, 0)),
-                *fused_seed_specs,
-                *fused_mask_specs,
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_q, d), lambda i: (i, 0, 0)),
-                pl.BlockSpec((1, block_k, d), lambda i: (i, 0, 0)),
-                pl.BlockSpec((1, block_k, d), lambda i: (i, 0, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-                jax.ShapeDtypeStruct((bh, kv_len, d), k.dtype),
-                jax.ShapeDtypeStruct((bh, kv_len, d), v.dtype),
-            ],
-            interpret=interpret,
-            **grid_1d,
-        )(qf, kf, vf, dof, lse, delta, *seed_ops, *mask_ops)
-        dqh = (_unflatten_heads(dq, b, h), _unflatten_heads(dk, b, h),
-               _unflatten_heads(dv, b, h))
-        return dqh + (jnp.zeros_like(kv_mask) if masked else None, None)
-
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          masked=masked, dropout=drop, single=(n_kb == 1)),
-        grid=(bh, n_qb, n_kb),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j, kb: (i, kb, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j, kb: (i, kb, 0)),
-            pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda i, j, kb: (i, 0, j)),
-            pl.BlockSpec((1, 1, block_q), lambda i, j, kb: (i, 0, j)),
-            *seed_specs,
-            *mask_specs,
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-        scratch_shapes=[_VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-        **_grid_params(interpret),
-    )(qf, kf, vf, dof, lse, delta, *seed_ops, *mask_ops)
-
-    # grid (bh, k blocks, q blocks): mask/seed specs take (i, kb, j) index
-    # order, so the kb-indexed mask slice rides program_id(1)
-    dkv_mask_specs = ((pl.BlockSpec((1, 1, block_k),
-                                    lambda i, kb, j: (i // h, 0, kb)),)
-                      if masked else ())
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          masked=masked, dropout=drop, single=(n_qb == 1)),
-        grid=(bh, n_kb, n_qb),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, kb, j: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, kb, j: (i, kb, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, kb, j: (i, kb, 0)),
-            pl.BlockSpec((1, block_q, d), lambda i, kb, j: (i, j, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda i, kb, j: (i, 0, j)),
-            pl.BlockSpec((1, 1, block_q), lambda i, kb, j: (i, 0, j)),
-            *seed_specs,
-            *dkv_mask_specs,
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda i, kb, j: (i, kb, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, kb, j: (i, kb, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, kv_len, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, kv_len, d), v.dtype),
-        ],
-        scratch_shapes=[
-            _VMEM((block_k, d), jnp.float32),
-            _VMEM((block_k, d), jnp.float32),
-        ],
-        interpret=interpret,
-        **_grid_params(interpret),
-    )(qf, kf, vf, dof, lse, delta, *seed_ops, *mask_ops)
-
-    dqh = (_unflatten_heads(dq, b, h), _unflatten_heads(dk, b, h),
-           _unflatten_heads(dv, b, h))
-    return dqh + (jnp.zeros_like(kv_mask) if masked else None, None)
+    ops = _Operands(b, h, d, d)
+    grads = _bwd_call(
+        ops, ops.to_kernel(q), ops.to_kernel(k), ops.to_kernel(v),
+        ops.to_kernel(g), ops.to_kernel(out), lse, (s, k.shape[1], d),
+        kv_mask, dropout_seed, causal, block_q, block_k, interpret,
+        dropout_rate)
+    return (*map(ops.from_kernel, grads),
+            None if kv_mask is None else jnp.zeros_like(kv_mask), None)
 
 
-flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+flash_attention.defvjp(_flash_fwd, _flash_bwd_rule)
+
+
+def flash_self_attention(qkv, kv_mask=None, dropout_seed=None, causal=False,
+                         block_q=None, block_k=None, interpret=False,
+                         dropout_rate=0.0):
+    """``flash_attention(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2], ...)`` for
+    the fused projection ``qkv`` [b, s, 3, h, d] of a self-attention layer.
+
+    A kernel call wants whole buffers, so three slices of one activation
+    are three copies going in and a concatenate coming back.  Where the
+    heads fill lane tiles (``_Operands``) the kernels index the ONE array
+    three times instead, a third of its last dimension apart; any other
+    shape takes the slices."""
+    b, s, _, h, d = qkv.shape
+    if not _Operands(b, h, d, d).packed:
+        return flash_attention(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2],
+                               kv_mask, dropout_seed, causal, block_q,
+                               block_k, interpret, dropout_rate)
+    return _flash_fused(qkv, kv_mask, dropout_seed, causal, block_q, block_k,
+                        interpret, dropout_rate)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_fused(qkv, kv_mask, dropout_seed, causal, block_q, block_k,
+                 interpret, dropout_rate):
+    return _flash_fused_fwd(qkv, kv_mask, dropout_seed, causal, block_q,
+                            block_k, interpret, dropout_rate)[0]
+
+
+def _flash_fused_fwd(qkv, kv_mask, dropout_seed, causal, block_q, block_k,
+                     interpret, dropout_rate):
+    b, s, _, h, d = qkv.shape
+    ops = _Operands(b, h, d, d, fused=True)
+    x = qkv.reshape(b, s, 3 * h * d)
+    out, lse = _fwd_call(ops, x, x, x, (s, s, d, d), kv_mask, dropout_seed,
+                         causal, block_q, block_k, interpret, dropout_rate)
+    return ops.from_kernel(out), (qkv, kv_mask, dropout_seed, out, lse)
+
+
+def _flash_fused_bwd(causal, block_q, block_k, interpret, dropout_rate, res,
+                     g):
+    qkv, kv_mask, dropout_seed, out, lse = res
+    b, s, _, h, d = qkv.shape
+    ops = _Operands(b, h, d, d, fused=True)
+    x = qkv.reshape(b, s, 3 * h * d)
+    grad = _bwd_call(ops, x, x, x, ops.to_kernel(g), out, lse, (s, s, d),
+                     kv_mask, dropout_seed, causal, block_q, block_k,
+                     interpret, dropout_rate)
+    return (grad.reshape(qkv.shape),
+            None if kv_mask is None else jnp.zeros_like(kv_mask), None)
+
+
+_flash_fused.defvjp(_flash_fused_fwd, _flash_fused_bwd)

@@ -7,8 +7,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.ops.transformer import flash_attention as fa
 from deepspeed_tpu.ops.transformer.attention import reference_attention
-from deepspeed_tpu.ops.transformer.flash_attention import flash_attention
+from deepspeed_tpu.ops.transformer.flash_attention import (
+    flash_attention, flash_attention_forward, flash_self_attention)
 
 
 def rand_qkv(b, s, h, d, seed=0, dtype=jnp.float32):
@@ -46,69 +48,127 @@ def test_dispatch_follows_platform_and_shape(monkeypatch, platform, batch,
     assert _use_pallas(q, q) is kernel
 
 
-@pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("s", [256, 384])
-def test_flash_forward_matches_reference(causal, s):
-    q, k, v = rand_qkv(2, s, 4, 64)
-    out_ref = reference_attention(q, k, v, causal=causal)
-    out = flash_attention(q, k, v, causal=causal, block_q=128, block_k=128,
-                          interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),
+def test_self_attention_hands_the_kernel_the_fused_projection(monkeypatch):
+    """``TransformerLayer``'s call: where the flash kernel runs it gets the
+    [b, s, 3, h, d] projection whole, elsewhere its three slices go to
+    ``dot_product_attention``; the result is the same either way."""
+    from deepspeed_tpu.ops.transformer import attention
+    from deepspeed_tpu.parallel import mesh
+
+    qkv = jax.random.normal(jax.random.PRNGKey(23), (2, 512, 3, 2, 64))
+    kvm, additive = padding_masks(2, 512, [512, 300])
+    want = reference_attention(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2],
+                               mask=additive, causal=True)
+    off_tpu = attention.self_attention(qkv, key_padding_mask=kvm, causal=True)
+    np.testing.assert_allclose(np.asarray(off_tpu), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+    seen = []
+
+    def kernel(qkv, **kw):
+        seen.append(qkv.shape)
+        return flash_self_attention(qkv, interpret=True, **kw)
+
+    monkeypatch.setattr(mesh, "current_platform", lambda: "tpu")
+    monkeypatch.setattr(mesh, "get_current_mesh", lambda: None)
+    monkeypatch.setattr(fa, "flash_self_attention", kernel)
+    on_tpu = attention.self_attention(qkv, key_padding_mask=kvm, causal=True)
+    assert seen == [qkv.shape]
+    np.testing.assert_allclose(np.asarray(on_tpu), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_backward_matches_reference(causal):
-    q, k, v = rand_qkv(1, 256, 2, 64, seed=3)
+# Operand layouts (``flash_attention._Operands``): two 64-wide heads a
+# 128-lane block, one 128-wide head a block — and, by ``BLOCKS``, streamed
+# over several k blocks or one tile (forward + the fused backward).
+SHAPES = [(4, 64), (2, 128)]
+BLOCKS = [128, None]
+shapes = pytest.mark.parametrize("h,d", SHAPES, ids=["h4-d64", "h2-d128"])
+blocks = pytest.mark.parametrize("block", BLOCKS, ids=["streamed", "tile"])
+entries = pytest.mark.parametrize("entry", ["qkv", "fused"])
 
-    def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=causal, block_q=128,
-                                       block_k=128, interpret=True) ** 2)
 
-    def loss_ref(q, k, v):
-        return jnp.sum(reference_attention(q, k, v, causal=causal) ** 2)
+def attend(entry, q, k, v, **kw):
+    """``flash_attention`` on q, k, v, or ``flash_self_attention`` on the
+    fused projection they are the thirds of."""
+    if entry == "qkv":
+        return flash_attention(q, k, v, interpret=True, **kw)
+    return flash_self_attention(jnp.stack([q, k, v], axis=2), interpret=True,
+                                **kw)
 
+
+def assert_grads_match(loss_flash, loss_ref, q, k, v):
     g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for gf, gr, name in zip(g_flash, g_ref, "qkv"):
         np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
                                    atol=5e-4, rtol=5e-4,
                                    err_msg=f"d{name} mismatch")
+    return g_flash
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_key_padding_mask_forward(causal):
-    b, s = 2, 256
-    q, k, v = rand_qkv(b, s, 4, 64, seed=5)
-    kvm, additive = padding_masks(b, s, [200, 131])
-    out_ref = reference_attention(q, k, v, mask=additive, causal=causal)
-    out = flash_attention(q, k, v, kv_mask=kvm, causal=causal, block_q=128,
-                          block_k=128, interpret=True)
+@pytest.mark.parametrize("s", [256, 384])
+@shapes
+@blocks
+def test_flash_forward_matches_reference(causal, s, h, d, block):
+    q, k, v = rand_qkv(2, s, h, d)
+    out_ref = reference_attention(q, k, v, causal=causal)
+    out = flash_attention(q, k, v, causal=causal, block_q=block,
+                          block_k=block, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),
                                atol=2e-5, rtol=2e-5)
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_key_padding_mask_backward(causal):
+@shapes
+@blocks
+@entries
+def test_flash_backward_matches_reference(causal, h, d, block, entry):
+    q, k, v = rand_qkv(1, 256, h, d, seed=3)
+
+    def loss_flash(q, k, v):
+        return jnp.sum(attend(entry, q, k, v, causal=causal, block_q=block,
+                              block_k=block) ** 2)
+
+    def loss_ref(q, k, v):
+        return jnp.sum(reference_attention(q, k, v, causal=causal) ** 2)
+
+    assert_grads_match(loss_flash, loss_ref, q, k, v)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@shapes
+@blocks
+def test_flash_key_padding_mask_forward(causal, h, d, block):
     b, s = 2, 256
-    q, k, v = rand_qkv(b, s, 2, 64, seed=7)
+    q, k, v = rand_qkv(b, s, h, d, seed=5)
+    kvm, additive = padding_masks(b, s, [200, 131])
+    out_ref = reference_attention(q, k, v, mask=additive, causal=causal)
+    out = flash_attention(q, k, v, kv_mask=kvm, causal=causal, block_q=block,
+                          block_k=block, interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@shapes
+@blocks
+@entries
+def test_flash_key_padding_mask_backward(causal, h, d, block, entry):
+    b, s = 2, 256
+    q, k, v = rand_qkv(b, s, h, d, seed=7)
     kvm, additive = padding_masks(b, s, [256, 77])
 
     def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, kv_mask=kvm, causal=causal,
-                                       block_q=128, block_k=128,
-                                       interpret=True) ** 2)
+        return jnp.sum(attend(entry, q, k, v, kv_mask=kvm, causal=causal,
+                              block_q=block, block_k=block) ** 2)
 
     def loss_ref(q, k, v):
         return jnp.sum(reference_attention(q, k, v, mask=additive,
                                            causal=causal) ** 2)
 
-    g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for gf, gr, name in zip(g_flash, g_ref, "qkv"):
-        np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
-                                   atol=5e-4, rtol=5e-4,
-                                   err_msg=f"d{name} mismatch")
+    g_flash = assert_grads_match(loss_flash, loss_ref, q, k, v)
     # masked keys must receive exactly zero dK/dV
     for g, name in zip(g_flash[1:], "kv"):
         masked_part = np.asarray(g)[1, 77:]
@@ -116,21 +176,23 @@ def test_flash_key_padding_mask_backward(causal):
                                       err_msg=f"d{name} leak into padding")
 
 
-def test_flash_fully_masked_row_is_zero():
+@shapes
+@blocks
+@entries
+def test_flash_fully_masked_row_is_zero(h, d, block, entry):
     """A sequence whose every key is padded out must yield zero output and
     zero gradients (not NaN/garbage from an all-NEG_INF softmax)."""
     b, s = 2, 256
-    q, k, v = rand_qkv(b, s, 2, 64, seed=9)
+    q, k, v = rand_qkv(b, s, h, d, seed=9)
     kvm, _ = padding_masks(b, s, [128, 0])
-    out = flash_attention(q, k, v, kv_mask=kvm, block_q=128, block_k=128,
-                          interpret=True)
+    out = attend(entry, q, k, v, kv_mask=kvm, block_q=block, block_k=block)
     out = np.asarray(out)
     assert np.all(np.isfinite(out))
     np.testing.assert_array_equal(out[1], 0.0)
 
     def loss(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, kv_mask=kvm, block_q=128,
-                                       block_k=128, interpret=True) ** 2)
+        return jnp.sum(attend(entry, q, k, v, kv_mask=kvm, block_q=block,
+                              block_k=block) ** 2)
 
     grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
     for g, name in zip(grads, "qkv"):
@@ -138,6 +200,115 @@ def test_flash_fully_masked_row_is_zero():
         assert np.all(np.isfinite(g)), f"d{name} not finite"
         np.testing.assert_array_equal(g[1], 0.0,
                                       err_msg=f"d{name} on masked batch row")
+
+
+def test_fused_backward_without_the_resident_row_block(monkeypatch):
+    """Past ``_ROW_BLOCK_BYTES`` a batch row's [s, 3·h·d] gradient block is
+    not kept in VMEM: the fused backward writes dq, dk, dv apart and they
+    are concatenated, to the same gradient."""
+    qkv = jax.random.normal(jax.random.PRNGKey(29), (2, 256, 3, 4, 64))
+
+    def loss(qkv):
+        return jnp.sum(flash_self_attention(qkv, causal=True,
+                                            interpret=True) ** 2)
+
+    resident = jax.grad(loss)(qkv)
+    monkeypatch.setattr(fa, "_ROW_BLOCK_BYTES", 0)
+    np.testing.assert_array_equal(np.asarray(jax.grad(loss)(qkv)),
+                                  np.asarray(resident))
+
+
+@pytest.fixture
+def layouts(monkeypatch):
+    """The operand layout of every kernel geometry logged in the test."""
+    seen = []
+    monkeypatch.setattr(fa, "_log_geometry", lambda *a: seen.append(a[-1]))
+    return seen
+
+
+@pytest.mark.parametrize("h,d,layout", [
+    (4, 64, "heads/block=2, projection layout"),
+    (2, 128, "heads/block=1, projection layout"),
+    (25, 64, "flattened"),   # gpt2_xl: an odd number of 64-wide heads
+], ids=["h4-d64", "h2-d128", "h25-d64"])
+def test_operand_layout_follows_heads_and_width(layouts, h, d, layout):
+    """Which layout a shape takes, and that the one it falls back to still
+    computes attention, forward and gradient, through either entry."""
+    q, k, v = rand_qkv(1, 128, h, d, seed=13)
+
+    def loss_ref(q, k, v):
+        return jnp.sum(reference_attention(q, k, v, causal=True) ** 2)
+
+    for entry in ("qkv", "fused"):
+        assert_grads_match(
+            lambda q, k, v: jnp.sum(attend(entry, q, k, v, causal=True) ** 2),
+            loss_ref, q, k, v)
+    fused = layout if layout == "flattened" else layout + ", fused qkv"
+    assert set(layouts) == {layout, fused}
+
+
+def test_forward_at_key_width_192_value_width_128_stays_flattened(layouts):
+    """The latent-attention prefill's widths: no whole number of 192-wide
+    heads fills lane tiles, so this entry keeps [b·h, s, d] operands."""
+    ks = jax.random.split(jax.random.PRNGKey(17), 3)
+    q, k = (jax.random.normal(key, (1, 256, 2, 192)) for key in ks[:2])
+    v = jax.random.normal(ks[2], (1, 256, 2, 128))
+    out = flash_attention_forward(q, k, v, causal=True, block_q=128,
+                                  block_k=128, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(reference_attention(q, k, v, causal=True)),
+        atol=2e-5, rtol=2e-5)
+    assert layouts == ["flattened"]
+
+
+@shapes
+@blocks
+@entries
+def test_dropout_masks_are_seeded_by_batch_times_heads_plus_head(
+        monkeypatch, h, d, block, entry):
+    """The keep mask of head ``n`` of batch row ``r`` is drawn from the seed
+    words ``(seed[0] ^ (r·h + n), seed[1] ^ tile)`` whatever the operand
+    layout, forward and backward: the masks are the ones the kernels drew
+    while every grid step held one head.  The hardware PRNG exists on a
+    TPU only, so ``_keep_mask`` is replaced by a hash of the same
+    arguments, and the kernels are compared with attention under the
+    explicit mask that hash gives for head ``r·h + n``
+    (``test_flash_dropout_matches_explicit_mask_reference`` checks the real
+    draw on a chip)."""
+    b, s, rate = 2, 256, 0.25
+    blk = block or s
+    _, inv_keep = fa._dropout_thresh(rate)
+
+    def keep_of(head, j, kb, rows, cols):
+        # a pure function of (head, tile): 3 of 4 kept
+        return (head * 7 + j * 5 + kb * 3 + rows * 13 + cols * 11) % 4 != 0
+
+    def fake_keep_mask(seed_ref, i, j, kb, shape, thresh):
+        rows = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        return keep_of(seed_ref[0] ^ i, j, kb, rows, cols)
+
+    monkeypatch.setattr(fa, "_keep_mask", fake_keep_mask)
+    seed = np.asarray([40, 0], np.int32)
+    heads = (seed[0] ^ np.arange(b * h)).reshape(b, h, 1, 1)
+    pos = np.arange(s)
+    keep = keep_of(heads, pos[:, None] // blk, pos[None, :] // blk,
+                   pos[:, None] % blk, pos[None, :] % blk)  # [b, h, s, s]
+    q, k, v = rand_qkv(b, s, h, d, seed=19)
+
+    def loss_ref(q, k, v):
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
+        probs = jnp.where(keep, jax.nn.softmax(scores, axis=-1) * inv_keep, 0.0)
+        return jnp.sum(jnp.einsum("bhqk,bkhd->bqhd", probs, v) ** 2)
+
+    def loss_flash(q, k, v):
+        return jnp.sum(attend(entry, q, k, v, dropout_seed=jnp.asarray(seed),
+                              dropout_rate=rate, block_q=block,
+                              block_k=block) ** 2)
+
+    np.testing.assert_allclose(float(loss_flash(q, k, v)),
+                               float(loss_ref(q, k, v)), rtol=1e-5)
+    assert_grads_match(loss_flash, loss_ref, q, k, v)
 
 
 def test_flash_dropout_zero_rate_identity():
@@ -214,7 +385,8 @@ def test_flash_dropout_matches_explicit_mask_reference():
 
 @pytest.mark.parametrize("dims", [{"data": 4}, {"data": 2, "model": 2}],
                          ids=["data4", "data2-model2"])
-def test_kernel_call_is_sharded_over_the_mesh(dims):
+@entries
+def test_kernel_call_is_sharded_over_the_mesh(dims, entry):
     """XLA cannot partition a Mosaic kernel call, so the dispatch wraps it
     in a shard_map over every mesh axis not manual yet: each device runs
     the kernel on its own batch (and head) shard, forward and backward —
@@ -234,13 +406,22 @@ def test_kernel_call_is_sharded_over_the_mesh(dims):
     seen = []
 
     def kernel(q, k, v, mask, seed):
-        seen.append((q.shape, mask.shape))
+        # through the fused entry ``q`` is the whole [b, s, 3, h, d] shard
+        seen.append((q.shape[:2] + q.shape[-2:], mask.shape))
+        if entry == "fused":
+            assert k is None and v is None and q.shape[2] == 3
+            return flash_self_attention(q, kv_mask=mask, causal=True,
+                                        interpret=True)
         return flash_attention(q, k, v, kv_mask=mask, causal=True,
                                interpret=True)
 
     def loss(q, k, v, mask):
-        return jnp.sum(shard_kernel_over_mesh(kernel, q, k, v,
-                                              kv_mask=mask) ** 2)
+        if entry == "fused":
+            out = shard_kernel_over_mesh(kernel, jnp.stack([q, k, v], axis=2),
+                                         kv_mask=mask)
+        else:
+            out = shard_kernel_over_mesh(kernel, q, k, v, kv_mask=mask)
+        return jnp.sum(out ** 2)
 
     want, g_want = jax.value_and_grad(
         lambda q, k, v: jnp.sum(reference_attention(

@@ -26,7 +26,7 @@ from deepspeed_tpu.ops.sparse_attention import (  # noqa: E402
 from deepspeed_tpu.ops.transformer.attention import (  # noqa: E402
     dot_product_attention)
 from deepspeed_tpu.ops.transformer.flash_attention import (  # noqa: E402
-    flash_attention)
+    flash_attention, flash_self_attention)
 from deepspeed_tpu.ops.transformer.paged_attention import (  # noqa: E402
     check_tpu_geometry, paged_decode_attention)
 
@@ -89,6 +89,71 @@ def test_flash_kernel_grad_compiles(v5e, shape, causal, masked, dropout):
                     *extra)
     # forward + the single-tile fused backward
     assert text.count("tpu_custom_call") >= 2
+
+
+def _relayouts(text, *shapes):
+    """Lines of an optimized program that copy or transpose a tensor of
+    one of ``shapes`` ("[32,16,512,64]"), inside fusions too."""
+    return [line.strip()[:160] for line in text.splitlines()
+            if (" copy(" in line or " transpose(" in line)
+            and any(shape in line.split("(")[0] for shape in shapes)]
+
+
+@pytest.mark.parametrize("entry", ["qkv", "fused"])
+def test_no_head_transposes_around_the_seq512_kernels(v5e, entry):
+    """One layer's attention as ``bert_large.seq512`` runs it (b 32, s 512,
+    16 heads of 64, key mask, dropout 0.1): QKV GEMM, the kernels, output
+    GEMM, and the gradient.  The kernels index the projection's layout, so
+    the program holds no relayout of a [b, h, s, d] tensor and exactly the
+    forward and the fused backward call; through the fused entry, which
+    ``TransformerLayer`` takes, no slice of the projection going in and no
+    concatenate coming back is materialised either."""
+    b, s, h, d = 32, 512, 16, 64
+    hidden = h * d
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=v5e)
+
+    def loss(x, w_qkv, b_qkv, w_out, kv_mask, seed):
+        qkv = (x @ w_qkv + b_qkv).reshape(b, s, 3, h, d)
+        kw = dict(kv_mask=kv_mask, dropout_seed=seed, dropout_rate=0.1)
+        if entry == "fused":
+            ctx = flash_self_attention(qkv, **kw)
+        else:
+            ctx = flash_attention(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2],
+                                  **kw)
+        out = ctx.reshape(b, s, hidden) @ w_out
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3)),
+                    shape((b, s, hidden)), shape((hidden, 3 * hidden)),
+                    shape((3 * hidden,)), shape((hidden, hidden)),
+                    shape((b, s), jnp.float32), shape((2,), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert _relayouts(text, "[32,16,512,64]") == []
+    if entry == "fused":
+        assert _relayouts(text, "[32,512,1024]", "[32,512,3072]",
+                          "[32,512,3,1024]", "[32,512,3,16,64]") == []
+
+
+def test_no_head_transposes_around_the_prefill_kernel(v5e):
+    """GPT-2-large's bucket-512 prefill (b 1, 20 heads of 64, causal, key
+    mask), forward: one kernel call and no [1, 20, 512, 64] relayout."""
+    s, h, d = 512, 20, 64
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=v5e)
+
+    def prefill(x, w_qkv, w_out, visible):
+        qkv = (x @ w_qkv).reshape(1, s, 3, h * d)
+        q, k, v = (qkv[:, :, j].reshape(1, s, h, d) for j in range(3))
+        ctx = flash_attention(q, k, v, kv_mask=visible, causal=True)
+        return ctx.reshape(1, s, h * d) @ w_out
+
+    text = _compile(prefill, shape((1, s, h * d)), shape((h * d, 3 * h * d)),
+                    shape((h * d, h * d)), shape((1, s), jnp.float32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert _relayouts(text, "[1,20,512,64]") == []
 
 
 def test_block_sparse_flash_kernel_grad_compiles(v5e):
