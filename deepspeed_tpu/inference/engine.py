@@ -73,7 +73,8 @@ class _Enqueued:
     """One enqueued program whose outputs the host has not read: the
     device ``out`` dict, the ``(request, block grant)`` each lane was
     dispatched for, the clock at its enqueue and, for a decode, the KV
-    blocks it reads (None marks a prefill)."""
+    blocks it reads in a layer of each cache group (None marks a
+    prefill)."""
 
     __slots__ = ("out", "lanes", "enqueued_at", "live_blocks")
 
@@ -131,17 +132,27 @@ class InferenceEngine:
             # a geometry the decode kernel cannot tile fails here, at
             # construction, never by a silent second path
             serving.check_tpu_geometry(icfg)
+        # a pool, its buffers and a block table for every cache group the
+        # model names (one, for a model whose layers all cache the whole
+        # context); the programs take the buffers as one flat tuple in
+        # cache_buffers' order and the tables as a tuple, one a group
+        groups = self.cache_groups = tuple(serving.cache_groups(icfg))
         buffers = serving.cache_buffers(icfg)
-        self._caches = init_cache_buffers(
-            serving.num_layers, icfg.kv_blocks, icfg.kv_block_size,
-            tuple(buffers.values()), dtype=cache_dtype)
+        assert list(buffers) == [n for g in groups for n in g.buffers]
+        self.allocators = [BlockAllocator(g.num_blocks(icfg), g.pages)
+                           for g in groups]
+        self._caches = tuple(
+            cache for g in groups for cache in init_cache_buffers(
+                g.layers, g.num_blocks(icfg), icfg.kv_block_size,
+                tuple(g.buffers.values()), dtype=cache_dtype))
         # bytes one live block holds in each buffer (the live-bytes gauges)
         self.cache_block_bytes = {
-            name: serving.num_layers * icfg.kv_block_size * row
+            name: g.layers * icfg.kv_block_size * row
             * jnp.dtype(cache_dtype).itemsize
-            for name, row in buffers.items()}
-        self.allocator = BlockAllocator(icfg.kv_blocks)
-        self.scheduler = ContinuousBatchScheduler(icfg, self.allocator)
+            for g in groups for name, row in g.buffers.items()}
+        # the first group's pool is the one the occupancy gauges follow
+        self.allocator = self.allocators[0]
+        self.scheduler = ContinuousBatchScheduler(icfg, self.allocators)
 
         # -- telemetry + ledgers (the training engine's wiring, reused) --
         self.telemetry_config = DeepSpeedTelemetryConfig(param_dict)
@@ -185,9 +196,9 @@ class InferenceEngine:
                               donate_argnums=(1,)))
         # the decode program's host tables, kept between iterations: a
         # slot's row changes only when its request does
-        self._tables = np.full(
-            (icfg.max_batch_slots, icfg.max_blocks_per_seq), NULL_BLOCK,
-            np.int32)
+        self._tables = [np.full(
+            (icfg.max_batch_slots, g.table_width(icfg)), NULL_BLOCK,
+            np.int32) for g in groups]
         self._table_owner = [None] * icfg.max_batch_slots
         # ...and their copy on the device, made anew when a row changed
         self._tables_dev = None
@@ -233,11 +244,16 @@ class InferenceEngine:
                                     "prefill_buckets": list(
                                         icfg.prefill_buckets)})
         logger.info(
-            "InferenceEngine: %s, %d layers, %d slots, %d blocks x %d "
-            "tokens of %s, prefill buckets %s, weights %s",
+            "InferenceEngine: %s, %d layers, %d slots, blocks of %d tokens: "
+            "%s; prefill buckets %s, weights %s",
             type(model).__name__, serving.num_layers, icfg.max_batch_slots,
-            icfg.kv_blocks, icfg.kv_block_size,
-            " + ".join(f"{n}[{w}]" for n, w in buffers.items()),
+            icfg.kv_block_size,
+            "; ".join(
+                f"{g.name} ({g.layers} layers, {g.num_blocks(icfg)} blocks, "
+                + ("the whole context" if g.pages is None
+                   else f"{g.pages} a request") + ") "
+                + " + ".join(f"{n}[{w}]" for n, w in g.buffers.items())
+                for g in groups),
             list(icfg.prefill_buckets), icfg.weights_dtype)
 
     @staticmethod
@@ -349,13 +365,12 @@ class InferenceEngine:
                 # read its host arguments (the CPU backend aliases them)
                 ids = np.zeros((1, request.bucket), np.int32)
                 ids[0, :len(request.prompt)] = request.prompt
-                table = np.asarray(
-                    self.scheduler.block_table_row(request), np.int32)
+                tables = self.scheduler.block_tables(request)
             with span("prefill.dispatch"):
                 out, self._caches, self._next_tokens = self._prefills[
                     request.bucket](
                         self.params, self._caches, ids,
-                        np.int32(len(request.prompt)), table,
+                        np.int32(len(request.prompt)), tables,
                         self._next_tokens, np.int32(request.slot))
             with span("prefill.fetch"):
                 # kept so that a `prefill` still holds its four phases
@@ -365,7 +380,7 @@ class InferenceEngine:
             with span("prefill.account"):
                 request.dispatched = 1
                 self._unread.append(_Enqueued(
-                    out, [(request, request.blocks)], t_pre))
+                    out, [(request, request.grants)], t_pre))
 
     def _emit_finish(self, request):
         self.observability.note_finish(request)
@@ -389,26 +404,31 @@ class InferenceEngine:
         span = self.telemetry.span
         # counted before the span opens: an annotation's arguments are
         # fixed at its start
-        live_blocks = sched.live_blocks()
+        live_blocks = tuple(sched.live_blocks(g)
+                            for g in range(len(self.cache_groups)))
         in_flight = int(any(p.is_decode for p in self._unread))
-        with span("decode", active=active, live_blocks=live_blocks,
-                  in_flight=in_flight):
+        with span("decode", active=active, live_blocks=live_blocks[0],
+                  in_flight=in_flight, **{
+                      f"live_blocks_{g.name}": n
+                      for g, n in zip(self.cache_groups, live_blocks)}):
             with span("decode.prep"):
                 t_prep = self._clock()
                 tables, owner = self._tables, self._table_owner
                 ctx_lens = np.zeros((icfg.max_batch_slots,), np.int32)
                 lanes = []
                 for slot, request in enumerate(sched.slots):
-                    grant = (request.blocks if sched.decodes_next(request)
+                    grant = (request.grants if sched.decodes_next(request)
                              else None)
                     if grant is not owner[slot]:
-                        # a block grant (a list made at admission) stays
+                        # a block grant (a tuple made at admission) stays
                         # as it is until the request finishes, so a row is
                         # rewritten only when the slot's grant changes; a
                         # freed slot, and one whose request has all its
                         # tokens dispatched, goes back to the null block
-                        tables[slot] = (NULL_BLOCK if grant is None else
-                                        sched.block_table_row(request))
+                        for g, table in enumerate(tables):
+                            table[slot] = (
+                                NULL_BLOCK if grant is None else
+                                sched.block_table_row(request, g))
                         owner[slot] = grant
                         self._tables_dev = None
                     if grant is None:
@@ -422,7 +442,8 @@ class InferenceEngine:
                 if self._tables_dev is None:
                     # a COPY goes to the device: the host rows are written
                     # again while programs that read the old ones wait
-                    self._tables_dev = jax.device_put(tables.copy())
+                    self._tables_dev = jax.device_put(
+                        tuple(table.copy() for table in tables))
                 if self._health is not None:
                     # liveness tick for ENTERING this iteration
                     # (throttled O(1) publish; a wedged decode never
@@ -470,7 +491,7 @@ class InferenceEngine:
             now = self._clock()
             for program, out in zip(unread, outs):
                 lanes = [request for request, grant in program.lanes
-                         if request.blocks is grant]
+                         if request.grants is grant]
                 if not program.is_decode:
                     for request in lanes:
                         # the TTFT is first_token_at - submitted;

@@ -16,7 +16,17 @@ dimension every page would be padded to 128 lanes: the GPT-2-large
 cache measured 1.5x its logical bytes on a v5e in the old ``[..., heads,
 head_dim]`` layout, 1.0x in this one), and a token's entry is one row —
 what the projection emits, so the append is a row write.  The same block
-table serves every buffer.  Sequences never own contiguous cache
+table serves every buffer of a GROUP (:class:`CacheGroup`): the layers
+whose pages cover the same span of a request.  Most models have one group,
+every layer caching the whole context.  A model with sliding-window layers
+beside full ones names two: the window layers' group holds a fixed number
+of pages a request, a RING that position ``p`` enters at page ``(p //
+kv_block_size) % pages`` (``ops/transformer/paged_attention.py::
+ring_pages``), whatever the request's length — so its buffers are
+``slots x pages + 1`` blocks, not the context's.  Each group has its own
+pool (:class:`BlockAllocator`) and its own table; a request is granted its
+blocks of every group together at admission and gives them back together.
+Sequences never own contiguous cache
 memory: each holds a *block table* (host list of block ids); prefill
 scatters whole pages through it, decode scatters one row a slot and the
 paged kernel fetches the live pages by id.  Both programs
@@ -33,6 +43,8 @@ slots harmlessly overwrite scratch (two dead slots write the same row
 in one scatter: any winner will do).
 """
 
+from typing import NamedTuple, Optional
+
 import jax.numpy as jnp
 
 # block id every table slot starts at (and dead slots stay at): the
@@ -40,8 +52,32 @@ import jax.numpy as jnp
 NULL_BLOCK = 0
 
 
+class CacheGroup(NamedTuple):
+    """Cache buffers that share a block table: ``layers`` layers (the
+    buffers' leading dimension), ``buffers`` name -> row width, and the
+    span a request holds — ``pages`` blocks whatever its length (a window
+    layer's ring), or None for the whole context."""
+    name: str
+    layers: int
+    buffers: dict
+    pages: Optional[int] = None
+
+    def num_blocks(self, icfg):
+        """Blocks of this group's pool, the null block among them: the
+        configured ``kv_blocks`` for the whole context, every slot's ring
+        otherwise."""
+        if self.pages is None:
+            return icfg.kv_blocks
+        return icfg.max_batch_slots * self.pages + 1
+
+    def table_width(self, icfg):
+        return icfg.max_blocks_per_seq if self.pages is None else self.pages
+
+
 class BlockAllocator:
-    """Host-side free list over the preallocated KV blocks.
+    """Host-side free list over the preallocated KV blocks of one cache
+    group; ``pages_per_request`` is the fixed grant of a ring's pool (None:
+    a request is granted its worst-case context).
 
     Pure Python bookkeeping — nothing here touches the device.  The
     scheduler allocates a sequence's whole worst-case block budget at
@@ -50,9 +86,10 @@ class BlockAllocator:
     mid-decode the table is already paid for.
     """
 
-    def __init__(self, num_blocks):
+    def __init__(self, num_blocks, pages_per_request=None):
         assert num_blocks > 1, "need at least one block beyond the null block"
         self.num_blocks = int(num_blocks)
+        self.pages_per_request = pages_per_request
         # LIFO free list, block 0 excluded (the null block)
         self._free = list(range(self.num_blocks - 1, 0, -1))
         # pool-occupancy high-water mark (allocatable blocks in use at

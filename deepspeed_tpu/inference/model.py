@@ -6,18 +6,27 @@ it can serve has a ``serving()`` method that returns an object with:
 
 - ``num_layers``;
 - ``cache_buffers(icfg) -> {name: row width}``: the paged buffers a layer
-  keeps, each ``[layers, kv_blocks, kv_block_size, row width]`` in the
+  keeps, each ``[layers, blocks, kv_block_size, row width]`` in the
   serving dtype, allocated once by the engine and DONATED to every program
   (GPT-2: ``k_cache`` and ``v_cache`` rows of ``hidden``; DeepSeek-V2: one
   ``latent_cache`` row of 512 + 64, ``models/deepseek_v2.py``);
+- ``cache_groups(icfg) -> [CacheGroup]`` (``inference/kv_cache.py``): the
+  same buffers, in the same order, by the span of a request their layers
+  cache — each group's layer count, its buffers, and ``pages``: None for
+  the whole context, or the fixed number of blocks a request holds
+  whatever its length (a sliding-window layer's ring).  The engine keeps
+  a pool and a block table a group.  GPT-2 and DeepSeek-V2 name one group;
+  K-EXAONE (``models/exaone_moe.py``) a ``full`` and a ``window`` one;
 - ``check_tpu_geometry(icfg)``: raise for a cache its decode kernel cannot
   tile on a TPU (called at construction there, never a second path);
 - ``build_prefill(icfg, bucket) -> prefill(params, caches, input_ids[1, S],
-  true_len, block_table, next_tokens, slot) -> (out, caches,
+  true_len, block_tables, next_tokens, slot) -> (out, caches,
   next_tokens)``, one program a bucket, and ``build_decode(icfg) ->
   decode(params, caches, block_tables, ctx_lens, tokens) -> (out,
   caches)``, one program for the serve; ``caches`` is the tuple of
-  buffers in ``cache_buffers`` order, ``out`` a dict the engine fetches
+  buffers in ``cache_buffers`` order, ``block_tables`` a tuple with one
+  table a cache group (prefill: the request's row ``[width]``; decode:
+  ``[slots, width]``), ``out`` a dict the engine fetches
   whole: ``"tokens"`` (prefill: the first token; decode: ``[slots]``) and
   any scalar counters the model reports (published as ``serving/<key>``
   gauges on the print cadence).  The functions are NAMED ``prefill`` and
@@ -75,6 +84,7 @@ from ..ops.transformer.attention import dot_product_attention
 from ..ops.transformer.paged_attention import (check_tpu_geometry,
                                                paged_decode_attention)
 from ..parallel.mesh import current_platform
+from .kv_cache import CacheGroup
 
 
 def _write_prefill_blocks(cache, layer_idx, seq_kv, block_table, block_size):
@@ -194,16 +204,19 @@ class GPT2Serving:
         return {"k_cache": self.config.hidden_size,
                 "v_cache": self.config.hidden_size}
 
+    def cache_groups(self, icfg):
+        return [CacheGroup("kv", self.num_layers, self.cache_buffers(icfg))]
+
     def check_tpu_geometry(self, icfg):
         check_tpu_geometry(self.config.hidden_size, icfg.kv_block_size)
 
     def build_prefill(self, icfg, bucket_len):
         inner = build_prefill(self.config, icfg, bucket_len)
 
-        def prefill(params, caches, input_ids, true_len, block_table,
+        def prefill(params, caches, input_ids, true_len, block_tables,
                     next_tokens, slot):
             token, k_cache, v_cache = inner(params, *caches, input_ids,
-                                            true_len, block_table)
+                                            true_len, *block_tables)
             return ({"tokens": token}, (k_cache, v_cache),
                     next_tokens.at[slot].set(token))
 
@@ -214,7 +227,7 @@ class GPT2Serving:
 
         def decode(params, caches, block_tables, ctx_lens, tokens):
             next_tokens, k_cache, v_cache = inner(
-                params, *caches, block_tables, ctx_lens, tokens)
+                params, *caches, *block_tables, ctx_lens, tokens)
             return {"tokens": next_tokens}, (k_cache, v_cache)
 
         return decode

@@ -117,7 +117,7 @@ class ServingObservability:
         self._win_good_tokens = 0
         self._win_active_sum = 0
         self._win_reserved_sum = 0
-        self._win_live_blocks = 0
+        self._win_live_blocks = [0] * len(self.engine.cache_groups)
         self._win_traces = set()
         # run-cumulative accumulators (the bench receipt)
         self._run_start = self._win_start
@@ -179,8 +179,9 @@ class ServingObservability:
 
     def note_decode(self, before, gaps, live_blocks):
         """Per-iteration accounting on already-fetched scalars: window
-        occupancy/budget sums, the KV blocks the iteration read
-        (``live_blocks``) and the per-token SLO leg, judged on
+        occupancy/budget sums, the KV blocks the iteration read in a
+        layer of each cache group (``live_blocks``, a count a group) and
+        the per-token SLO leg, judged on
         ``gaps`` — for each request of ``before`` the seconds since ITS
         previous token.  With telemetry on, also the per-token P²
         observations: O(active) host arithmetic, zero syncs; with it
@@ -192,7 +193,8 @@ class ServingObservability:
         self._cum_tokens += n
         self._win_active_sum += n
         self._cum_active_sum += n
-        self._win_live_blocks += live_blocks
+        self._win_live_blocks = [a + b for a, b in zip(
+            self._win_live_blocks, live_blocks)]
         reserved = self.engine.scheduler.reserved_tokens()
         self._win_reserved_sum += reserved
         self._cum_reserved_sum += reserved
@@ -286,16 +288,26 @@ class ServingObservability:
         # full-table gather reads (slots x max_blocks_per_seq): the
         # share of the reservation that is live context
         gauge("serving/kv_live_block_share").set(
-            self._win_live_blocks
+            self._win_live_blocks[0]
             / (iters * icfg.max_batch_slots * icfg.max_blocks_per_seq)
             if iters else 0.0)
-        # the same live blocks as bytes of each of the model's buffers
+        # the same live blocks as bytes: of each of the model's buffers
         # (GPT-2: serving/k_cache_live_bytes and v_cache's; a latent-
-        # attention model: serving/latent_cache_live_bytes)
-        for name, block_bytes in self.engine.cache_block_bytes.items():
-            gauge(f"serving/{name}_live_bytes").set(
-                block_bytes * self._win_live_blocks / iters
-                if iters else 0.0)
+        # attention model: serving/latent_cache_live_bytes), and of each
+        # cache group whole — serving/<group>_cache_live_bytes where the
+        # group follows the context, serving/<group>_cache_bytes where a
+        # request holds a fixed ring (K-EXAONE: full_cache_live_bytes,
+        # window_cache_bytes)
+        block_bytes = self.engine.cache_block_bytes
+        for group, blocks in zip(self.engine.cache_groups,
+                                 self._win_live_blocks):
+            live = blocks / iters if iters else 0.0
+            for name in group.buffers:
+                gauge(f"serving/{name}_live_bytes").set(
+                    block_bytes[name] * live)
+            gauge(f"serving/{group.name}_cache_"
+                  + ("live_bytes" if group.pages is None else "bytes")).set(
+                sum(block_bytes[name] for name in group.buffers) * live)
         gauge("serving/slo_attainment").set(attainment)
         gauge("serving/goodput_tokens_per_second").set(
             self._win_good_tokens / window)
@@ -311,7 +323,7 @@ class ServingObservability:
         self._win_good_tokens = 0
         self._win_active_sum = 0
         self._win_reserved_sum = 0
-        self._win_live_blocks = 0
+        self._win_live_blocks = [0] * len(self.engine.cache_groups)
         self._win_traces = set()
 
     # -- the receipt ----------------------------------------------------

@@ -21,6 +21,10 @@ dispatches the compiled programs.  Per engine iteration:
    has been read and the sweep recycles it — so no write ever leaves
    the block grant.
 
+A model whose layers cache different spans has a pool a cache group
+(``inference/kv_cache.py``): the request's grant holds one block list a
+group, all granted at admission or none, all released together.
+
 The token budget is the Orca admission knob: the sum of each active
 request's worst case (prompt + remaining generation) stays under
 ``inference.token_budget``, bounding both cache pressure and
@@ -29,6 +33,8 @@ per-iteration latency under load.
 
 import time
 from collections import deque
+
+import numpy as np
 
 from .kv_cache import NULL_BLOCK
 
@@ -57,10 +63,12 @@ class Request:
     ``reason="deadline"`` and the partial tokens it generated so far.
     ``dispatched`` counts the tokens whose programs have been enqueued
     (the prefill's and one a decode), ``generated`` holds those the host
-    has read: ``dispatched - len(generated)`` are in flight."""
+    has read: ``dispatched - len(generated)`` are in flight.  ``grants``
+    is the block grant, one list of block ids a cache group (empty while
+    the request holds none); ``blocks`` the first group's."""
 
     __slots__ = ("request_id", "prompt", "max_new_tokens", "state",
-                 "generated", "dispatched", "blocks", "slot", "bucket",
+                 "generated", "dispatched", "grants", "slot", "bucket",
                  "submitted", "first_token_at", "last_token_at",
                  "finished_at", "finish_reason", "step_times",
                  "deadline_at", "requeues", "trace_id", "admitted_at",
@@ -75,7 +83,7 @@ class Request:
         self.state = QUEUED
         self.generated = []
         self.dispatched = 0
-        self.blocks = []
+        self.grants = ()
         self.slot = None
         self.bucket = None
         self.submitted = time.monotonic()
@@ -110,7 +118,7 @@ class Request:
         self.state = QUEUED
         self.generated = []
         self.dispatched = 0
-        self.blocks = []
+        self.grants = ()
         self.slot = None
         self.bucket = None
         self.first_token_at = None
@@ -121,6 +129,11 @@ class Request:
         self.requeues += 1
         self.admitted_at = None
         self._cached_summary = None
+
+    @property
+    def blocks(self):
+        """The first cache group's block ids ([] while none are held)."""
+        return self.grants[0] if self.grants else []
 
     @property
     def context_len(self):
@@ -170,8 +183,12 @@ class ContinuousBatchScheduler:
     :class:`~deepspeed_tpu.inference.engine.InferenceEngine`."""
 
     def __init__(self, icfg, allocator):
+        """``allocator``: the pool, or one pool a cache group (a list)."""
         self.icfg = icfg
-        self.allocator = allocator
+        self.allocators = (list(allocator)
+                           if isinstance(allocator, (list, tuple))
+                           else [allocator])
+        self.allocator = self.allocators[0]
         self.waiting = deque()
         self.slots = [None] * icfg.max_batch_slots
         self.admitted_total = 0
@@ -207,12 +224,16 @@ class ContinuousBatchScheduler:
         """Slots the next decode advances."""
         return sum(1 for r in self.slots if self.decodes_next(r))
 
-    def live_blocks(self):
-        """KV blocks the next decode iteration reads: for each slot it
-        advances, the blocks holding its context and the token being
-        decoded, ``ceil((prompt + dispatched) / block_size)`` — what
-        the paged kernel walks, of the ``slots * max_blocks_per_seq`` a
-        full-table gather would."""
+    def live_blocks(self, group=0):
+        """KV blocks the next decode iteration reads in one layer of cache
+        group ``group``: for each slot it advances, the blocks holding its
+        context and the token being decoded, ``ceil((prompt + dispatched)
+        / block_size)`` — what the paged kernel walks, of the ``slots *
+        max_blocks_per_seq`` a full-table gather would — or, in a group
+        of fixed spans, the slot's whole ring."""
+        pages = self.allocators[group].pages_per_request
+        if pages is not None:
+            return pages * self.decoding_count
         bs = self.icfg.kv_block_size
         return sum(-(-(len(r.prompt) + r.dispatched) // bs)
                    for r in self.slots if self.decodes_next(r))
@@ -223,7 +244,7 @@ class ContinuousBatchScheduler:
     # -- admission ------------------------------------------------------
     def submit(self, request):
         icfg = self.icfg
-        assert not request.blocks and request.slot is None, (
+        assert not request.grants and request.slot is None, (
             f"request {request.request_id!r} submitted while still "
             "holding a block grant/slot — a requeued request must go "
             "through reset_for_requeue() first (a stale grant would be "
@@ -254,6 +275,25 @@ class ContinuousBatchScheduler:
         span = max(bucket, request.worst_case_tokens())
         return -(-span // bs)  # ceil
 
+    def _grant(self, request, bucket):
+        """The request's blocks of every cache group — its worst-case
+        context, or the group's fixed span — or None, with nothing taken,
+        when any pool cannot cover its part."""
+        grants = []
+        for allocator in self.allocators:
+            blocks = allocator.allocate(
+                allocator.pages_per_request
+                or self._blocks_needed(request, bucket))
+            if blocks is None:
+                self._release(grants)
+                return None
+            grants.append(blocks)
+        return tuple(grants)
+
+    def _release(self, grants):
+        for allocator, blocks in zip(self.allocators, grants):
+            allocator.release(blocks)
+
     def try_admit(self):
         """Admit the queue head if a slot, the token budget, and the
         block pool all allow it; None otherwise (FIFO — no overtaking,
@@ -268,16 +308,15 @@ class ContinuousBatchScheduler:
                 > self.icfg.token_budget:
             return None
         bucket = self.icfg.bucket_for(len(request.prompt))
-        blocks = self.allocator.allocate(self._blocks_needed(request,
-                                                             bucket))
-        if blocks is None:
+        grants = self._grant(request, bucket)
+        if grants is None:
             return None
         try:
             self.waiting.popleft()
             request.state = ACTIVE
             request.slot = free_slots[0]
             request.bucket = bucket
-            request.blocks = blocks
+            request.grants = grants
             request.admitted_at = time.monotonic()
             self.slots[request.slot] = request
             self.admitted_total += 1
@@ -286,11 +325,11 @@ class ContinuousBatchScheduler:
             # blocks to the pool — a raise here would otherwise strand
             # the grant forever (the allocator has no owner to reclaim
             # from; the blocks-conserved invariant test pins this)
-            self.allocator.release(blocks)
+            self._release(grants)
             if request.slot is not None \
                     and self.slots[request.slot] is request:
                 self.slots[request.slot] = None
-            request.blocks = []
+            request.grants = ()
             request.slot = None
             request.bucket = None
             if request.state == ACTIVE:
@@ -298,12 +337,21 @@ class ContinuousBatchScheduler:
             raise
         return request
 
-    def block_table_row(self, request):
-        """The request's block table padded to the fixed
-        ``max_blocks_per_seq`` width with the null block."""
-        width = self.icfg.max_blocks_per_seq
-        row = list(request.blocks)[:width]
+    def block_table_row(self, request, group=0):
+        """The request's block table in cache group ``group``, padded with
+        the null block to the group's fixed width: ``max_blocks_per_seq``,
+        or a ring's pages."""
+        width = (self.allocators[group].pages_per_request
+                 or self.icfg.max_blocks_per_seq)
+        row = list(request.grants[group])[:width]
         return row + [NULL_BLOCK] * (width - len(row))
+
+    def block_tables(self, request):
+        """The request's table row in every cache group, as the int32
+        arrays a prefill program takes (fresh: the program may read its
+        host arguments after this returns)."""
+        return tuple(np.array(self.block_table_row(request, g), np.int32)
+                     for g in range(len(self.allocators)))
 
     # -- recycling ------------------------------------------------------
     def finish(self, request, reason):
@@ -311,8 +359,8 @@ class ContinuousBatchScheduler:
         continuous-batching move: siblings keep decoding)."""
         assert self.slots[request.slot] is request
         self.slots[request.slot] = None
-        self.allocator.release(request.blocks)
-        request.blocks = []
+        self._release(request.grants)
+        request.grants = ()
         request.state = FINISHED
         request.finish_reason = reason
         request.finished_at = time.monotonic()
@@ -339,13 +387,13 @@ class ContinuousBatchScheduler:
         if request.state == ACTIVE:
             assert self.slots[request.slot] is request
             self.slots[request.slot] = None
-            self.allocator.release(request.blocks)
+            self._release(request.grants)
         elif request.state == QUEUED:
             try:
                 self.waiting.remove(request)
             except ValueError:
                 pass
-        request.blocks = []
+        request.grants = ()
         request.slot = None
         request.bucket = None
         request.state = QUEUED

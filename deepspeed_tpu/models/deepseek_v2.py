@@ -49,6 +49,7 @@ import numpy as np
 from ..ops.transformer.flash_attention import flash_attention_forward
 from ..ops.transformer.mla_paged_attention import (
     check_tpu_geometry, mla_paged_decode_attention, padded_row_width)
+from ..inference.kv_cache import CacheGroup
 from ..parallel.mesh import current_platform
 from . import expert_shard
 from .layers import gated_silu_mlp, rms_norm
@@ -245,6 +246,10 @@ class DeepseekV2Serving:
         row]`` a layer keeps (all donated)."""
         return {"latent_cache": self.row}
 
+    def cache_groups(self, icfg):
+        return [CacheGroup("latent", self.num_layers,
+                           self.cache_buffers(icfg))]
+
     def check_tpu_geometry(self, icfg):
         check_tpu_geometry(self.row, self.config.kv_lora_rank,
                            icfg.kv_block_size)
@@ -306,7 +311,7 @@ class DeepseekV2Serving:
 
     # -- the two programs --------------------------------------------------
     def build_prefill(self, icfg, bucket_len):
-        """``(params, caches, input_ids[1, S], true_len, block_table,
+        """``(params, caches, input_ids[1, S], true_len, block_tables,
         next_tokens, slot) -> (out, caches, next_tokens)``: the expanded
         path over one request padded to the bucket; its first token is
         also put into lane ``slot`` of the next decode's input."""
@@ -316,9 +321,9 @@ class DeepseekV2Serving:
         block = math.gcd(bucket_len, self.PREFILL_BLOCK)
         n_pages = bucket_len // bs
 
-        def prefill(params, caches, input_ids, true_len, block_table,
+        def prefill(params, caches, input_ids, true_len, block_tables,
                     next_tokens, slot):
-            (cache,) = caches
+            (cache,), (block_table,) = caches, block_tables
             s = input_ids.shape[1]
             positions = jnp.arange(s)
             valid = positions < true_len
@@ -374,7 +379,7 @@ class DeepseekV2Serving:
         n_slots = icfg.max_batch_slots
 
         def decode(params, caches, block_tables, ctx_lens, tokens):
-            (cache,) = caches
+            (cache,), (block_tables,) = caches, block_tables
             dtype = params["embed"].dtype
             x = jnp.take(params["embed"], tokens, axis=0).astype(
                 jnp.float32)

@@ -1,9 +1,17 @@
 """One chip's share of an expert-parallel mixture-of-experts layer, for
-serving: group-limited routing over ALL the experts at the published
-width, and the part of the result that the experts held here give.
+serving: routing over ALL the experts at the published width, and the part
+of the result that the experts held here give.
+
+Two published routers run through :func:`route`: DeepSeek-V2's
+(``softmax`` scores, group-limited, the chosen scores themselves as
+weights, not renormalised) and the DeepSeek-V3 lineage's that K-EXAONE
+uses (``sigmoid`` scores, the choice made on ``score + bias``, the weights
+the chosen experts' scores renormalised over ALL the chosen, held here or
+not; one group, so the group limit does nothing).
 
 The layer is told which experts it holds (``first_expert``, and as many as
-its weights have).  Every token is routed over all ``n_routed_experts``;
+its weights have: a routing group, or any contiguous slice).  Every token
+is routed over all the experts the router scores;
 the (token, choice) pairs that fall to held experts are sorted by expert
 and multiplied by a grouped matrix product
 (``ops/transformer/grouped_matmul.py``) — no capacity, so no token is ever
@@ -36,17 +44,29 @@ def group_limited_topk(scores, n_group, topk_group, top_k):
     return jax.lax.top_k(masked, top_k)
 
 
-def route(x, router_kernel, *, n_group, topk_group, top_k, scaling):
-    """Softmax scores over every expert in fp32 (``x`` as the norm gave
-    it, not rounded to the compute dtype first, and the product too: a
-    near-tie between two experts must not flip on a bf16 product), then
-    the group-limited choice; weights ``scaling * score``, not
-    renormalised."""
+def route(x, router_kernel, *, n_group, topk_group, top_k, scaling,
+          scoring="softmax", bias=None, renormalise=False):
+    """Scores over every expert in fp32 (``x`` as the norm gave it, not
+    rounded to the compute dtype first, and the product too: a near-tie
+    between two experts must not flip on a bf16 product) — ``softmax`` or
+    ``sigmoid`` of the router's logits — then the group-limited choice,
+    made on ``score + bias`` where the router has a selection ``bias``;
+    weights ``scaling * score`` of the chosen (the bias chooses, it does
+    not weigh), with ``renormalise`` divided by their sum over all
+    ``top_k`` chosen, wherever those experts are held."""
     logits = jnp.matmul(x.astype(jnp.float32),
                         router_kernel.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
-    weights, ids = group_limited_topk(jax.nn.softmax(logits, axis=-1),
-                                      n_group, topk_group, top_k)
+    scores = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    if bias is None:
+        weights, ids = group_limited_topk(scores, n_group, topk_group, top_k)
+    else:
+        _, ids = group_limited_topk(scores + bias.astype(jnp.float32),
+                                    n_group, topk_group, top_k)
+        weights = jnp.take_along_axis(scores, ids, axis=-1)
+    if renormalise:
+        weights = weights / weights.sum(axis=-1, keepdims=True)
     return scaling * weights, ids
 
 
@@ -83,6 +103,16 @@ def held_experts_ffn(x, weights, ids, valid, experts, *, first_expert,
     pairs = out[back].reshape(tokens, top_k, -1)
     w = jnp.where(here, weights, 0.0)
     return jnp.einsum("tk,tkh->th", w, pairs.astype(jnp.float32)), counts
+
+
+def tokens_without_held_expert(ids, valid, first_expert, held):
+    """Share of the ``valid`` tokens none of whose chosen experts ``ids
+    [tokens, top_k]`` is held here (fp32 scalar): for them this chip's
+    routed sum is empty."""
+    local = (ids >= first_expert) & (ids < first_expert + held)
+    nowhere = valid & ~local.any(axis=-1)
+    return nowhere.sum().astype(jnp.float32) / jnp.maximum(
+        valid.sum().astype(jnp.float32), 1.0)
 
 
 def load_counters(counts):

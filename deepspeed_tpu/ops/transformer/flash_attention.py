@@ -164,8 +164,10 @@ def _keep_mask(seed_ref, i, j, kb, shape, thresh):
 
 
 def _scores(q_blk, k_blk, scale, causal, masked, kvm_ref, j, kb, block_q,
-            block_k):
-    """Scaled [Bq, Bk] score tile + causal/key-padding masking."""
+            block_k, window=None):
+    """Scaled [Bq, Bk] score tile + causal/key-padding masking; with
+    ``window`` a query sees its last ``window`` keys only, itself among
+    them."""
     s = jax.lax.dot_general(q_blk, k_blk, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     if causal:
@@ -174,6 +176,8 @@ def _scores(q_blk, k_blk, scale, causal, masked, kvm_ref, j, kb, block_q,
         k_idx = kb * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
         s = jnp.where(q_idx >= k_idx, s, NEG_INF)
+        if window is not None:
+            s = jnp.where(q_idx - k_idx < window, s, NEG_INF)
     if masked:
         kvm = kvm_ref[0, 0]  # [Bk] fp32 0/1 — this grid step's k block
         s = jnp.where(kvm[None, :] > 0.0, s, NEG_INF)
@@ -247,7 +251,8 @@ def _head_index(i, g, heads):
     return i if heads == 1 else i * heads + g
 
 
-def _fwd_kernel(*refs, scale, causal, masked, dropout, single, heads, lead):
+def _fwd_kernel(*refs, scale, causal, masked, dropout, single, heads, lead,
+                window=None):
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[:3]
     rest = refs[3:]
@@ -262,6 +267,12 @@ def _fwd_kernel(*refs, scale, causal, masked, dropout, single, heads, lead):
     i, j, kb = _grid_ids(lead)
     n_kb = pl.num_programs(lead + 1)
     wide = o_ref.shape[1:]
+    # where the step stands in the streamed dimension, and the k block it
+    # holds: the same number, unless the stream is a window's band — then
+    # it walks only the last n_kb blocks up to the diagonal (``_band``)
+    at = kb
+    if window is not None:
+        kb = j - (n_kb - 1) + at
 
     if single:
         # one k block: straight-line softmax, no scratch round-trips (the
@@ -269,7 +280,8 @@ def _fwd_kernel(*refs, scale, causal, masked, dropout, single, heads, lead):
         outs, stats = [], []
         for g in range(heads):
             s = _scores(_own_lanes(q_ref[0], g, heads), k_ref[0], scale,
-                        causal, masked, kvm_ref, j, kb, block_q, block_k)
+                        causal, masked, kvm_ref, j, kb, block_q, block_k,
+                        window)
             m = jnp.maximum(jnp.max(s, axis=1, keepdims=True), MAX_FLOOR)
             p = jnp.exp(s - m)
             l = jnp.sum(p, axis=1, keepdims=True)
@@ -289,7 +301,7 @@ def _fwd_kernel(*refs, scale, causal, masked, dropout, single, heads, lead):
             lse_ref[g, 0] = (m + jnp.log(l_safe))[:, 0]
         return
 
-    @pl.when(kb == 0)
+    @pl.when(at == 0)
     def _init():
         for m_sc, l_sc in zip(m_scs, l_scs):
             m_sc[...] = jnp.full_like(m_sc, NEG_INF)
@@ -301,6 +313,10 @@ def _fwd_kernel(*refs, scale, causal, masked, dropout, single, heads, lead):
     # (BlockSpec fetches are unconditional) — acceptable because K/V bytes
     # are a rounding error next to the score matmuls at these block sizes.
     needed = True if not causal else kb * block_k <= (j + 1) * block_q - 1
+    if window is not None:
+        # every block of the band lies at or below the diagonal; the first
+        # query blocks' bands start before the sequence does
+        needed = kb >= 0
 
     # (round-4 negative result: splitting this step into masked/unmasked
     # variants so fully-below-diagonal tiles skip the causal iota/select
@@ -311,7 +327,8 @@ def _fwd_kernel(*refs, scale, causal, masked, dropout, single, heads, lead):
         corrs, ps = [], []
         for g, (m_sc, l_sc) in enumerate(zip(m_scs, l_scs)):
             s = _scores(_own_lanes(q_ref[0], g, heads), k_ref[0], scale,
-                        causal, masked, kvm_ref, j, kb, block_q, block_k)
+                        causal, masked, kvm_ref, j, kb, block_q, block_k,
+                        window)
             m, l = m_sc[...], l_sc[...]
             m_new = jnp.maximum(
                 jnp.maximum(m, jnp.max(s, axis=1, keepdims=True)), MAX_FLOOR)
@@ -336,7 +353,7 @@ def _fwd_kernel(*refs, scale, causal, masked, dropout, single, heads, lead):
                                  preferred_element_type=jnp.float32)
              for p in ps], wide)
 
-    @pl.when(kb == n_kb - 1)
+    @pl.when(at == n_kb - 1)
     def _finalize():
         l_safes = []
         for l_sc in l_scs:
@@ -560,10 +577,15 @@ class _Operands:
     block, for shapes whose heads do not fill lane tiles (an odd number of
     64-wide heads, key width 192) — a block narrower than 128 lanes of the
     projection's layout cannot be indexed.
+
+    *Grouped KV heads* (``kv_group`` query heads read one K/V head, forward
+    only): k and v are ``[b, s, (h / kv_group)·d]`` and query head ``i``'s
+    step indexes their block ``i // kv_group`` — nothing is repeated in
+    HBM; one head a block, so the widths are multiples of 128.
     """
 
-    def __init__(self, b, h, d, dv, fused=False):
-        self.b, self.h, self.fused = b, h, fused
+    def __init__(self, b, h, d, dv, fused=False, kv_group=1):
+        self.b, self.h, self.fused, self.kv_group = b, h, fused, kv_group
         if d % 128 == 0 and dv % 128 == 0:
             self.heads, self.packed = 1, True
         elif d == dv == 64 and h % 2 == 0:
@@ -571,12 +593,17 @@ class _Operands:
         else:
             self.heads, self.packed = 1, False
         assert self.packed or not fused
+        assert kv_group == 1 or (self.packed and self.heads == 1
+                                 and not fused), (
+            "grouped KV heads need head widths that are multiples of 128")
         self.blocks = h // self.heads  # blocks a batch row
         # the grid's leading dimensions, one step a block of heads
         self.steps = (b, self.blocks) if self.packed else (b * h,)
         self.name = "flattened" if not self.packed else (
             f"heads/block={self.heads}, projection layout"
-            + (", fused qkv" if fused else ""))
+            + (", fused qkv" if fused else "")
+            + (f", {kv_group} query heads a kv head" if kv_group > 1
+               else ""))
 
     def to_kernel(self, x):
         if not self.packed:
@@ -597,7 +624,13 @@ class _Operands:
     def spec(self, rows, width, seq_block, third=0):
         """Block of a q/k/v-like operand; ``seq_block`` maps the grid ids
         to the block's index along the sequence, ``third`` says which of
-        q, k, v a fused array is read as."""
+        q, k, v a fused array is read as (a key or value of grouped heads
+        is indexed by its own head, the query's // ``kv_group``)."""
+        if self.kv_group > 1 and third:
+            return pl.BlockSpec(
+                (1, rows, width),
+                lambda *ids: (ids[0], seq_block(*ids),
+                              ids[1] // self.kv_group))
         if self.packed:
             first = third * self.blocks if self.fused else 0
             return pl.BlockSpec(
@@ -680,14 +713,21 @@ def flash_attention(q, k, v, kv_mask=None, dropout_seed=None, causal=False,
 
 
 def flash_attention_forward(q, k, v, *, causal, block_q, block_k,
-                            interpret=False, name=None):
+                            window=None, interpret=False, name=None):
     """The forward kernel alone, for serving: ``q``, ``k`` [b, s, h, d] and
     ``v`` [b, s, h, dv] with a value width of its own, blocks given by the
     caller (no first-use tuning inside a served program), softmax scale
     1/sqrt(d) (fold any other factor into ``q``).  ``name`` is the kernel's
-    name in a device trace."""
+    name in a device trace.
+
+    ``k`` and ``v`` may hold fewer heads than ``q`` (grouped KV heads:
+    query head ``i`` reads head ``i // (h / h_kv)``; they are indexed, not
+    repeated).  With ``window`` (causal, ``block_q == block_k``) a query
+    sees its last ``window`` keys, itself among them, and the blocks
+    outside that band are neither computed nor fetched: the streamed grid
+    dimension is the band's width (``_band``), not the sequence's."""
     out, _ = _flash_fwd(q, k, v, None, None, causal, block_q, block_k,
-                        interpret, 0.0, name=name)
+                        interpret, 0.0, name=name, window=window)
     return out
 
 
@@ -770,8 +810,14 @@ def _mask_ops(kv_mask, ops, rows, seq_block):
             (ops.mask_spec(rows, seq_block),))
 
 
+def _band(window, block, n_kb):
+    """K blocks a query block's window reaches (``block_q == block_k``):
+    its own and as many before it as ``window - 1`` keys span."""
+    return min(n_kb, -(-(window - 1) // block) + 1)
+
+
 def _fwd_call(ops, q, k, v, dims, kv_mask, dropout_seed, causal, block_q,
-              block_k, interpret, dropout_rate, name=None):
+              block_k, interpret, dropout_rate, name=None, window=None):
     """The forward kernel over operands already in ``ops``' layout (q, k
     and v one array where it is fused); ``dims`` = (s, kv_len, d, dv).
     Returns the output in that layout and the logsumexp [b·h, 1, s]."""
@@ -783,6 +829,15 @@ def _fwd_call(ops, q, k, v, dims, kv_mask, dropout_seed, causal, block_q,
     n_kb = pl.cdiv(kv_len, block_k)
 
     at_q, at_k = _outer, _inner  # grid (*steps, q blocks, k blocks)
+    if window is not None:
+        assert causal and block_q == block_k and not masked, (
+            "a window is causal, over square blocks, with no key mask")
+        n_kb = _band(window, block_k, n_kb)
+
+        # the band's blocks end at the diagonal; one that would start
+        # before the sequence re-reads block 0 and is skipped in the kernel
+        def at_k(*ids):
+            return jnp.maximum(ids[-2] - (n_kb - 1) + ids[-1], 0)
     seed_ops, seed_specs, drop = _dropout_ops(dropout_rate, dropout_seed)
     if masked:
         assert kv_mask.shape == (ops.b, kv_len), (
@@ -793,7 +848,7 @@ def _fwd_call(ops, q, k, v, dims, kv_mask, dropout_seed, causal, block_q,
     kernel = functools.partial(_fwd_kernel, scale=1.0 / math.sqrt(d),
                                causal=causal, masked=masked, dropout=drop,
                                single=(n_kb == 1), heads=ops.heads,
-                               lead=len(ops.steps))
+                               lead=len(ops.steps), window=window)
     return pl.pallas_call(
         kernel,
         grid=(*ops.steps, n_qb, n_kb),
@@ -928,17 +983,17 @@ def _bwd_call(ops, q, k, v, g, out, lse, dims, kv_mask, dropout_seed, causal,
 
 
 def _flash_fwd(q, k, v, kv_mask, dropout_seed, causal, block_q, block_k,
-               interpret, dropout_rate, name=None):
+               interpret, dropout_rate, name=None, window=None):
     b, s, h, d = q.shape
     # the values may be narrower or wider than the keys (latent attention
     # expands keys of 192 beside values of 128): the score tile is q.k over
     # d, the accumulator and the output are dv wide
     dv = v.shape[-1]
-    ops = _Operands(b, h, d, dv)
+    ops = _Operands(b, h, d, dv, kv_group=h // k.shape[2])
     out, lse = _fwd_call(
         ops, ops.to_kernel(q), ops.to_kernel(k), ops.to_kernel(v),
         (s, k.shape[1], d, dv), kv_mask, dropout_seed, causal, block_q,
-        block_k, interpret, dropout_rate, name)
+        block_k, interpret, dropout_rate, name, window)
     out = ops.from_kernel(out)
     return out, (q, k, v, kv_mask, dropout_seed, out, lse)
 

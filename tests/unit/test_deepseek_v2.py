@@ -232,7 +232,8 @@ def test_tokens_reach_the_next_decode_without_leaving_the_device(
                 assert tokens is produced[-1]
             result = program(*args)
             if decode:
-                assert isinstance(args[2], jax.Array)       # tables
+                (tables,) = args[2]             # one a cache group
+                assert isinstance(tables, jax.Array)
                 assert isinstance(args[3], np.ndarray)      # positions
                 produced.append(result[0]["tokens"])
             else:

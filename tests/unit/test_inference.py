@@ -557,8 +557,8 @@ class TestOneProgramInFlight:
             # parked: position 0 AND the null block in every table entry
             parked = [c for c, x in zip(calls, handed) if not x]
             assert parked or cap == max(caps)
-            for c in parked:
-                assert (np.asarray(c[2])[slot] == NULL_BLOCK).all()
+            for c in parked:    # c[2]: one table a cache group
+                assert (np.asarray(c[2][0])[slot] == NULL_BLOCK).all()
         assert len(calls) == max(caps) - 1
         engine.close()
 
@@ -659,9 +659,12 @@ class TestOneProgramInFlight:
         def keeping(program, which):
             def run(*args):
                 for i in which:
-                    assert not np.shares_memory(np.asarray(args[i]),
-                                                engine._tables)
-                    handed.append((args[i], np.array(args[i], copy=True)))
+                    # the tables come as a tuple, one a cache group
+                    for arg in (args[i] if isinstance(args[i], tuple)
+                                else (args[i],)):
+                        assert not np.shares_memory(np.asarray(arg),
+                                                    engine._tables[0])
+                        handed.append((arg, np.array(arg, copy=True)))
                 return program(*args)
             return run
 

@@ -285,3 +285,71 @@ def test_grouped_matmul_compiles_for_decode_and_prefill(v5e, rows, tiling):
                                                        tiling=tiling),
             s((rows, k)), s((20, k, n)), s((21,), jnp.int32))
         assert "moe_grouped_matmul" in text
+
+
+# -- grouped KV heads and windows at K-EXAONE's published widths -------------
+
+@pytest.mark.parametrize("window,pages,name", [
+    (None, 4, "gqa_paged_decode_attention"),
+    (128, 3, "window_paged_decode_attention")])
+def test_grouped_paged_decode_kernel_compiles(v5e, window, pages, name):
+    """64 query heads over 8 KV heads of 128 (a cache row of 1024), 64
+    slots: the full layer's whole-context table of 192 pages, and a window
+    layer's ring of 3."""
+    from deepspeed_tpu.ops.transformer.paged_attention import (
+        check_gqa_tpu_geometry, ring_pages)
+
+    check_gqa_tpu_geometry(8, 128, 64)
+    layers, blocks, per_seq = ((1, 1025, 192) if window is None
+                               else (4, 193, ring_pages(128, 64)))
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    text = _compile(
+        lambda q, k, v, t, l: paged_decode_attention(
+            q, k, v, t, l, layer=layers - 1, num_heads=64, window=window,
+            pages_per_step=pages),
+        s((64, 8192)), s((layers, blocks, 64, 1024)),
+        s((layers, blocks, 64, 1024)), s((64, per_seq), jnp.int32),
+        s((64,), jnp.int32))
+    assert "tpu_custom_call" in text and name in text
+
+
+@pytest.mark.parametrize("seq,block,window,name", [
+    (6144, 1024, None, "gqa_prefill_attention"),
+    (6144, 512, 128, "window_prefill_attention"),
+    (3072, 512, 128, "window_prefill_attention")])
+def test_flash_forward_compiles_with_grouped_heads_and_a_window(
+        v5e, seq, block, window, name):
+    from deepspeed_tpu.ops.transformer.flash_attention import (
+        flash_attention_forward)
+
+    def s(heads):
+        return jax.ShapeDtypeStruct((1, seq, heads, 128), jnp.bfloat16,
+                                    sharding=v5e)
+
+    text = _compile(
+        lambda q, k, v: flash_attention_forward(
+            q, k, v, causal=True, block_q=block, block_k=block,
+            window=window, name=name), s(64), s(8), s(8))
+    assert "tpu_custom_call" in text and name in text
+
+
+@pytest.mark.parametrize("rows,tiling", [(512, (128, 6144, 256)),
+                                         (49152, (256, 2048, 1024))])
+def test_grouped_matmul_compiles_for_the_sigmoid_expert_layer(v5e, rows,
+                                                               tiling):
+    """16 held experts of 6144 x 4096 (gate and up fused) and 2048 x 6144,
+    17 groups: the last is the pairs held elsewhere."""
+    from deepspeed_tpu.ops.transformer.grouped_matmul import (
+        moe_grouped_matmul)
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    for k, n in ((6144, 4096), (2048, 6144)):
+        text = _compile(
+            lambda l, r, g: moe_grouped_matmul(l, r, g, tiling=tiling),
+            s((rows, k)), s((16, k, n)), s((17,), jnp.int32))
+        assert "tpu_custom_call" in text
