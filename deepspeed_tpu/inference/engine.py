@@ -98,7 +98,9 @@ class InferenceEngine:
     GPT2LMHeadTPU`, :class:`~deepspeed_tpu.models.deepseek_v2.
     DeepseekV2ForServing`.  ``params`` is its parameter pytree (for an HF
     Flax GPT-2 checkpoint see :meth:`from_hf_gpt2`); leaves already in
-    the serving dtype are taken as they are, not copied.  ``config`` is
+    the serving dtype are taken as they are, not copied, and
+    ``engine.params`` is what ``serving.prepare_params`` made of it, once
+    (:meth:`_prepare_params`).  ``config`` is
     the usual DeepSpeed config dict; the ``inference`` block is
     DSC4xx-schema-validated like every other section.
     """
@@ -122,12 +124,12 @@ class InferenceEngine:
             f"({mc.max_position_embeddings})")
         self.steps_per_print = int(param_dict.get(
             C.STEPS_PER_PRINT, C.STEPS_PER_PRINT_DEFAULT))
+        serving = self.serving = model.serving()
         if icfg.weights_dtype == "bfloat16":
             params = cast_weights(params, jnp.bfloat16)
-        self.params = jax.device_put(params)
+        self.params = self._prepare_params(jax.device_put(params))
         cache_dtype = (jnp.bfloat16 if icfg.weights_dtype == "bfloat16"
                        else jnp.float32)
-        serving = self.serving = model.serving()
         if current_platform() == "tpu":
             # a geometry the decode kernel cannot tile fails here, at
             # construction, never by a silent second path
@@ -255,6 +257,34 @@ class InferenceEngine:
                 + " + ".join(f"{n}[{w}]" for n, w in g.buffers.items())
                 for g in groups),
             list(icfg.prefill_buckets), icfg.weights_dtype)
+
+    def _prepare_params(self, params):
+        """The tree every program is handed: what the served model makes
+        of its weights ONCE (``serving.prepare_params``,
+        ``inference/model.py``), so that nothing which depends on the
+        weights alone is computed inside a program call.  Whatever
+        replaces the engine's weights goes through here.  What it did is
+        one log line and ``self.prepared_params`` (the
+        ``serving/prepared_param_*`` gauges): the leaves of the result
+        that are not the caller's, and their bytes."""
+        t0 = time.perf_counter()
+        prepared = self.serving.prepare_params(params)
+
+        def apart(tree, other):
+            ids = {id(leaf) for leaf in jax.tree_util.tree_leaves(other)}
+            return [leaf for leaf in jax.tree_util.tree_leaves(tree)
+                    if id(leaf) not in ids]
+
+        made, dropped = apart(prepared, params), apart(params, prepared)
+        jax.block_until_ready(made)
+        self.prepared_params = {
+            "leaves": len(made), "bytes": sum(x.nbytes for x in made)}
+        logger.info(
+            "InferenceEngine: prepare_params: %d leaves prepared, %d bytes "
+            "in, %d bytes out, %.3f s", len(made),
+            sum(x.nbytes for x in dropped), self.prepared_params["bytes"],
+            time.perf_counter() - t0)
+        return prepared
 
     @staticmethod
     def _validate_config(param_dict):
@@ -555,6 +585,9 @@ class InferenceEngine:
             float(self.allocator.free_blocks))
         self.telemetry.gauge("serving/generated_tokens").set(
             float(self.generated_tokens))
+        for key, value in self.prepared_params.items():
+            self.telemetry.gauge(f"serving/prepared_param_{key}").set(
+                float(value))
         for key, value in self.model_counters.items():
             self.telemetry.gauge(f"serving/{key}").set(float(value))
         decodes = self.decode_iterations - self._sampled_decodes

@@ -19,6 +19,17 @@ it can serve has a ``serving()`` method that returns an object with:
   K-EXAONE (``models/exaone_moe.py``) a ``full`` and a ``window`` one;
 - ``check_tpu_geometry(icfg)``: raise for a cache its decode kernel cannot
   tile on a TPU (called at construction there, never a second path);
+- ``prepare_params(params) -> params``: the tree its programs take, made
+  of the caller's ONCE, when the engine takes the weights (after the cast
+  to the serving dtype; the result is ``engine.params``).  The rule it
+  stands for: nothing that depends on the weights alone is computed
+  inside ``decode`` or ``prefill`` — a split, a transpose or a fold of a
+  weight written there is done again in every step.  A model with
+  nothing to prepare returns the tree it was given, the same object
+  (GPT-2, K-EXAONE); DeepSeek-V2 replaces each layer's ``kv_b`` by
+  ``w_uk`` and ``w_uv`` in the layouts the absorbed decode's two products
+  take.  Leaves it replaces it drops, so the served tree holds no weight
+  twice;
 - ``build_prefill(icfg, bucket) -> prefill(params, caches, input_ids[1, S],
   true_len, block_tables, next_tokens, slot) -> (out, caches,
   next_tokens)``, one program a bucket, and ``build_decode(icfg) ->
@@ -209,6 +220,9 @@ class GPT2Serving:
 
     def check_tpu_geometry(self, icfg):
         check_tpu_geometry(self.config.hidden_size, icfg.kv_block_size)
+
+    def prepare_params(self, params):
+        return params
 
     def build_prefill(self, icfg, bucket_len):
         inner = build_prefill(self.config, icfg, bucket_len)

@@ -37,7 +37,12 @@ Parameter tree: ``embed``, ``layers/layer_<i>/{input_norm, q_a, q_a_norm,
 q_b, kv_a, kv_a_norm, kv_b, o, post_norm, mlp | moe}``, ``final_norm``,
 ``lm_head``; every matrix a ``kernel [in, out]`` with no bias, gate and up
 fused as ``gate_up`` (gate first); ``moe`` holds ``router``, ``shared`` and
-``experts/{gate_up [held, in, 2w], down [held, w, in]}``.
+``experts/{gate_up [held, in, 2w], down [held, w, in]}``.  That is the tree
+a caller hands the engine.  The programs take the tree
+:meth:`DeepseekV2Serving.prepare_params` makes of it, once: each layer's
+``kv_b`` replaced by ``w_uk [heads, nope, latent]`` and ``w_uv [heads,
+latent, value]``, the two halves its kernel interleaves per head, each
+laid out as the absorbed decode's product takes it.
 """
 
 import math
@@ -277,12 +282,28 @@ class DeepseekV2Serving:
         pad = jnp.zeros((h.shape[0], self.row - c.latent_row), h.dtype)
         return c_kv, k_r, jnp.concatenate([c_kv, k_r, pad], axis=-1)
 
-    def _kv_b(self, lp):
-        """``(W_UK, W_UV)`` ``[latent, heads, .]`` out of ``kv_b``."""
+    def prepare_params(self, params):
+        """The tree the programs take: each layer's ``kv_b/kernel [latent,
+        heads * (nope + value)]`` split into ``w_uk [heads, nope, latent]``
+        and ``w_uv [heads, latent, value]``, batched over the leading axis
+        as decode's two absorbed products take them.  Inside the program
+        the split was a transpose of the whole kernel for each product, in
+        every step, of a weight that does not change between steps.
+        ``kv_b`` is not kept: the served tree holds the same bytes."""
         c = self.config
-        w = lp["kv_b"]["kernel"].reshape(
-            c.kv_lora_rank, c.num_attention_heads, -1)
-        return w[..., :c.qk_nope_head_dim], w[..., c.qk_nope_head_dim:]
+
+        @jax.jit
+        def split(kernel):
+            w = kernel.reshape(c.kv_lora_rank, c.num_attention_heads, -1)
+            return (w[..., :c.qk_nope_head_dim].transpose(1, 2, 0),
+                    w[..., c.qk_nope_head_dim:].transpose(1, 0, 2))
+
+        layers = {}
+        for name, lp in params["layers"].items():
+            lp = dict(lp)
+            lp["w_uk"], lp["w_uv"] = split(lp.pop("kv_b")["kernel"])
+            layers[name] = lp
+        return {**params, "layers": layers}
 
     def _mlp(self, lp, z32, dtype, valid, tiling):
         """The layer's MLP of the normed stream ``z32`` (fp32), computed in
@@ -340,9 +361,8 @@ class DeepseekV2Serving:
                 cache = cache.at[i, block_table[:n_pages]].set(
                     rows.reshape(n_pages, bs, self.row).astype(cache.dtype),
                     unique_indices=True)
-                w_uk, w_uv = self._kv_b(lp)
-                k_nope = jnp.einsum("sc,chd->shd", c_kv, w_uk)
-                v = jnp.einsum("sc,chd->shd", c_kv, w_uv)
+                k_nope = jnp.einsum("sc,hdc->shd", c_kv, lp["w_uk"])
+                v = jnp.einsum("sc,hcd->shd", c_kv, lp["w_uv"])
                 k = jnp.concatenate([k_nope, jnp.broadcast_to(
                     k_r[:, None], q_rope.shape)], axis=-1)
                 # the kernel scales by (nope + rope)^-1/2; YaRN's m^2 rides
@@ -399,8 +419,7 @@ class DeepseekV2Serving:
                 # the append: every slot's new row in one scatter
                 cache = cache.at[i, block_ids, offsets].set(
                     rows.astype(cache.dtype))
-                w_uk, w_uv = self._kv_b(lp)
-                q_abs = jnp.einsum("bhd,chd->bhc", q_nope, w_uk)
+                q_abs = jnp.einsum("bhd,hdc->bhc", q_nope, lp["w_uk"])
                 pad = jnp.zeros(q_abs.shape[:2] + (self.row - c.latent_row,),
                                 q_abs.dtype)
                 u = mla_paged_decode_attention(
@@ -410,7 +429,7 @@ class DeepseekV2Serving:
                     pages_per_step=min(self.DECODE_PAGES,
                                        icfg.max_blocks_per_seq),
                     interpret=self.interpret)
-                o = jnp.einsum("bhc,chd->bhd", u, w_uv)
+                o = jnp.einsum("bhc,hcd->bhd", u, lp["w_uv"])
                 x = x + jnp.matmul(o.reshape(n_slots, -1), lp["o"]["kernel"],
                                    preferred_element_type=jnp.float32)
                 z = rms_norm(lp["post_norm"], x, c.rms_norm_eps)

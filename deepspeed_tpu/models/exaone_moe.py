@@ -244,6 +244,9 @@ class ExaoneMoeServing:
         check_gqa_tpu_geometry(self.config.num_key_value_heads,
                                self.config.head_dim, icfg.kv_block_size)
 
+    def prepare_params(self, params):
+        return params
+
     def _places(self, icfg):
         """layer -> (position of its K buffer in ``caches``, position of
         its group's table in ``block_tables``, its index inside the

@@ -273,3 +273,125 @@ def test_tokens_reach_the_next_decode_without_leaving_the_device(
         assert engine.model_counters == {}
     assert engine.generated_tokens == 6 + 4 + 7 + 5 + 6
     engine.close()
+
+
+# ------------------------- weights are prepared once, outside every program
+def test_models_with_nothing_to_prepare_hand_their_tree_back():
+    from deepspeed_tpu.inference import InferenceEngine
+    from deepspeed_tpu.models.exaone_moe import (ExaoneMoeConfig,
+                                                 ExaoneMoeForServing)
+    tree = {"a": jnp.ones((2, 2))}
+    assert ExaoneMoeForServing(ExaoneMoeConfig()).serving().prepare_params(
+        tree) is tree
+    model, params, config = _tiny_served("gpt2")
+    assert model.serving().prepare_params(tree) is tree
+    engine = InferenceEngine(model, params, config=config)
+    assert engine.prepared_params == {"leaves": 0, "bytes": 0}
+    for mine, theirs in zip(jax.tree_util.tree_leaves(engine.params),
+                            jax.tree_util.tree_leaves(params)):
+        assert mine is theirs
+    engine.close()
+
+
+def test_prepare_params_splits_kv_b_exactly_and_keeps_every_other_leaf():
+    model, params, _ = _tiny_served("deepseek_v2")
+    c = model.config
+    prepared = model.serving().prepare_params(params)
+    assert params["layers"]["layer_0"]["kv_b"]["kernel"].shape == (48, 128)
+    for name, lp in prepared["layers"].items():
+        theirs = params["layers"][name]
+        assert "kv_b" not in lp
+        # what the programs sliced out of kv_b in every call, until PR 32:
+        # [latent, heads, nope | value], the halves interleaved per head
+        w = np.asarray(theirs["kv_b"]["kernel"]).reshape(
+            c.kv_lora_rank, c.num_attention_heads, -1)
+        assert lp["w_uk"].shape == (4, 16, 48)      # [heads, nope, latent]
+        assert lp["w_uv"].shape == (4, 48, 16)      # [heads, latent, value]
+        np.testing.assert_array_equal(
+            np.asarray(lp["w_uk"]), w[..., :16].transpose(1, 2, 0))
+        np.testing.assert_array_equal(
+            np.asarray(lp["w_uv"]), w[..., 16:].transpose(1, 0, 2))
+        assert lp["o"]["kernel"] is theirs["o"]["kernel"]
+        assert lp["kv_a"] is theirs["kv_a"]
+    assert prepared["embed"] is params["embed"]
+    assert "w_uk" not in params["layers"]["layer_0"]    # the caller's: whole
+
+
+class _SplitsInsideEveryCall:
+    """DeepSeek-V2 as it was served until PR 32: the programs are handed the
+    caller's tree and split ``kv_b`` themselves, in every call."""
+
+    def __init__(self, model):
+        self.config = model.config
+        self._serving = model.serving()
+
+    def serving(self):
+        return self
+
+    def __getattr__(self, name):
+        return getattr(self._serving, name)
+
+    def prepare_params(self, params):
+        return params
+
+    def _inside(self, program):
+        def run(params, *args):
+            return program(self._serving.prepare_params(params), *args)
+        run.__name__ = program.__name__
+        return run
+
+    def build_prefill(self, icfg, bucket_len):
+        return self._inside(self._serving.build_prefill(icfg, bucket_len))
+
+    def build_decode(self, icfg):
+        return self._inside(self._serving.build_decode(icfg))
+
+
+def test_prepared_tree_serves_what_the_split_inside_the_programs_served(
+        monkeypatch):
+    """Prefill and decode through the engine, slots recycled: the same
+    tokens and the same latent cache (every row of a layer past the first
+    is a function of the layers before it, ``W_UK`` and ``W_UV`` among
+    them) as programs that split ``kv_b`` in every call.  The split is
+    exact and the products are the same; the order in which a dot sums
+    its contraction follows the operands' layout, so float32's last bits
+    differ: rows of a few units, 48 to 64 terms a sum, three layers
+    (4e-6 seen).  ``prepare_params`` runs once, at construction, and never
+    from ``step()``."""
+    from deepspeed_tpu.inference import InferenceEngine
+    from deepspeed_tpu.models.deepseek_v2 import DeepseekV2Serving
+    model, params, config = _tiny_served("deepseek_v2")
+    calls = []
+    real = DeepseekV2Serving.prepare_params
+    monkeypatch.setattr(
+        DeepseekV2Serving, "prepare_params",
+        lambda self, tree: calls.append(1) or real(self, tree))
+    engine = InferenceEngine(model, params, config=config)
+    assert calls == [1]
+    n_layers = model.config.num_hidden_layers
+    assert engine.prepared_params == {
+        "leaves": 2 * n_layers,
+        "bytes": n_layers * params["layers"]["layer_0"]["kv_b"][
+            "kernel"].nbytes}
+    assert "kv_b" not in engine.params["layers"]["layer_0"]
+    monkeypatch.setattr(DeepseekV2Serving, "prepare_params", real)
+    before = InferenceEngine(_SplitsInsideEveryCall(model), params,
+                             config=config)
+    assert before.prepared_params == {"leaves": 0, "bytes": 0}
+    rng = np.random.default_rng(11)
+    prompts = [(rng.integers(0, 256, size=n), cap)
+               for n, cap in [(5, 9), (14, 6), (27, 12), (9, 8), (3, 10)]]
+    served = []
+    for e in (engine, before):
+        ids = [e.submit(p, max_new_tokens=cap) for p, cap in prompts]
+        results = e.run()
+        served.append([results[i]["tokens"] for i in ids])
+    assert calls == [1]
+    assert served[0] == served[1]
+    assert [len(t) for t in served[0]] == [9, 6, 12, 8, 10]
+    (mine,), (theirs,) = engine._caches, before._caches
+    assert np.asarray(mine[1:]).any()
+    np.testing.assert_allclose(np.asarray(mine), np.asarray(theirs),
+                               rtol=0, atol=3e-5)
+    engine.close()
+    before.close()

@@ -567,6 +567,30 @@ def test_decode_span_and_gauge_count_the_live_blocks(tmp_path, telemetry_on):
     engine.close()
 
 
+@pytest.mark.parametrize("family,leaves", [("gpt2", 0), ("deepseek_v2", 6)])
+def test_prepared_param_gauges_say_what_the_model_made_of_its_weights(
+        tmp_path, family, leaves):
+    """``serving/prepared_param_leaves`` and ``serving/prepared_param_bytes``
+    (print cadence): what ``serving.prepare_params`` put in place of the
+    caller's leaves when the engine took the weights — nothing for GPT-2,
+    ``w_uk`` and ``w_uv`` a layer for DeepSeek-V2 (``kv_b``'s bytes)."""
+    from deepspeed_tpu.inference import InferenceEngine
+
+    from .test_deepseek_v2 import _tiny_served
+
+    model, params, config = _tiny_served(family)
+    engine = InferenceEngine(model, params, config=dict(
+        config, steps_per_print=1,
+        telemetry={"enabled": True, "run_dir": str(tmp_path / "run")}))
+    engine.submit([1, 2, 3], max_new_tokens=3)
+    engine.run()
+    gauge = engine.telemetry.registry.gauge
+    assert gauge("serving/prepared_param_leaves").value == leaves
+    assert gauge("serving/prepared_param_bytes").value == (
+        3 * 48 * 128 * 4 if leaves else 0)
+    engine.close()
+
+
 def test_scheduler_counts_live_blocks_of_a_hand_built_slot_state():
     from deepspeed_tpu.inference import (BlockAllocator,
                                          ContinuousBatchScheduler,

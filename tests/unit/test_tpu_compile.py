@@ -12,6 +12,7 @@ described device is written to it but cannot be read back without a chip.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -353,3 +354,91 @@ def test_grouped_matmul_compiles_for_the_sigmoid_expert_layer(v5e, rows,
             lambda l, r, g: moe_grouped_matmul(l, r, g, tiling=tiling),
             s((rows, k)), s((16, k, n)), s((17,), jnp.int32))
         assert "tpu_custom_call" in text
+
+
+# -- the serving cells' decode programs, whole, at the benchmark's widths ------
+
+_ITEM_BYTES = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
+
+
+def _top_level(text, ops):
+    """``(op, output bytes, minor-to-major of the output and of the operand
+    where the line gives one, line)`` of the ENTRY computation's
+    instructions named in ``ops``; of a tuple output (``copy-start``) the
+    first element, the destination."""
+    entry = text[text.index("\nENTRY "):]
+    found = []
+    for line in entry[:entry.index("\n}")].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \(?(\w+)\[([\d,]*)\]"
+                     r".*? ([\w\-]+)\(", line)
+        if m and m.group(3) in ops:
+            size = _ITEM_BYTES[m.group(1)]
+            for dim in filter(None, m.group(2).split(",")):
+                size *= int(dim)
+            layouts = re.findall(r"\]\{([\d,]+)", line.split(" = ", 1)[1])
+            found.append((m.group(3), size, layouts[:2], line.strip()))
+    return found
+
+
+def _serve_decode(cell, sharding, monkeypatch):
+    """The optimized ``serve_decode`` of a serving cell of BENCHMARK.json:
+    the model object and the engine config the harness builds, the tree
+    ``InferenceEngine`` would hold (``prepare_params``) as shapes, the TPU
+    path steered from here."""
+    from benchmarks import common, models
+    from deepspeed_tpu.inference import model as gpt2_serving
+    from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
+    from deepspeed_tpu.models import deepseek_v2, exaone_moe
+
+    for module in (gpt2_serving, deepseek_v2, exaone_moe):
+        monkeypatch.setattr(module, "current_platform", lambda: "tpu")
+    spec = common.load_cell(cell)
+    config = spec["config"]
+    shapes_of = models.load(config["model"])
+    serving = shapes_of.build_program_model(
+        config["model_config"], spec["traffic"]).serving()
+    icfg = DeepSpeedInferenceConfig(config["engine"])
+    dtype = (jnp.bfloat16 if icfg.weights_dtype == "bfloat16"
+             else jnp.float32)
+
+    def s(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dt, sharding=sharding)
+
+    params = jax.tree_util.tree_map(
+        lambda x: s(x.shape, x.dtype),
+        jax.eval_shape(serving.prepare_params, jax.tree_util.tree_map(
+            s, shapes_of.param_shapes(config["model_config"]),
+            is_leaf=lambda x: isinstance(x, tuple))))
+    groups = serving.cache_groups(icfg)
+    slots = icfg.max_batch_slots
+    caches = tuple(
+        s((g.layers, g.num_blocks(icfg), icfg.kv_block_size, row))
+        for g in groups for row in g.buffers.values())
+    tables = tuple(s((slots, g.table_width(icfg)), jnp.int32)
+                   for g in groups)
+    return jax.jit(serving.build_decode(icfg), donate_argnums=(1,)).lower(
+        params, caches, tables, s((slots,), jnp.int32),
+        s((slots,), jnp.int32)).compile().as_text()
+
+
+@pytest.mark.parametrize("cell", ["deepseek_v2_ep8.repo_backlog",
+                                  "gpt2_large.backlog",
+                                  "k_exaone_ep8.reason_backlog"])
+def test_decode_makes_no_copy_of_a_weight(v5e, monkeypatch, cell):
+    """A weight does not change between decode steps, so a step re-lays
+    none out: no top-level ``copy`` or ``transpose`` of 1 MB or more whose
+    ``op_name`` or operand names a parameter, and no ``copy-start`` of one
+    into ANOTHER layout (XLA's prefetches of a weight into faster memory
+    keep its layout and stay).  DeepSeek-V2's ``kv_b`` split inside the
+    program was nine such copies of 33.5 MB a step, 328.6 MB of copy
+    outputs in all, before ``prepare_params`` made the split once."""
+    text = _serve_decode(cell, v5e, monkeypatch)
+    assert "tpu_custom_call" in text
+    moved = _top_level(text, ("copy", "transpose", "copy-start"))
+    assert moved
+    relaid = [line[:240] for op, size, layouts, line in moved
+              if size >= 2 ** 20
+              and ("params[" in line or "(%params__" in line)
+              and not (op == "copy-start" and len(set(layouts)) == 1)]
+    assert relaid == []
+    assert sum(size for op, size, _, _ in moved if op == "copy") <= 40e6
