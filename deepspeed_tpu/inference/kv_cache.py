@@ -53,8 +53,10 @@ NULL_BLOCK = 0
 
 
 class CacheGroup(NamedTuple):
-    """Cache buffers that share a block table: ``layers`` layers (the
-    buffers' leading dimension), ``buffers`` name -> row width, and the
+    """Cache buffers that share a block table: ``layers`` planes (the
+    buffers' leading dimension: one a layer, or one a (loop step, layer)
+    for a model that runs its layers several times over shared weights,
+    ``models/ouro.py``), ``buffers`` name -> row width, and the
     span a request holds — ``pages`` blocks whatever its length (a window
     layer's ring), or None for the whole context."""
     name: str

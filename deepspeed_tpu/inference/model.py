@@ -16,7 +16,11 @@ it can serve has a ``serving()`` method that returns an object with:
   the whole context, or the fixed number of blocks a request holds
   whatever its length (a sliding-window layer's ring).  The engine keeps
   a pool and a block table a group.  GPT-2 and DeepSeek-V2 name one group;
-  K-EXAONE (``models/exaone_moe.py``) a ``full`` and a ``window`` one;
+  K-EXAONE (``models/exaone_moe.py``) a ``full`` and a ``window`` one.  A
+  group's ``layers`` counts the buffers' PLANES, which need not be the
+  model's layers: Ouro (``models/ouro.py``) runs its layers
+  ``total_ut_steps`` times over shared weights and names ``steps x
+  layers`` planes, with ``num_layers`` the weights' count;
 - ``check_tpu_geometry(icfg)``: raise for a cache its decode kernel cannot
   tile on a TPU (called at construction there, never a second path);
 - ``prepare_params(params) -> params``: the tree its programs take, made

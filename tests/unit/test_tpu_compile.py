@@ -381,6 +381,19 @@ def _top_level(text, ops):
 
 
 def _serve_decode(cell, sharding, monkeypatch):
+    return _compiled_serve_decode(cell, sharding, monkeypatch).as_text()
+
+
+_COMPILED = {}      # cell -> its compiled decode: two tests read Ouro's
+
+
+def _compiled_serve_decode(cell, sharding, monkeypatch):
+    if cell not in _COMPILED:
+        _COMPILED[cell] = _compile_serve_decode(cell, sharding, monkeypatch)
+    return _COMPILED[cell]
+
+
+def _compile_serve_decode(cell, sharding, monkeypatch):
     """The optimized ``serve_decode`` of a serving cell of BENCHMARK.json:
     the model object and the engine config the harness builds, the tree
     ``InferenceEngine`` would hold (``prepare_params``) as shapes, the TPU
@@ -388,9 +401,9 @@ def _serve_decode(cell, sharding, monkeypatch):
     from benchmarks import common, models
     from deepspeed_tpu.inference import model as gpt2_serving
     from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
-    from deepspeed_tpu.models import deepseek_v2, exaone_moe
+    from deepspeed_tpu.models import deepseek_v2, exaone_moe, ouro
 
-    for module in (gpt2_serving, deepseek_v2, exaone_moe):
+    for module in (gpt2_serving, deepseek_v2, exaone_moe, ouro):
         monkeypatch.setattr(module, "current_platform", lambda: "tpu")
     spec = common.load_cell(cell)
     config = spec["config"]
@@ -418,12 +431,13 @@ def _serve_decode(cell, sharding, monkeypatch):
                    for g in groups)
     return jax.jit(serving.build_decode(icfg), donate_argnums=(1,)).lower(
         params, caches, tables, s((slots,), jnp.int32),
-        s((slots,), jnp.int32)).compile().as_text()
+        s((slots,), jnp.int32)).compile()
 
 
 @pytest.mark.parametrize("cell", ["deepseek_v2_ep8.repo_backlog",
                                   "gpt2_large.backlog",
-                                  "k_exaone_ep8.reason_backlog"])
+                                  "k_exaone_ep8.reason_backlog",
+                                  "ouro_2_6b.think_backlog"])
 def test_decode_makes_no_copy_of_a_weight(v5e, monkeypatch, cell):
     """A weight does not change between decode steps, so a step re-lays
     none out: no top-level ``copy`` or ``transpose`` of 1 MB or more whose
@@ -442,3 +456,24 @@ def test_decode_makes_no_copy_of_a_weight(v5e, monkeypatch, cell):
               and not (op == "copy-start" and len(set(layouts)) == 1)]
     assert relaid == []
     assert sum(size for op, size, _, _ in moved if op == "copy") <= 40e6
+
+
+def test_the_looped_decode_carries_its_caches_in_place(v5e, monkeypatch):
+    """Ouro-2.6B's decode at the published size: the 48-layer body once,
+    under ONE ``while`` over the four loop steps (48 kernel calls, not
+    192), both 4.08 GB cache buffers aliased onto their inputs and carried
+    through the loop without a copy — the program's temporaries are tens
+    of megabytes, where one copied buffer would be 4,077 MB (and the two
+    do not fit the chip twice)."""
+    compiled = _compiled_serve_decode("ouro_2_6b.think_backlog", v5e,
+                                      monkeypatch)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 48
+    assert len(re.findall(r" while\(", text)) == 1
+    header = text.split("\n", 1)[0]
+    assert header.count("may-alias") + header.count("must-alias") == 2
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 2 * 192 * 81 * 64 * 2048 * 2
+    assert memory.temp_size_in_bytes < 256 * 2 ** 20
+    assert not [line for op, size, _, line in _top_level(
+        text, ("copy", "transpose", "copy-start")) if size >= 2 ** 30]
