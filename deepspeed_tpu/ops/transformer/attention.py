@@ -17,33 +17,88 @@ import jax.numpy as jnp
 from ..op_common import random_keep
 
 # Dispatch policy, measured on v5e (BERT-large shapes, h16 d64):
-# - short sequences (128-256): XLA's batched attention wins — blocks are too
-#   small for the flash pipeline (seq 128: 416 vs 344 samples/s end-to-end);
 # - seq >= 512: the tuned-block Pallas kernel wins (seq 512: 5.4 vs 6.8 ms
 #   fwd+bwd; seq 2048: 7.3 vs 15.8 ms — see flash_attention._auto_blocks,
 #   the authoritative tuning record) AND never materializes the [s, s]
 #   score tensor, which is also what lifts the memory ceiling for long
-#   sequences.
+#   sequences;
+# - short self-attention (128, 256): the kernels where a grid step can
+#   hold two or more of the device's batch rows (``rows_per_step``).  One
+#   layer's QKV GEMM + attention + output GEMM, forward and backward, at
+#   b112 s128 with mask and dropout 0.1 (builder's microbenchmark, chip
+#   runs of PR 36): XLA's batched attention 4.61 ms; the kernels at 1 row
+#   a step 3.17, 2 rows 2.88, 4 rows 2.69, 8 rows 2.59, 16 rows 2.57.
+#   The cell ``bert_large.seq128``, tokens/s/chip: 61,987 through XLA's
+#   attention → 70,276 with eight rows a step looped in the kernel
+#   (ledger, PR 36: +13.4% on six pairs, step 231.20 → 203.94 ms) →
+#   72,833 with the rows spelled out (chip runs of PR 37, four pairs:
+#   +17.5%, step 196.76 ms, ``train_mfu`` 59.2 → 69.5);
+# - the rest under 512 stays with XLA's batched attention: one-row calls
+#   (every serving prefill bucket), a query-gathered layer (s != kv_len),
+#   heads that fill no lane tile (25 heads, key width 192), a data mesh
+#   that leaves a device one row.  History: on the old [b·h, s, d] operand
+#   layout (a head transpose each way, one head of one row a step) the
+#   kernels LOST at seq 128, 344 against 416 samples/s end to end.
 PALLAS_MIN_SEQ = 512
 PALLAS_MIN_SCORE_BYTES = 2 * 1024 ** 3
 
 
 def _use_pallas(q, k):
-    from ...parallel.mesh import current_platform, get_current_mesh
+    from ...parallel.mesh import (DATA_AXIS, MODEL_AXIS, current_platform,
+                                  get_current_mesh)
 
     shapes_ok = (current_platform() == "tpu" and q.shape[1] >= 128
                  and q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0
                  and q.shape[-1] % 64 == 0)
     if q.shape[1] >= PALLAS_MIN_SEQ and k.shape[1] >= PALLAS_MIN_SEQ:
         return shapes_ok
-    b, sq, h, _ = q.shape
+    if not shapes_ok:
+        return False
+    b, sq, h, d = q.shape
+    if sq == k.shape[1]:
+        # a short self-attention: the kernels where a grid step can be
+        # filled with several of the device's batch rows
+        from .flash_attention import rows_per_step
+
+        split = _KernelSplit()
+        if rows_per_step(split.local(DATA_AXIS, b), sq,
+                         split.local(MODEL_AXIS, h), d) >= 2:
+            return True
     score_bytes = 4 * b * h * sq * k.shape[1]
     # shapes here are logical/global; under data-parallel GSPMD each
     # chip materializes 1/dp of the batch — budget the PER-DEVICE size
     mesh = get_current_mesh()
     if mesh is not None:
         score_bytes //= max(mesh.shape.get("data", 1), 1)
-    return shapes_ok and score_bytes > PALLAS_MIN_SCORE_BYTES
+    return score_bytes > PALLAS_MIN_SCORE_BYTES
+
+
+class _KernelSplit:
+    """How a kernel call is split over the current mesh: over every axis
+    no enclosing ``shard_map`` is manual over yet (``auto``), where the
+    axis divides the dimension."""
+
+    def __init__(self):
+        from ...parallel.mesh import get_current_mesh
+
+        self.mesh = get_current_mesh()
+        self.context = jax.sharding.get_abstract_mesh()
+        self.auto = frozenset()
+        if self.mesh is not None and self.mesh.size > 1:
+            self.auto = frozenset(self.mesh.axis_names) - frozenset(
+                () if self.context.empty else self.context.manual_axes)
+
+    def axis_for(self, axis, dim):
+        """``axis`` if a dimension of ``dim`` is split over it, else None."""
+        if axis not in self.auto:
+            return None
+        n = self.mesh.shape[axis]
+        return axis if n > 1 and dim % n == 0 else None
+
+    def local(self, axis, dim):
+        """What one device's call holds of a dimension of ``dim``."""
+        return (dim // self.mesh.shape[axis] if self.axis_for(axis, dim)
+                else dim)
 
 
 def shard_kernel_over_mesh(kernel, q, k=None, v=None, kv_mask=None, seed=None,
@@ -69,23 +124,15 @@ def shard_kernel_over_mesh(kernel, q, k=None, v=None, kv_mask=None, seed=None,
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from ...parallel.mesh import DATA_AXIS, MODEL_AXIS, get_current_mesh
+    from ...parallel.mesh import DATA_AXIS, MODEL_AXIS
 
-    mesh = get_current_mesh()
-    if mesh is None or mesh.size == 1:
-        return kernel(q, k, v, kv_mask, seed)
-    context = jax.sharding.get_abstract_mesh()
-    auto = frozenset(mesh.axis_names) - frozenset(
-        () if context.empty else context.manual_axes)
+    split = _KernelSplit()
+    mesh, context, auto = split.mesh, split.context, split.auto
     if not auto:
         return kernel(q, k, v, kv_mask, seed)
-
-    def axis_for(axis, dim):
-        n = mesh.shape.get(axis, 1)
-        return axis if axis in auto and n > 1 and dim % n == 0 else None
-
-    batch_axis = axis_for(DATA_AXIS, q.shape[0])
-    head_axis = axis_for(MODEL_AXIS, q.shape[-2]) if shard_heads else None
+    batch_axis = split.axis_for(DATA_AXIS, q.shape[0])
+    head_axis = (split.axis_for(MODEL_AXIS, q.shape[-2]) if shard_heads
+                 else None)
     qkv_spec = P(batch_axis, None, head_axis, None)
     if k is None:
         args, specs = [q], [P(batch_axis, None, None, head_axis, None)]

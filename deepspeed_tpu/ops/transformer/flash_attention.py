@@ -21,8 +21,10 @@ layer hands over its fused [b, s, 3, h, d] projection whole
 indices, and the fused backward returns the one gradient.  Shapes whose
 heads do not fill lane tiles (an odd number of 64-wide heads, key width
 192) run on [batch·heads, seq, head_dim] through a transpose each way.  The
-grid is (batch · blocks a row, outer blocks, inner blocks).  K/V stream
-through VMEM one block per grid step — VMEM usage is
+grid is (batch rows, blocks a row, outer blocks, inner blocks); where a
+batch row is one short tile (seq 128, 256) a step holds several rows, up
+to 1024 query rows' worth (``_Operands.tile``).  K/V stream through VMEM
+one block per grid step — VMEM usage is
 O(block), not O(seq), so sequence length is bounded by HBM alone — measured
 on one v5e chip: BERT-large trains at seq 8192 (1.1 samples/s), 16384, and
 32768 (batch 1, per-layer remat), vs the reference's 16x-over-512 best with
@@ -39,7 +41,14 @@ In-kernel dropout: keep masks are drawn from the TPU hardware PRNG seeded
 by (user seed, tile coordinates), so the backward kernels regenerate the
 forward masks bit-for-bit instead of storing an O(s²) mask tensor — the
 reference's saved-seed cuRAND trick (``dropout_kernels.cu``) minus the
-saved mask.
+saved mask.  With several batch rows a step each row keeps the seed its
+own step would have had, so the masks do not depend on the rows a step.
+
+A model calls the kernels once a layer with the same shapes: the calls are
+built under an inlined ``jit`` (``_fwd_kernels``, ``_bwd_kernels``) whose
+cache answers every layer after the first with the first one's equations,
+so a kernel is traced and lowered once a geometry, not once a layer
+(``trace_stats()`` counts both; logged with the compile counters).
 """
 
 import functools
@@ -57,6 +66,12 @@ _VMEM = pltpu.VMEM
 # largest [s, 3·h·d] output block the fused backward keeps resident (it is
 # double-buffered): 16M holds s 1024 at a hidden size of 2,730 in bf16
 _ROW_BLOCK_BYTES = 16 * 1024 * 1024
+# where one batch row is a single tile shorter than ``_SHORT_SEQ``, a grid
+# step holds as many batch rows as make ``_STEP_ROWS`` query rows at most
+# (``_Operands.tile``; the readings are in ``_auto_blocks``' docstring).
+# From 512 a row's own tile fills a step: one row a step, as measured there
+_STEP_ROWS = 1024
+_SHORT_SEQ = 512
 
 NEG_INF = -1e30
 # Running-max floor: keeps exp(NEG_INF - m) == 0 even for rows where every
@@ -77,6 +92,17 @@ def _auto_blocks(s, kv_len, d=64, causal=False):
     QKV GEMM + attention + output GEMM, forward and backward, fell from
     5.77 to 4.56 ms at (512, 512), the kernels' share of it from 3.19 to
     2.73 (chip runs of PR 30; the geometry was not searched again).
+    At s=128 a row is one (128, 128) tile and the loss is the grid's, not
+    the block's: with one batch row a step a call is 896 steps of ~0.3 µs
+    of work.  The same sandwich at b112 s128 h16 d64 (mask, dropout 0.1;
+    builder's microbenchmark, chip runs of PR 36), by batch rows a grid
+    step: XLA's attention 4.61 ms; 1 row 3.17; 2 rows 2.88; 4 rows 2.69;
+    7 rows 2.60; 8 rows 2.59; 14 and 16 rows 2.57 — so ``_STEP_ROWS`` is
+    1024 query rows a step (8 × 128; past it nothing is left to gain).
+    In the cell ``bert_large.seq128`` (tokens/s/chip): XLA's attention
+    61,987; eight rows a step, looped in the kernel, 70,276 (ledger, PR
+    36), spelled out 72,833 (chip runs of PR 37; ``_row_steps``); four
+    rows spelled out 71,970 (chip runs of PR 36).
     Bigger k blocks win until the double-buffered K/V block footprint
     presses on scoped VMEM, so block_k·d caps at 128K elements.
 
@@ -128,6 +154,13 @@ def _auto_blocks(s, kv_len, d=64, causal=False):
     return min(block_q, s), min(block_k, kv_len)
 
 
+def rows_per_step(b, s, h, d):
+    """Batch rows a grid step holds for self-attention over [b, s, h, d]
+    with the blocks ``_auto_blocks`` picks (``_Operands.tile``): what the
+    dispatch asks before it sends a sequence under 512 here."""
+    return _Operands(b, h, d, d).tile(s, s, *_auto_blocks(s, s, d)).rows
+
+
 def _dropout_thresh(rate):
     """Static uint32 threshold + inverse-keep scale for in-kernel dropout.
 
@@ -164,10 +197,10 @@ def _keep_mask(seed_ref, i, j, kb, shape, thresh):
 
 
 def _scores(q_blk, k_blk, scale, causal, masked, kvm_ref, j, kb, block_q,
-            block_k, window=None):
+            block_k, window=None, row=0):
     """Scaled [Bq, Bk] score tile + causal/key-padding masking; with
     ``window`` a query sees its last ``window`` keys only, itself among
-    them."""
+    them.  ``row`` is the batch row of the step's block the tile is of."""
     s = jax.lax.dot_general(q_blk, k_blk, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     if causal:
@@ -179,7 +212,7 @@ def _scores(q_blk, k_blk, scale, causal, masked, kvm_ref, j, kb, block_q,
         if window is not None:
             s = jnp.where(q_idx - k_idx < window, s, NEG_INF)
     if masked:
-        kvm = kvm_ref[0, 0]  # [Bk] fp32 0/1 — this grid step's k block
+        kvm = kvm_ref[row, 0]  # [Bk] fp32 0/1 — this grid step's k block
         s = jnp.where(kvm[None, :] > 0.0, s, NEG_INF)
     return s
 
@@ -230,14 +263,43 @@ def _by_head(parts, shape):
     return out
 
 
-def _step_id(lead):
+def _step_id(lead, rows=1):
     """The step's number: the steps are the grid's ``lead`` leading
     dimensions — ``(b·h,)`` flattened, ``(batch rows, blocks a row)`` in
-    the projection's layout — counted in row-major order."""
+    the projection's layout — counted in row-major order.  Where a step
+    holds ``rows`` batch rows (``_Operands.rows``), the number its first
+    row would have with one row a step: the dropout seeds follow it."""
     i = pl.program_id(0)
+    if rows > 1:
+        i = i * rows
     if lead == 2:
         i = i * pl.num_programs(1) + pl.program_id(1)
     return i
+
+
+def _row_steps(rows, lead, i):
+    """``(r, i_r)`` for each batch row ``r`` of the step's blocks, ``i_r``
+    the number the row's step would have with one row a step — the dropout
+    seeds follow it, so the masks do not depend on the rows a step holds.
+    One row: the step's own ``i``.  The kernels spell the rows out, one
+    copy of the row's body each: a loop over them in the kernel
+    (``lax.fori_loop``) costs BERT-large's seq-128 cell 3.5% of its tokens
+    (70,273 against 72,833 a second a chip; the kernels 27.0 against 19.9
+    ms of a step), and since a kernel is traced and lowered once a
+    geometry (``_fwd_kernels``) the longer body costs set-up about a
+    second of tracing (chip runs of PR 37)."""
+    if rows == 1:
+        return [(0, i)]
+    first = _step_id(lead, rows)
+    stride = pl.num_programs(1) if lead == 2 else 1
+    return [(0, first)] + [(r, first + r * stride) for r in range(1, rows)]
+
+
+def _stat_at(ref, r, g):
+    """Index of head ``g`` of the block's row ``r`` in a block of per-row
+    statistics: ``[G, 1, s]``, or ``[R, G, 1, s]`` with several rows a
+    step (``_Operands.row_spec``)."""
+    return (g, 0) if len(ref.shape) == 3 else (r, g, 0)
 
 
 def _grid_ids(lead):
@@ -252,7 +314,7 @@ def _head_index(i, g, heads):
 
 
 def _fwd_kernel(*refs, scale, causal, masked, dropout, single, heads, lead,
-                window=None):
+                window=None, rows=1):
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[:3]
     rest = refs[3:]
@@ -276,29 +338,32 @@ def _fwd_kernel(*refs, scale, causal, masked, dropout, single, heads, lead,
 
     if single:
         # one k block: straight-line softmax, no scratch round-trips (the
-        # common short-sequence case; ~25% faster than the streamed form)
-        outs, stats = [], []
-        for g in range(heads):
-            s = _scores(_own_lanes(q_ref[0], g, heads), k_ref[0], scale,
-                        causal, masked, kvm_ref, j, kb, block_q, block_k,
-                        window)
-            m = jnp.maximum(jnp.max(s, axis=1, keepdims=True), MAX_FLOOR)
-            p = jnp.exp(s - m)
-            l = jnp.sum(p, axis=1, keepdims=True)
-            if dropout:
-                thresh, inv_keep = _dropout_thresh(dropout)
-                keep = _keep_mask(seed_ref, _head_index(i, g, heads), j, kb,
-                                  (block_q, block_k), thresh)
-                p = jnp.where(keep, p * inv_keep, 0.0)
-            acc = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0],
-                                      (((1,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-            l_safe = jnp.where(l == 0.0, 1.0, l)
-            outs.append(acc / l_safe)
-            stats.append((m, l_safe))
-        o_ref[0] = _by_head(outs, wide).astype(o_ref.dtype)
-        for g, (m, l_safe) in enumerate(stats):
-            lse_ref[g, 0] = (m + jnp.log(l_safe))[:, 0]
+        # common short-sequence case; ~25% faster than the streamed form).
+        # A step holds ``rows`` batch rows where a row is one tile: the
+        # row's body as it is, once a row
+        for r, step in _row_steps(rows, lead, i):
+            outs, stats = [], []
+            for g in range(heads):
+                s = _scores(_own_lanes(q_ref[r], g, heads), k_ref[r], scale,
+                            causal, masked, kvm_ref, j, kb, block_q, block_k,
+                            window, r)
+                m = jnp.maximum(jnp.max(s, axis=1, keepdims=True), MAX_FLOOR)
+                p = jnp.exp(s - m)
+                l = jnp.sum(p, axis=1, keepdims=True)
+                if dropout:
+                    thresh, inv_keep = _dropout_thresh(dropout)
+                    keep = _keep_mask(seed_ref, _head_index(step, g, heads),
+                                      j, kb, (block_q, block_k), thresh)
+                    p = jnp.where(keep, p * inv_keep, 0.0)
+                acc = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[r],
+                                          (((1,), (0,)), ((), ())),
+                                          preferred_element_type=jnp.float32)
+                l_safe = jnp.where(l == 0.0, 1.0, l)
+                outs.append(acc / l_safe)
+                stats.append((m, l_safe))
+            o_ref[r] = _by_head(outs, wide).astype(o_ref.dtype)
+            for g, (m, l_safe) in enumerate(stats):
+                lse_ref[_stat_at(lse_ref, r, g)] = (m + jnp.log(l_safe))[:, 0]
         return
 
     @pl.when(at == 0)
@@ -374,7 +439,7 @@ def _sum(parts):
 
 
 def _bwd_tile(q, k_g, v_g, do, lse, delta, seed_ref, kvm_ref, head, j, kb, *,
-              scale, causal, masked, dropout, want_pv):
+              scale, causal, masked, dropout, want_pv, row=0):
     """One head's [Bq, Bk] tile of the backward recurrence, recomputed from
     the saved logsumexp: ``ds = p ∘ (dp − Δ)`` and, where the caller forms
     dv, the (dropped) probabilities ``p_v`` that met the values — both in
@@ -383,7 +448,7 @@ def _bwd_tile(q, k_g, v_g, do, lse, delta, seed_ref, kvm_ref, head, j, kb, *,
     [Bq, 1] columns."""
     block_q, block_k = q.shape[0], k_g.shape[0]
     s = _scores(q, k_g, scale, causal, masked, kvm_ref, j, kb, block_q,
-                block_k)
+                block_k, row=row)
     p = jnp.exp(s - lse)  # [Bq, Bk] fp32
     dp = _dot(do, v_g, ((1,), (1,)))
     p_v = p
@@ -500,7 +565,7 @@ def _bwd_dkv_kernel(*refs, scale, causal, masked, dropout, single, heads,
 
 
 def _bwd_fused_kernel(*refs, scale, causal, masked, dropout, heads, lead,
-                      fused_out):
+                      fused_out, rows=1):
     """Single-tile fused backward: dq, dk, dv from ONE score
     materialization.  The streamed pair (_bwd_dq_kernel + _bwd_dkv_kernel)
     each recompute the q·kᵀ scores, the softmax exp, the dᵒ·vᵀ dot and —
@@ -510,45 +575,56 @@ def _bwd_fused_kernel(*refs, scale, causal, masked, dropout, heads, lead,
     and feeds all three gradient dots (round-5 follow-up to the round-4b
     single-tile forward: the same win applied to the backward).  It holds
     every row of dO and O, so Δ = rowsum(dO ∘ O) is formed here, as the
-    column the tile subtracts, and no pass of XLA's reads the two again."""
+    column the tile subtracts, and no pass of XLA's reads the two again.
+    With ``rows`` batch rows a step (short sequences), once a row."""
     refs = list(refs)
     q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref = refs[:6]
     rest = refs[6:]
     seed_ref = rest.pop(0) if dropout else None
     kvm_ref = rest.pop(0) if masked else None
-    i = _step_id(lead)
+    for r, i in _row_steps(rows, lead, _step_id(lead)):
+        _bwd_fused_row(r, i, q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
+                       seed_ref, kvm_ref, rest, scale=scale, causal=causal,
+                       masked=masked, dropout=dropout, heads=heads,
+                       fused_out=fused_out)
 
+
+def _bwd_fused_row(r, i, q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref, seed_ref,
+                   kvm_ref, out_refs, *, scale, causal, masked, dropout, heads,
+                   fused_out):
+    """Row ``r`` of the step's blocks, step number ``i`` (``_row_steps``)."""
     dqs, dks, dvs = [], [], []
     for g in range(heads):
-        k_g = _own_lanes(k_ref[0], g, heads)
-        do_g = _own_lanes(do_ref[0], g, heads)
+        k_g = _own_lanes(k_ref[r], g, heads)
+        do_g = _own_lanes(do_ref[r], g, heads)
         delta = jnp.sum(do_g.astype(jnp.float32)
-                        * o_ref[0].astype(jnp.float32), axis=1, keepdims=True)
+                        * o_ref[r].astype(jnp.float32), axis=1, keepdims=True)
         p_v, ds = _bwd_tile(
-            q_ref[0], k_g, _own_lanes(v_ref[0], g, heads), do_ref[0],
-            lse_ref[g, 0][:, None], delta, seed_ref, kvm_ref,
-            _head_index(i, g, heads), 0, 0, scale=scale, causal=causal,
-            masked=masked, dropout=dropout, want_pv=True)
+            q_ref[r], k_g, _own_lanes(v_ref[r], g, heads), do_ref[r],
+            lse_ref[_stat_at(lse_ref, r, g)][:, None], delta, seed_ref,
+            kvm_ref, _head_index(i, g, heads), 0, 0, scale=scale,
+            causal=causal, masked=masked, dropout=dropout, want_pv=True,
+            row=r)
         dqs.append(_dot(ds, k_g, ((1,), (0,))))
-        dks.append(_dot(ds, _own_lanes(q_ref[0], g, heads), ((0,), (0,))))
+        dks.append(_dot(ds, _own_lanes(q_ref[r], g, heads), ((0,), (0,))))
         dvs.append(_dot(p_v, do_g, ((0,), (0,))))
     # s was scaled after the q·kᵀ dot, so the 1/√d factor lands on dk too
     grads = (_sum(dqs) * scale, _sum(dks) * scale, _sum(dvs))
     if not fused_out:
-        for ref, grad in zip(rest, grads):
-            ref[0] = grad.astype(ref.dtype)
+        for ref, grad in zip(out_refs, grads):
+            ref[r] = grad.astype(ref.dtype)
         return
     # ONE gradient [b, s, 3·h·d] for a fused projection: the output block
-    # is the batch row's whole [s, 3·h·d], resident in VMEM while the
-    # row's steps each store their three [s, G·d] pieces at their lanes
-    # (whole lane tiles, so the stores are aligned), and written to HBM
-    # once a row, in full lines.
-    dqkv_ref, = rest
+    # is the batch row's whole [s, 3·h·d] (the step's rows'), resident in
+    # VMEM while the row's steps each store their three [s, G·d] pieces at
+    # their lanes (whole lane tiles, so the stores are aligned), and
+    # written to HBM once a row, in full lines.
+    dqkv_ref, = out_refs
     width = q_ref.shape[2]
     for third, grad in enumerate(grads):
         at = pl.multiple_of(
             third * (dqkv_ref.shape[2] // 3) + pl.program_id(1) * width, 128)
-        dqkv_ref[0, :, pl.ds(at, width)] = grad.astype(dqkv_ref.dtype)
+        dqkv_ref[r, :, pl.ds(at, width)] = grad.astype(dqkv_ref.dtype)
 
 
 def _flatten_heads(x):
@@ -582,10 +658,20 @@ class _Operands:
     only): k and v are ``[b, s, (h / kv_group)·d]`` and query head ``i``'s
     step indexes their block ``i // kv_group`` — nothing is repeated in
     HBM; one head a block, so the widths are multiples of 128.
+
+    *Rows a step* (``tile``): where a batch row is ONE tile of the
+    projection layout (``s`` = ``kv_len`` = both blocks: the forward's
+    straight-line form and the fused backward) and short, a grid step
+    holds ``rows`` batch rows — the largest divisor of ``b`` with
+    ``rows · s`` ≤ ``_STEP_ROWS`` — so that a step's overhead and DMAs are
+    spread over eight 128-row tiles and not one; every block gains that
+    leading extent.  1 for every ``s`` ≥ 512 (``_SHORT_SEQ``), streamed
+    geometry, grouped KV heads and flattened operand.
     """
 
-    def __init__(self, b, h, d, dv, fused=False, kv_group=1):
+    def __init__(self, b, h, d, dv, fused=False, kv_group=1, rows=1):
         self.b, self.h, self.fused, self.kv_group = b, h, fused, kv_group
+        self.dims, self.rows = (b, h, d, dv, fused, kv_group), rows
         if d % 128 == 0 and dv % 128 == 0:
             self.heads, self.packed = 1, True
         elif d == dv == 64 and h % 2 == 0:
@@ -597,13 +683,38 @@ class _Operands:
                                  and not fused), (
             "grouped KV heads need head widths that are multiples of 128")
         self.blocks = h // self.heads  # blocks a batch row
-        # the grid's leading dimensions, one step a block of heads
-        self.steps = (b, self.blocks) if self.packed else (b * h,)
         self.name = "flattened" if not self.packed else (
             f"heads/block={self.heads}, projection layout"
             + (", fused qkv" if fused else "")
             + (f", {kv_group} query heads a kv head" if kv_group > 1
                else ""))
+
+    # a static argument of the traced kernel calls (``_fwd_kernels``,
+    # ``_bwd_kernels``): equal where everything the specs follow from is
+    def __eq__(self, other):
+        return (isinstance(other, _Operands)
+                and (self.dims, self.rows) == (other.dims, other.rows))
+
+    def __hash__(self):
+        return hash((self.dims, self.rows))
+
+    def tile(self, s, kv_len, block_q, block_k):
+        """These operands with the ``rows`` a step holds at these lengths
+        and blocks."""
+        rows = 1
+        if (self.packed and self.kv_group == 1 and s < _SHORT_SEQ
+                and block_q == s == kv_len == block_k):
+            rows = max(r for r in range(1, _STEP_ROWS // s + 1)
+                       if self.b % r == 0)
+        return self if rows == self.rows else _Operands(*self.dims, rows=rows)
+
+    @property
+    def steps(self):
+        """The grid's leading dimensions, one step a block of heads (of
+        ``rows`` batch rows)."""
+        if self.packed:
+            return (self.b // self.rows, self.blocks)
+        return (self.b * self.h,)
 
     def to_kernel(self, x):
         if not self.packed:
@@ -634,7 +745,7 @@ class _Operands:
         if self.packed:
             first = third * self.blocks if self.fused else 0
             return pl.BlockSpec(
-                (1, rows, self.heads * width),
+                (self.rows, rows, self.heads * width),
                 lambda *ids: (ids[0], seq_block(*ids), first + ids[1]))
         return pl.BlockSpec((1, rows, width),
                             lambda *ids: (ids[0], seq_block(*ids), 0))
@@ -643,9 +754,21 @@ class _Operands:
         return [self.spec(block_q, d, at_q, 0), self.spec(block_k, d, at_k, 1),
                 self.spec(block_k, dv, at_k, 2)]
 
+    def stat_shape(self, s):
+        """Of a per-row statistic (logsumexp, Δ): [b·h, 1, s] in either
+        layout, the step's heads consecutive there; with several batch
+        rows a step the same bytes seen as [b, h, 1, s], so that a block
+        takes the heads of each of its rows."""
+        if self.rows > 1:
+            return (self.b, self.h, 1, s)
+        return (self.b * self.h, 1, s)
+
     def row_spec(self, rows, seq_block):
-        """Block of a per-row statistic (logsumexp, Δ), kept [b·h, 1, s] in
-        either layout: the step's heads are consecutive there."""
+        """Block of a per-row statistic (``stat_shape``)."""
+        if self.rows > 1:
+            return pl.BlockSpec(
+                (self.rows, self.heads, 1, rows),
+                lambda *ids: (ids[0], ids[1], 0, seq_block(*ids)))
         if self.packed:
             return pl.BlockSpec(
                 (self.heads, 1, rows),
@@ -656,10 +779,10 @@ class _Operands:
 
     def mask_spec(self, rows, seq_block):
         # one [1, 1, rows] slice of the [b, 1, kv_len] key mask per step,
-        # the step's batch row's.  The singleton middle axis keeps the
-        # block's trailing-two dims Mosaic-tileable.
+        # the step's batch row's (its ``self.rows`` rows').  The singleton
+        # middle axis keeps the block's trailing-two dims Mosaic-tileable.
         if self.packed:
-            return pl.BlockSpec((1, 1, rows),
+            return pl.BlockSpec((self.rows, 1, rows),
                                 lambda *ids: (ids[0], 0, seq_block(*ids)))
         return pl.BlockSpec((1, 1, rows),
                             lambda *ids: (ids[0] // self.h, 0,
@@ -746,20 +869,24 @@ def _dropout_ops(dropout_rate, dropout_seed):
 
 
 @functools.lru_cache(maxsize=None)
-def _log_geometry(s, kv_len, d, causal, dropout, block_q, block_k, chosen,
-                  layout):
+def _log_geometry(s, kv_len, d, causal, dropout, block_q, block_k, rows,
+                  chosen, layout):
     """One line per distinct kernel geometry per process (traced calls
-    repeat per layer and per pass): which blocks a shape ran with, whether
-    the caller, the measured heuristic or the first-use tuner chose them —
-    two cold runs of an un-anchored shape may differ — and which operand
-    layout the kernels index (``_Operands``)."""
+    repeat per layer and per pass): which blocks a shape ran with, how
+    many batch rows a grid step holds, whether the caller, the measured
+    heuristic or the first-use tuner chose the blocks — two cold runs of
+    an un-anchored shape may differ — and which operand layout the kernels
+    index (``_Operands``)."""
     logger.info("flash_attention geometry: s=%d kv=%d d=%d causal=%s "
-                "dropout=%s -> block_q=%d block_k=%d (%s; %s)", s, kv_len, d,
-                causal, dropout, block_q, block_k, chosen, layout)
+                "dropout=%s -> block_q=%d block_k=%d rows/step=%d (%s; %s)",
+                s, kv_len, d, causal, dropout, block_q, block_k, rows, chosen,
+                layout)
 
 
-def _resolve_blocks(s, kv_len, d, block_q, block_k, causal=False,
-                    dropout_rate=0.0, layout=""):
+def _resolve_blocks(ops, s, kv_len, d, block_q, block_k, causal=False,
+                    dropout_rate=0.0):
+    """The call's operands, with the batch rows a step holds of them
+    (``_Operands.tile``), and its blocks."""
     auto_q, auto_k = _auto_blocks(s, kv_len, d, causal)
     chosen = "caller"
     if block_q is None and block_k is None:
@@ -776,8 +903,9 @@ def _resolve_blocks(s, kv_len, d, block_q, block_k, causal=False,
         chosen = "heuristic" if (auto_q, auto_k) == heuristic else "tuned"
     block_q = block_q or auto_q
     block_k = block_k or auto_k
+    ops = ops.tile(s, kv_len, block_q, block_k)
     _log_geometry(s, kv_len, d, causal, dropout_rate, block_q, block_k,
-                  chosen, layout)
+                  ops.rows, chosen, ops.name)
     # The kernels index K/V in whole blocks; a ragged tail would silently
     # attend over out-of-block garbage.  Dispatchers (attention.py) only
     # route divisible shapes here; direct callers must pad or shrink blocks.
@@ -785,7 +913,7 @@ def _resolve_blocks(s, kv_len, d, block_q, block_k, causal=False,
         raise ValueError(
             f"flash_attention requires seq divisible by block sizes: "
             f"q_len={s} % block_q={block_q}, kv_len={kv_len} % block_k={block_k}")
-    return block_q, block_k
+    return ops, block_q, block_k
 
 
 # which block along the sequence a grid step reads, by the grid's ids: the
@@ -816,14 +944,59 @@ def _band(window, block, n_kb):
     return min(n_kb, -(-(window - 1) // block) + 1)
 
 
+# kernel calls asked for, and those of them whose builder ran (the others
+# were answered with an earlier trace's equations): ``trace_stats``
+_calls = {"asked": 0, "traced": 0}
+
+
+def trace_stats():
+    """How often this process traced a kernel builder and how often the
+    cache spared it that (``_fwd_kernels``): BERT-large's training step,
+    23 layers' kernels forward and backward, reads 2 and 44 after its
+    trace.  Logged with the compile counters (``CompileStats.close``)."""
+    return {"geometries_traced": _calls["traced"],
+            "calls_from_cache": _calls["asked"] - _calls["traced"]}
+
+
 def _fwd_call(ops, q, k, v, dims, kv_mask, dropout_seed, causal, block_q,
               block_k, interpret, dropout_rate, name=None, window=None):
     """The forward kernel over operands already in ``ops``' layout (q, k
     and v one array where it is fused); ``dims`` = (s, kv_len, d, dv).
-    Returns the output in that layout and the logsumexp [b·h, 1, s]."""
+    Returns the output in that layout and the logsumexp
+    (``_Operands.stat_shape``)."""
+    s, kv_len, d, _ = dims
+    ops, block_q, block_k = _resolve_blocks(ops, s, kv_len, d, block_q,
+                                            block_k, causal, dropout_rate)
+    _calls["asked"] += 1
+    return _fwd_kernels(ops, q, k, v, dims, kv_mask, dropout_seed, causal,
+                        block_q, block_k, interpret, dropout_rate, name,
+                        window)
+
+
+# An inlined ``jit`` stages no call of a sub-function: it is there for its
+# cache, which answers a model's second layer with the first layer's
+# equations, so that a kernel call is traced and lowered once a geometry
+# (operands, lengths, blocks, mask, dropout: the static arguments and the
+# operands' types) and not once a layer.  Host seconds on this sandbox's
+# CPU, a step of BERT-large (23 layers' kernels, forward and backward)
+# lowered for a described v5e.  seq 512, one row a step: 6.5–7.1 without
+# the cache, 3.7–3.8 with it (PR 37).  seq 128, eight rows a step: 2.8–3.1
+# with XLA's attention, 18.6 with the rows spelled out in each of 46
+# kernel bodies and no cache (PR 36: a ``setup_s`` of 83–87 s on the chip,
+# where a set-up lowered the step three times), 3.5–3.7 with the rows
+# spelled out in the TWO bodies the cache leaves (PR 37: ``setup_s`` 35.8 s
+# beside the 36.0 of XLA's attention; seq 512's 51.3 → 37.8 with the step
+# traced once a process, ``engine._step_scalars``).  The cached trace
+# shares the helper functions of the caller's module, so a program's text
+# differs from the uncached one's in their NAMES alone
+# (``test_tpu_compile.py`` holds the one-row programs to PR 35's, names
+# normalised).
+@functools.partial(jax.jit, inline=True,
+                   static_argnums=(0, 4, 7, 8, 9, 10, 11, 12, 13))
+def _fwd_kernels(ops, q, k, v, dims, kv_mask, dropout_seed, causal, block_q,
+                 block_k, interpret, dropout_rate, name, window):
+    _calls["traced"] += 1
     s, kv_len, d, dv = dims
-    block_q, block_k = _resolve_blocks(s, kv_len, d, block_q, block_k, causal,
-                                       dropout_rate, ops.name)
     masked = kv_mask is not None
     n_qb = pl.cdiv(s, block_q)
     n_kb = pl.cdiv(kv_len, block_k)
@@ -848,7 +1021,8 @@ def _fwd_call(ops, q, k, v, dims, kv_mask, dropout_seed, causal, block_q,
     kernel = functools.partial(_fwd_kernel, scale=1.0 / math.sqrt(d),
                                causal=causal, masked=masked, dropout=drop,
                                single=(n_kb == 1), heads=ops.heads,
-                               lead=len(ops.steps), window=window)
+                               lead=len(ops.steps), window=window,
+                               rows=ops.rows)
     return pl.pallas_call(
         kernel,
         grid=(*ops.steps, n_qb, n_kb),
@@ -863,7 +1037,7 @@ def _fwd_call(ops, q, k, v, dims, kv_mask, dropout_seed, causal, block_q,
         ],
         out_shape=[
             ops.shape(s, dv, q.dtype),
-            jax.ShapeDtypeStruct((ops.b * ops.h, 1, s), jnp.float32),
+            jax.ShapeDtypeStruct(ops.stat_shape(s), jnp.float32),
         ],
         scratch_shapes=[
             *[_VMEM((block_q, 1), jnp.float32)] * ops.heads,  # running max m
@@ -883,9 +1057,20 @@ def _bwd_call(ops, q, k, v, g, out, lse, dims, kv_mask, dropout_seed, causal,
     """(dq, dk, dv) in ``ops``' layout from operands, the output and its
     cotangent ``g`` in that layout; ``dims`` = (s, kv_len, d).  Where q,
     k, v are one fused array, its one gradient."""
+    ops, block_q, block_k = _resolve_blocks(ops, *dims, block_q, block_k,
+                                            causal, dropout_rate)
+    _calls["asked"] += 1
+    return _bwd_kernels(ops, q, k, v, g, out, lse, dims, kv_mask,
+                        dropout_seed, causal, block_q, block_k, interpret,
+                        dropout_rate)
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnums=(0, 7, 10, 11, 12, 13, 14))
+def _bwd_kernels(ops, q, k, v, g, out, lse, dims, kv_mask, dropout_seed,
+                 causal, block_q, block_k, interpret, dropout_rate):
+    _calls["traced"] += 1
     s, kv_len, d = dims
-    block_q, block_k = _resolve_blocks(s, kv_len, d, block_q, block_k, causal,
-                                       dropout_rate, ops.name)
     masked = kv_mask is not None
     n_qb = pl.cdiv(s, block_q)
     n_kb = pl.cdiv(kv_len, block_k)
@@ -911,9 +1096,10 @@ def _bwd_call(ops, q, k, v, g, out, lse, dims, kv_mask, dropout_seed, causal,
         # (_bwd_fused_kernel) while a batch row's whole [s, 3·h·d] fits
         # VMEM twice over beside the operands; a row's blocks share that
         # output block, so they run in order
-        one_out = ops.fused and q[0].size * q.dtype.itemsize <= _ROW_BLOCK_BYTES
+        one_out = ops.fused and (ops.rows * q[0].size * q.dtype.itemsize
+                                 <= _ROW_BLOCK_BYTES)
         if one_out:
-            out_specs = pl.BlockSpec((1, s, q.shape[2]),
+            out_specs = pl.BlockSpec((ops.rows, s, q.shape[2]),
                                      lambda row, block: (row, 0, 0))
             out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
         else:
@@ -925,7 +1111,8 @@ def _bwd_call(ops, q, k, v, g, out, lse, dims, kv_mask, dropout_seed, causal,
         mask_ops, specs = in_specs(_first, _first,
                                    ops.spec(block_q, d, _first))
         grads = pl.pallas_call(
-            functools.partial(_bwd_fused_kernel, fused_out=one_out, **static),
+            functools.partial(_bwd_fused_kernel, fused_out=one_out,
+                              rows=ops.rows, **static),
             grid=ops.steps,
             in_specs=specs,
             out_specs=out_specs,

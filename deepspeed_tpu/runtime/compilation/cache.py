@@ -24,6 +24,7 @@ variable for its children from the jax-free side.
 """
 
 import os
+import sys
 import threading
 import time
 
@@ -207,9 +208,29 @@ class CompileStats:
         self.trace_secs += duration
 
     def close(self):
+        """Stop counting; the first close logs what was counted."""
         with _stats_lock:
-            if self in _stats_sinks:
-                _stats_sinks.remove(self)
+            if self not in _stats_sinks:
+                return
+            _stats_sinks.remove(self)
+        logger.info("compile stats: %s", self.summary())
+
+    def summary(self):
+        """The counters on one line, the attention kernels' trace cache
+        (``flash_attention.trace_stats``) beside them where the process
+        has loaded the kernels."""
+        line = (f"{self.programs} programs, cache {self.hits} hits / "
+                f"{self.misses} misses, compile {self.cold_secs:.2f} s "
+                f"(retrieval {self.warm_secs:.2f}), trace "
+                f"{self.trace_secs:.2f} s, lower {self.lower_secs:.2f} s")
+        kernels = sys.modules.get(
+            "deepspeed_tpu.ops.transformer.flash_attention")
+        if kernels is not None:
+            traces = kernels.trace_stats()
+            line += (f"; flash_attention {traces['geometries_traced']} "
+                     f"geometries traced, {traces['calls_from_cache']} "
+                     "calls from the cache")
+        return line
 
     def as_dict(self):
         return {"compile_cache_hits": self.hits,

@@ -649,12 +649,12 @@ class DeepSpeedEngine:
             "opt": opt0,
             "hostgrad": hostgrad0,
             "qres": qres0,
-            "scale": scale0,
-            "skipped": jnp.asarray(0, jnp.int32),
-            # device-resident step counter: the fused train step derives its
-            # dropout/rng stream from it on-device, so no per-step host
-            # scalar transfer is needed
-            "ustep": jnp.asarray(0, jnp.uint32),
+            **self._step_scalars(
+                scale0, jnp.asarray(0, jnp.int32),
+                # device-resident step counter: the fused train step derives
+                # its dropout/rng stream from it on-device, so no per-step
+                # host scalar transfer is needed
+                jnp.asarray(0, jnp.uint32)),
         }
 
         # cached module-dtype params (stage<=2 keeps them resident;
@@ -1672,6 +1672,19 @@ class DeepSpeedEngine:
             bytes_limit=summary["bytes_limit"],
             devices=summary["devices"], reporting=summary["reporting"],
             host_buffer_bytes=self.memory_ledger.host_buffers.total_bytes())
+
+    def _step_scalars(self, scale, skipped, ustep):
+        """The step's scalar state, replicated over the mesh as the step
+        hands it back.  A scalar made off the mesh has another type (its
+        aval names no mesh) than the same scalar as the first step
+        returns it, so the SECOND ``train_batch`` of a process traced,
+        lowered and compiled the whole step again: 63–69 s cold and 5–10 s
+        warm of BERT-large's set-up (PERF.md section 6, PR 37).  Plan mode
+        (``aot_plan``) holds shapes for devices it may not have: as made."""
+        scalars = {"scale": scale, "skipped": skipped, "ustep": ustep}
+        if self._aot_plan:
+            return scalars
+        return jax.device_put(scalars, NamedSharding(self.mesh, P()))
 
     def aot_lower_train_step(self, sample_batch):
         """Lower (trace + StableHLO emission) the fused train-step
@@ -4305,16 +4318,19 @@ class DeepSpeedEngine:
             self._refresh_module_params()
 
         ss = meta["scale_state"]
-        self.state["scale"] = DynamicScaleState(
-            cur_scale=jnp.asarray(ss["cur_scale"], jnp.float32),
-            cur_iter=jnp.asarray(ss["cur_iter"], jnp.int32),
-            last_overflow_iter=jnp.asarray(ss["last_overflow_iter"], jnp.int32),
-            cur_hysteresis=jnp.asarray(ss["cur_hysteresis"], jnp.int32))
-        self.state["skipped"] = jnp.asarray(meta["skipped_steps"], jnp.int32)
-        # rng-stream counter for the fused path; old checkpoints predate it —
-        # fall back to global_steps (same cadence: one bump per update)
-        self.state["ustep"] = jnp.asarray(
-            meta.get("ustep", meta["global_steps"]), jnp.uint32)
+        self.state.update(self._step_scalars(
+            DynamicScaleState(
+                cur_scale=jnp.asarray(ss["cur_scale"], jnp.float32),
+                cur_iter=jnp.asarray(ss["cur_iter"], jnp.int32),
+                last_overflow_iter=jnp.asarray(ss["last_overflow_iter"],
+                                               jnp.int32),
+                cur_hysteresis=jnp.asarray(ss["cur_hysteresis"], jnp.int32)),
+            jnp.asarray(meta["skipped_steps"], jnp.int32),
+            # rng-stream counter for the fused path; old checkpoints predate
+            # it — fall back to global_steps (same cadence: one bump per
+            # update)
+            jnp.asarray(meta.get("ustep", meta["global_steps"]),
+                        jnp.uint32)))
         self.global_steps = meta["global_steps"]
         self.micro_steps = meta["micro_steps"]
         self.global_samples = meta["global_samples"]

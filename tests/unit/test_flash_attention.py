@@ -13,6 +13,21 @@ from deepspeed_tpu.ops.transformer.flash_attention import (
     flash_attention, flash_attention_forward, flash_self_attention)
 
 
+@pytest.fixture(autouse=True)
+def fresh_kernel_traces():
+    """The kernel calls are traced once a geometry (``fa._fwd_kernels``,
+    ``fa._bwd_kernels``): a test that replaces what a trace reads
+    (``_keep_mask``, ``_ROW_BLOCK_BYTES``) neither meets nor leaves
+    another's."""
+    def clear():
+        fa._fwd_kernels.clear_cache()
+        fa._bwd_kernels.clear_cache()
+
+    clear()
+    yield
+    clear()
+
+
 def rand_qkv(b, s, h, d, seed=0, dtype=jnp.float32):
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     shape = (b, s, h, d)
@@ -29,23 +44,46 @@ def padding_masks(b, s, lengths):
     return kvm, additive
 
 
-@pytest.mark.parametrize("platform,batch,seq,kernel", [
-    ("tpu", 112, 128, False),   # bert_large.seq128: XLA attention
-    ("tpu", 32, 512, True),     # bert_large.seq512: the Pallas kernel
-    ("cpu", 32, 512, False),    # off the TPU: never the kernel
+def _bert(batch, seq, heads=16, d=64):
+    return (batch, seq, heads, d)
+
+
+@pytest.mark.parametrize("platform,q,k,data,kernel", [
+    ("tpu", _bert(112, 128), None, 1, True),   # bert_large.seq128's step
+    ("tpu", _bert(2, 128), None, 1, True),     # ...and its eval_batch rows
+    ("tpu", _bert(1, 128), None, 1, False),    # a serving prefill: one row
+    ("tpu", _bert(1, 256), None, 1, False),
+    ("tpu", _bert(4, 256), None, 1, True),     # four rows of 256 a step
+    ("tpu", _bert(3, 256), None, 1, True),     # three
+    ("tpu", _bert(5, 256), None, 1, False),    # no 2, 3 or 4 rows divide 5
+    ("tpu", _bert(4, 384), None, 1, False),    # blocks of 128: streamed
+    ("tpu", _bert(32, 512), None, 1, True),    # bert_large.seq512, as before
+    ("cpu", _bert(32, 512), None, 1, False),   # off the TPU: never the kernel
+    ("cpu", _bert(112, 128), None, 1, False),
+    ("tpu", _bert(8, 128, heads=25), None, 1, False),  # flattened layout
+    ("tpu", _bert(8, 128, d=192), None, 1, False),     # key width 192
+    ("tpu", _bert(32, 80), _bert(32, 512), 1, False),  # query-gathered layer
+    ("tpu", _bert(32, 128), _bert(32, 512), 1, False),
+    ("tpu", _bert(8, 128), None, 4, True),     # two rows a device
+    ("tpu", _bert(4, 128), None, 4, False),    # one row a device
+    ("tpu", _bert(6, 128), None, 4, True),     # 4 does not divide 6: whole
 ])
-def test_dispatch_follows_platform_and_shape(monkeypatch, platform, batch,
-                                             seq, kernel):
+def test_dispatch_follows_platform_and_shape(monkeypatch, platform, q, k,
+                                             data, kernel):
     """The one decision ``dot_product_attention`` takes from what it
-    observes, at the benchmark cells' shapes (BERT-large: 16 heads of
-    64)."""
+    observes, at the benchmark cells' shapes (BERT-large: 16 heads of 64)
+    and around them: from 512 the kernels; below, where the operands are
+    in the projection's layout and a grid step can hold two or more of the
+    device's batch rows."""
     from deepspeed_tpu.ops.transformer.attention import _use_pallas
-    from deepspeed_tpu.parallel import mesh
+    from deepspeed_tpu.parallel import make_mesh, mesh
 
     monkeypatch.setattr(mesh, "current_platform", lambda: platform)
-    monkeypatch.setattr(mesh, "get_current_mesh", lambda: None)
-    q = jax.ShapeDtypeStruct((batch, seq, 16, 64), jnp.bfloat16)
-    assert _use_pallas(q, q) is kernel
+    current = make_mesh({"data": data}) if data > 1 else None
+    monkeypatch.setattr(mesh, "get_current_mesh", lambda: current)
+    q = jax.ShapeDtypeStruct(q, jnp.bfloat16)
+    k = q if k is None else jax.ShapeDtypeStruct(k, jnp.bfloat16)
+    assert _use_pallas(q, k) is kernel
 
 
 def test_self_attention_hands_the_kernel_the_fused_projection(monkeypatch):
@@ -309,6 +347,76 @@ def test_dropout_masks_are_seeded_by_batch_times_heads_plus_head(
     np.testing.assert_allclose(float(loss_flash(q, k, v)),
                                float(loss_ref(q, k, v)), rtol=1e-5)
     assert_grads_match(loss_flash, loss_ref, q, k, v)
+
+
+def hashed_keep_mask(seed_ref, i, j, kb, shape, thresh):
+    """Stand-in for ``_keep_mask`` off the TPU, as the test above has it: a
+    pure function of the arguments the hardware PRNG is seeded by."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return ((seed_ref[0] ^ i) * 7 + j * 5 + kb * 3 + rows * 13
+            + cols * 11) % 4 != 0
+
+
+@pytest.mark.parametrize("rows,s,causal", [
+    (1, 128, False), (2, 128, False), (4, 128, False), (8, 128, False),
+    (2, 256, False), (4, 256, False),
+    # a causal decoder trained under 512 (no cell: ROADMAP W4)
+    (4, 128, True), (2, 256, True),
+], ids=["rows1", "rows2", "rows4", "rows8", "rows2-s256", "rows4-s256",
+        "rows4-causal", "rows2-s256-causal"])
+@pytest.mark.parametrize("masked", [True, False], ids=["mask", "nomask"])
+@pytest.mark.parametrize("dropout", [0.25, 0.0], ids=["dropout", "nodrop"])
+@entries
+def test_rows_a_step_equal_one_row_a_step(monkeypatch, rows, s, causal,
+                                          masked, dropout, entry):
+    """Where a batch row is one tile a grid step holds ``rows`` of them
+    (``_Operands.tile``): output, logsumexp and every gradient are bit for
+    bit those of one row a step — same tiles, same dropout seeds — and,
+    without dropout, the reference's within the file's tolerance."""
+    b, h, d = max(4, rows), 4, 64
+    logged = []     # rows a step of every geometry logged
+    monkeypatch.setattr(fa, "_log_geometry", lambda *a: logged.append(a[-3]))
+    monkeypatch.setattr(fa, "_keep_mask", hashed_keep_mask)
+    q, k, v = rand_qkv(b, s, h, d, seed=31)
+    w = jax.random.normal(jax.random.PRNGKey(37), (b, s, h, d))
+    kvm, additive = padding_masks(b, s, ([128, 77, 128, 100] * 2)[:b])
+    if not masked:
+        kvm = additive = None
+    seed = jnp.asarray([40, 3], jnp.int32) if dropout else None
+
+    def run(step_rows):
+        monkeypatch.setattr(fa, "_STEP_ROWS", step_rows)
+        if entry == "fused":
+            qkv = jnp.stack([q, k, v], axis=2)
+            _, res = fa._flash_fused_fwd(qkv, kvm, seed, causal, None, None,
+                                         True, dropout)
+        else:
+            _, res = fa._flash_fwd(q, k, v, kvm, seed, causal, None, None,
+                                   True, dropout)
+        out, grads = jax.value_and_grad(
+            lambda q, k, v: jnp.sum(w * attend(
+                entry, q, k, v, kv_mask=kvm, dropout_seed=seed,
+                causal=causal, dropout_rate=dropout)),
+            argnums=(0, 1, 2))(q, k, v)
+        return (res[-2].reshape(b, s, h, d), res[-1].reshape(b * h, 1, s),
+                out, *grads)
+
+    one = run(s)
+    assert set(logged) == {1}
+    del logged[:]
+    many = run(rows * s)
+    assert set(logged) == {rows}
+    for a, r in zip(many, one):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(r))
+    if not dropout:
+        want = jax.value_and_grad(
+            lambda q, k, v: jnp.sum(w * reference_attention(
+                q, k, v, mask=additive, causal=causal)),
+            argnums=(0, 1, 2))(q, k, v)
+        for a, r in zip(many[2:], (want[0], *want[1])):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                       atol=5e-4, rtol=5e-4)
 
 
 def test_flash_dropout_zero_rate_identity():

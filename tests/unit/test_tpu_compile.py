@@ -18,18 +18,22 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 from jax.experimental.compilation_cache import compilation_cache  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from deepspeed_tpu.ops.sparse_attention import (  # noqa: E402
     BigBirdSparsityConfig, flash_block_sparse_attention)
+from deepspeed_tpu.ops.transformer import (  # noqa: E402
+    flash_attention as flash_kernels)
 from deepspeed_tpu.ops.transformer.attention import (  # noqa: E402
     dot_product_attention)
 from deepspeed_tpu.ops.transformer.flash_attention import (  # noqa: E402
     flash_attention, flash_self_attention)
 from deepspeed_tpu.ops.transformer.paged_attention import (  # noqa: E402
     check_tpu_geometry, paged_decode_attention)
+from deepspeed_tpu.runtime.compilation import CompileStats  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -100,6 +104,26 @@ def _relayouts(text, *shapes):
             and any(shape in line.split("(")[0] for shape in shapes)]
 
 
+def _layer_grad_text(v5e, b, s, attend, seed_dtype, h=16, d=64):
+    """Optimized program of one layer's attention sandwich and its
+    gradient: QKV GEMM, ``attend(qkv [b, s, 3, h, d], kv_mask [b, s],
+    seed [2])``, output GEMM."""
+    hidden = h * d
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=v5e)
+
+    def loss(x, w_qkv, b_qkv, w_out, kv_mask, seed):
+        qkv = (x @ w_qkv + b_qkv).reshape(b, s, 3, h, d)
+        out = attend(qkv, kv_mask, seed).reshape(b, s, hidden) @ w_out
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    return _compile(jax.grad(loss, argnums=(0, 1, 2, 3)),
+                    shape((b, s, hidden)), shape((hidden, 3 * hidden)),
+                    shape((3 * hidden,)), shape((hidden, hidden)),
+                    shape((b, s), jnp.float32), shape((2,), seed_dtype))
+
+
 @pytest.mark.parametrize("entry", ["qkv", "fused"])
 def test_no_head_transposes_around_the_seq512_kernels(v5e, entry):
     """One layer's attention as ``bert_large.seq512`` runs it (b 32, s 512,
@@ -109,32 +133,183 @@ def test_no_head_transposes_around_the_seq512_kernels(v5e, entry):
     forward and the fused backward call; through the fused entry, which
     ``TransformerLayer`` takes, no slice of the projection going in and no
     concatenate coming back is materialised either."""
-    b, s, h, d = 32, 512, 16, 64
-    hidden = h * d
-
-    def shape(dims, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(dims, dtype, sharding=v5e)
-
-    def loss(x, w_qkv, b_qkv, w_out, kv_mask, seed):
-        qkv = (x @ w_qkv + b_qkv).reshape(b, s, 3, h, d)
+    def attend(qkv, kv_mask, seed):
         kw = dict(kv_mask=kv_mask, dropout_seed=seed, dropout_rate=0.1)
         if entry == "fused":
-            ctx = flash_self_attention(qkv, **kw)
-        else:
-            ctx = flash_attention(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2],
-                                  **kw)
-        out = ctx.reshape(b, s, hidden) @ w_out
-        return jnp.sum(out.astype(jnp.float32) ** 2)
+            return flash_self_attention(qkv, **kw)
+        return flash_attention(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2], **kw)
 
-    text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3)),
-                    shape((b, s, hidden)), shape((hidden, 3 * hidden)),
-                    shape((3 * hidden,)), shape((hidden, hidden)),
-                    shape((b, s), jnp.float32), shape((2,), jnp.int32))
+    text = _layer_grad_text(v5e, 32, 512, attend, jnp.int32)
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert _relayouts(text, "[32,16,512,64]") == []
     if entry == "fused":
         assert _relayouts(text, "[32,512,1024]", "[32,512,3072]",
                           "[32,512,3,1024]", "[32,512,3,16,64]") == []
+
+
+@pytest.mark.parametrize("b,s,causal,rows", [
+    (112, 128, False, 8),   # bert_large.seq128's step: mask + dropout
+    (2, 128, False, 2),     # ...and its eval_batch's rows
+    (8, 256, True, 4),      # a causal decoder trained at seq 256
+], ids=["bert-b112-s128", "bert-b2-s128", "causal-b8-s256"])
+def test_short_sequences_take_the_kernels_several_rows_a_step(
+        v5e, monkeypatch, b, s, causal, rows):
+    """Under 512 the dispatch hands a self-attention to the flash kernels
+    where a grid step can hold several batch rows (``_Operands.tile``): one
+    layer's QKV GEMM, attention, output GEMM and the gradient, 16 heads of
+    64, dropout 0.1 — the forward and the fused backward call compile for
+    the chip at ``rows`` rows a step, with no relayout of a [b, h, s, d]
+    tensor or of the projection around them."""
+    from deepspeed_tpu.ops.transformer import attention
+    from deepspeed_tpu.ops.transformer import flash_attention as fa
+    from deepspeed_tpu.parallel import mesh
+
+    h, d = 16, 64
+    hidden = h * d
+    logged = []
+    monkeypatch.setattr(mesh, "current_platform", lambda: "tpu")
+    # one described chip: no mesh an earlier test of the worker left current
+    monkeypatch.setattr(mesh, "get_current_mesh", lambda: None)
+    monkeypatch.setattr(fa, "_log_geometry", lambda *a: logged.append(a[-3]))
+
+    def attend(qkv, kv_mask, key):
+        return attention.self_attention(
+            qkv, key_padding_mask=None if causal else kv_mask, causal=causal,
+            dropout_rate=0.1, dropout_rng=key, deterministic=False)
+
+    text = _layer_grad_text(v5e, b, s, attend, jnp.uint32)
+    assert set(logged) == {rows}
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert _relayouts(text, f"[{b},{h},{s},{d}]", f"[{b},{s},{hidden}]",
+                      f"[{b},{s},{3 * hidden}]", f"[{b},{s},3,{hidden}]",
+                      f"[{b},{s},3,{h},{d}]") == []
+
+
+def _lowered(monkeypatch, fn, *args):
+    """(StableHLO text of ``fn`` lowered for the described chip with each
+    kernel call's ``backend_config`` taken out and the helper functions
+    named after their bodies, the distinct Mosaic bodies that were in the
+    calls as text without locations): what a change to the kernels' source
+    lines or to the order in which helpers were traced leaves as it is."""
+    from jax._src import tpu_custom_call
+
+    bodies = set()
+    serialize = tpu_custom_call._lower_mosaic_module_to_asm
+
+    def keep(module, **kw):
+        bodies.add(module.operation.get_asm(enable_debug_info=False))
+        return serialize(module, **kw)
+
+    monkeypatch.setattr(tpu_custom_call, "_lower_mosaic_module_to_asm", keep)
+    text = jax.jit(fn).lower(*args).as_text()
+    return (_names_normalised(re.sub(r'backend_config = "[^"]*"', "", text)),
+            bodies)
+
+
+def _names_normalised(text):
+    """A StableHLO module's text with every function but ``main`` named
+    after its own body (its callees' names replaced first), the functions
+    sorted and equal bodies kept once: two modules that differ in the
+    numbering, the order or the sharing of their helper functions alone
+    (``_where_3`` here, ``_where_7`` there) give the same text."""
+    funcs, head, name = {}, [], None
+    for line in text.splitlines():
+        m = re.match(r"  func\.func (?:\w+ )?@([\w.$\-]+)\(", line)
+        if m:
+            name = m.group(1)
+            funcs[name] = []
+        (head if name is None else funcs[name]).append(line)
+        if line == "  }":
+            name = None
+    called = re.compile(r"@(" + "|".join(
+        map(re.escape, sorted(funcs, key=len, reverse=True))) + r")\b")
+    bodies = {}
+
+    def body(fn):
+        if fn not in bodies:
+            bodies[fn] = "\n".join(
+                called.sub(lambda m: "@SELF" if m.group(1) == fn
+                           else "@f_" + _digest(body(m.group(1))), line)
+                for line in funcs[fn])
+        return bodies[fn]
+
+    return "\n".join(head + [body("main")] + sorted(
+        {body(fn) for fn in funcs if fn != "main"}))
+
+
+def _digest(*texts):
+    import hashlib
+
+    return hashlib.sha256("\n".join(texts).encode()).hexdigest()[:16]
+
+
+def _seq512_layers(shape):
+    """Two layers of ``bert_large.seq512``'s attention (b 32, mask,
+    dropout 0.1) and their gradient."""
+    from deepspeed_tpu.ops.transformer import attention
+
+    def two_layers(qkv, kv_mask, key):
+        for layer in range(2):
+            out = attention.self_attention(
+                qkv, key_padding_mask=kv_mask, dropout_rate=0.1,
+                dropout_rng=jax.random.fold_in(key, layer),
+                deterministic=False)
+            qkv = qkv + out[:, :, None]
+        return jnp.sum(qkv.astype(jnp.float32))
+
+    return jax.grad(two_layers), (shape((32, 512, 3, 16, 64)),
+                                  shape((32, 512), jnp.float32),
+                                  shape((2,), jnp.uint32))
+
+
+def _prefill_layers(s):
+    """Two layers of GPT-2-large's bucket-``s`` prefill attention (one row,
+    20 heads of 64, causal, key mask)."""
+    def build(shape):
+        from deepspeed_tpu.ops.transformer import attention
+
+        def two_layers(q, k, v, visible):
+            for _ in range(2):
+                q = q + attention.dot_product_attention(
+                    q, k, v, key_padding_mask=visible, causal=True)
+            return q
+
+        return two_layers, (*[shape((1, s, 20, 64))] * 3,
+                            shape((1, s), jnp.float32))
+
+    return build
+
+
+@pytest.mark.parametrize("program,text_digest,body_digests", [
+    (_seq512_layers, "093a0768c2ce9cb7", ["169ef6412f20687f", "888a5e6e32a4cf3d"]),
+    (_prefill_layers(512), "bd37f3a5f4150dc7", ["4c1432cca2ba5260"]),
+    (_prefill_layers(128), "08f5eaebab9f6b8a", []),
+], ids=["seq512-step", "prefill-512", "prefill-128"])
+def test_one_row_programs_are_the_parents(v5e, monkeypatch, program,
+                                          text_digest, body_digests):
+    """Several rows a step changed the kernels' source and the dispatch
+    under 512, and every kernel call is traced once a geometry (PR 37);
+    what the cells ran at one row a step is what they run now.  Two layers
+    of ``bert_large.seq512``'s attention with their gradient, and of
+    GPT-2-large's bucket-512 prefill: the lowered text, its helper
+    functions named after their bodies, and every Mosaic body are PR 35's
+    (the digests were taken from its tree with this file's helpers).  The
+    bucket-128 prefill — one row, nothing for a step to hold — stays XLA's
+    attention, the same text.  A PR that means to change these re-pins
+    the digests (they are printed) and says so."""
+    from deepspeed_tpu.parallel import mesh
+
+    monkeypatch.setattr(mesh, "current_platform", lambda: "tpu")
+    monkeypatch.setattr(mesh, "get_current_mesh", lambda: None)
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=v5e)
+
+    fn, args = program(shape)
+    text, bodies = _lowered(monkeypatch, fn, *args)
+    got = (_digest(text), sorted(map(_digest, bodies)))
+    assert got == (text_digest, body_digests), got
+    assert ("tpu_custom_call" in text) == bool(body_digests)
 
 
 def test_no_head_transposes_around_the_prefill_kernel(v5e):
@@ -477,3 +652,88 @@ def test_the_looped_decode_carries_its_caches_in_place(v5e, monkeypatch):
     assert memory.temp_size_in_bytes < 256 * 2 ** 20
     assert not [line for op, size, _, line in _top_level(
         text, ("copy", "transpose", "copy-start")) if size >= 2 ** 30]
+
+
+# -- a training cell's whole step, as the chip gets it ----------------------
+
+def _lowered_step(cell, sharding, layers=None):
+    """(the cell's ``train_step`` lowered for the described chip, its global
+    batch, heads, sequence length): the engine of a ``BENCHMARK.json``
+    training cell in plan mode (``aot_plan=True``: shapes alone) — the
+    model, the engine config and the batch shapes the harness builds,
+    ``layers`` of the model's where given."""
+    import deepspeed_tpu as deepspeed
+    from benchmarks import common, generators, models, train
+    from deepspeed_tpu.parallel import make_mesh
+
+    spec = common.load_cell(cell)
+    cfg, traffic = spec["config"], spec["traffic"]
+    mc = dict(cfg["model_config"])
+    if layers is not None:
+        mc["num_hidden_layers"] = layers
+    model = models.load(cfg["model"])
+    batch_rows = traffic["batch_per_chip"]
+    batch = generators.load(traffic["generator"]).make(
+        traffic, mc, 1, batch_rows)[0]
+    engine, *_ = deepspeed.initialize(
+        model=model.build_program_model(mc, traffic),
+        config=train._engine_config(spec, batch_rows),
+        mesh=make_mesh(cfg["mesh"], devices=list(sharding.device_set)),
+        aot_plan=True)
+    try:
+        lowered = engine.aot_lower_train_step(
+            {k: np.asarray(v) for k, v in batch.items()})
+    finally:
+        engine.close()
+    return (lowered, batch_rows, mc["num_attention_heads"],
+            traffic["seq_len"])
+
+
+def _fresh_kernel_traces():
+    flash_kernels._fwd_kernels.clear_cache()
+    flash_kernels._bwd_kernels.clear_cache()
+    return flash_kernels.trace_stats()
+
+
+@pytest.mark.parametrize("cell,rows", [("bert_large.seq128", 8),
+                                       ("bert_large.seq512", 1)])
+def test_a_four_layer_model_traces_each_kernel_builder_once(
+        v5e, monkeypatch, cell, rows):
+    """Four layers of BERT-large, three of them self-attention over the
+    whole sequence (the last gathers its queries: XLA's attention): the
+    forward and the backward kernel builder are each traced ONCE and the
+    other two layers are answered from the cache — at eight rows a step
+    (seq 128) and at one (seq 512) alike — and the program still holds a
+    kernel call a layer each way."""
+    logged = []
+    monkeypatch.setattr(flash_kernels, "_log_geometry",
+                        lambda *a: logged.append(a[-3]))
+    before = _fresh_kernel_traces()
+    stats = CompileStats()
+    try:
+        lowered, *_ = _lowered_step(cell, v5e, layers=4)
+    finally:
+        stats.close()
+    after = flash_kernels.trace_stats()
+    assert set(logged) == {rows}
+    assert {k: after[k] - before[k] for k in after} == {
+        "geometries_traced": 2, "calls_from_cache": 4}
+    assert "flash_attention" in stats.summary()
+    assert lowered.as_text().count("tpu_custom_call") == 6
+
+
+def test_the_seq128_step_compiles_to_a_kernel_call_a_layer_each_way(v5e):
+    """``bert_large.seq128``'s step as the chip gets it (b 112, 24 layers,
+    23 of them self-attention with mask and dropout): 46 Mosaic calls —
+    two builders traced, 44 calls from the cache — and, compiled, no
+    ``[b, h, s, s]`` buffer anywhere: the score tensor XLA's attention
+    materialised (and relaid) is gone from the program.  The compile
+    takes this machine's CPU about a minute."""
+    before = _fresh_kernel_traces()
+    lowered, b, h, s = _lowered_step("bert_large.seq128", v5e)
+    after = flash_kernels.trace_stats()
+    assert {k: after[k] - before[k] for k in after} == {
+        "geometries_traced": 2, "calls_from_cache": 44}
+    text = lowered.compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 46
+    assert re.findall(rf"\[{b},{h},{s},{s}\]", text) == []
