@@ -321,8 +321,10 @@ def serve(seed, stats, geometry, cfg=None, inference=None,
     warmup_seconds = time.perf_counter() - t1
     compile_receipt = _compile_delta(stats, before)
     compile_by_program = {
-        name: round(stats.by_program.get(f"jit({name})", 0.0), 3)
-        for name in ("decode", "prefill")}  # prefill: all buckets together
+        name: round(sum(seconds for program, seconds
+                        in stats.by_program.items()
+                        if program.startswith(f"jit({name}")), 3)
+        for name in ("decode", "prefill")}  # prefill_<bucket>: all together
     programs_after_warmup = stats.programs
 
     # the served wave: the first five arrive together (four slots, so one

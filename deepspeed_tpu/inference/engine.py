@@ -193,9 +193,13 @@ class InferenceEngine:
         for bucket in icfg.prefill_buckets:
             name = prefill_program_name(bucket)
             self._donation_specs[name] = (1,)
+            prefill = serving.build_prefill(icfg, bucket)
+            # a device trace names a compiled program after its function:
+            # jit_prefill_<bucket>, so that the buckets' runs (and their
+            # instructions, which share names) can be told apart
+            prefill.__name__ = prefill.__qualname__ = f"prefill_{bucket}"
             self._prefills[bucket] = self.memory_ledger.wrap(
-                name, jax.jit(serving.build_prefill(icfg, bucket),
-                              donate_argnums=(1,)))
+                name, jax.jit(prefill, donate_argnums=(1,)))
         # the decode program's host tables, kept between iterations: a
         # slot's row changes only when its request does
         self._tables = [np.full(
@@ -706,7 +710,8 @@ class InferenceEngine:
                     self._sample_integrity()
                     # the operator's on-demand device trace (touch
                     # <run_dir>/device_trace.trigger), as train_batch polls it
-                    self.telemetry.poll_device_trace(self.decode_iterations)
+                    self.telemetry.poll_device_trace(self.decode_iterations,
+                                                     self.program_scopes)
             return finished
 
     def run(self):
@@ -840,6 +845,17 @@ class InferenceEngine:
         from ..profiling.verify import verify_engine_programs
 
         return verify_engine_programs(self)
+
+    def program_scopes(self):
+        """``{module name as a device trace prints it ("jit_decode",
+        "jit_prefill_<bucket>"): {instruction: (scope path, direction)}}``
+        of every serve program compiled so far (``telemetry/scopes.py``).
+        Built when asked, never at construction or in ``step``; empty
+        where the ledger keeps no program (``profiling.memory_ledger``,
+        on with telemetry)."""
+        from ..telemetry import scopes
+
+        return scopes.program_scopes(self.memory_ledger.compiled_programs())
 
     # ------------------------------------------------------------------
     # resilience plane (inference/resilience.py)

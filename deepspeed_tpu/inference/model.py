@@ -45,7 +45,12 @@ it can serve has a ``serving()`` method that returns an object with:
   whole: ``"tokens"`` (prefill: the first token; decode: ``[slots]``) and
   any scalar counters the model reports (published as ``serving/<key>``
   gauges on the print cadence).  The functions are NAMED ``prefill`` and
-  ``decode``: a device trace names a compiled program after its function.
+  ``decode``: a device trace names a compiled program after its function
+  (the engine names a bucket's program ``prefill_<bucket>``, so that a
+  trace tells the buckets apart).  Inside them the work stands under the
+  program's scope words (``telemetry/scopes.py``): ``embed``,
+  ``layer_<i>`` ⊃ ``attention``, ``mlp`` | ``moe``, then ``final_norm``,
+  ``lm_head``, ``sample``.
 
 The engine keeps one program in flight, so a program's tokens never
 travel device -> host -> device: ``decode``'s ``tokens`` argument IS the
@@ -126,32 +131,41 @@ def build_prefill(model_config, icfg, bucket_len):
 
     def prefill(params, k_cache, v_cache, input_ids, true_len, block_table):
         s = input_ids.shape[1]
-        x = jnp.take(params["wte"], input_ids, axis=0) \
-            + params["wpe"][None, :s]
+        with jax.named_scope("embed"):
+            x = jnp.take(params["wte"], input_ids, axis=0) \
+                + params["wpe"][None, :s]
         # pad keys masked out of every softmax row; the causal structure
         # already hides them from positions < true_len, so this only
         # pins the (discarded) pad rows
         visible = (jnp.arange(s)[None, :] < true_len).astype(jnp.float32)
         for i in range(c.num_layers):
             lp = params["blocks"][f"layer_{i}"]
-            y = layer_norm(lp["ln_attn"], x, c.layer_norm_eps)
-            qkv = dense(lp["qkv"], y).reshape(1, s, 3, c.hidden_size)
-            k_cache = _write_prefill_blocks(k_cache, i, qkv[0, :, 1],
-                                            block_table, bs)
-            v_cache = _write_prefill_blocks(v_cache, i, qkv[0, :, 2],
-                                            block_table, bs)
-            q, k, v = (qkv[:, :, j].reshape(1, s, heads, head_dim)
-                       for j in range(3))
-            ctx = dot_product_attention(q, k, v, key_padding_mask=visible,
-                                        causal=True)
-            x = x + dense(lp["attn_out"], ctx.reshape(1, s, c.hidden_size))
-            z = layer_norm(lp["ln_mlp"], x, c.layer_norm_eps)
-            x = x + dense(lp["fc2"], gelu(dense(lp["fc1"], z)))
-        x = layer_norm(params["ln_f"], x, c.layer_norm_eps)
-        last = jax.lax.dynamic_slice(
-            x, (0, true_len - 1, 0), (1, 1, c.hidden_size))
-        logits = last[0, 0] @ params["wte"].T.astype(last.dtype)
-        return jnp.argmax(logits).astype(jnp.int32), k_cache, v_cache
+            with jax.named_scope(f"layer_{i}"):
+                with jax.named_scope("attention"):
+                    y = layer_norm(lp["ln_attn"], x, c.layer_norm_eps)
+                    qkv = dense(lp["qkv"], y).reshape(1, s, 3, c.hidden_size)
+                    k_cache = _write_prefill_blocks(
+                        k_cache, i, qkv[0, :, 1], block_table, bs)
+                    v_cache = _write_prefill_blocks(
+                        v_cache, i, qkv[0, :, 2], block_table, bs)
+                    q, k, v = (qkv[:, :, j].reshape(1, s, heads, head_dim)
+                               for j in range(3))
+                    ctx = dot_product_attention(
+                        q, k, v, key_padding_mask=visible, causal=True)
+                    x = x + dense(lp["attn_out"],
+                                  ctx.reshape(1, s, c.hidden_size))
+                with jax.named_scope("mlp"):
+                    z = layer_norm(lp["ln_mlp"], x, c.layer_norm_eps)
+                    x = x + dense(lp["fc2"], gelu(dense(lp["fc1"], z)))
+        with jax.named_scope("final_norm"):
+            x = layer_norm(params["ln_f"], x, c.layer_norm_eps)
+            last = jax.lax.dynamic_slice(
+                x, (0, true_len - 1, 0), (1, 1, c.hidden_size))
+        with jax.named_scope("lm_head"):
+            logits = last[0, 0] @ params["wte"].T.astype(last.dtype)
+        with jax.named_scope("sample"):
+            token = jnp.argmax(logits).astype(jnp.int32)
+        return token, k_cache, v_cache
 
     return prefill
 
@@ -174,32 +188,42 @@ def build_decode(model_config, icfg):
     interpret = current_platform() != "tpu"
 
     def decode(params, k_cache, v_cache, block_tables, ctx_lens, tokens):
-        x = jnp.take(params["wte"], tokens, axis=0) \
-            + jnp.take(params["wpe"], ctx_lens, axis=0)       # [B, h]
-        block_ids = jnp.take_along_axis(
-            block_tables, (ctx_lens // bs)[:, None], axis=1)[:, 0]
-        offsets = ctx_lens % bs
+        with jax.named_scope("embed"):
+            x = jnp.take(params["wte"], tokens, axis=0) \
+                + jnp.take(params["wpe"], ctx_lens, axis=0)       # [B, h]
+            block_ids = jnp.take_along_axis(
+                block_tables, (ctx_lens // bs)[:, None], axis=1)[:, 0]
+            offsets = ctx_lens % bs
         for i in range(c.num_layers):
             lp = params["blocks"][f"layer_{i}"]
-            y = layer_norm(lp["ln_attn"], x, c.layer_norm_eps)
-            qkv = dense(lp["qkv"], y).reshape(n_slots, 3, c.hidden_size)
-            # the append: every slot's new row in one scatter a cache
-            k_cache = k_cache.at[i, block_ids, offsets].set(
-                qkv[:, 1].astype(k_cache.dtype))
-            v_cache = v_cache.at[i, block_ids, offsets].set(
-                qkv[:, 2].astype(v_cache.dtype))
-            # each slot's context now includes its own new token at
-            # position ctx_len; the kernel reads the live pages only
-            ctx = paged_decode_attention(
-                qkv[:, 0], k_cache, v_cache, block_tables, ctx_lens,
-                layer=i, num_heads=heads, interpret=interpret)
-            x = x + dense(lp["attn_out"], ctx)
-            z = layer_norm(lp["ln_mlp"], x, c.layer_norm_eps)
-            x = x + dense(lp["fc2"], gelu(dense(lp["fc1"], z)))
-        x = layer_norm(params["ln_f"], x, c.layer_norm_eps)
-        logits = x @ params["wte"].T.astype(x.dtype)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), \
-            k_cache, v_cache
+            with jax.named_scope(f"layer_{i}"):
+                with jax.named_scope("attention"):
+                    y = layer_norm(lp["ln_attn"], x, c.layer_norm_eps)
+                    qkv = dense(lp["qkv"], y).reshape(n_slots, 3,
+                                                      c.hidden_size)
+                    # the append: every slot's new row in one scatter a
+                    # cache
+                    k_cache = k_cache.at[i, block_ids, offsets].set(
+                        qkv[:, 1].astype(k_cache.dtype))
+                    v_cache = v_cache.at[i, block_ids, offsets].set(
+                        qkv[:, 2].astype(v_cache.dtype))
+                    # each slot's context now includes its own new token
+                    # at position ctx_len; the kernel reads the live pages
+                    # only
+                    ctx = paged_decode_attention(
+                        qkv[:, 0], k_cache, v_cache, block_tables, ctx_lens,
+                        layer=i, num_heads=heads, interpret=interpret)
+                    x = x + dense(lp["attn_out"], ctx)
+                with jax.named_scope("mlp"):
+                    z = layer_norm(lp["ln_mlp"], x, c.layer_norm_eps)
+                    x = x + dense(lp["fc2"], gelu(dense(lp["fc1"], z)))
+        with jax.named_scope("final_norm"):
+            x = layer_norm(params["ln_f"], x, c.layer_norm_eps)
+        with jax.named_scope("lm_head"):
+            logits = x @ params["wte"].T.astype(x.dtype)
+        with jax.named_scope("sample"):
+            next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return next_tokens, k_cache, v_cache
 
     return decode
 
@@ -235,8 +259,9 @@ class GPT2Serving:
                     next_tokens, slot):
             token, k_cache, v_cache = inner(params, *caches, input_ids,
                                             true_len, *block_tables)
-            return ({"tokens": token}, (k_cache, v_cache),
-                    next_tokens.at[slot].set(token))
+            with jax.named_scope("sample"):
+                next_tokens = next_tokens.at[slot].set(token)
+            return {"tokens": token}, (k_cache, v_cache), next_tokens
 
         return prefill
 

@@ -138,16 +138,17 @@ class BertModel:
         c = self.config
         b, s = input_ids.shape
         emb = params["embeddings"]
-        x = (jnp.take(emb["word"], input_ids, axis=0)
-             + emb["position"][None, :s]
-             + (jnp.take(emb["token_type"], token_type_ids, axis=0)
-                if token_type_ids is not None else 0.0))
-        if dtype is not None:
-            x = x.astype(dtype)
-        x = layer_norm(emb["ln"], x, c.layer_norm_eps)
-        if rng is not None and not deterministic:
-            rng_e, rng = jax.random.split(rng)
-            x = dropout(rng_e, x, c.hidden_dropout_prob, deterministic)
+        with jax.named_scope("embed"):
+            x = (jnp.take(emb["word"], input_ids, axis=0)
+                 + emb["position"][None, :s]
+                 + (jnp.take(emb["token_type"], token_type_ids, axis=0)
+                    if token_type_ids is not None else 0.0))
+            if dtype is not None:
+                x = x.astype(dtype)
+            x = layer_norm(emb["ln"], x, c.layer_norm_eps)
+            if rng is not None and not deterministic:
+                rng_e, rng = jax.random.split(rng)
+                x = dropout(rng_e, x, c.hidden_dropout_prob, deterministic)
 
         # Key-padding form (1 = visible), so the flash kernel can fuse the
         # mask into its softmax instead of falling back to O(s²) attention.
@@ -197,7 +198,8 @@ class BertModel:
                 x = jnp.where(keep, y, x)
             else:
                 x = y
-        pooled = jnp.tanh(dense(params["pooler"], x[:, 0]))
+        with jax.named_scope("pooler"):
+            pooled = jnp.tanh(dense(params["pooler"], x[:, 0]))
         return x, pooled
 
 
@@ -269,46 +271,52 @@ class BertForPreTrainingTPU:
                   and n_pred < input_ids.shape[1])
         final_positions = None
         if gather:
-            is_masked = (mlm_labels != -100).astype(jnp.int32)
-            _, pos = jax.lax.top_k(is_masked, n_pred)  # [b, n_pred]
-            mlm_labels = jnp.take_along_axis(mlm_labels, pos, axis=1)
-            # final-layer query gather needs the dense bidirectional
-            # attention core and uniform shapes (no PLD select); other
-            # configs keep the full final layer + post-encode head gather
-            if pld_theta is None and c.attn_impl == "auto":
-                final_positions = jnp.concatenate(
-                    [jnp.zeros((pos.shape[0], 1), pos.dtype), pos], axis=1)
+            with jax.named_scope("mlm_head"):
+                is_masked = (mlm_labels != -100).astype(jnp.int32)
+                _, pos = jax.lax.top_k(is_masked, n_pred)  # [b, n_pred]
+                mlm_labels = jnp.take_along_axis(mlm_labels, pos, axis=1)
+                # final-layer query gather needs the dense bidirectional
+                # attention core and uniform shapes (no PLD select); other
+                # configs keep the full final layer + post-encode head
+                # gather
+                if pld_theta is None and c.attn_impl == "auto":
+                    final_positions = jnp.concatenate(
+                        [jnp.zeros((pos.shape[0], 1), pos.dtype), pos],
+                        axis=1)
         seq_out, pooled = self.bert.encode(
             params["bert"], input_ids, attention_mask, token_type_ids,
             rng=rng, deterministic=not train, pld_theta=pld_theta,
             dtype=self.compute_dtype, final_positions=final_positions)
 
         cls = params["cls"]
-        head_in = seq_out
-        if gather:
-            if final_positions is not None:
-                # encode returned [b, 1 + n_pred, h]: CLS row + label rows
-                head_in = seq_out[:, 1:]
-            else:  # PLD active — encode ran full-length; gather here
-                head_in = jnp.take_along_axis(seq_out, pos[..., None], axis=1)
-        h = gelu(dense(cls["transform"], head_in))
-        h = layer_norm(cls["transform_ln"], h, c.layer_norm_eps)
-        # decoder tied to word embeddings (standard BERT; the reference ties
-        # them through TiedLayerSpec under pipelining, module.py:71)
-        logits = h @ params["bert"]["embeddings"]["word"].T.astype(h.dtype) \
-            + cls["decoder_bias"].astype(h.dtype)
+        with jax.named_scope("mlm_head"):
+            head_in = seq_out
+            if gather:
+                if final_positions is not None:
+                    # encode returned [b, 1 + n_pred, h]: CLS row + label
+                    # rows
+                    head_in = seq_out[:, 1:]
+                else:  # PLD active — encode ran full-length; gather here
+                    head_in = jnp.take_along_axis(seq_out, pos[..., None],
+                                                  axis=1)
+            h = gelu(dense(cls["transform"], head_in))
+            h = layer_norm(cls["transform_ln"], h, c.layer_norm_eps)
+            # decoder tied to word embeddings (standard BERT; the reference
+            # ties them through TiedLayerSpec under pipelining,
+            # module.py:71)
+            logits = h @ params["bert"]["embeddings"]["word"].T.astype(
+                h.dtype) + cls["decoder_bias"].astype(h.dtype)
 
         if not train and mlm_labels is None:
             return logits
 
-        mlm_loss = cross_entropy_with_logits(logits, mlm_labels,
+        with jax.named_scope("loss"):
+            loss = cross_entropy_with_logits(logits, mlm_labels,
                                              ignore_index=-100)
-        loss = mlm_loss
-        if "next_sentence_labels" in batch:
-            nsp_logits = dense(cls["seq_relationship"], pooled)
-            nsp_loss = cross_entropy_with_logits(nsp_logits,
-                                                 batch["next_sentence_labels"])
-            loss = loss + nsp_loss
+            if "next_sentence_labels" in batch:
+                nsp_logits = dense(cls["seq_relationship"], pooled)
+                loss = loss + cross_entropy_with_logits(
+                    nsp_logits, batch["next_sentence_labels"])
         return loss
 
 

@@ -313,22 +313,29 @@ class DeepseekV2Serving:
         if "mlp" in lp:
             return gated_silu_mlp(lp["mlp"], z, jnp.float32), None
         c, moe = self.config, lp["moe"]
-        weights, ids = expert_shard.route(
-            z32, moe["router"]["kernel"], n_group=c.n_group,
-            topk_group=c.topk_group, top_k=c.num_experts_per_tok,
-            scaling=c.routed_scaling_factor)
-        y, counts = expert_shard.held_experts_ffn(
-            z, weights, ids, valid, moe["experts"],
-            first_expert=c.first_expert, interpret=self.interpret,
-            tiling=tiling)
-        return y + gated_silu_mlp(moe["shared"], z, jnp.float32), counts
+        with jax.named_scope("router"):
+            weights, ids = expert_shard.route(
+                z32, moe["router"]["kernel"], n_group=c.n_group,
+                topk_group=c.topk_group, top_k=c.num_experts_per_tok,
+                scaling=c.routed_scaling_factor)
+        with jax.named_scope("experts"):
+            y, counts = expert_shard.held_experts_ffn(
+                z, weights, ids, valid, moe["experts"],
+                first_expert=c.first_expert, interpret=self.interpret,
+                tiling=tiling)
+        with jax.named_scope("shared_experts"):
+            y = y + gated_silu_mlp(moe["shared"], z, jnp.float32)
+        return y, counts
 
     def _next_token(self, params, x):
         head = params["lm_head"]["kernel"]
-        x = rms_norm(params["final_norm"], x, self.config.rms_norm_eps)
-        logits = jnp.matmul(x.astype(head.dtype), head,
-                            preferred_element_type=jnp.float32)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("final_norm"):
+            x = rms_norm(params["final_norm"], x, self.config.rms_norm_eps)
+        with jax.named_scope("lm_head"):
+            logits = jnp.matmul(x.astype(head.dtype), head,
+                                preferred_element_type=jnp.float32)
+        with jax.named_scope("sample"):
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     # -- the two programs --------------------------------------------------
     def build_prefill(self, icfg, bucket_len):
@@ -342,17 +349,9 @@ class DeepseekV2Serving:
         block = math.gcd(bucket_len, self.PREFILL_BLOCK)
         n_pages = bucket_len // bs
 
-        def prefill(params, caches, input_ids, true_len, block_tables,
-                    next_tokens, slot):
-            (cache,), (block_table,) = caches, block_tables
-            s = input_ids.shape[1]
-            positions = jnp.arange(s)
-            valid = positions < true_len
-            dtype = params["embed"].dtype
-            x = jnp.take(params["embed"], input_ids[0], axis=0).astype(
-                jnp.float32)
-            for i in range(self.num_layers):
-                lp = params["layers"][f"layer_{i}"]
+        def layer(lp, x, cache, i, block_table, positions, valid, dtype):
+            s = x.shape[0]
+            with jax.named_scope("attention"):
                 h = rms_norm(lp["input_norm"], x, c.rms_norm_eps).astype(
                     dtype)
                 q_nope, q_rope = self._queries(lp, h, positions)
@@ -378,14 +377,34 @@ class DeepseekV2Serving:
                     name="mla_prefill_attention")[0]
                 x = x + jnp.matmul(ctx.reshape(s, -1), lp["o"]["kernel"],
                                    preferred_element_type=jnp.float32)
+            with jax.named_scope("mlp" if "mlp" in lp else "moe"):
                 z = rms_norm(lp["post_norm"], x, c.rms_norm_eps)
                 y, _ = self._mlp(lp, z, dtype, valid, self.PREFILL_TILING)
                 x = x + y
-            last = jax.lax.dynamic_slice(
-                x, (true_len - 1, 0), (1, c.hidden_size))
+            return x, cache
+
+        def prefill(params, caches, input_ids, true_len, block_tables,
+                    next_tokens, slot):
+            (cache,), (block_table,) = caches, block_tables
+            s = input_ids.shape[1]
+            positions = jnp.arange(s)
+            valid = positions < true_len
+            dtype = params["embed"].dtype
+            with jax.named_scope("embed"):
+                x = jnp.take(params["embed"], input_ids[0], axis=0).astype(
+                    jnp.float32)
+            for i in range(self.num_layers):
+                lp = params["layers"][f"layer_{i}"]
+                with jax.named_scope(f"layer_{i}"):
+                    x, cache = layer(lp, x, cache, i, block_table,
+                                     positions, valid, dtype)
+            with jax.named_scope("final_norm"):
+                last = jax.lax.dynamic_slice(
+                    x, (true_len - 1, 0), (1, c.hidden_size))
             token = self._next_token(params, last)[0]
-            return ({"tokens": token}, (cache,),
-                    next_tokens.at[slot].set(token))
+            with jax.named_scope("sample"):
+                next_tokens = next_tokens.at[slot].set(token)
+            return {"tokens": token}, (cache,), next_tokens
 
         return prefill
 
@@ -398,27 +417,15 @@ class DeepseekV2Serving:
         bs = icfg.kv_block_size
         n_slots = icfg.max_batch_slots
 
-        def decode(params, caches, block_tables, ctx_lens, tokens):
-            (cache,), (block_tables,) = caches, block_tables
-            dtype = params["embed"].dtype
-            x = jnp.take(params["embed"], tokens, axis=0).astype(
-                jnp.float32)
-            block_ids = jnp.take_along_axis(
-                block_tables, (ctx_lens // bs)[:, None], axis=1)[:, 0]
-            offsets = ctx_lens % bs
-            # a slot that serves a request decodes at position >= 1: the
-            # dead ones (parked at 0) are routed to no expert
-            valid = ctx_lens > 0
-            counters = []
-            for i in range(self.num_layers):
-                lp = params["layers"][f"layer_{i}"]
+        def layer(lp, x, cache, i, block_tables, ctx_lens, target, valid,
+                  dtype):
+            with jax.named_scope("attention"):
                 h = rms_norm(lp["input_norm"], x, c.rms_norm_eps).astype(
                     dtype)
                 q_nope, q_rope = self._queries(lp, h, ctx_lens)
                 _, _, rows = self._latent_rows(lp, h, ctx_lens)
                 # the append: every slot's new row in one scatter
-                cache = cache.at[i, block_ids, offsets].set(
-                    rows.astype(cache.dtype))
+                cache = cache.at[(i, *target)].set(rows.astype(cache.dtype))
                 q_abs = jnp.einsum("bhd,hdc->bhc", q_nope, lp["w_uk"])
                 pad = jnp.zeros(q_abs.shape[:2] + (self.row - c.latent_row,),
                                 q_abs.dtype)
@@ -432,15 +439,41 @@ class DeepseekV2Serving:
                 o = jnp.einsum("bhc,hcd->bhd", u, lp["w_uv"])
                 x = x + jnp.matmul(o.reshape(n_slots, -1), lp["o"]["kernel"],
                                    preferred_element_type=jnp.float32)
+            with jax.named_scope("mlp" if "mlp" in lp else "moe"):
                 z = rms_norm(lp["post_norm"], x, c.rms_norm_eps)
                 y, counts = self._mlp(lp, z, dtype, valid,
                                       self.DECODE_TILING)
                 x = x + y
-                if counts is not None:
-                    counters.append(expert_shard.load_counters(counts))
+                load = (None if counts is None
+                        else expert_shard.load_counters(counts))
+            return x, cache, load
+
+        def decode(params, caches, block_tables, ctx_lens, tokens):
+            (cache,), (block_tables,) = caches, block_tables
+            dtype = params["embed"].dtype
+            with jax.named_scope("embed"):
+                x = jnp.take(params["embed"], tokens, axis=0).astype(
+                    jnp.float32)
+                block_ids = jnp.take_along_axis(
+                    block_tables, (ctx_lens // bs)[:, None], axis=1)[:, 0]
+                offsets = ctx_lens % bs
+                # a slot that serves a request decodes at position >= 1:
+                # the dead ones (parked at 0) are routed to no expert
+                valid = ctx_lens > 0
+            counters = []
+            for i in range(self.num_layers):
+                lp = params["layers"][f"layer_{i}"]
+                with jax.named_scope(f"layer_{i}"):
+                    x, cache, load = layer(
+                        lp, x, cache, i, block_tables, ctx_lens,
+                        (block_ids, offsets), valid, dtype)
+                if load is not None:
+                    counters.append(load)
             out = {"tokens": self._next_token(params, x)}
             if counters:
-                share, peak = (jnp.mean(jnp.stack(v)) for v in zip(*counters))
+                with jax.named_scope("sample"):
+                    share, peak = (jnp.mean(jnp.stack(v))
+                                   for v in zip(*counters))
                 out["moe_local_assignment_share"] = share
                 out["moe_expert_load_max_over_mean"] = peak
             return out, (cache,)

@@ -285,26 +285,32 @@ class ExaoneMoeServing:
         if "mlp" in lp:
             return gated_silu_mlp(lp["mlp"], z, jnp.float32), None
         c, moe = self.config, lp["moe"]
-        weights, ids = expert_shard.route(
-            z32, moe["router"]["kernel"], n_group=c.n_group,
-            topk_group=c.topk_group, top_k=c.num_experts_per_tok,
-            scaling=c.routed_scaling_factor, scoring="sigmoid",
-            bias=moe["router"]["bias"], renormalise=c.norm_topk_prob)
-        y, counts = expert_shard.held_experts_ffn(
-            z, weights, ids, valid, moe["experts"],
-            first_expert=c.first_expert, interpret=self.interpret,
-            tiling=tiling)
-        nowhere = expert_shard.tokens_without_held_expert(
-            ids, valid, c.first_expert, c.experts_held)
-        return (y + gated_silu_mlp(moe["shared"], z, jnp.float32),
-                (counts, nowhere))
+        with jax.named_scope("router"):
+            weights, ids = expert_shard.route(
+                z32, moe["router"]["kernel"], n_group=c.n_group,
+                topk_group=c.topk_group, top_k=c.num_experts_per_tok,
+                scaling=c.routed_scaling_factor, scoring="sigmoid",
+                bias=moe["router"]["bias"], renormalise=c.norm_topk_prob)
+        with jax.named_scope("experts"):
+            y, counts = expert_shard.held_experts_ffn(
+                z, weights, ids, valid, moe["experts"],
+                first_expert=c.first_expert, interpret=self.interpret,
+                tiling=tiling)
+            nowhere = expert_shard.tokens_without_held_expert(
+                ids, valid, c.first_expert, c.experts_held)
+        with jax.named_scope("shared_experts"):
+            y = y + gated_silu_mlp(moe["shared"], z, jnp.float32)
+        return y, (counts, nowhere)
 
     def _next_token(self, params, x):
         head = params["lm_head"]["kernel"]
-        x = rms_norm(params["final_norm"], x, self.config.rms_norm_eps)
-        logits = jnp.matmul(x.astype(head.dtype), head,
-                            preferred_element_type=jnp.float32)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("final_norm"):
+            x = rms_norm(params["final_norm"], x, self.config.rms_norm_eps)
+        with jax.named_scope("lm_head"):
+            logits = jnp.matmul(x.astype(head.dtype), head,
+                                preferred_element_type=jnp.float32)
+        with jax.named_scope("sample"):
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     # -- the two programs --------------------------------------------------
     def build_prefill(self, icfg, bucket_len):
@@ -323,23 +329,14 @@ class ExaoneMoeServing:
         blocks = {False: math.gcd(bucket_len, self.PREFILL_BLOCK),
                   True: math.gcd(bucket_len, self.WINDOW_PREFILL_BLOCK)}
 
-        def prefill(params, caches, input_ids, true_len, block_tables,
-                    next_tokens, slot):
-            caches = list(caches)
-            s = input_ids.shape[1]
-            positions = jnp.arange(s)
-            valid = positions < true_len
-            dtype = params["embed"].dtype
-            # the ring's pages that hold the last keys before true_len
-            # (not before the bucket's end): the page of the last token
-            # and the ring - 1 before it, each at its number modulo ring
-            first_page = jnp.maximum((true_len - 1) // bs - (ring - 1), 0)
-            ring_slots = (first_page + jnp.arange(ring)) % ring
-            x = jnp.take(params["embed"], input_ids[0], axis=0).astype(
-                jnp.float32)
-            for i in range(self.num_layers):
-                lp = params["layers"][f"layer_{i}"]
-                window = c.layer_types[i] == WINDOW
+        def layer(lp, x, caches, i, block_tables, positions, ring_at, valid,
+                  dtype):
+            """Layer ``i`` over the stream; its K and V pages written into
+            the list ``caches`` in place."""
+            s = x.shape[0]
+            window = c.layer_types[i] == WINDOW
+            first_page, ring_slots = ring_at
+            with jax.named_scope("attention"):
                 u = rms_norm(lp["input_norm"], x, c.rms_norm_eps).astype(
                     dtype)
                 q, k, v = self._qkv(lp, u, positions, window)
@@ -365,14 +362,39 @@ class ExaoneMoeServing:
                           else "gqa_prefill_attention"))[0]
                 x = x + jnp.matmul(ctx.reshape(s, -1), lp["o"]["kernel"],
                                    preferred_element_type=jnp.float32)
+            with jax.named_scope("mlp" if "mlp" in lp else "moe"):
                 z = rms_norm(lp["post_norm"], x, c.rms_norm_eps)
                 y, _ = self._mlp(lp, z, dtype, valid, self.PREFILL_TILING)
                 x = x + y
-            last = jax.lax.dynamic_slice(
-                x, (true_len - 1, 0), (1, c.hidden_size))
+            return x
+
+        def prefill(params, caches, input_ids, true_len, block_tables,
+                    next_tokens, slot):
+            caches = list(caches)
+            s = input_ids.shape[1]
+            positions = jnp.arange(s)
+            valid = positions < true_len
+            dtype = params["embed"].dtype
+            # the ring's pages that hold the last keys before true_len
+            # (not before the bucket's end): the page of the last token
+            # and the ring - 1 before it, each at its number modulo ring
+            first_page = jnp.maximum((true_len - 1) // bs - (ring - 1), 0)
+            ring_slots = (first_page + jnp.arange(ring)) % ring
+            with jax.named_scope("embed"):
+                x = jnp.take(params["embed"], input_ids[0], axis=0).astype(
+                    jnp.float32)
+            for i in range(self.num_layers):
+                lp = params["layers"][f"layer_{i}"]
+                with jax.named_scope(f"layer_{i}"):
+                    x = layer(lp, x, caches, i, block_tables, positions,
+                              (first_page, ring_slots), valid, dtype)
+            with jax.named_scope("final_norm"):
+                last = jax.lax.dynamic_slice(
+                    x, (true_len - 1, 0), (1, c.hidden_size))
             token = self._next_token(params, last)[0]
-            return ({"tokens": token}, tuple(caches),
-                    next_tokens.at[slot].set(token))
+            with jax.named_scope("sample"):
+                next_tokens = next_tokens.at[slot].set(token)
+            return {"tokens": token}, tuple(caches), next_tokens
 
         return prefill
 
@@ -387,28 +409,14 @@ class ExaoneMoeServing:
         ring = self._ring(icfg)
         where = self._places(icfg)
 
-        def decode(params, caches, block_tables, ctx_lens, tokens):
-            caches = list(caches)
-            dtype = params["embed"].dtype
-            x = jnp.take(params["embed"], tokens, axis=0).astype(
-                jnp.float32)
-            page = ctx_lens // bs
-            offsets = ctx_lens % bs
-            # where the new token's row goes, by cache group: its page of
-            # the whole context, or that page's place in the ring
-            targets = [
-                jnp.take_along_axis(
-                    block_tables[g], (page if group.pages is None
-                                      else page % ring)[:, None],
-                    axis=1)[:, 0]
-                for g, group in enumerate(self.cache_groups(icfg))]
-            # a slot that serves a request decodes at position >= 1: the
-            # dead ones (parked at 0) are routed to no expert
-            valid = ctx_lens > 0
-            counters = []
-            for i in range(self.num_layers):
-                lp = params["layers"][f"layer_{i}"]
-                window = c.layer_types[i] == WINDOW
+        def layer(lp, x, caches, i, block_tables, ctx_lens, target, valid,
+                  dtype):
+            """Layer ``i`` over the stream and, for a sparse layer, its
+            load counters; the new K and V rows written into the list
+            ``caches`` in place."""
+            window = c.layer_types[i] == WINDOW
+            targets, offsets = target
+            with jax.named_scope("attention"):
                 u = rms_norm(lp["input_norm"], x, c.rms_norm_eps).astype(
                     dtype)
                 q, k, v = self._qkv(lp, u, ctx_lens, window)
@@ -428,16 +436,46 @@ class ExaoneMoeServing:
                     interpret=self.interpret)
                 x = x + jnp.matmul(ctx, lp["o"]["kernel"],
                                    preferred_element_type=jnp.float32)
+            with jax.named_scope("mlp" if "mlp" in lp else "moe"):
                 z = rms_norm(lp["post_norm"], x, c.rms_norm_eps)
                 y, load = self._mlp(lp, z, dtype, valid, self.DECODE_TILING)
                 x = x + y
                 if load is not None:
-                    counters.append((*expert_shard.load_counters(load[0]),
-                                     load[1]))
+                    load = (*expert_shard.load_counters(load[0]), load[1])
+            return x, load
+
+        def decode(params, caches, block_tables, ctx_lens, tokens):
+            caches = list(caches)
+            dtype = params["embed"].dtype
+            with jax.named_scope("embed"):
+                x = jnp.take(params["embed"], tokens, axis=0).astype(
+                    jnp.float32)
+                page = ctx_lens // bs
+                offsets = ctx_lens % bs
+                # where the new token's row goes, by cache group: its page
+                # of the whole context, or that page's place in the ring
+                targets = [
+                    jnp.take_along_axis(
+                        block_tables[g], (page if group.pages is None
+                                          else page % ring)[:, None],
+                        axis=1)[:, 0]
+                    for g, group in enumerate(self.cache_groups(icfg))]
+                # a slot that serves a request decodes at position >= 1:
+                # the dead ones (parked at 0) are routed to no expert
+                valid = ctx_lens > 0
+            counters = []
+            for i in range(self.num_layers):
+                lp = params["layers"][f"layer_{i}"]
+                with jax.named_scope(f"layer_{i}"):
+                    x, load = layer(lp, x, caches, i, block_tables, ctx_lens,
+                                    (targets, offsets), valid, dtype)
+                if load is not None:
+                    counters.append(load)
             out = {"tokens": self._next_token(params, x)}
             if counters:
-                share, peak, nowhere = (jnp.mean(jnp.stack(v))
-                                        for v in zip(*counters))
+                with jax.named_scope("sample"):
+                    share, peak, nowhere = (jnp.mean(jnp.stack(v))
+                                            for v in zip(*counters))
                 out["moe_local_assignment_share"] = share
                 out["moe_expert_load_max_over_mean"] = peak
                 out["moe_tokens_without_local_expert"] = nowhere

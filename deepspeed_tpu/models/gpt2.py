@@ -168,12 +168,14 @@ class GPT2LMHeadTPU:
         """Trunk + final layernorm → [b, s, h] (pre-LM-head hidden states)."""
         c = self.config
         b, s = input_ids.shape
-        x = jnp.take(params["wte"], input_ids, axis=0) + params["wpe"][None, :s]
-        if self.compute_dtype is not None:
-            x = x.astype(self.compute_dtype)
-        if rng is not None and not deterministic:
-            rng_e, rng = jax.random.split(rng)
-            x = dropout(rng_e, x, c.embd_dropout, deterministic)
+        with jax.named_scope("embed"):
+            x = jnp.take(params["wte"], input_ids, axis=0) \
+                + params["wpe"][None, :s]
+            if self.compute_dtype is not None:
+                x = x.astype(self.compute_dtype)
+            if rng is not None and not deterministic:
+                rng_e, rng = jax.random.split(rng)
+                x = dropout(rng_e, x, c.embd_dropout, deterministic)
 
         aux_losses = []
 
@@ -204,7 +206,7 @@ class GPT2LMHeadTPU:
 
                     if ds_ckpt.should_checkpoint_layer(i, c.num_layers):
                         fn = ck_moe_layer
-                with jax.named_scope(f"layer_{i}_moe"):
+                with jax.named_scope(f"layer_{i}"):
                     x, aux = fn(params["blocks"][f"layer_{i}"], x, layer_rng)
                     aux_losses.append(aux)
                 continue
@@ -217,12 +219,14 @@ class GPT2LMHeadTPU:
             with jax.named_scope(f"layer_{i}"):
                 x = fn(params["blocks"][f"layer_{i}"], x, layer_rng)
 
-        x = layer_norm(params["ln_f"], x, c.layer_norm_eps)
+        with jax.named_scope("final_norm"):
+            x = layer_norm(params["ln_f"], x, c.layer_norm_eps)
         self._last_moe_aux = (sum(aux_losses) / len(aux_losses)
                               if aux_losses else None)
         return x
 
     @staticmethod
+    @jax.named_scope("lm_head")
     def _lm_head(params, x):
         """Tied LM head (wte shared with the input embedding; the
         reference ties them through TiedLayerSpec under pipelining)."""
@@ -254,15 +258,17 @@ class GPT2LMHeadTPU:
         @jax.checkpoint
         def one(args):
             xc, lc = args
-            logits = (xc @ w.T.astype(xc.dtype)).astype(jnp.float32)
+            with jax.named_scope("lm_head"):
+                logits = (xc @ w.T.astype(xc.dtype)).astype(jnp.float32)
             mask = lc != -100
             lse = jax.scipy.special.logsumexp(logits, axis=-1)
             gold = jnp.take_along_axis(
                 logits, jnp.where(mask, lc, 0)[..., None], axis=-1)[..., 0]
             return jnp.sum((lse - gold) * mask), jnp.sum(mask)
 
-        sums, counts = jax.lax.map(one, (xs, ls))
-        return jnp.sum(sums) / jnp.maximum(jnp.sum(counts), 1)
+        with jax.named_scope("loss"):
+            sums, counts = jax.lax.map(one, (xs, ls))
+            return jnp.sum(sums) / jnp.maximum(jnp.sum(counts), 1)
 
     def apply(self, params, batch, rng=None, train=True, **kw):
         c = self.config
@@ -292,8 +298,10 @@ class GPT2LMHeadTPU:
         if use_chunked:
             loss = self._chunked_lm_loss(params, x, labels, int(chunk))
         else:
-            loss = cross_entropy_with_logits(self._lm_head(params, x), labels,
-                                             ignore_index=-100)
+            logits = self._lm_head(params, x)
+            with jax.named_scope("loss"):
+                loss = cross_entropy_with_logits(logits, labels,
+                                                 ignore_index=-100)
         if train and getattr(self, "_last_moe_aux", None) is not None:
             # Switch load-balancing aux loss (training-only regularizer),
             # averaged over MoE blocks; eval loss stays comparable to dense

@@ -133,27 +133,31 @@ class MoEFFN:
         B, S, H = x.shape
         E, C = self.num_experts, self.capacity(S)
 
-        logits = (x.astype(jnp.float32)
-                  @ params["router"]["kernel"])  # [B, S, E] fp32 routing
-        probs = jax.nn.softmax(logits, axis=-1)
-        # grouped routing: each sequence routes independently
-        dispatch, combine, aux = jax.vmap(
-            lambda p: _router_dispatch(p, self.k, C))(probs)
-        aux = jnp.mean(aux)
+        with jax.named_scope("router"):
+            logits = (x.astype(jnp.float32)
+                      @ params["router"]["kernel"])  # [B, S, E] fp32 routing
+            probs = jax.nn.softmax(logits, axis=-1)
+            # grouped routing: each sequence routes independently
+            dispatch, combine, aux = jax.vmap(
+                lambda p: _router_dispatch(p, self.k, C))(probs)
+            aux = jnp.mean(aux)
 
         # expert-major dispatch with the group dim along for the ride; the
         # sharding constraint makes XLA move token blocks to their expert's
         # devices (all-to-all over ICI)
-        expert_in = jnp.einsum("bsec,bsh->bech", dispatch.astype(x.dtype), x)
-        expert_in = _constrain_expert(expert_in, P(None, "expert", None, None))
-        h = gelu(jnp.einsum("bech,ehi->beci", expert_in,
-                            params["fc1"]["kernel"].astype(x.dtype))
-                 + params["fc1"]["bias"].astype(x.dtype)[None, :, None, :])
-        out_e = (jnp.einsum("beci,eih->bech", h,
-                            params["fc2"]["kernel"].astype(x.dtype))
-                 + params["fc2"]["bias"].astype(x.dtype)[None, :, None, :])
-        out_e = _constrain_expert(out_e, P(None, "expert", None, None))
-        y = jnp.einsum("bsec,bech->bsh", combine.astype(x.dtype), out_e)
+        with jax.named_scope("experts"):
+            expert_in = jnp.einsum("bsec,bsh->bech", dispatch.astype(x.dtype),
+                                   x)
+            expert_in = _constrain_expert(expert_in,
+                                          P(None, "expert", None, None))
+            h = gelu(jnp.einsum("bech,ehi->beci", expert_in,
+                                params["fc1"]["kernel"].astype(x.dtype))
+                     + params["fc1"]["bias"].astype(x.dtype)[None, :, None, :])
+            out_e = (jnp.einsum("beci,eih->bech", h,
+                                params["fc2"]["kernel"].astype(x.dtype))
+                     + params["fc2"]["bias"].astype(x.dtype)[None, :, None, :])
+            out_e = _constrain_expert(out_e, P(None, "expert", None, None))
+            y = jnp.einsum("bsec,bech->bsh", combine.astype(x.dtype), out_e)
         return y, aux
 
 
@@ -216,6 +220,7 @@ class MoETransformerLayer:
         if rng is not None and not deterministic:
             r1, r2, r3 = jax.random.split(rng, 3)
 
+        @jax.named_scope("attention")
         def attention_block(p, y):
             ctx = self.attn.attention_core(p, y,
                                            key_padding_mask=key_padding_mask,
@@ -224,6 +229,7 @@ class MoETransformerLayer:
             out = dense(p["attn_out"], ctx)
             return dropout(r2, out, self.hidden_dropout_ratio, deterministic)
 
+        @jax.named_scope("moe")
         def moe_block(p, y):
             moe_out, aux = self.moe.apply(p["moe"], y)
             # residual dropout on the FFN path, matching the dense mlp_block

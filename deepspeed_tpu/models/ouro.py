@@ -184,18 +184,21 @@ class OuroServing:
         and ``v [tokens, heads, d]`` and attends, the program's own way."""
         c = self.config
         eps, dtype = c.rms_norm_eps, lp["qkv"]["kernel"].dtype
-        u = rms_norm(lp["norm_attn_in"], x, eps).astype(dtype)
-        qkv = (u @ lp["qkv"]["kernel"]).reshape(
-            x.shape[0], 3, -1, c.head_dim)
-        ctx, caches = attend(plane, rotate(qkv[:, 0], positions, c),
-                             rotate(qkv[:, 1], positions, c), qkv[:, 2],
-                             caches)
-        a = jnp.matmul(ctx, lp["o"]["kernel"],
-                       preferred_element_type=jnp.float32)
-        x = x + rms_norm(lp["norm_attn_out"], a, eps)
-        z = rms_norm(lp["norm_mlp_in"], x, eps).astype(dtype)
-        return x + rms_norm(lp["norm_mlp_out"],
-                            gated_silu_mlp(lp, z, jnp.float32), eps), caches
+        with jax.named_scope("attention"):
+            u = rms_norm(lp["norm_attn_in"], x, eps).astype(dtype)
+            qkv = (u @ lp["qkv"]["kernel"]).reshape(
+                x.shape[0], 3, -1, c.head_dim)
+            ctx, caches = attend(plane, rotate(qkv[:, 0], positions, c),
+                                 rotate(qkv[:, 1], positions, c), qkv[:, 2],
+                                 caches)
+            a = jnp.matmul(ctx, lp["o"]["kernel"],
+                           preferred_element_type=jnp.float32)
+            x = x + rms_norm(lp["norm_attn_out"], a, eps)
+        with jax.named_scope("mlp"):
+            z = rms_norm(lp["norm_mlp_in"], x, eps).astype(dtype)
+            x = x + rms_norm(lp["norm_mlp_out"],
+                             gated_silu_mlp(lp, z, jnp.float32), eps)
+        return x, caches
 
     def _ut_loop(self, params, x, caches, positions, attend):
         """The stream through every layer ``total_ut_steps`` times, the
@@ -210,9 +213,11 @@ class OuroServing:
         def ut_step(r, carry):
             x, caches, gates = carry
             for i, lp in enumerate(layers):
-                x, caches = self._layer(lp, x, caches, positions,
-                                        r * self.num_layers + i, attend)
-            x = rms_norm(params["final_norm"], x, c.rms_norm_eps)
+                with jax.named_scope(f"layer_{i}"):
+                    x, caches = self._layer(lp, x, caches, positions,
+                                            r * self.num_layers + i, attend)
+            with jax.named_scope("final_norm"):
+                x = rms_norm(params["final_norm"], x, c.rms_norm_eps)
             with jax.named_scope("exit_gate"):
                 # a sum of fp32 products, not a matmul: the MXU would
                 # round the stream to bf16
@@ -229,9 +234,11 @@ class OuroServing:
 
     def _next_token(self, params, h):
         head = params["lm_head"]["kernel"]
-        logits = jnp.matmul(h.astype(head.dtype), head,
-                            preferred_element_type=jnp.float32)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("lm_head"):
+            logits = jnp.matmul(h.astype(head.dtype), head,
+                                preferred_element_type=jnp.float32)
+        with jax.named_scope("sample"):
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     # -- the two programs --------------------------------------------------
     def build_prefill(self, icfg, bucket_len):
@@ -265,15 +272,18 @@ class OuroServing:
                     name="loop_prefill_attention")[0]
                 return ctx.reshape(s, -1), caches
 
-            x = jnp.take(params["embed"], input_ids[0], axis=0).astype(
-                jnp.float32)
+            with jax.named_scope("embed"):
+                x = jnp.take(params["embed"], input_ids[0], axis=0).astype(
+                    jnp.float32)
             h, caches, _ = self._ut_loop(params, x, caches, jnp.arange(s),
                                          attend)
-            last = jax.lax.dynamic_slice(
-                h, (true_len - 1, 0), (1, c.hidden_size))
+            with jax.named_scope("final_norm"):
+                last = jax.lax.dynamic_slice(
+                    h, (true_len - 1, 0), (1, c.hidden_size))
             token = self._next_token(params, last)[0]
-            return ({"tokens": token}, caches,
-                    next_tokens.at[slot].set(token))
+            with jax.named_scope("sample"):
+                next_tokens = next_tokens.at[slot].set(token)
+            return {"tokens": token}, caches, next_tokens
 
         return prefill
 
@@ -306,21 +316,25 @@ class OuroServing:
                     interpret=self.interpret)
                 return ctx, (k_cache, v_cache)
 
-            x = jnp.take(params["embed"], tokens, axis=0).astype(
-                jnp.float32)
+            with jax.named_scope("embed"):
+                x = jnp.take(params["embed"], tokens, axis=0).astype(
+                    jnp.float32)
             h, caches, gates = self._ut_loop(params, x, caches, ctx_lens,
                                              attend)
-            # a slot that serves a request decodes at position >= 1
-            live = (ctx_lens > 0).astype(jnp.float32)
-            mass = (exit_masses(gates) * live).sum(axis=1) \
-                / jnp.maximum(live.sum(), 1.0)
-            out = {"tokens": self._next_token(params, h),
-                   "ut_steps": jnp.float32(c.total_ut_steps),
-                   "cache_planes": jnp.float32(c.cache_planes),
-                   "exit_step_mean": jnp.sum(
-                       mass * jnp.arange(1.0, c.total_ut_steps + 1.0))}
-            for r in range(c.total_ut_steps):
-                out[f"exit_mass_step_{r + 1}"] = mass[r]
+            with jax.named_scope("sample"):
+                # a slot that serves a request decodes at position >= 1
+                live = (ctx_lens > 0).astype(jnp.float32)
+                mass = (exit_masses(gates) * live).sum(axis=1) \
+                    / jnp.maximum(live.sum(), 1.0)
+            out = {"tokens": self._next_token(params, h)}
+            with jax.named_scope("sample"):
+                out.update(
+                    ut_steps=jnp.float32(c.total_ut_steps),
+                    cache_planes=jnp.float32(c.cache_planes),
+                    exit_step_mean=jnp.sum(
+                        mass * jnp.arange(1.0, c.total_ut_steps + 1.0)))
+                for r in range(c.total_ut_steps):
+                    out[f"exit_mass_step_{r + 1}"] = mass[r]
             return out, caches
 
         return decode

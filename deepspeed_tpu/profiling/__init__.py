@@ -10,8 +10,6 @@ from .memory import (HostBufferRegistry, MemoryLedger, device_memory_summary,
                      see_memory_usage)
 from .overlap import analyze_hlo, parse_hlo_transfers, transfer_summary
 from .sharding import analyze_sharding, entry_parameters
-from .step_profiler import (model_scope_breakdown, timed_loop, timed_scan,
-                            wall_breakdown)
 from .utilization import (PEAK_TFLOPS, chip_peak_tflops, chip_specs,
                           model_flops_utilization)
 
@@ -20,8 +18,7 @@ __all__ = ["CommLedger", "collective_summary", "parse_hlo_collectives",
            "read_fleet_latencies", "fleet_skew",
            "DeepSpeedFlopsProfilerConfig", "DeepSpeedProfilingConfig",
            "FlopsProfiler", "count_fn_flops", "get_model_profile",
-           "wall_breakdown", "model_scope_breakdown", "timed_loop",
-           "timed_scan", "MemoryLedger", "HostBufferRegistry",
+           "MemoryLedger", "HostBufferRegistry",
            "device_memory_summary", "see_memory_usage", "PEAK_TFLOPS",
            "chip_peak_tflops", "chip_specs",
            "model_flops_utilization", "analyze_hlo",

@@ -189,13 +189,30 @@ def get_model_profile(model=None, batch=None, params=None, fn=None, args=None,
 
 class FlopsProfile:
     def __init__(self, flops, macs, params, by_scope=None, wall_ms=None,
-                 backend_cost=None):
+                 backend_cost=None, device_ms_by_scope=None):
         self.flops = flops
         self.macs = macs
         self.params = params
         self.by_scope = by_scope or {}
         self.wall_ms = wall_ms
         self.backend_cost = backend_cost or {}
+        # {(scope path, direction): device ms a step}, as
+        # telemetry.scopes.ms_per_run gives them from a scopes.json
+        self.device_ms_by_scope = device_ms_by_scope or {}
+
+    def by_program_scope(self):
+        """The FLOPs by the program's own scopes and direction (``by_scope``
+        is keyed by JAX's name stacks; ``telemetry.scopes.scope_of`` reads
+        both the same way), beside the device milliseconds where given:
+        ``{(path, direction): (flops, ms or None)}``."""
+        from ...telemetry.scopes import scope_of
+
+        flops = defaultdict(int)
+        for stack, count in self.by_scope.items():
+            # scope_of drops the primitive at a name's end: stand one in
+            flops[scope_of(f"{stack}/_")] += count
+        return {key: (count, self.device_ms_by_scope.get(key))
+                for key, count in flops.items()}
 
     def achieved_tflops(self):
         if not self.wall_ms:
@@ -231,6 +248,17 @@ class FlopsProfile:
         scopes = sorted(self.by_scope.items(), key=lambda kv: -kv[1])
         for name, fl in scopes[:top_modules]:
             log(f"  {100.0 * fl / max(self.flops, 1):5.1f}%  {_fmt(fl)}FLOPs  {name}")
+        if not self.device_ms_by_scope:
+            return
+        # the reference's latency column: device time and achieved rate
+        # beside each scope's FLOPs
+        rows = sorted(self.by_program_scope().items(),
+                      key=lambda kv: -(kv[1][1] or 0.0))
+        for (path, direction), (fl, ms) in rows[:max(top_modules, 10)]:
+            name = path + (f".{direction}" if direction else "")
+            rate = (f"{ms:9.3f} ms  {fl / (ms * 1e-3) / 1e12:7.2f} TFLOP/s"
+                    if ms else "        - ms        - TFLOP/s")
+            log(f"  {_fmt(fl):>9}FLOPs  {rate}  {name or '<top>'}")
 
 
 class FlopsProfiler:
@@ -243,7 +271,13 @@ class FlopsProfiler:
         self.engine = engine
         self.profile = None
 
-    def profile_train_step(self, batch, wall_ms=None):
+    def profile_train_step(self, batch, wall_ms=None,
+                           device_ms_by_scope=None):
+        """``device_ms_by_scope``: ``{(scope path, direction): ms a step}``
+        of a device trace read by scope (``telemetry.scopes.ms_per_run`` of
+        the ``jit_train_step`` entry of a trigger's ``scopes.json``); the
+        profile then prints device ms and achieved TFLOP/s beside each
+        scope's FLOPs.  No trace is started here."""
         eng = self.engine
         flops, by_scope = count_fn_flops(
             eng._fwd_bwd_fn, eng._forward_params(), eng._shard_batch(batch),
@@ -251,15 +285,17 @@ class FlopsProfiler:
         # optimizer apply cost (elementwise over the flat space); a
         # master-shaped placeholder stands in for the gradient operand
         flat_g_like = eng.state["master"]
-        apply_flops, _ = count_fn_flops(
+        apply_flops, apply_scopes = count_fn_flops(
             eng._apply_fn, eng.state["master"], eng.state["opt"],
             eng.state["scale"], eng.state["skipped"], flat_g_like,
             eng._device_hyperparams(), eng._segment_ids)
+        for scope, count in apply_scopes.items():
+            by_scope[scope] = by_scope.get(scope, 0) + count
         total = flops * eng.gradient_accumulation_steps() + apply_flops
         self.profile = FlopsProfile(
             flops=total, macs=total // 2,
             params=params_count(eng._param_template), by_scope=by_scope,
-            wall_ms=wall_ms)
+            wall_ms=wall_ms, device_ms_by_scope=device_ms_by_scope)
         return self.profile
 
     def print_model_profile(self, batch=None, top_modules=3):

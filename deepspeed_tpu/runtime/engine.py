@@ -1533,6 +1533,18 @@ class DeepSpeedEngine:
 
         return verify_engine_programs(self)
 
+    def program_scopes(self):
+        """``{module name as a device trace prints it ("jit_train_step"):
+        {instruction: (scope path, direction)}}`` of every program the
+        memory ledger has compiled so far (``telemetry/scopes.py``): the
+        join a trace needs to be read by the program's own scopes.  Built
+        when asked, from the compiled programs' text — nothing at set-up,
+        nothing on the step path; empty where the ledger keeps no program
+        (``profiling.memory_ledger``, on with telemetry)."""
+        from ..telemetry import scopes
+
+        return scopes.program_scopes(self.memory_ledger.compiled_programs())
+
     def _sample_comm_skew(self):
         """Per-rank step-latency export + cross-rank skew at the
         steps_per_print cadence.  Everything here is host arithmetic on
@@ -2696,6 +2708,7 @@ class DeepSpeedEngine:
             out = tuple(hostgs) if type(hostg) is tuple else hostgs[0]
             return out, sq, finite
 
+        @jax.named_scope("optimizer")
         def apply_update_hostg(master, opt_state, scale_state, skipped,
                                hostg, sq, finite, hp, qres=None):
             """The offload_gradients update: gradients stream back from
@@ -2731,6 +2744,7 @@ class DeepSpeedEngine:
             return (new_master, new_opt, scale_state, skipped, overflow,
                     gnorm, qres, cast_list)
 
+        @jax.named_scope("cast_params")
         def cast_params(master):
             if self._comm_overlap:
                 # bucketed overlap_comm layout: per-allgather-group
@@ -2872,7 +2886,8 @@ class DeepSpeedEngine:
                     return (loss.astype(jnp.float32) * cur_scale_) / grad_acc
 
                 sloss, grads = jax.value_and_grad(scaled_loss)(params_)
-                exchanged, drops = exchange(grads, batch_)
+                with jax.named_scope("grad_exchange"):
+                    exchanged, drops = exchange(grads, batch_)
                 return jax.lax.pmean(sloss, DATA_AXIS), exchanged, drops
 
             rep = P()
@@ -2885,7 +2900,8 @@ class DeepSpeedEngine:
             # the per-leaf drop counts flow OUT of the compiled program
             # (no device callback in the step program) and the engine
             # reports them host-side — see _check_sparse_overflow
-            flat_g = self.flat.flatten_grads(grads)
+            with jax.named_scope("grad_flatten"):
+                flat_g = self.flat.flatten_grads(grads)
             flat_g = jax.lax.with_sharding_constraint(flat_g, grad_sharding)
             return sloss * grad_acc / cur_scale, flat_g, drops
 
@@ -2939,12 +2955,15 @@ class DeepSpeedEngine:
                 # frees later leaves first, so the first-issued bucket
                 # is ready while earlier layers still differentiate
                 for bi in reversed(range(bucket_plan.n_buckets)):
-                    block = bucket_plan.bucket_block_from_leaves(
-                        leaves, bi, jnp.float32)
-                    pieces[bi] = jax.lax.psum_scatter(
-                        block, DATA_AXIS, scatter_dimension=0,
-                        tiled=True) * inv_dp
-                local = jnp.concatenate(pieces, axis=0)
+                    with jax.named_scope("grad_flatten"):
+                        block = bucket_plan.bucket_block_from_leaves(
+                            leaves, bi, jnp.float32)
+                    with jax.named_scope("grad_exchange"):
+                        pieces[bi] = jax.lax.psum_scatter(
+                            block, DATA_AXIS, scatter_dimension=0,
+                            tiled=True) * inv_dp
+                with jax.named_scope("grad_flatten"):
+                    local = jnp.concatenate(pieces, axis=0)
                 return jax.lax.pmean(sloss, DATA_AXIS), local
 
             sloss, flat_g = shard_map(
@@ -3047,7 +3066,9 @@ class DeepSpeedEngine:
                 m_out = jnp.concatenate(new_m, axis=0)
                 flats_out = tuple(jnp.concatenate(f, axis=0)
                                   for f in new_flats)
-                cast = (_gather_cast_leaves(m_out) if want_cast else ())
+                with jax.named_scope("cast_params"):
+                    cast = (_gather_cast_leaves(m_out) if want_cast
+                            else ())
                 return m_out, flats_out, tuple(scalars_out or ()), cast
 
             n_scalars = len(opt_leaves) - len(flat_idx)
@@ -3098,7 +3119,10 @@ class DeepSpeedEngine:
                                          jax.lax.axis_index(DATA_AXIS))
 
                 def scaled_loss(m):
-                    leaves = _gather_cast_leaves(m, remat=True)
+                    # the per-group gathers; their transpose is the
+                    # gradient's reduce-scatter
+                    with jax.named_scope("grad_exchange"):
+                        leaves = _gather_cast_leaves(m, remat=True)
                     p = jax.tree_util.tree_unflatten(param_treedef,
                                                      list(leaves))
                     loss = self._loss_fn(p, batch_, rng=key, train=True,
@@ -3120,6 +3144,7 @@ class DeepSpeedEngine:
                 batch, rng, cur_scale, extra, master)
             return sloss * grad_acc / cur_scale, flat_g, {}
 
+        @jax.named_scope("loss_and_grads")
         def loss_and_flat_grads(params, batch, rng, cur_scale, extra):
             if sparse_paths:
                 return sparse_loss_and_flat_grads(params, batch, rng,
@@ -3138,11 +3163,17 @@ class DeepSpeedEngine:
                 return (loss.astype(jnp.float32) * cur_scale) / grad_acc
 
             sloss, grads = jax.value_and_grad(scaled_loss)(params)
-            flat_g = self.flat.flatten_grads(grads, dtype=grad_flat_dtype)
-            flat_g = jax.lax.with_sharding_constraint(flat_g, grad_sharding)
+            with jax.named_scope("grad_flatten"):
+                flat_g = self.flat.flatten_grads(grads, dtype=grad_flat_dtype)
+            # GSPMD places the reduce-scatter / all-reduce where the flat
+            # buffer meets its sharding
+            with jax.named_scope("grad_exchange"):
+                flat_g = jax.lax.with_sharding_constraint(flat_g,
+                                                          grad_sharding)
             loss = sloss * grad_acc / cur_scale
             return loss, flat_g, {}
 
+        @jax.named_scope("loss_and_grads")
         def loss_and_grads_tree(params, batch, rng, cur_scale, extra):
             """offload_gradients path: returns the raw gradient TREE (no
             device flatten — grads_tree_to_host streams it out leaf-wise)."""
@@ -3185,6 +3216,7 @@ class DeepSpeedEngine:
             "accum", jax.jit(accum, donate_argnums=accum_donate,
                              out_shardings=grad_sharding))
 
+        @jax.named_scope("optimizer")
         def apply_update(master, opt_state, scale_state, skipped, flat_g, hp,
                          segment_ids, qres=None, want_cast=False):
             inv = 1.0 / scale_state.cur_scale
@@ -3323,18 +3355,23 @@ class DeepSpeedEngine:
                     cast_params(master)
             else:
                 fwd_params = params
-            batches = _unpack_batches(packed, unpack_spec)
-            rng = jax.random.fold_in(base_rng,
-                                     ustep * jnp.uint32(acc_steps))
+            with jax.named_scope("unpack"):
+                batches = _unpack_batches(packed, unpack_spec)
+                rng = jax.random.fold_in(base_rng,
+                                         ustep * jnp.uint32(acc_steps))
 
             if offload_grads_mode:
                 # capacity path: grads stream to pinned host as the
                 # backward frees them; the update streams them back per
                 # chunk.  acc_steps == 1 enforced at init.
-                one = jax.tree_util.tree_map(lambda x: x[0], batches)
+                with jax.named_scope("unpack"):
+                    one = jax.tree_util.tree_map(lambda x: x[0], batches)
                 loss, grads = loss_and_grads_tree(fwd_params, one, rng,
                                                   cur_scale, extra)
-                hostgrad, sq, finite = grads_tree_to_host(grads, hostgrad)
+                with jax.named_scope("loss_and_grads"), \
+                        jax.named_scope("grad_flatten"):
+                    hostgrad, sq, finite = grads_tree_to_host(grads,
+                                                              hostgrad)
                 del grads
                 (master, opt_state, scale_state, skipped, overflow,
                  gnorm, qres, cast_list) = apply_update_hostg(
@@ -3362,11 +3399,14 @@ class DeepSpeedEngine:
                 drops_acc = {k: (jnp.maximum(v, drops[k]) if k in drops
                                  else v)
                              for k, v in drops_acc.items()}
-                return (acc + flat_g, i + 1, drops_acc), loss
+                with jax.named_scope("loss_and_grads"):
+                    acc = acc + flat_g
+                return (acc, i + 1, drops_acc), loss
 
             drops0 = {k: jnp.asarray(0, jnp.int32) for k in sparse_paths}
             if acc_steps == 1:
-                one = jax.tree_util.tree_map(lambda x: x[0], batches)
+                with jax.named_scope("unpack"):
+                    one = jax.tree_util.tree_map(lambda x: x[0], batches)
                 loss, flat_g, drops = loss_and_flat_grads(fwd_params, one, rng,
                                                           cur_scale, extra)
                 losses = loss[None]
@@ -4032,7 +4072,8 @@ class DeepSpeedEngine:
                 self.telemetry.counter("train/overflow_steps").inc()
             self.telemetry.histogram("train/host_step_secs").observe(
                 time.perf_counter() - t_host0)
-            self.telemetry.poll_device_trace(self.global_steps)
+            self.telemetry.poll_device_trace(self.global_steps,
+                                             self.program_scopes)
         self._step_beat()
         return loss
 

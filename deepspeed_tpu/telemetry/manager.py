@@ -141,9 +141,9 @@ class TelemetryManager:
         ``telemetry.trace`` on, also an event in the Chrome-trace file."""
         return program_span(self.tracer, name, args)
 
-    def poll_device_trace(self, step=None):
+    def poll_device_trace(self, step=None, program_scopes=None):
         if self.device_trace is not None:
-            self.device_trace.poll(step)
+            self.device_trace.poll(step, program_scopes)
 
     # --------------------------------------------------------- shutdown
     def flush(self, reason=None):

@@ -23,7 +23,11 @@ Two complementary tools behind it:
   ``SIGUSR2`` when the engine could install the handler — and the next
   :meth:`poll` starts ``jax.profiler.start_trace`` into the run dir,
   stopping automatically after ``max_secs``.  Polling is one
-  ``os.path.exists`` per step (only when tracing is configured).
+  ``os.path.exists`` per step (only when tracing is configured).  When
+  it stops a trace the engine handed it its ``program_scopes`` for, the
+  trace is reduced by the program's own scopes (:mod:`.scopes`) on a
+  worker thread: ``<run_dir>/device_trace/scopes.json`` and one log line
+  of the ten largest.
 """
 
 import json
@@ -193,7 +197,9 @@ class DeviceTraceTrigger:
     - trigger file present and no trace running → start a device trace
       into ``<run_dir>/device_trace/`` and delete the trigger (one
       touch, one trace);
-    - trace running for more than ``max_secs`` → stop it.
+    - trace running for more than ``max_secs`` → stop it and, where the
+      caller gave ``program_scopes`` (an engine's method of that name),
+      reduce it by scope into ``device_trace/scopes.json``.
 
     Everything is best-effort with loud logging: profiling must never
     take training down.
@@ -218,6 +224,7 @@ class DeviceTraceTrigger:
         self._polls = 0
         self._deadline = None
         self._signal_flag = False
+        self._reducer = None
 
     def request(self):
         """Programmatic trigger (e.g. from a SIGUSR2 handler)."""
@@ -227,12 +234,20 @@ class DeviceTraceTrigger:
     def active(self):
         return self._deadline is not None
 
-    def poll(self, step=None):
+    def poll(self, step=None, program_scopes=None):
         """Start/stop the device trace as the trigger + deadline dictate;
-        returns True while a trace is running."""
+        returns True while a trace is running.  ``program_scopes``: a
+        callable that gives the engine's scope maps, called (off this
+        thread) only when a trace has just stopped."""
         if self._deadline is not None:
             if time.monotonic() >= self._deadline:
                 self._stop(step)
+                if program_scopes is not None:
+                    self._reducer = threading.Thread(
+                        target=self._reduce_by_scope,
+                        args=(program_scopes,), daemon=True,
+                        name="device-trace-scopes")
+                    self._reducer.start()
             return self._deadline is not None
         self._polls += 1
         if not self._signal_flag and self._polls % self.check_every:
@@ -268,6 +283,39 @@ class DeviceTraceTrigger:
             logger.error("device trace stop failed: %s", e)
         self._deadline = None
 
+    def _reduce_by_scope(self, program_scopes):
+        """The stopped trace by the program's scopes: ``scopes.json``
+        beside it and the ten largest in the log.  The one place the
+        program reads an ``.xplane.pb``."""
+        from . import scopes
+
+        try:
+            reduced = scopes.write_scopes_json(self.out_dir,
+                                               program_scopes())
+        except Exception as e:  # noqa: BLE001 — profiling is best-effort
+            logger.error("device trace by scope failed: %s", e)
+            return
+        if reduced is None:
+            logger.warning("device trace by scope: no .xplane.pb under %s",
+                           self.out_dir)
+            return
+        busy = sum(p["seconds"] for p in reduced.values())
+        unplaced = sum(p["unplaced_s"] for p in reduced.values())
+        logger.info(
+            "device trace by scope (%s; %.3f s of operations, %.1f%% in no "
+            "scope): %s", os.path.join(self.out_dir, "scopes.json"), busy,
+            100.0 * unplaced / busy if busy else 0.0,
+            ", ".join(f"{module}:{scope or '-'}{'.' + d if d else ''} "
+                      f"{seconds:.4f}"
+                      for module, scope, d, seconds in
+                      scopes.largest(reduced)))
+
+    def wait(self, timeout=None):
+        """Join the reduction of the last stopped trace, if one runs."""
+        if self._reducer is not None:
+            self._reducer.join(timeout)
+
     def close(self):
         if self._deadline is not None:
             self._stop(None)
+        self.wait(timeout=60.0)

@@ -67,7 +67,7 @@ register_rule(Rule(
     rationale="Evaluates once at trace time; every execution of the "
               "compiled program sees the same frozen timestamp.",
     autofix_hint="Time around the dispatch on the host, fencing with a "
-                 "device_get of an output (see profiling/step_profiler)."))
+                 "device_get of an output (see utils/timer.device_fence)."))
 
 register_rule(Rule(
     id="DSH106", name="hot-device-loop", severity="error",

@@ -665,7 +665,13 @@ def _lowered_step(cell, sharding, layers=None):
     import deepspeed_tpu as deepspeed
     from benchmarks import common, generators, models, train
     from deepspeed_tpu.parallel import make_mesh
+    from deepspeed_tpu.parallel.mesh import (get_current_mesh,
+                                             set_current_mesh)
 
+    # the engine registers its mesh of DESCRIBED devices as the current
+    # one: left behind, every later test of this worker would build its
+    # programs for a TPU
+    mesh_before = get_current_mesh()
     spec = common.load_cell(cell)
     cfg, traffic = spec["config"], spec["traffic"]
     mc = dict(cfg["model_config"])
@@ -685,6 +691,7 @@ def _lowered_step(cell, sharding, layers=None):
             {k: np.asarray(v) for k, v in batch.items()})
     finally:
         engine.close()
+        set_current_mesh(mesh_before)
     return (lowered, batch_rows, mc["num_attention_heads"],
             traffic["seq_len"])
 
