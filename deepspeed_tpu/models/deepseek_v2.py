@@ -322,7 +322,7 @@ class DeepseekV2Serving:
             y, counts = expert_shard.held_experts_ffn(
                 z, weights, ids, valid, moe["experts"],
                 first_expert=c.first_expert, interpret=self.interpret,
-                tiling=tiling)
+                tiling=tiling, routed=moe["router"]["kernel"].shape[-1])
         with jax.named_scope("shared_experts"):
             y = y + gated_silu_mlp(moe["shared"], z, jnp.float32)
         return y, counts
@@ -444,8 +444,12 @@ class DeepseekV2Serving:
                 y, counts = self._mlp(lp, z, dtype, valid,
                                       self.DECODE_TILING)
                 x = x + y
-                load = (None if counts is None
-                        else expert_shard.load_counters(counts))
+                load = None if counts is None else (
+                    *expert_shard.load_counters(counts),
+                    expert_shard.pair_passes(
+                        counts, n_slots * c.num_experts_per_tok,
+                        lp["moe"]["router"]["kernel"].shape[-1],
+                        self.DECODE_TILING[0]))
             return x, cache, load
 
         def decode(params, caches, block_tables, ctx_lens, tokens):
@@ -472,10 +476,12 @@ class DeepseekV2Serving:
             out = {"tokens": self._next_token(params, x)}
             if counters:
                 with jax.named_scope("sample"):
-                    share, peak = (jnp.mean(jnp.stack(v))
-                                   for v in zip(*counters))
+                    share, peak, passes = (
+                        jnp.mean(jnp.stack(v).astype(jnp.float32))
+                        for v in zip(*counters))
                 out["moe_local_assignment_share"] = share
                 out["moe_expert_load_max_over_mean"] = peak
+                out["moe_pair_passes"] = passes
             return out, (cache,)
 
         return decode

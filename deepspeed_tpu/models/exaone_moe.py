@@ -295,7 +295,7 @@ class ExaoneMoeServing:
             y, counts = expert_shard.held_experts_ffn(
                 z, weights, ids, valid, moe["experts"],
                 first_expert=c.first_expert, interpret=self.interpret,
-                tiling=tiling)
+                tiling=tiling, routed=moe["router"]["kernel"].shape[-1])
             nowhere = expert_shard.tokens_without_held_expert(
                 ids, valid, c.first_expert, c.experts_held)
         with jax.named_scope("shared_experts"):
@@ -441,7 +441,12 @@ class ExaoneMoeServing:
                 y, load = self._mlp(lp, z, dtype, valid, self.DECODE_TILING)
                 x = x + y
                 if load is not None:
-                    load = (*expert_shard.load_counters(load[0]), load[1])
+                    load = (
+                        *expert_shard.load_counters(load[0]), load[1],
+                        expert_shard.pair_passes(
+                            load[0], n_slots * c.num_experts_per_tok,
+                            lp["moe"]["router"]["kernel"].shape[-1],
+                            self.DECODE_TILING[0]))
             return x, load
 
         def decode(params, caches, block_tables, ctx_lens, tokens):
@@ -474,11 +479,13 @@ class ExaoneMoeServing:
             out = {"tokens": self._next_token(params, x)}
             if counters:
                 with jax.named_scope("sample"):
-                    share, peak, nowhere = (jnp.mean(jnp.stack(v))
-                                            for v in zip(*counters))
+                    share, peak, nowhere, passes = (
+                        jnp.mean(jnp.stack(v).astype(jnp.float32))
+                        for v in zip(*counters))
                 out["moe_local_assignment_share"] = share
                 out["moe_expert_load_max_over_mean"] = peak
                 out["moe_tokens_without_local_expert"] = nowhere
+                out["moe_pair_passes"] = passes
             return out, tuple(caches)
 
         return decode

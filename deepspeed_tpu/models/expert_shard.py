@@ -11,20 +11,54 @@ not; one group, so the group limit does nothing).
 
 The layer is told which experts it holds (``first_expert``, and as many as
 its weights have: a routing group, or any contiguous slice).  Every token
-is routed over all the experts the router scores;
-the (token, choice) pairs that fall to held experts are sorted by expert
-and multiplied by a grouped matrix product
-(``ops/transformer/grouped_matmul.py``) — no capacity, so no token is ever
-dropped, whatever the imbalance; pairs that fall to experts held elsewhere
-are left out of the sum, and no code stands in for the other chips or for
-their exchange.  ``models/moe.py`` is the other expert layer: GShard
-capacity routing that drops overflow, for training.
+is routed over all the experts the router scores; the (token, choice) pairs
+that fall to held experts are sorted by expert and multiplied by a grouped
+matrix product (``ops/transformer/grouped_matmul.py``); pairs that fall to
+experts held elsewhere are left out of the sum, and no code stands in for
+the other chips or for their exchange.
+
+Only the pairs held here are moved.  One chip of ``n`` holds ``1/n`` of the
+pairs on average, so :func:`held_experts_ffn` gathers, multiplies and
+combines ``capacity = 2 * (held / routed experts) * tokens * top_k`` sorted
+pairs at a time (whole row tiles, never more than there are:
+:func:`pair_capacity`, from shapes alone) and forms no array of ``tokens *
+top_k`` rows.  That is a size of the buffers, not a limit on the routing:
+held pairs past one pass are computed by further trips of the same loop
+(:func:`pair_passes`: 1.0 in the decode programs' counters wherever the
+capacity held), so no token is ever dropped, whatever the
+imbalance.  ``models/moe.py`` is the other expert layer: GShard capacity
+routing that drops overflow, for training.
+
+Readings that chose the form (on a v5e; PERF.md section 6, PR 39).  One
+DeepSeek-V2 expert layer, 8,192 tokens, 6 choices, 20 of 160 experts:
+moving every pair took 16.9 ms, of it 3.7 ms the gather back of 49,152
+rows, 2.9 ms the relayout of the float32 ``[tokens, 6, hidden]`` array and
+2.2 ms its weighted sum.  The way out as ``top_k`` gathers of ``[tokens,
+hidden]`` from the compact output, summed in one fusion: 10.1 ms.  As a
+scatter-add of the ``capacity`` weighted rows: 21.9 ms — XLA's row scatter
+with repeated indices runs row after row, 1.4 us each.  The first pass
+outside the loop would save 0.5 ms of such a layer (the carried sum is
+zeroed and read once more) and cost set-up more than that is worth: a
+program that holds the body twice lowered 1.3 s and loaded 1.2 s slower in
+K-EXAONE's cell, 7% of a warm set-up.  In the decode programs any control
+flow in the layer — the loop, or a ``cond`` around it, even when it never
+runs — costs 0.1 to 0.3 ms a step: XLA no longer prefetches the shared
+experts' and the next layer's weights across it (48 of 104 ``slice-start``
+gone from DeepSeek-V2's compiled step).  A capacity of one row tile is a
+decode step's, whose pairs are a few tiles in all and cost microseconds to
+move: there a pass takes every pair, the program holds no loop, and the
+way out stays one gather of all the pairs with their weighted sum —
+``top_k`` gathers of 64 rows cost 26 to 42 us a layer more than that
+(1.367 for 1.341 ms, 1.753 for 1.711).
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
 
 from ..ops.transformer.grouped_matmul import moe_grouped_matmul
+from ..utils.logging import logger
 from .layers import gated_silu
 
 
@@ -70,39 +104,122 @@ def route(x, router_kernel, *, n_group, topk_group, top_k, scaling,
     return scaling * weights, ids
 
 
+def pair_capacity(pairs, held, routed, tile):
+    """Rows one pass over the sorted pairs holds: twice this chip's mean
+    share of the ``pairs`` (``held`` of the ``routed`` experts the router
+    scores), rounded up to the row ``tile``, never above ``pairs`` — and
+    all of them where that is one tile (a decode step: see the module's
+    readings) or ``routed`` is not given.  From shapes alone: 12,288 of
+    49,152 for 8,192 tokens at 6 choices and 20 of 160, 384 of 384 for
+    64."""
+    if routed is None:
+        return pairs
+    capacity = -(-2 * held * pairs // (routed * tile)) * tile
+    return capacity if tile < capacity < pairs else pairs
+
+
+def pair_passes(counts, pairs, routed, tile):
+    """Passes over the ``pairs`` sorted pairs that :func:`held_experts_ffn`
+    makes for ``counts`` as it returns them (int32 scalar): 1 while the
+    held pairs fit one pass's capacity, ``ceil(held pairs / capacity)``
+    past that."""
+    capacity = pair_capacity(pairs, counts.size - 1, routed, tile)
+    return jnp.maximum(-(-counts[:-1].sum() // capacity), 1)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "first_expert", "interpret", "tiling", "routed"))
 def held_experts_ffn(x, weights, ids, valid, experts, *, first_expert,
-                     interpret, tiling):
+                     interpret, tiling, routed=None):
     """``sum over the chosen experts held here of w_e F_e(x)`` for
     ``x [tokens, hidden]``, with what the sum ran over.
 
     ``experts`` holds ``gate_up [held, hidden, 2 * width]`` and
     ``down [held, width, hidden]``; ``valid [tokens]`` marks the rows that
-    are tokens (a bucket's padding and dead slots are routed nowhere).
+    are tokens (a bucket's padding and dead slots are routed nowhere);
+    ``routed`` is the number of experts the router scores (its kernel's
+    width), which sets the rows of a pass (:func:`pair_capacity`).
     Returns ``(y [tokens, hidden] in fp32, counts)``, ``counts`` the number
     of pairs each held expert got, then of the pairs held elsewhere.
+
+    The pairs are sorted by expert, the held ones first.  A pass gathers
+    ``x`` for ``capacity`` sorted pairs, runs both grouped products over
+    the held experts' rows among them (one last group fills the pass and
+    is computed by nobody), and adds ``w[t, j] * out[place of (t, j)]`` to
+    ``y`` in fp32, the choices ``j`` in their own order, a pair of another
+    pass or another chip masked: ``top_k`` gathers of ``[tokens, hidden]``
+    summed in one fusion.  The passes are :func:`pair_passes` trips of one
+    loop — one wherever the capacity holds — so no pair is dropped under
+    any routing.  Where a pass holds every pair (a decode step) there is
+    no loop, and the way out is one gather of all the pairs and their
+    weighted sum: the program the layer always was.  Traced once a
+    geometry: a model's expert layers share the equations, and one log
+    line says what capacity a traced geometry got.
     """
     tokens, top_k = ids.shape
     held, width = experts["down"].shape[:2]
+    pairs = tokens * top_k
+    capacity = pair_capacity(pairs, held, routed, tiling[0])
+    # once a geometry: the jit's cache answers a model's later layers
+    logger.info(
+        "held_experts_ffn geometry: tokens=%d top_k=%d pairs=%d held=%d of "
+        "%s -> capacity=%d rows a pass", tokens, top_k, pairs, held, routed,
+        capacity)
     local = ids - first_expert
     here = (local >= 0) & (local < held) & valid[:, None]
     # group ``held`` is everything not computed here; it sorts last
     group = jnp.where(here, local, held).reshape(-1)
     order = jnp.argsort(group, stable=True)
+    # where each (token, choice) pair stands among the sorted
+    place = jnp.zeros_like(order).at[order].set(jnp.arange(pairs)).reshape(
+        tokens, top_k)
     sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)
     # the padding's pairs are rows of the last group to the product, and
     # nobody's to the counters
     counts = sizes.at[held].add(
         -top_k * (tokens - valid.sum().astype(jnp.int32)))
-    rows = x[order // top_k]
-    hidden = moe_grouped_matmul(rows, experts["gate_up"], sizes,
-                                tiling=tiling, interpret=interpret)
-    out = moe_grouped_matmul(gated_silu(hidden, width), experts["down"],
-                             sizes, tiling=tiling, interpret=interpret)
-    # back to (token, choice) order; pairs held elsewhere come back zero
-    back = jnp.zeros_like(order).at[order].set(jnp.arange(order.size))
-    pairs = out[back].reshape(tokens, top_k, -1)
-    w = jnp.where(here, weights, 0.0)
-    return jnp.einsum("tk,tkh->th", w, pairs.astype(jnp.float32)), counts
+
+    def products(pair, groups):
+        """Both grouped products over the rows of the sorted pairs
+        ``pair``, in groups of ``groups`` rows."""
+        hidden = moe_grouped_matmul(x[pair // top_k], experts["gate_up"],
+                                    groups, tiling=tiling,
+                                    interpret=interpret)
+        return moe_grouped_matmul(gated_silu(hidden, width), experts["down"],
+                                  groups, tiling=tiling, interpret=interpret)
+
+    if capacity == pairs:
+        # a decode step's few row tiles: one gather back to (token, choice)
+        # order and the choices' weighted sum; pairs held elsewhere come
+        # back zero
+        out = products(order, sizes)[place].reshape(tokens, top_k, -1)
+        return jnp.einsum("tk,tkh->th", jnp.where(here, weights, 0.0),
+                          out.astype(jnp.float32)), counts
+    ends = jnp.cumsum(sizes[:held])
+
+    def one_pass(p, y):
+        """``y`` with sorted pairs ``[p * capacity, (p + 1) * capacity)``
+        added."""
+        lo = p * capacity
+        pair = order[jnp.minimum(lo + jnp.arange(capacity), pairs - 1)]
+        mine = jnp.diff(jnp.clip(ends, lo, lo + capacity), prepend=lo)
+        out = products(pair, jnp.append(mine, capacity - mine.sum()))
+        row = place - lo
+        inside = here & (row >= 0) & (row < capacity)
+        row = jnp.where(inside, row, 0)
+        for j in range(top_k):
+            y = y + jnp.where(
+                inside[:, j, None],
+                weights[:, j, None] * out[row[:, j]].astype(jnp.float32),
+                0.0)
+        return y
+
+    passes = pair_passes(counts, pairs, routed, tiling[0])
+    _, y = jax.lax.while_loop(
+        lambda carry: carry[0] < passes,
+        lambda carry: (carry[0] + 1, one_pass(*carry)),
+        (jnp.int32(0), jnp.zeros((tokens, x.shape[1]), jnp.float32)))
+    return y, counts
 
 
 def tokens_without_held_expert(ids, valid, first_expert, held):

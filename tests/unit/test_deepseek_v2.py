@@ -174,6 +174,32 @@ def test_both_models_describe_their_cache_through_one_interface():
 
 
 # ------------------------------- the interface's tokens stay on the device
+def _tiny_expert_model(family):
+    """16 routed experts, 4 held, 3 a token: DeepSeek-V2's softmax router
+    over 4 groups or K-EXAONE's sigmoid one."""
+    if family == "exaone_moe":
+        from deepspeed_tpu.models.exaone_moe import (ExaoneMoeConfig,
+                                                     ExaoneMoeForServing)
+        return ExaoneMoeForServing(ExaoneMoeConfig(
+            vocab_size=256, hidden_size=64, num_hidden_layers=4,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=128,
+            intermediate_size=128, moe_intermediate_size=32, num_experts=16,
+            experts_held=4, num_experts_per_tok=3, sliding_window=8,
+            max_position_embeddings=2560))
+    return DeepseekV2ForServing(DeepseekV2Config(
+        vocab_size=256, hidden_size=64, num_hidden_layers=3,
+        num_attention_heads=4, q_lora_rank=32, kv_lora_rank=48,
+        qk_nope_head_dim=16, qk_rope_head_dim=16, v_head_dim=16,
+        intermediate_size=128, moe_intermediate_size=32,
+        n_routed_experts=16, experts_held=4, n_shared_experts=1,
+        num_experts_per_tok=3, n_group=4, topk_group=2,
+        routed_scaling_factor=4.0, max_position_embeddings=2560,
+        rope_scaling={"type": "yarn", "factor": 40, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 0.707,
+                      "mscale_all_dim": 0.707,
+                      "original_max_position_embeddings": 64}))
+
+
 def _tiny_served(family):
     """A tiny model of ``family`` with seeded weights and the engine
     config it is served under."""
@@ -184,18 +210,7 @@ def _tiny_served(family):
             resid_dropout=0.0))
         params = model.init(jax.random.PRNGKey(0))
     else:
-        model = DeepseekV2ForServing(DeepseekV2Config(
-            vocab_size=256, hidden_size=64, num_hidden_layers=3,
-            num_attention_heads=4, q_lora_rank=32, kv_lora_rank=48,
-            qk_nope_head_dim=16, qk_rope_head_dim=16, v_head_dim=16,
-            intermediate_size=128, moe_intermediate_size=32,
-            n_routed_experts=16, experts_held=4, n_shared_experts=1,
-            num_experts_per_tok=3, n_group=4, topk_group=2,
-            routed_scaling_factor=4.0, max_position_embeddings=2560,
-            rope_scaling={"type": "yarn", "factor": 40, "beta_fast": 32,
-                          "beta_slow": 1, "mscale": 0.707,
-                          "mscale_all_dim": 0.707,
-                          "original_max_position_embeddings": 64}))
+        model = _tiny_expert_model(family)
         leaves, tree = jax.tree_util.tree_flatten(
             model.param_shapes(), is_leaf=lambda x: isinstance(x, tuple))
         keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
@@ -272,6 +287,29 @@ def test_tokens_reach_the_next_decode_without_leaving_the_device(
     if family == "gpt2":
         assert engine.model_counters == {}
     assert engine.generated_tokens == 6 + 4 + 7 + 5 + 6
+    engine.close()
+
+
+@pytest.mark.parametrize("family", ["deepseek_v2", "exaone_moe"])
+def test_one_pass_over_the_pairs_at_uniform_routing(family, tmp_path):
+    """Seeded weights route near uniformly, so the held pairs fit the
+    capacity of one pass in every expert layer of every decode step: the
+    counter in the decode's fetch and its gauge read 1.0."""
+    from deepspeed_tpu.inference import InferenceEngine
+    model, params, config = _tiny_served(family)
+    config = dict(config, steps_per_print=2, telemetry={
+        "enabled": True, "run_dir": str(tmp_path)})
+    engine = InferenceEngine(model, params, config=config)
+    rng = np.random.default_rng(5)
+    for n in (5, 12, 9):
+        engine.submit(rng.integers(0, 256, size=n), max_new_tokens=6)
+    seen = []
+    while not engine.scheduler.idle():
+        engine.step()
+        if "moe_pair_passes" in engine.model_counters:
+            seen.append(float(engine.model_counters["moe_pair_passes"]))
+    assert len(seen) >= 4 and set(seen) == {1.0}
+    assert engine.telemetry.gauge("serving/moe_pair_passes").value == 1.0
     engine.close()
 
 

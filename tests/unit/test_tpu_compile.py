@@ -531,6 +531,46 @@ def test_grouped_matmul_compiles_for_the_sigmoid_expert_layer(v5e, rows,
         assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize(
+    "tokens,hidden,width,top_k,held,routed,tiling,compact,gone", [
+        # DeepSeek-V2's 8,192-token prefill: 12,288 of 49,152 pairs a pass
+        (8192, 5120, 1536, 6, 20, 160, (256, 2560, 1024),
+         ("bf16[12288,5120]", "bf16[12288,3072]"),
+         ("[49152,5120]", "[49152,3072]", "[49152,1536]",
+          "f32[8192,6,5120]")),
+        # K-EXAONE's 4,096-token prefill: 8,192 of 32,768
+        (4096, 6144, 2048, 8, 16, 128, (256, 2048, 1024),
+         ("bf16[8192,6144]", "bf16[8192,4096]"),
+         ("[32768,6144]", "[32768,4096]", "[32768,2048]",
+          "f32[4096,8,6144]"))])
+def test_an_expert_layer_forms_no_array_of_every_pair(
+        v5e, tokens, hidden, width, top_k, held, routed, tiling, compact,
+        gone):
+    """One chip of eight holds an eighth of the pairs on average: the
+    compiled layer gathers, multiplies and combines a capacity's worth of
+    rows, and holds no ``[tokens * top_k, hidden]``, ``[tokens * top_k,
+    2 * width]`` or float32 ``[tokens, top_k, hidden]`` array (the last
+    was 1.0 GB an 8,192-token layer, six choices in eight-row tiles)."""
+    from deepspeed_tpu.models import expert_shard
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    text = _compile(
+        lambda x, w, ids, valid, experts: expert_shard.held_experts_ffn(
+            x, w, ids, valid, experts, first_expert=0, interpret=False,
+            tiling=tiling, routed=routed),
+        s((tokens, hidden)), s((tokens, top_k), jnp.float32),
+        s((tokens, top_k), jnp.int32), s((tokens,), jnp.bool_),
+        {"gate_up": s((held, hidden, 2 * width)),
+         "down": s((held, width, hidden))})
+    assert text.count("tpu_custom_call") >= 2
+    for shape in compact:
+        assert shape in text
+    for shape in gone:
+        assert shape not in text, shape
+
+
 # -- the serving cells' decode programs, whole, at the benchmark's widths ------
 
 _ITEM_BYTES = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
