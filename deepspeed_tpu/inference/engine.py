@@ -146,11 +146,11 @@ class InferenceEngine:
         self._caches = tuple(
             cache for g in groups for cache in init_cache_buffers(
                 g.layers, g.num_blocks(icfg), icfg.kv_block_size,
-                tuple(g.buffers.values()), dtype=cache_dtype))
+                tuple(g.buffers.values()), dtype=g.dtype or cache_dtype))
         # bytes one live block holds in each buffer (the live-bytes gauges)
         self.cache_block_bytes = {
             name: g.layers * icfg.kv_block_size * row
-            * jnp.dtype(cache_dtype).itemsize
+            * jnp.dtype(g.dtype or cache_dtype).itemsize
             for g in groups for name, row in g.buffers.items()}
         # the first group's pool is the one the occupancy gauges follow
         self.allocator = self.allocators[0]

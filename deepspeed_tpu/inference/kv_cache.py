@@ -26,6 +26,14 @@ ring_pages``), whatever the request's length — so its buffers are
 ``slots x pages + 1`` blocks, not the context's.  Each group has its own
 pool (:class:`BlockAllocator`) and its own table; a request is granted its
 blocks of every group together at admission and gives them back together.
+A group need not hold keys and values at all: a linear-attention
+layer's per-request STATE is a group of ``pages=1`` whose one block is the
+state matrix (``models/minicpm_sala.py``: 32 heads x 128 x 128 float32
+values are exactly one block of 64 rows x 8192), in a dtype of its own
+(``CacheGroup.dtype``).  Prefill writes that block whole, every decode
+step reads and rewrites it in place, a dead slot's table points at the
+null block (whose content is scratch), and a requeued request is granted
+a block afresh that its new prefill overwrites — nothing is inherited.
 Sequences never own contiguous cache
 memory: each holds a *block table* (host list of block ids); prefill
 scatters whole pages through it, decode scatters one row a slot and the
@@ -58,11 +66,14 @@ class CacheGroup(NamedTuple):
     for a model that runs its layers several times over shared weights,
     ``models/ouro.py``), ``buffers`` name -> row width, and the
     span a request holds — ``pages`` blocks whatever its length (a window
-    layer's ring), or None for the whole context."""
+    layer's ring, a recurrent layer's state), or None for the whole
+    context — and ``dtype``, the buffers' own where it is not the engine's
+    serving dtype (a float32 state beside a bfloat16 cache)."""
     name: str
     layers: int
     buffers: dict
     pages: Optional[int] = None
+    dtype: Optional[str] = None
 
     def num_blocks(self, icfg):
         """Blocks of this group's pool, the null block among them: the

@@ -20,7 +20,15 @@ it can serve has a ``serving()`` method that returns an object with:
   group's ``layers`` counts the buffers' PLANES, which need not be the
   model's layers: Ouro (``models/ouro.py``) runs its layers
   ``total_ut_steps`` times over shared weights and names ``steps x
-  layers`` planes, with ``num_layers`` the weights' count;
+  layers`` planes, with ``num_layers`` the weights' count.  A group need
+  not hold keys and values, nor be in the serving dtype: MiniCPM-SALA
+  (``models/minicpm_sala.py``) names ``kv`` (its sparse layers' pages),
+  ``ckeys`` (their compressed keys, a fixed grant of pages a request) and
+  ``state`` — ``pages=1``, ``dtype="float32"``: a linear-attention layer's
+  recurrent state, ONE block a request, which ``prefill`` writes whole
+  (the state after the prompt's last true position), ``decode`` reads and
+  rewrites in place every step, a dead slot parks on the null block, and
+  a requeued request's new prefill overwrites in a fresh grant;
 - ``check_tpu_geometry(icfg)``: raise for a cache its decode kernel cannot
   tile on a TPU (called at construction there, never a second path);
 - ``prepare_params(params) -> params``: the tree its programs take, made
@@ -49,7 +57,9 @@ it can serve has a ``serving()`` method that returns an object with:
   (the engine names a bucket's program ``prefill_<bucket>``, so that a
   trace tells the buckets apart).  Inside them the work stands under the
   program's scope words (``telemetry/scopes.py``): ``embed``,
-  ``layer_<i>`` ⊃ ``attention``, ``mlp`` | ``moe``, then ``final_norm``,
+  ``layer_<i>`` ⊃ ``attention`` (⊃ ``sparse_select``, ``sparse_attention``
+  on a block-selected layer, ⊃ ``lightning`` ⊃ ``state_update`` on a
+  linear-attention one), ``mlp`` | ``moe``, then ``final_norm``,
   ``lm_head``, ``sample``.
 
 The engine keeps one program in flight, so a program's tokens never

@@ -11,7 +11,10 @@ no operation added or moved:
   ``transpose(jvp(…))`` into an operation's name;
 - every model, the same words: ``embed``, ``layer_<i>`` (a looped body
   under ``ut_loop``) ⊃ ``attention`` (norm, projections, rotary, cache
-  write, the kernel, the output projection) and ``mlp`` or ``moe`` ⊃
+  write, the kernel, the output projection; ⊃ ``sparse_select`` (the
+  choice of blocks and nothing else) and ``sparse_attention`` on a
+  block-selected layer, ⊃ ``lightning`` ⊃
+  ``state_update`` on a linear-attention one) and ``mlp`` or ``moe`` ⊃
   ``router``, ``experts``, ``shared_experts``; ``final_norm``,
   ``lm_head``, ``sample`` (the argmax and what the decode fetch reads);
   BERT's ``pooler``, ``mlm_head``, ``loss``.

@@ -613,12 +613,28 @@ def _compile_serve_decode(cell, sharding, monkeypatch):
     the model object and the engine config the harness builds, the tree
     ``InferenceEngine`` would hold (``prepare_params``) as shapes, the TPU
     path steered from here."""
+    serving, icfg, s, params, caches = _serving_shapes(cell, sharding,
+                                                       monkeypatch)
+    slots = icfg.max_batch_slots
+    tables = tuple(s((slots, g.table_width(icfg)), jnp.int32)
+                   for g in serving.cache_groups(icfg))
+    return jax.jit(serving.build_decode(icfg), donate_argnums=(1,)).lower(
+        params, caches, tables, s((slots,), jnp.int32),
+        s((slots,), jnp.int32)).compile()
+
+
+def _serving_shapes(cell, sharding, monkeypatch):
+    """``(serving object, inference config, shape maker, parameter shapes,
+    cache shapes)`` of a serving cell, the TPU path steered from here; a
+    cache group's buffers in its own dtype where it names one."""
     from benchmarks import common, models
     from deepspeed_tpu.inference import model as gpt2_serving
     from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
-    from deepspeed_tpu.models import deepseek_v2, exaone_moe, ouro
+    from deepspeed_tpu.models import (deepseek_v2, exaone_moe, minicpm_sala,
+                                      ouro)
 
-    for module in (gpt2_serving, deepseek_v2, exaone_moe, ouro):
+    for module in (gpt2_serving, deepseek_v2, exaone_moe, ouro,
+                   minicpm_sala):
         monkeypatch.setattr(module, "current_platform", lambda: "tpu")
     spec = common.load_cell(cell)
     config = spec["config"]
@@ -637,22 +653,18 @@ def _compile_serve_decode(cell, sharding, monkeypatch):
         jax.eval_shape(serving.prepare_params, jax.tree_util.tree_map(
             s, shapes_of.param_shapes(config["model_config"]),
             is_leaf=lambda x: isinstance(x, tuple))))
-    groups = serving.cache_groups(icfg)
-    slots = icfg.max_batch_slots
     caches = tuple(
-        s((g.layers, g.num_blocks(icfg), icfg.kv_block_size, row))
-        for g in groups for row in g.buffers.values())
-    tables = tuple(s((slots, g.table_width(icfg)), jnp.int32)
-                   for g in groups)
-    return jax.jit(serving.build_decode(icfg), donate_argnums=(1,)).lower(
-        params, caches, tables, s((slots,), jnp.int32),
-        s((slots,), jnp.int32)).compile()
+        s((g.layers, g.num_blocks(icfg), icfg.kv_block_size, row),
+          jnp.dtype(g.dtype) if g.dtype else dtype)
+        for g in serving.cache_groups(icfg) for row in g.buffers.values())
+    return serving, icfg, s, params, caches
 
 
 @pytest.mark.parametrize("cell", ["deepseek_v2_ep8.repo_backlog",
                                   "gpt2_large.backlog",
                                   "k_exaone_ep8.reason_backlog",
-                                  "ouro_2_6b.think_backlog"])
+                                  "ouro_2_6b.think_backlog",
+                                  "minicpm_sala_pp8.longdoc_backlog"])
 def test_decode_makes_no_copy_of_a_weight(v5e, monkeypatch, cell):
     """A weight does not change between decode steps, so a step re-lays
     none out: no top-level ``copy`` or ``transpose`` of 1 MB or more whose
@@ -692,6 +704,56 @@ def test_the_looped_decode_carries_its_caches_in_place(v5e, monkeypatch):
     assert memory.temp_size_in_bytes < 256 * 2 ** 20
     assert not [line for op, size, _, line in _top_level(
         text, ("copy", "transpose", "copy-start")) if size >= 2 ** 30]
+
+
+def test_the_sparse_and_lightning_decode_rewrites_every_cache_in_place(
+        v5e, monkeypatch):
+    """MiniCPM-SALA's decode at the published size: five kernel calls (the
+    scores over the compressed keys, the attention over the chosen pages,
+    three state updates), all four cache buffers — K, V, the compressed
+    keys and the 409 MB of float32 states — aliased onto their inputs, and
+    temporaries of a few megabytes: nothing of ``max_seq_len`` but the
+    scores, no copy of a state."""
+    compiled = _compiled_serve_decode("minicpm_sala_pp8.longdoc_backlog",
+                                      v5e, monkeypatch)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 5
+    for name in ("sparse_block_select", "sparse_paged_decode_attention",
+                 "lightning_decode_update"):
+        assert name in text
+    header = text.split("\n", 1)[0]
+    assert header.count("may-alias") + header.count("must-alias") == 4
+    memory = compiled.memory_analysis()
+    pages = 19457 * 64 * 256 * 2
+    assert memory.alias_size_in_bytes == (
+        2 * pages + (64 * 19 + 1) * 64 * 256 * 2 + 3 * 65 * 64 * 8192 * 4)
+    assert memory.temp_size_in_bytes < 64 * 2 ** 20
+    assert not [line for op, size, _, line in _top_level(
+        text, ("copy", "transpose", "copy-start")) if size >= 2 ** 21]
+
+
+def test_the_largest_sparse_and_lightning_prefill_fits_beside_the_caches(
+        v5e, monkeypatch):
+    """The 16,384-token bucket at the published size: four kernel calls
+    (the masked flash attention, three chunked scans), the caches written
+    in place, and under 2.5 GB of temporaries (the MLP's rows in blocks of
+    4,096: whole, its intermediate alone is 1 GB)."""
+    serving, icfg, s, params, caches = _serving_shapes(
+        "minicpm_sala_pp8.longdoc_backlog", v5e, monkeypatch)
+    bucket = max(icfg.prefill_buckets)
+    tables = tuple(s((g.table_width(icfg),), jnp.int32)
+                   for g in serving.cache_groups(icfg))
+    compiled = jax.jit(serving.build_prefill(icfg, bucket),
+                       donate_argnums=(1,)).lower(
+        params, caches, s((1, bucket), jnp.int32), s((), jnp.int32), tables,
+        s((icfg.max_batch_slots,), jnp.int32), s((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 4
+    assert "sparse_prefill_attention" in text
+    assert "lightning_prefill_scan" in text
+    header = text.split("\n", 1)[0]
+    assert header.count("may-alias") + header.count("must-alias") == 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
 
 
 # -- a training cell's whole step, as the chip gets it ----------------------
