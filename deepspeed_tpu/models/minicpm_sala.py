@@ -345,9 +345,9 @@ class MiniCPMSALAServing:
             for at, rows in ((at_k, k), (at_v, v)):
                 caches[at] = caches[at].at[plane, ids].set(
                     rows.reshape(n_pages, bs, -1), unique_indices=True)
-            # ``sparse_select`` is the choice alone: compress, score, top_k
-            # (the projection and the cache writes stand under
-            # ``attention`` itself)
+            # ``sparse_select`` is the choice alone: compress, score, count
+            # out the best blocks (the projection and the cache writes
+            # stand under ``attention`` itself)
             with jax.named_scope("sparse_select"):
                 ck = sparse.compress_keys(k, g).astype(k.dtype)
             # every compressed key of the bucket, the grant's pages whole:
@@ -447,7 +447,7 @@ class MiniCPMSALAServing:
                 caches[at] = caches[at].at[plane, target[0], target[1]].set(
                     rows.astype(caches[at].dtype))
             # the choice alone: the compressed key this step closes, the
-            # scores, top_k
+            # scores, the best blocks counted out and listed
             with jax.named_scope("sparse_select"):
                 # the compressed key this token closes, if it closes one:
                 # the mean of the last kernel_size cached keys, its own

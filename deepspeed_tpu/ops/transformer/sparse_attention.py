@@ -15,16 +15,18 @@ layer; ``benchmarks/reference/minicpm_sala.py`` the plain form):
 (read from their own pages, ``[block_size, kv_heads * d]`` rows addressed by
 a table of their own) and returns ``r``; :func:`choose_decode_blocks` turns
 it into ``top_k`` block numbers a (slot, KV head) on the device
-(``lax.top_k``: no host round trip); :func:`sparse_paged_decode_attention`
-reads those pages only — each page's lanes of ONE KV head, by the K/V block
-table, ``pages_per_step`` pages a product, the page that holds ``t`` masked
-past it — for the ``heads / kv_heads`` query heads that share the choice.
+(:func:`top_blocks`, then a compaction by counts: no sort, no host round
+trip); :func:`sparse_paged_decode_attention` reads those pages only — each
+page's lanes of ONE KV head, by the K/V block table, ``pages_per_step``
+pages a product, the page that holds ``t`` masked past it — for the
+``heads / kv_heads`` query heads that share the choice.
 A context of at most ``dense_len`` tokens chooses every visible block: the
 same kernel sweeps it.
 
 **Prefill**: :func:`prefill_block_mask` makes the per-row choice for a whole
-bucket (plain XLA over row blocks; the mask ``[kv_heads, seq, blocks]``), and
-:func:`sparse_prefill_attention` is flash attention under it: dense tiles,
+bucket (plain XLA over row blocks; the mask ``[kv_heads, seq, blocks]`` is
+:func:`top_blocks`' own rows), and :func:`sparse_prefill_attention` is
+flash attention under it: dense tiles,
 each masked by the rows' chosen blocks (expanded to keys by one small
 product a tile) and by causality, the ``heads / kv_heads`` query heads of a
 group sharing a tile's K, V and mask.
@@ -117,6 +119,37 @@ class SparseGeometry:
                             | (block >= first_window[..., None]))
         return jnp.where(forced, jnp.inf,
                          jnp.where(visible, scores, -jnp.inf))
+
+
+def top_blocks(scores, top):
+    """The ``top`` best blocks of each row of ``scores [..., blocks]`` as a
+    boolean row: exactly the set that jax's own top-k names (the tests hold
+    it to that), less its entries at -inf.  Equal scores go to the lower
+    block; a row with fewer than ``top`` blocks above -inf takes them all.
+    ``scores`` are :meth:`SparseGeometry.adjusted`'s: float32, each +inf,
+    -inf or >= +0.
+
+    The model needs the SET, and the library's top-k is a full sort of
+    every row on the TPU (a tenth of a 16k prefill), so the set is counted
+    out instead.  The bit patterns of such floats order them as int32 (-inf
+    below them all), so the ``top``-th largest is built from its highest
+    bit down, one compare-and-count over the row a bit; what lies above it
+    is in, and of its equals the first that still fit."""
+    keys = jax.lax.bitcast_convert_type(scores, jnp.int32)
+
+    def with_bit(i, kth):
+        raised = kth | (jnp.int32(1) << (30 - i))
+        enough = (keys >= raised[..., None]).sum(
+            axis=-1, dtype=jnp.int32) >= top
+        return jnp.where(enough, raised, kth)
+
+    # stays 0 where fewer than ``top`` stand above -inf: all of them enter
+    kth = jax.lax.fori_loop(
+        0, 31, with_bit, jnp.zeros(keys.shape[:-1], jnp.int32))[..., None]
+    above, tied = keys > kth, keys == kth
+    room = top - above.sum(axis=-1, keepdims=True, dtype=jnp.int32)
+    return above | (tied & (jnp.cumsum(tied, axis=-1, dtype=jnp.int32)
+                            <= room))
 
 
 def _precision(dtype):
@@ -219,21 +252,31 @@ def sparse_block_select(q, ck_cache, ck_tables, ctx_lens, *, layer, kv_heads,
 def choose_decode_blocks(r, ctx_lens, geometry, blocks_per_seq):
     """``(chosen [slots, kv_heads, width] block numbers, counts [slots,
     kv_heads])`` of a decode step from :func:`sparse_block_select`'s
-    ``r``: the forced blocks and the best of the rest, ``top_k`` in all —
-    or every visible block while the context (the new token in it) is at
-    most ``dense_len``.  Entries past a count are not read."""
+    ``r``: the forced blocks and the best of the rest, ``top_k`` in all
+    (:func:`top_blocks`' set, in ascending block order) — or every visible
+    block while the context (the new token in it) is at most ``dense_len``.
+    Entries past a count are block 0: fetched with the rest of their
+    product and masked, never read."""
     g = geometry
     width = g.decode_width(blocks_per_seq)
     positions = ctx_lens[:, None]                       # [slots, 1]
     scores = g.adjusted(g.block_scores(r, blocks_per_seq), positions)
     top = min(g.topk, blocks_per_seq)
-    _, ids = jax.lax.top_k(scores, top)
+    # one row a (slot, KV head): tiles of [kv_heads, blocks] are mostly
+    # padding, and every pass below would walk them
+    rows = scores.reshape(-1, blocks_per_seq)
+    # the set as a list: the j-th chosen block is the first with j + 1 of
+    # them up to it, so as many blocks lie before it as hold at most j
+    held = jnp.cumsum(top_blocks(rows, top), axis=-1, dtype=jnp.int32)
+    place = jnp.arange(top, dtype=jnp.int32)
+    ids = (held[:, None, :] <= place[:, None]).sum(axis=-1, dtype=jnp.int32)
+    ids = jnp.where(place < held[:, -1:], ids, 0).reshape(
+        *scores.shape[:-1], top)
     visible = positions // g.block_size + 1
     dense = positions + 1 <= g.dense_len
     every = jnp.arange(width, dtype=jnp.int32)
     chosen = jnp.where(dense[..., None], every,
-                       jnp.pad(ids.astype(jnp.int32),
-                               ((0, 0), (0, 0), (0, width - top))))
+                       jnp.pad(ids, ((0, 0), (0, 0), (0, width - top))))
     counts = jnp.where(dense, jnp.minimum(visible, width),
                        jnp.minimum(visible, top))
     return chosen, jnp.broadcast_to(counts, chosen.shape[:2]).astype(
@@ -452,10 +495,7 @@ def prefill_block_mask(q, ck, true_len, geometry, *, kv_heads, row_block=512):
         scores = g.adjusted(g.block_scores(p.sum(axis=2), blocks), positions)
         # forced blocks stand at +inf, blocks past the row at -inf (never
         # chosen); equal scores go to the lower block, as in decode
-        values, ids = jax.lax.top_k(scores, top)
-        taken = jax.nn.one_hot(ids, blocks, dtype=jnp.bool_) \
-            & (values > -jnp.inf)[..., None]
-        return taken.any(axis=-2)
+        return top_blocks(scores, top)
 
     block = math.gcd(seq, row_block)
     chosen = jax.lax.map(rows, (jnp.arange(seq).reshape(-1, block),

@@ -9,6 +9,7 @@ the compressed keys a decode step closes, a requeued request, the counters.
 The logits through the engine against the reference's full forward, and the
 planted faults, are ``tests/benchmarks/test_bench_minicpm_sala.py``."""
 
+import functools
 import math
 
 import jax
@@ -276,6 +277,107 @@ def test_the_prefill_mask_is_the_references_choice():
     # a prompt of at most dense_len chooses everything: causality masks
     assert np.asarray(sparse.prefill_block_mask(
         q, ck.reshape(-1, 2, 32), 96, GEOMETRY, kv_heads=2)).all()
+
+
+def _top_k_set(scores, top):
+    """The oracle: ``lax.top_k``'s ids whose values stand above -inf, as a
+    boolean row."""
+    values, ids = jax.lax.top_k(scores, min(top, scores.shape[-1]))
+    rows = np.zeros(scores.shape, bool)
+    np.put_along_axis(rows, np.asarray(ids), np.asarray(values) > -np.inf,
+                      axis=-1)
+    return rows
+
+
+def _block_scores(kind, blocks, rows=24, seed=5):
+    """``[2, rows, blocks]`` scores >= 0 of one kind of difficulty."""
+    rng = np.random.default_rng(seed)
+    s = rng.random((2, rows, blocks)).astype(np.float32)
+    if kind == "tied":            # four values: every place is tied over
+        s = np.round(s * 3) / 3
+    elif kind == "coarse":        # the k-th place tied a few times
+        s = np.round(s * 40) / 40
+    elif kind == "zeros":         # columns of exact zeros, most of a row
+        s[..., rng.random(blocks) < 0.7] = 0.0
+    return s
+
+
+@pytest.mark.parametrize("blocks", [15, 40, 160, 256, 304])
+@pytest.mark.parametrize("kind", ["continuous", "tied", "coarse", "zeros"])
+def test_the_chosen_set_is_top_ks(blocks, kind):
+    """``top_blocks`` names ``lax.top_k``'s set, ties to the lower block: on
+    raw scores, and under ``adjusted`` — rows that see 0, fewer than, exactly
+    and more than ``top`` blocks, their forced blocks at +inf (with a window
+    of 30 blocks more of them than ``top``).  Blocks 40 are fewer than the
+    published ``top`` 64."""
+    scores = _block_scores(kind, blocks)
+    for top in (6, 64):
+        np.testing.assert_array_equal(
+            sparse.top_blocks(jnp.asarray(scores), min(top, blocks)),
+            _top_k_set(scores, top))
+    for window in (20, 480):
+        g = sparse.SparseGeometry(**dict(SPARSE_CONFIG, window_size=window))
+        # positions in blocks 0, 1, 4, 5 (exactly top), 6, ... and the last
+        positions = jnp.asarray(sorted(
+            {0, 15, 16, 79, 80, 95, 96, 16 * blocks - 1}
+            | set(range(7, 16 * blocks, 16 * blocks // 15))))[:24]
+        adjusted = g.adjusted(jnp.asarray(scores[:, :len(positions)]),
+                              positions)
+        want = _top_k_set(adjusted, g.topk)
+        assert (np.isinf(np.asarray(adjusted)) & (np.asarray(adjusted) > 0)
+                ).sum(-1).max() > (g.topk if window == 480 else 1)
+        assert not want[:, 0, 1:].any() and want[:, 0, 0].all()
+        np.testing.assert_array_equal(
+            sparse.top_blocks(adjusted, min(g.topk, blocks)), want)
+
+
+@pytest.mark.parametrize("blocks", [15, 40, 304])
+@pytest.mark.parametrize("kind", ["continuous", "tied", "zeros"])
+@pytest.mark.parametrize("window", [20, 480])
+def test_the_decode_list_is_top_ks_set_in_block_order(blocks, kind, window):
+    """``choose_decode_blocks``' first ``counts`` entries are ``lax.top_k``'s
+    set over the same adjusted block scores, ascending and each once; the
+    entries past a count lie inside the slot's table row."""
+    config = dict(SPARSE_CONFIG, window_size=window, dense_len=32)
+    g = sparse.SparseGeometry(**config)
+    # [2, kernels], four kernels a block
+    r = jnp.asarray(_block_scores(kind, 4 * blocks, rows=1)[:, 0])
+    positions = jnp.asarray(sorted(
+        {40, 47, 48, 79, 80, 95, 96, 111, 112, 16 * blocks - 1}
+        & set(range(16 * blocks))) + list(range(133, 16 * blocks, 509)))
+    r = jnp.where(jnp.arange(4 * blocks) < g.closed(positions)[:, None, None],
+                  r[None], 0.0)
+    chosen, counts = sparse.choose_decode_blocks(r, positions, g, blocks)
+    chosen, counts = np.asarray(chosen), np.asarray(counts)
+    want = _top_k_set(g.adjusted(g.block_scores(r, blocks),
+                                 positions[:, None]), g.topk)
+    assert chosen.shape[2] == g.decode_width(blocks)
+    assert ((0 <= chosen) & (chosen < blocks)).all()
+    for n, g_head in np.ndindex(*counts.shape):
+        listed = chosen[n, g_head, :counts[n, g_head]].tolist()
+        assert listed == np.nonzero(want[n, g_head])[0].tolist(), (n, g_head)
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_the_choice_holds_no_sort_at_the_published_geometry(program):
+    """``lax.top_k`` is a sort of every row on the TPU (a tenth of the
+    long-document cell's prefill before PR 41): neither program's choice
+    lowers to one."""
+    g = sparse.SparseGeometry(32, 16, 64, 64, 1, 2048, 8192)
+    if program == "prefill":
+        text = jax.jit(functools.partial(
+            sparse.prefill_block_mask, geometry=g, kv_heads=2)).lower(
+                jax.ShapeDtypeStruct((16384, 32, 128), jnp.bfloat16),
+                jax.ShapeDtypeStruct((1023, 2, 128), jnp.bfloat16),
+                jax.ShapeDtypeStruct((), jnp.int32)).as_text()
+    else:
+        text = jax.jit(functools.partial(
+            sparse.choose_decode_blocks, geometry=g,
+            blocks_per_seq=304)).lower(
+                jax.ShapeDtypeStruct((64, 2, 1216), jnp.float32),
+                jax.ShapeDtypeStruct((64,), jnp.int32)).as_text()
+    assert "stablehlo.while" in text and "stablehlo.compare" in text
+    assert "sort" not in text and "top_k" not in text
 
 
 def test_sparse_prefill_attention_is_masked_attention():

@@ -721,6 +721,7 @@ def test_the_sparse_and_lightning_decode_rewrites_every_cache_in_place(
     for name in ("sparse_block_select", "sparse_paged_decode_attention",
                  "lightning_decode_update"):
         assert name in text
+    assert " sort(" not in text          # the choice counts (PR 41)
     header = text.split("\n", 1)[0]
     assert header.count("may-alias") + header.count("must-alias") == 4
     memory = compiled.memory_analysis()
@@ -751,6 +752,7 @@ def test_the_largest_sparse_and_lightning_prefill_fits_beside_the_caches(
     assert text.count("tpu_custom_call") == 4
     assert "sparse_prefill_attention" in text
     assert "lightning_prefill_scan" in text
+    assert " sort(" not in text          # the choice counts (PR 41)
     header = text.split("\n", 1)[0]
     assert header.count("may-alias") + header.count("must-alias") == 4
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
