@@ -1,13 +1,15 @@
 """One chip's share of an expert-parallel mixture-of-experts layer, for
-serving: routing over ALL the experts at the published width, and the part
-of the result that the experts held here give.
+serving and for training: routing over ALL the experts at the published
+width, and the part of the result that the experts held here give.
 
-Two published routers run through :func:`route`: DeepSeek-V2's
+Three published routers run through :func:`route`: DeepSeek-V2's
 (``softmax`` scores, group-limited, the chosen scores themselves as
-weights, not renormalised) and the DeepSeek-V3 lineage's that K-EXAONE
+weights, not renormalised), the DeepSeek-V3 lineage's that K-EXAONE
 uses (``sigmoid`` scores, the choice made on ``score + bias``, the weights
 the chosen experts' scores renormalised over ALL the chosen, held here or
-not; one group, so the group limit does nothing).
+not; one group, so the group limit does nothing), and the plain one Mellum
+trains (``softmax`` scores, one group, the chosen renormalised), whose
+load-balancing loss is :func:`aux_load_balance`.
 
 The layer is told which experts it holds (``first_expert``, and as many as
 its weights have: a routing group, or any contiguous slice).  Every token
@@ -27,7 +29,19 @@ held pairs past one pass are computed by further trips of the same loop
 (:func:`pair_passes`: 1.0 in the decode programs' counters wherever the
 capacity held), so no token is ever dropped, whatever the
 imbalance.  ``models/moe.py`` is the other expert layer: GShard capacity
-routing that drops overflow, for training.
+routing that drops overflow, over an ``expert`` mesh axis.
+
+The layer is differentiable in ``x``, the combine weights and the expert
+weights.  Its ways in and out are gathers in both directions
+(:func:`_rows_of_pairs`, :func:`_weighted_rows`: the reverse of a gather
+of sorted pairs is a gather by the inverse permutation, never XLA's row
+scatter, which the readings below put at 1.4 us a row), and the grouped
+product has a gradient rule of its own.  A ``while_loop`` over the passes
+has no reverse rule, so a training program asks for ``reverse=True``: the
+first pass as it is, and each further pass the routing could need under a
+``cond`` on ``pair_passes``, recomputed on the way back, so that a pass
+that does not run costs neither time nor memory in either direction.  The
+serving programs keep the forms they had.
 
 Readings that chose the form (on a v5e; PERF.md section 6, PR 39).  One
 DeepSeek-V2 expert layer, 8,192 tokens, 6 choices, 20 of 160 experts:
@@ -78,21 +92,25 @@ def group_limited_topk(scores, n_group, topk_group, top_k):
     return jax.lax.top_k(masked, top_k)
 
 
-def route(x, router_kernel, *, n_group, topk_group, top_k, scaling,
-          scoring="softmax", bias=None, renormalise=False):
+def router_scores(x, router_kernel, scoring="softmax"):
     """Scores over every expert in fp32 (``x`` as the norm gave it, not
     rounded to the compute dtype first, and the product too: a near-tie
     between two experts must not flip on a bf16 product) — ``softmax`` or
-    ``sigmoid`` of the router's logits — then the group-limited choice,
-    made on ``score + bias`` where the router has a selection ``bias``;
-    weights ``scaling * score`` of the chosen (the bias chooses, it does
-    not weigh), with ``renormalise`` divided by their sum over all
-    ``top_k`` chosen, wherever those experts are held."""
+    ``sigmoid`` of the router's logits."""
     logits = jnp.matmul(x.astype(jnp.float32),
                         router_kernel.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
-    scores = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
-              else jax.nn.softmax(logits, axis=-1))
+    return (jax.nn.sigmoid(logits) if scoring == "sigmoid"
+            else jax.nn.softmax(logits, axis=-1))
+
+
+def choose_experts(scores, *, n_group, topk_group, top_k, scaling, bias=None,
+                   renormalise=False):
+    """The group-limited choice over :func:`router_scores`, made on
+    ``score + bias`` where the router has a selection ``bias``; weights
+    ``scaling * score`` of the chosen (the bias chooses, it does not
+    weigh), with ``renormalise`` divided by their sum over all ``top_k``
+    chosen, wherever those experts are held."""
     if bias is None:
         weights, ids = group_limited_topk(scores, n_group, topk_group, top_k)
     else:
@@ -102,6 +120,29 @@ def route(x, router_kernel, *, n_group, topk_group, top_k, scaling,
     if renormalise:
         weights = weights / weights.sum(axis=-1, keepdims=True)
     return scaling * weights, ids
+
+
+def route(x, router_kernel, *, n_group, topk_group, top_k, scaling,
+          scoring="softmax", bias=None, renormalise=False):
+    """``(weights, ids)``: :func:`choose_experts` over
+    :func:`router_scores`.  A model that needs the scores too
+    (:func:`aux_load_balance`) calls the two itself."""
+    return choose_experts(
+        router_scores(x, router_kernel, scoring), n_group=n_group,
+        topk_group=topk_group, top_k=top_k, scaling=scaling, bias=bias,
+        renormalise=renormalise)
+
+
+def aux_load_balance(scores, ids, n_experts):
+    """The load-balancing loss of the Switch / Mixtral lineage over ALL the
+    ``n_experts`` the router scores: ``n_experts * sum_e f_e P_e`` with
+    ``f_e`` the (token, choice) pairs routed to expert ``e`` over the
+    tokens and ``P_e`` the tokens' mean score of ``e``.  1 * ``top_k`` under
+    an even load; the gradient flows through the scores alone."""
+    tokens = ids.shape[0]
+    load = jnp.zeros((n_experts,), jnp.float32).at[ids.reshape(-1)].add(
+        1.0) / tokens
+    return n_experts * jnp.sum(load * scores.astype(jnp.float32).mean(0))
 
 
 def pair_capacity(pairs, held, routed, tile):
@@ -127,10 +168,71 @@ def pair_passes(counts, pairs, routed, tile):
     return jnp.maximum(-(-counts[:-1].sum() // capacity), 1)
 
 
+@jax.custom_vjp
+def _rows_of_pairs(x, token, row, inside):
+    """``x[token]``: the rows of ``x [tokens, hidden]`` a pass's sorted
+    pairs read, ``token [capacity]``.  ``row [tokens, top_k]`` is where each
+    (token, choice) pair stands in the pass and ``inside`` whether it does:
+    on the way back a token's gradient is the sum of its pairs' rows, top_k
+    gathers as in :func:`_weighted_rows`, not a scatter of the rows."""
+    return x[token]
+
+
+def _rows_of_pairs_fwd(x, token, row, inside):
+    return x[token], (row, inside)
+
+
+def _rows_of_pairs_bwd(res, g):
+    row, inside = res
+    dx = sum(jnp.where(inside[:, j, None], g[row[:, j]].astype(jnp.float32),
+                       0.0) for j in range(row.shape[1]))
+    return dx.astype(g.dtype), None, None, None
+
+
+_rows_of_pairs.defvjp(_rows_of_pairs_fwd, _rows_of_pairs_bwd)
+
+
+@jax.custom_vjp
+def _weighted_rows(y, out, weights, row, inside, token, choice, held_rows):
+    """``y + sum_j w[t, j] out[row[t, j]]`` over the pairs ``inside`` the
+    pass, in fp32, the choices in their own order.  On the way back row
+    ``r`` of ``out`` belongs to one pair, ``(token[r], choice[r])``, so its
+    gradient is that pair's weight times its token's — a gather; the rows
+    past ``held_rows`` are nobody's."""
+    for j in range(row.shape[1]):
+        y = y + jnp.where(
+            inside[:, j, None],
+            weights[:, j, None] * out[row[:, j]].astype(jnp.float32), 0.0)
+    return y
+
+
+def _weighted_rows_fwd(y, out, weights, row, inside, token, choice,
+                       held_rows):
+    return (_weighted_rows(y, out, weights, row, inside, token, choice,
+                           held_rows),
+            (out, weights, row, inside, token, choice, held_rows))
+
+
+def _weighted_rows_bwd(res, g):
+    out, weights, row, inside, token, choice, held_rows = res
+    mine = (jnp.arange(out.shape[0]) < held_rows)[:, None]
+    g_rows = g[token]   # each sorted pair's token's gradient, once
+    d_out = jnp.where(mine, weights[token, choice][:, None] * g_rows,
+                      0.0).astype(out.dtype)
+    # d w of a pair is its row's dot with its token's gradient: formed in
+    # the sorted order, then one gather of scalars back to (token, choice)
+    dots = jnp.sum(g_rows * out.astype(jnp.float32), axis=-1)
+    d_weights = jnp.where(inside, dots[row], 0.0).astype(weights.dtype)
+    return g, d_out, d_weights, None, None, None, None, None
+
+
+_weighted_rows.defvjp(_weighted_rows_fwd, _weighted_rows_bwd)
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "first_expert", "interpret", "tiling", "routed"))
+    "first_expert", "interpret", "tiling", "routed", "reverse"))
 def held_experts_ffn(x, weights, ids, valid, experts, *, first_expert,
-                     interpret, tiling, routed=None):
+                     interpret, tiling, routed=None, reverse=False):
     """``sum over the chosen experts held here of w_e F_e(x)`` for
     ``x [tokens, hidden]``, with what the sum ran over.
 
@@ -155,6 +257,10 @@ def held_experts_ffn(x, weights, ids, valid, experts, *, first_expert,
     weighted sum: the program the layer always was.  Traced once a
     geometry: a model's expert layers share the equations, and one log
     line says what capacity a traced geometry got.
+
+    ``reverse`` (a training program) spells the passes out in a form that
+    has a reverse rule — the first as it is, each further one under a
+    ``cond`` and recomputed on the way back — with the same sums.
     """
     tokens, top_k = ids.shape
     held, width = experts["down"].shape[:2]
@@ -163,8 +269,8 @@ def held_experts_ffn(x, weights, ids, valid, experts, *, first_expert,
     # once a geometry: the jit's cache answers a model's later layers
     logger.info(
         "held_experts_ffn geometry: tokens=%d top_k=%d pairs=%d held=%d of "
-        "%s -> capacity=%d rows a pass", tokens, top_k, pairs, held, routed,
-        capacity)
+        "%s -> capacity=%d rows a pass%s", tokens, top_k, pairs, held, routed,
+        capacity, ", reversible" if reverse else "")
     local = ids - first_expert
     here = (local >= 0) & (local < held) & valid[:, None]
     # group ``held`` is everything not computed here; it sorts last
@@ -179,12 +285,11 @@ def held_experts_ffn(x, weights, ids, valid, experts, *, first_expert,
     counts = sizes.at[held].add(
         -top_k * (tokens - valid.sum().astype(jnp.int32)))
 
-    def products(pair, groups):
-        """Both grouped products over the rows of the sorted pairs
-        ``pair``, in groups of ``groups`` rows."""
-        hidden = moe_grouped_matmul(x[pair // top_k], experts["gate_up"],
-                                    groups, tiling=tiling,
-                                    interpret=interpret)
+    def products(rows, groups, experts=experts):
+        """Both grouped products over ``rows``, the sorted pairs' rows of
+        ``x``, in groups of ``groups`` rows."""
+        hidden = moe_grouped_matmul(rows, experts["gate_up"], groups,
+                                    tiling=tiling, interpret=interpret)
         return moe_grouped_matmul(gated_silu(hidden, width), experts["down"],
                                   groups, tiling=tiling, interpret=interpret)
 
@@ -192,33 +297,41 @@ def held_experts_ffn(x, weights, ids, valid, experts, *, first_expert,
         # a decode step's few row tiles: one gather back to (token, choice)
         # order and the choices' weighted sum; pairs held elsewhere come
         # back zero
-        out = products(order, sizes)[place].reshape(tokens, top_k, -1)
+        out = products(x[order // top_k], sizes)[place].reshape(
+            tokens, top_k, -1)
         return jnp.einsum("tk,tkh->th", jnp.where(here, weights, 0.0),
                           out.astype(jnp.float32)), counts
     ends = jnp.cumsum(sizes[:held])
 
-    def one_pass(p, y):
+    def one_pass(p, y, x, weights, experts):
         """``y`` with sorted pairs ``[p * capacity, (p + 1) * capacity)``
         added."""
         lo = p * capacity
         pair = order[jnp.minimum(lo + jnp.arange(capacity), pairs - 1)]
         mine = jnp.diff(jnp.clip(ends, lo, lo + capacity), prepend=lo)
-        out = products(pair, jnp.append(mine, capacity - mine.sum()))
         row = place - lo
         inside = here & (row >= 0) & (row < capacity)
         row = jnp.where(inside, row, 0)
-        for j in range(top_k):
-            y = y + jnp.where(
-                inside[:, j, None],
-                weights[:, j, None] * out[row[:, j]].astype(jnp.float32),
-                0.0)
-        return y
+        token, held_rows = pair // top_k, mine.sum()
+        out = products(_rows_of_pairs(x, token, row, inside),
+                       jnp.append(mine, capacity - held_rows), experts)
+        return _weighted_rows(y, out, weights, row, inside, token,
+                              pair % top_k, held_rows)
 
     passes = pair_passes(counts, pairs, routed, tiling[0])
+    zeros = jnp.zeros((tokens, x.shape[1]), jnp.float32)
+    if reverse:
+        y = one_pass(0, zeros, x, weights, experts)
+        for p in range(1, -(-pairs // capacity)):
+            y = y + jax.lax.cond(
+                p < passes,
+                jax.checkpoint(functools.partial(one_pass, p, zeros)),
+                lambda *_: zeros, x, weights, experts)
+        return y, counts
     _, y = jax.lax.while_loop(
         lambda carry: carry[0] < passes,
-        lambda carry: (carry[0] + 1, one_pass(*carry)),
-        (jnp.int32(0), jnp.zeros((tokens, x.shape[1]), jnp.float32)))
+        lambda carry: (carry[0] + 1, one_pass(*carry, x, weights, experts)),
+        (jnp.int32(0), zeros))
     return y, counts
 
 

@@ -57,6 +57,7 @@ import operator
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -439,7 +440,7 @@ def _sum(parts):
 
 
 def _bwd_tile(q, k_g, v_g, do, lse, delta, seed_ref, kvm_ref, head, j, kb, *,
-              scale, causal, masked, dropout, want_pv, row=0):
+              scale, causal, masked, dropout, want_pv, row=0, window=None):
     """One head's [Bq, Bk] tile of the backward recurrence, recomputed from
     the saved logsumexp: ``ds = p ∘ (dp − Δ)`` and, where the caller forms
     dv, the (dropped) probabilities ``p_v`` that met the values — both in
@@ -448,7 +449,7 @@ def _bwd_tile(q, k_g, v_g, do, lse, delta, seed_ref, kvm_ref, head, j, kb, *,
     [Bq, 1] columns."""
     block_q, block_k = q.shape[0], k_g.shape[0]
     s = _scores(q, k_g, scale, causal, masked, kvm_ref, j, kb, block_q,
-                block_k, row=row)
+                block_k, window, row)
     p = jnp.exp(s - lse)  # [Bq, Bk] fp32
     dp = _dot(do, v_g, ((1,), (1,)))
     p_v = p
@@ -464,7 +465,7 @@ def _bwd_tile(q, k_g, v_g, do, lse, delta, seed_ref, kvm_ref, head, j, kb, *,
 
 
 def _bwd_dq_kernel(*refs, scale, causal, masked, dropout, single, heads,
-                   lead):
+                   lead, window=None):
     refs = list(refs)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     rest = refs[6:]
@@ -475,6 +476,11 @@ def _bwd_dq_kernel(*refs, scale, causal, masked, dropout, single, heads,
     block_q, block_k = q_ref.shape[1], k_ref.shape[1]
     i, j, kb = _grid_ids(lead)
     n_kb = pl.num_programs(lead + 1)
+    # the place in the stream and the k block held there, as in the forward:
+    # a window's stream is its band, which ends at the diagonal
+    at = kb
+    if window is not None:
+        kb = j - (n_kb - 1) + at
 
     def tile_dq():
         parts = []
@@ -484,7 +490,8 @@ def _bwd_dq_kernel(*refs, scale, causal, masked, dropout, single, heads,
                 q_ref[0], k_g, _own_lanes(v_ref[0], g, heads), do_ref[0],
                 lse_ref[g, 0][:, None], delta_ref[g, 0][:, None], seed_ref,
                 kvm_ref, _head_index(i, g, heads), j, kb, scale=scale,
-                causal=causal, masked=masked, dropout=dropout, want_pv=False)
+                causal=causal, masked=masked, dropout=dropout, want_pv=False,
+                window=window)
             parts.append(_dot(ds, k_g, ((1,), (0,))))
         return _sum(parts)
 
@@ -492,23 +499,31 @@ def _bwd_dq_kernel(*refs, scale, causal, masked, dropout, single, heads,
         dq_ref[0] = (tile_dq() * scale).astype(dq_ref.dtype)
         return
 
-    @pl.when(kb == 0)
+    @pl.when(at == 0)
     def _init():
         dq_sc[...] = jnp.zeros_like(dq_sc)
 
     needed = True if not causal else kb * block_k <= (j + 1) * block_q - 1
+    if window is not None:
+        needed = kb >= 0    # the first query blocks' bands start before 0
 
     @pl.when(needed)
     def _step():
         dq_sc[...] = dq_sc[...] + tile_dq()
 
-    @pl.when(kb == n_kb - 1)
+    @pl.when(at == n_kb - 1)
     def _finalize():
         dq_ref[0] = (dq_sc[...] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(*refs, scale, causal, masked, dropout, single, heads,
-                    lead):
+                    lead, window=None, per_head=None, n_qb=None):
+    """dk and dv of one k block, the q blocks streamed.  With grouped KV
+    heads a step is a KV head and the stream runs over each of its query
+    heads in turn, ``per_head`` q blocks each, into the one accumulator:
+    the group's sum is formed here.  With a ``window`` a head's stream is
+    the band of ``per_head`` q blocks from the diagonal down, ``n_qb`` the
+    sequence's (``_bwd_kernels`` maps the blocks the same way)."""
     refs = list(refs)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     rest = refs[6:]
@@ -518,8 +533,11 @@ def _bwd_dkv_kernel(*refs, scale, causal, masked, dropout, single, heads,
 
     block_q, block_k = q_ref.shape[1], k_ref.shape[1]
     # grid is (steps, k blocks, q blocks): q streams in the inner dimension
-    i, kb, j = _grid_ids(lead)
-    n_qb = pl.num_programs(lead + 1)
+    i, kb, at = _grid_ids(lead)
+    n_at = pl.num_programs(lead + 1)
+    j = at if per_head is None else at % per_head
+    if window is not None:
+        j = kb + j
 
     def tile_dkdv():
         dks, dvs = [], []
@@ -529,7 +547,8 @@ def _bwd_dkv_kernel(*refs, scale, causal, masked, dropout, single, heads,
                 _own_lanes(v_ref[0], g, heads), do_ref[0],
                 lse_ref[g, 0][:, None], delta_ref[g, 0][:, None], seed_ref,
                 kvm_ref, _head_index(i, g, heads), j, kb, scale=scale,
-                causal=causal, masked=masked, dropout=dropout, want_pv=True)
+                causal=causal, masked=masked, dropout=dropout, want_pv=True,
+                window=window)
             dvs.append(_dot(p_v, _own_lanes(do_ref[0], g, heads),
                             ((0,), (0,))))
             dks.append(_dot(ds, _own_lanes(q_ref[0], g, heads), ((0,), (0,))))
@@ -542,7 +561,7 @@ def _bwd_dkv_kernel(*refs, scale, causal, masked, dropout, single, heads,
         dv_ref[0] = dv_t.astype(dv_ref.dtype)
         return
 
-    @pl.when(j == 0)
+    @pl.when(at == 0)
     def _init():
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
@@ -550,6 +569,8 @@ def _bwd_dkv_kernel(*refs, scale, causal, masked, dropout, single, heads,
     # causal: q block j contributes to k block kb iff its last row can see
     # the block's first key
     needed = True if not causal else (j + 1) * block_q - 1 >= kb * block_k
+    if window is not None:
+        needed = j < n_qb   # the last k blocks' bands run past the sequence
 
     @pl.when(needed)
     def _step():
@@ -557,7 +578,7 @@ def _bwd_dkv_kernel(*refs, scale, causal, masked, dropout, single, heads,
         dk_sc[...] = dk_sc[...] + dk_t
         dv_sc[...] = dv_sc[...] + dv_t
 
-    @pl.when(j == n_qb - 1)
+    @pl.when(at == n_at - 1)
     def _finalize():
         # s was scaled after the q·kᵀ dot, so the 1/√d factor lands on dk.
         dk_ref[0] = (dk_sc[...] * scale).astype(dk_ref.dtype)
@@ -654,10 +675,14 @@ class _Operands:
     64-wide heads, key width 192) — a block narrower than 128 lanes of the
     projection's layout cannot be indexed.
 
-    *Grouped KV heads* (``kv_group`` query heads read one K/V head, forward
-    only): k and v are ``[b, s, (h / kv_group)·d]`` and query head ``i``'s
-    step indexes their block ``i // kv_group`` — nothing is repeated in
-    HBM; one head a block, so the widths are multiples of 128.
+    *Grouped KV heads* (``kv_group`` query heads read one K/V head): k and
+    v are ``[b, s, (h / kv_group)·d]`` and query head ``i``'s step indexes
+    their block ``i // kv_group`` — nothing is repeated in HBM; one head a
+    block, so the widths are multiples of 128.  Forward and dq run a step a
+    query head; the dk/dv kernel runs a step a KV head and streams its
+    query heads one after another into one accumulator, so the sum over
+    the group is formed in VMEM and dk, dv leave ``[b, s, (h / kv_group)·d]``
+    (``_bwd_kernels``).
 
     *Rows a step* (``tile``): where a batch row is ONE tile of the
     projection layout (``s`` = ``kv_len`` = both blocks: the forward's
@@ -810,10 +835,10 @@ class _Operands:
             vmem_limit_bytes=100 * 1024 * 1024)}
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def flash_attention(q, k, v, kv_mask=None, dropout_seed=None, causal=False,
                     block_q=None, block_k=None,
-                    interpret=False, dropout_rate=0.0):
+                    interpret=False, dropout_rate=0.0, window=None):
     """Flash attention on [b, s, h, d]; returns [b, s, h, d].
 
     ``kv_mask`` is an optional key-padding mask [b, kv_len] with 1 at
@@ -829,10 +854,30 @@ def flash_attention(q, k, v, kv_mask=None, dropout_seed=None, causal=False,
     fused softmax-dropout capability, ``dropout_kernels.cu``).
     ``dropout_seed`` is a scalar int32 array; vary it per step/layer.
     TPU-only: requires the Mosaic PRNG (not available in interpret mode).
+
+    ``k`` and ``v`` may hold fewer heads than ``q`` (grouped KV heads, head
+    widths multiples of 128: query head ``i`` reads head ``i // (h /
+    h_kv)``, and dk, dv come back summed over each group), and with
+    ``window`` (causal, ``block_q == block_k``) a query sees its last
+    ``window`` keys, itself among them: the blocks outside that band are
+    neither fetched nor computed, forward or backward (``_band``).  Neither
+    takes a key mask or dropout.  Their kernels carry names of their own in
+    a device trace (``_train_name``).
     """
-    out, _ = _flash_fwd(q, k, v, kv_mask, dropout_seed, causal, block_q,
-                        block_k, interpret, dropout_rate)
-    return out
+    return _flash_fwd_rule(q, k, v, kv_mask, dropout_seed, causal, block_q,
+                           block_k, interpret, dropout_rate, window)[0]
+
+
+def _train_name(kv_group, window, part):
+    """The differentiable kernels' names in a device trace where the heads
+    are grouped or windowed; None (Pallas' own) for the others."""
+    if window is not None:
+        return f"window_train_attention_{part}"
+    return f"gqa_train_attention_{part}" if kv_group > 1 else None
+
+
+def _window_note(window):
+    return "" if window is None else f", window {window}"
 
 
 def flash_attention_forward(q, k, v, *, causal, block_q, block_k,
@@ -884,9 +929,10 @@ def _log_geometry(s, kv_len, d, causal, dropout, block_q, block_k, rows,
 
 
 def _resolve_blocks(ops, s, kv_len, d, block_q, block_k, causal=False,
-                    dropout_rate=0.0):
+                    dropout_rate=0.0, note=""):
     """The call's operands, with the batch rows a step holds of them
-    (``_Operands.tile``), and its blocks."""
+    (``_Operands.tile``), and its blocks; ``note`` is added to the layout
+    in the geometry's log line."""
     auto_q, auto_k = _auto_blocks(s, kv_len, d, causal)
     chosen = "caller"
     if block_q is None and block_k is None:
@@ -905,7 +951,7 @@ def _resolve_blocks(ops, s, kv_len, d, block_q, block_k, causal=False,
     block_k = block_k or auto_k
     ops = ops.tile(s, kv_len, block_q, block_k)
     _log_geometry(s, kv_len, d, causal, dropout_rate, block_q, block_k,
-                  ops.rows, chosen, ops.name)
+                  ops.rows, chosen, ops.name + note)
     # The kernels index K/V in whole blocks; a ragged tail would silently
     # attend over out-of-block garbage.  Dispatchers (attention.py) only
     # route divisible shapes here; direct callers must pad or shrink blocks.
@@ -959,14 +1005,16 @@ def trace_stats():
 
 
 def _fwd_call(ops, q, k, v, dims, kv_mask, dropout_seed, causal, block_q,
-              block_k, interpret, dropout_rate, name=None, window=None):
+              block_k, interpret, dropout_rate, name=None, window=None,
+              note=""):
     """The forward kernel over operands already in ``ops``' layout (q, k
     and v one array where it is fused); ``dims`` = (s, kv_len, d, dv).
     Returns the output in that layout and the logsumexp
     (``_Operands.stat_shape``)."""
     s, kv_len, d, _ = dims
     ops, block_q, block_k = _resolve_blocks(ops, s, kv_len, d, block_q,
-                                            block_k, causal, dropout_rate)
+                                            block_k, causal, dropout_rate,
+                                            note)
     _calls["asked"] += 1
     return _fwd_kernels(ops, q, k, v, dims, kv_mask, dropout_seed, causal,
                         block_q, block_k, interpret, dropout_rate, name,
@@ -1053,27 +1101,34 @@ def _fwd_kernels(ops, q, k, v, dims, kv_mask, dropout_seed, causal, block_q,
 
 
 def _bwd_call(ops, q, k, v, g, out, lse, dims, kv_mask, dropout_seed, causal,
-              block_q, block_k, interpret, dropout_rate):
+              block_q, block_k, interpret, dropout_rate, window=None):
     """(dq, dk, dv) in ``ops``' layout from operands, the output and its
     cotangent ``g`` in that layout; ``dims`` = (s, kv_len, d).  Where q,
     k, v are one fused array, its one gradient."""
     ops, block_q, block_k = _resolve_blocks(ops, *dims, block_q, block_k,
-                                            causal, dropout_rate)
+                                            causal, dropout_rate,
+                                            _window_note(window))
     _calls["asked"] += 1
     return _bwd_kernels(ops, q, k, v, g, out, lse, dims, kv_mask,
                         dropout_seed, causal, block_q, block_k, interpret,
-                        dropout_rate)
+                        dropout_rate, window)
 
 
 @functools.partial(jax.jit, inline=True,
-                   static_argnums=(0, 7, 10, 11, 12, 13, 14))
+                   static_argnums=(0, 7, 10, 11, 12, 13, 14, 15))
 def _bwd_kernels(ops, q, k, v, g, out, lse, dims, kv_mask, dropout_seed,
-                 causal, block_q, block_k, interpret, dropout_rate):
+                 causal, block_q, block_k, interpret, dropout_rate,
+                 window=None):
     _calls["traced"] += 1
     s, kv_len, d = dims
     masked = kv_mask is not None
     n_qb = pl.cdiv(s, block_q)
     n_kb = pl.cdiv(kv_len, block_k)
+    # grouped or windowed heads: the streamed pair below, whatever the size
+    grouped = ops.kv_group > 1 or window is not None
+    if grouped:
+        assert not masked and not dropout_rate, (
+            "grouped or windowed heads take no key mask and no dropout")
 
     seed_ops, seed_specs, drop = _dropout_ops(dropout_rate, dropout_seed)
     static = dict(scale=1.0 / math.sqrt(d), causal=causal, masked=masked,
@@ -1090,7 +1145,7 @@ def _bwd_kernels(ops, q, k, v, g, out, lse, dims, kv_mask, dropout_seed,
             *mask_specs,
         ]
 
-    if n_qb == 1 and n_kb == 1:
+    if n_qb == 1 and n_kb == 1 and not grouped:
         # single-tile fused backward: one kernel, one score pass, Δ formed
         # inside.  A fused projection gets its ONE gradient from it
         # (_bwd_fused_kernel) while a batch row's whole [s, 3·h·d] fits
@@ -1132,17 +1187,68 @@ def _bwd_kernels(ops, q, k, v, g, out, lse, dims, kv_mask, dropout_seed,
                         axis=-1, keepdims=True).transpose(0, 2, 1)
 
     at_q, at_k = _outer, _inner  # grid (*steps, q blocks, k blocks)
+    band = n_kb     # k blocks a q block streams, q blocks a k block does
+    if window is not None:
+        assert causal and block_q == block_k, (
+            "a window is causal, over square blocks")
+        band = _band(window, block_k, n_kb)
+
+        def at_k(*ids):     # as the forward's: the band ends at the diagonal
+            return jnp.maximum(ids[-2] - (band - 1) + ids[-1], 0)
     mask_ops, specs = in_specs(at_q, at_k, ops.row_spec(block_q, at_q))
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, single=(n_kb == 1), **static),
-        grid=(*ops.steps, n_qb, n_kb),
+        functools.partial(_bwd_dq_kernel, single=(band == 1), window=window,
+                          **static),
+        grid=(*ops.steps, n_qb, band),
         in_specs=specs,
         out_specs=ops.spec(block_q, d, at_q),
         out_shape=ops.shape(s, d, q.dtype),
         scratch_shapes=[_VMEM((block_q, ops.heads * d), jnp.float32)],
         interpret=interpret,
+        name=_train_name(ops.kv_group, window, "bwd_dq"),
         **ops.grid_params(interpret, "parallel", "arbitrary"),
     )(q, k, v, g, lse, delta, *seed_ops, *mask_ops)
+
+    if grouped:
+        # grid (batch, KV heads, k blocks, the group's query heads x their
+        # q blocks): a k block's accumulators take every query head of its
+        # group in turn — the group's sum is formed in VMEM, in fp32 — and
+        # of each head the q blocks that see the k block: from the diagonal
+        # down, the band's width of them under a window
+        group, h = ops.kv_group, ops.h
+
+        def q_head(*ids):
+            return ids[1] * group + ids[-1] // band
+
+        def q_block(*ids):
+            at = ids[-1] % band
+            if window is None:
+                return at
+            return jnp.minimum(ids[-2] + at, n_qb - 1)
+
+        q_spec = pl.BlockSpec((1, block_q, d), lambda *ids: (
+            ids[0], q_block(*ids), q_head(*ids)))
+        kv_spec = pl.BlockSpec((1, block_k, d), lambda *ids: (
+            ids[0], ids[-2], ids[1]))
+        stat_spec = pl.BlockSpec((1, 1, block_q), lambda *ids: (
+            ids[0] * h + q_head(*ids), 0, q_block(*ids)))
+        kv_shape = jax.ShapeDtypeStruct((ops.b, kv_len, h // group * d),
+                                        k.dtype)
+        dk, dv = pl.pallas_call(
+            functools.partial(_bwd_dkv_kernel, single=(group * band == 1),
+                              window=window, per_head=band, n_qb=n_qb,
+                              **static),
+            grid=(ops.b, h // group, n_kb, group * band),
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec],
+            out_specs=[kv_spec, kv_spec],
+            out_shape=[kv_shape, kv_shape],
+            scratch_shapes=[_VMEM((block_k, d), jnp.float32),
+                            _VMEM((block_k, d), jnp.float32)],
+            interpret=interpret,
+            name=_train_name(group, window, "bwd_dkv"),
+            **ops.grid_params(interpret, "parallel", "arbitrary"),
+        )(q, k, v, g, lse, delta)
+        return dq, dk, dv
 
     # grid (*steps, k blocks, q blocks): q streams in the inner dimension
     at_q, at_k = _inner, _outer
@@ -1170,7 +1276,7 @@ def _bwd_kernels(ops, q, k, v, g, out, lse, dims, kv_mask, dropout_seed,
 
 
 def _flash_fwd(q, k, v, kv_mask, dropout_seed, causal, block_q, block_k,
-               interpret, dropout_rate, name=None, window=None):
+               interpret, dropout_rate, name=None, window=None, note=""):
     b, s, h, d = q.shape
     # the values may be narrower or wider than the keys (latent attention
     # expands keys of 192 beside values of 128): the score tile is q.k over
@@ -1180,28 +1286,52 @@ def _flash_fwd(q, k, v, kv_mask, dropout_seed, causal, block_q, block_k,
     out, lse = _fwd_call(
         ops, ops.to_kernel(q), ops.to_kernel(k), ops.to_kernel(v),
         (s, k.shape[1], d, dv), kv_mask, dropout_seed, causal, block_q,
-        block_k, interpret, dropout_rate, name, window)
+        block_k, interpret, dropout_rate, name, window, note)
     out = ops.from_kernel(out)
     return out, (q, k, v, kv_mask, dropout_seed, out, lse)
 
 
-def _flash_bwd_rule(causal, block_q, block_k, interpret, dropout_rate, res, g):
+# what a grouped or windowed forward leaves for its backward, by name: a
+# model that recomputes its layers may keep the two (``jax.checkpoint``'s
+# ``save_only_these_names``) and spare itself the second forward kernel
+SAVED_NAMES = ("flash_attention_out", "flash_attention_lse")
+
+
+def _flash_fwd_rule(q, k, v, kv_mask, dropout_seed, causal, block_q, block_k,
+                    interpret, dropout_rate, window):
+    name = _train_name(q.shape[2] // k.shape[2], window, "fwd")
+    out, res = _flash_fwd(q, k, v, kv_mask, dropout_seed, causal, block_q,
+                          block_k, interpret, dropout_rate, name=name,
+                          window=window, note=_window_note(window))
+    if name is not None:
+        out, lse = (checkpoint_name(x, n) for x, n in zip(
+            (out, res[-1]), SAVED_NAMES))
+        res = (*res[:-2], out, lse)
+    return out, res
+
+
+def _flash_bwd_rule(causal, block_q, block_k, interpret, dropout_rate, window,
+                    res, g):
     assert res[2].shape[-1] == res[0].shape[-1], (
         "the flash backward kernels assume values as wide as keys; a "
         "value width of its own is forward-only (flash_attention_forward)")
     q, k, v, kv_mask, dropout_seed, out, lse = res
     b, s, h, d = q.shape
-    ops = _Operands(b, h, d, d)
-    grads = _bwd_call(
+    ops = _Operands(b, h, d, d, kv_group=h // k.shape[2])
+    dq, dk, dv = _bwd_call(
         ops, ops.to_kernel(q), ops.to_kernel(k), ops.to_kernel(v),
         ops.to_kernel(g), ops.to_kernel(out), lse, (s, k.shape[1], d),
         kv_mask, dropout_seed, causal, block_q, block_k, interpret,
-        dropout_rate)
-    return (*map(ops.from_kernel, grads),
+        dropout_rate, window)
+    if ops.kv_group > 1:    # [b, s, kv heads · d], summed over each group
+        dk, dv = dk.reshape(k.shape), dv.reshape(v.shape)
+    else:
+        dk, dv = ops.from_kernel(dk), ops.from_kernel(dv)
+    return (ops.from_kernel(dq), dk, dv,
             None if kv_mask is None else jnp.zeros_like(kv_mask), None)
 
 
-flash_attention.defvjp(_flash_fwd, _flash_bwd_rule)
+flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 def flash_self_attention(qkv, kv_mask=None, dropout_seed=None, causal=False,

@@ -68,15 +68,20 @@ def is_configured():
     return _config is not None
 
 
-def make_remat_policy(cfg=None):
+def make_remat_policy(cfg=None, save_names=()):
     """The ``jax.checkpoint`` policy encoding the config's memory knobs.
-    ``None`` means plain full remat (save only the layer boundary)."""
+    ``None`` means plain full remat (save only the layer boundary).
+    ``save_names``: values the layer tagged with ``checkpoint_name`` that
+    stay on the device instead of being recomputed (a kernel's outputs
+    that cost more to make again than to keep)."""
     cfg = cfg or _config
     if cfg.cpu_checkpointing:
         return jax.checkpoint_policies.save_and_offload_only_these_names(
-            names_which_can_be_saved=[],
+            names_which_can_be_saved=list(save_names),
             names_which_can_be_offloaded=[_CKPT_NAME],
             offload_src="device", offload_dst="pinned_host")
+    if save_names:
+        return jax.checkpoint_policies.save_only_these_names(*save_names)
     return None
 
 
@@ -114,8 +119,9 @@ def _annotate(x, cfg):
     return x
 
 
-def checkpoint_wrapper(fn, cfg=None, argnums=None):
-    """Wrap a layer-apply function in config-driven rematerialization.
+def checkpoint_wrapper(fn, cfg=None, argnums=None, save_names=()):
+    """Wrap a layer-apply function in config-driven rematerialization;
+    ``save_names`` as in :func:`make_remat_policy`.
 
     The offload/partition annotations apply to the layer's *activations*,
     never its weights (annotating parameters would stream every weight to
@@ -135,7 +141,7 @@ def checkpoint_wrapper(fn, cfg=None, argnums=None):
             for i, a in enumerate(args))
         return fn(*args, **kwargs)
 
-    policy = make_remat_policy(cfg)
+    policy = make_remat_policy(cfg, save_names)
     if policy is not None:
         return jax.checkpoint(annotated, policy=policy)
     return jax.checkpoint(annotated)

@@ -310,6 +310,12 @@ class DeepSpeedEngine:
             self._loss_fn = model
         else:
             raise TypeError("model must expose .apply(params, batch, ...) or be callable")
+        # a model may report scalars of its own beside the loss (an expert
+        # layer's load, its auxiliary loss): ``apply_reporting(...) ->
+        # (loss, {name: device scalar})``.  They leave the fused step as
+        # outputs and are read at the print cadence alone
+        self._loss_reports_fn = getattr(model, "apply_reporting", None)
+        self._last_reports = {}
 
         # -- parameter init --
         rng_seed = int(self._config._param_dict.get("seed", 0))
@@ -3145,24 +3151,34 @@ class DeepSpeedEngine:
             return sloss * grad_acc / cur_scale, flat_g, {}
 
         @jax.named_scope("loss_and_grads")
-        def loss_and_flat_grads(params, batch, rng, cur_scale, extra):
+        def loss_grads_and_reports(params, batch, rng, cur_scale, extra):
+            """(loss, flat gradient, sparse drop counters, the model's own
+            reported scalars: {} unless it has ``apply_reporting`` and the
+            step takes the plain path)."""
             if sparse_paths:
-                return sparse_loss_and_flat_grads(params, batch, rng,
-                                                  cur_scale, extra)
+                return (*sparse_loss_and_flat_grads(params, batch, rng,
+                                                    cur_scale, extra), {})
             if stage3_overlap:
                 # ``params`` IS the sharded flat master here — gathers
                 # happen inside the differentiated body
-                return zero3_loss_and_flat_grads(params, batch, rng,
-                                                 cur_scale, extra)
+                return (*zero3_loss_and_flat_grads(params, batch, rng,
+                                                   cur_scale, extra), {})
             if comm_overlap:
-                return bucketed_loss_and_flat_grads(params, batch, rng,
-                                                    cur_scale, extra)
+                return (*bucketed_loss_and_flat_grads(params, batch, rng,
+                                                      cur_scale, extra), {})
 
             def scaled_loss(p):
-                loss = self._loss_fn(p, batch, rng=rng, train=True, **extra)
-                return (loss.astype(jnp.float32) * cur_scale) / grad_acc
+                if self._loss_reports_fn is None:
+                    loss, reports = self._loss_fn(p, batch, rng=rng,
+                                                  train=True, **extra), {}
+                else:
+                    loss, reports = self._loss_reports_fn(
+                        p, batch, rng=rng, train=True, **extra)
+                return ((loss.astype(jnp.float32) * cur_scale) / grad_acc,
+                        reports)
 
-            sloss, grads = jax.value_and_grad(scaled_loss)(params)
+            (sloss, reports), grads = jax.value_and_grad(
+                scaled_loss, has_aux=True)(params)
             with jax.named_scope("grad_flatten"):
                 flat_g = self.flat.flatten_grads(grads, dtype=grad_flat_dtype)
             # GSPMD places the reduce-scatter / all-reduce where the flat
@@ -3171,7 +3187,7 @@ class DeepSpeedEngine:
                 flat_g = jax.lax.with_sharding_constraint(flat_g,
                                                           grad_sharding)
             loss = sloss * grad_acc / cur_scale
-            return loss, flat_g, {}
+            return loss, flat_g, {}, reports
 
         @jax.named_scope("loss_and_grads")
         def loss_and_grads_tree(params, batch, rng, cur_scale, extra):
@@ -3193,7 +3209,8 @@ class DeepSpeedEngine:
             # zero3_loss_and_flat_grads gathers per group inside
             params = (params_or_master if not stage3 or stage3_overlap
                       else cast_params(params_or_master))
-            return loss_and_flat_grads(params, batch, rng, cur_scale, extra)
+            return loss_grads_and_reports(params, batch, rng, cur_scale,
+                                          extra)[:3]
 
         self._fwd_bwd_fn = self.memory_ledger.wrap(
             "fwd_bwd", jax.jit(
@@ -3386,12 +3403,12 @@ class DeepSpeedEngine:
                 drops = {k: jnp.asarray(0, jnp.int32) for k in sparse_paths}
                 return (loss, master, opt_state, scale_state, skipped,
                         ustep + jnp.uint32(1), overflow, gnorm, new_params,
-                        drops, hostgrad, qres)
+                        drops, hostgrad, qres, {})
 
             def micro(carry, xs):
                 acc, i, drops_acc = carry
                 batch_i = xs
-                loss, flat_g, drops = loss_and_flat_grads(
+                loss, flat_g, drops, reports = loss_grads_and_reports(
                     fwd_params, batch_i, jax.random.fold_in(rng, i), cur_scale,
                     extra)
                 # drops may cover a SUBSET of declared leaves (trace-time
@@ -3401,20 +3418,23 @@ class DeepSpeedEngine:
                              for k, v in drops_acc.items()}
                 with jax.named_scope("loss_and_grads"):
                     acc = acc + flat_g
-                return (acc, i + 1, drops_acc), loss
+                return (acc, i + 1, drops_acc), (loss, reports)
 
             drops0 = {k: jnp.asarray(0, jnp.int32) for k in sparse_paths}
             if acc_steps == 1:
                 with jax.named_scope("unpack"):
                     one = jax.tree_util.tree_map(lambda x: x[0], batches)
-                loss, flat_g, drops = loss_and_flat_grads(fwd_params, one, rng,
-                                                          cur_scale, extra)
+                loss, flat_g, drops, reports = loss_grads_and_reports(
+                    fwd_params, one, rng, cur_scale, extra)
                 losses = loss[None]
                 drops = {**drops0, **drops}
             else:
-                (flat_g, _, drops), losses = jax.lax.scan(
+                (flat_g, _, drops), (losses, reports) = jax.lax.scan(
                     micro, (jnp.zeros(flat_shape, jnp.float32),
                             jnp.asarray(0, jnp.int32), drops0), batches)
+                # the micro-batches' mean, as the loss
+                reports = jax.tree_util.tree_map(
+                    lambda x: jnp.mean(x, axis=0), reports)
 
             upd = apply_update(master, opt_state, scale_state, skipped,
                                flat_g, hp, segment_ids, qres=qres,
@@ -3437,7 +3457,7 @@ class DeepSpeedEngine:
                 new_params = cast_params(master)
             return (jnp.mean(losses), master, opt_state, scale_state, skipped,
                     ustep + jnp.uint32(1), overflow, gnorm, new_params, drops,
-                    hostgrad, qres)
+                    hostgrad, qres, reports)
 
         hostgrad_sharding = None
         if offload_grads_mode:
@@ -3458,7 +3478,7 @@ class DeepSpeedEngine:
                 out_shardings=(None, master_out_sharding, opt_out_shardings,
                                None, None, None, None, None,
                                None if stage3 else param_shardings, None,
-                               hostgrad_sharding, qres_sharding)),
+                               hostgrad_sharding, qres_sharding, None)),
             static_argnums=(7,))
 
         # 1-bit Adam compressed phase: a second program with NO dense
@@ -3958,6 +3978,8 @@ class DeepSpeedEngine:
             self.state["hostgrad"] = out[10]
         if len(out) > 11:
             self.state["qres"] = out[11]
+        if len(out) > 12:   # device scalars: fetched at the print cadence
+            self._last_reports = out[12]
         if self.zero_stage < 3:
             self._module_params = new_params
         if self._offload_eager:
@@ -4031,6 +4053,8 @@ class DeepSpeedEngine:
                 fp_dev = self._integrity_fingerprint_device()
                 if fp_dev is not None:
                     fetch["fingerprint"] = fp_dev
+                if self._last_reports:   # the model's own scalars ride along
+                    fetch["reports"] = self._last_reports
                 # dslint: disable=DSH203 -- print cadence; cannot batch with the per-step fp16 overflow fetch above
                 stats = jax.device_get(fetch)
                 loss_val = float(stats["loss"])
@@ -4049,6 +4073,8 @@ class DeepSpeedEngine:
                     "Train/Samples/train_loss": loss_val,
                     "Train/Samples/lr": lr,
                     "Train/Samples/loss_scale": scale,
+                    **{name: float(value) for name, value in
+                       stats.get("reports", {}).items()},
                 }, skipped=int(stats["skipped"]))
                 self._sample_memory_watermarks()
                 self._sample_comm_skew()

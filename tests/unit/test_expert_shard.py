@@ -104,3 +104,136 @@ def test_capacity_follows_from_shapes_alone():
     # never above the pairs there are; all of them with no router width
     assert expert_shard.pair_capacity(9, 4, 16, 128) == 9
     assert expert_shard.pair_capacity(384, 20, None, 128) == 384
+
+
+# -- the layer differentiated: d x, d weights, d expert weights ---------------
+
+def _dense_jax(x, weights, ids, valid, experts, first_expert):
+    """``_dense`` in jax, for ``jax.grad``."""
+    y = jnp.zeros(x.shape, jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        for e in range(experts["down"].shape[0]):
+            f = gated_silu_mlp(
+                {"gate_up": {"kernel": experts["gate_up"][e]},
+                 "down": {"kernel": experts["down"][e]}}, x)
+            chosen = (ids == first_expert + e) & valid[:, None]
+            y = y + (weights * chosen).sum(axis=1)[:, None] * f
+    return y
+
+
+GRAD_CASES = {
+    # every pair on two held experts: the second, third and fourth passes run
+    "every_pair_held_here": "reverse",
+    "uniform_top6_20_of_160": "reverse",
+    "no_pair_held_here": "reverse",
+    "padding_rows_invalid": "reverse",
+    # a capacity of every pair: the loop-free form, by plain autodiff
+    "capacity_is_every_pair": "plain",
+}
+
+
+@pytest.mark.parametrize("case", GRAD_CASES)
+def test_layer_gradients_match_the_dense_sum_and_nothing_is_dropped(case):
+    tokens, top_k, held, routed, first, make_ids, n_valid, want = CASES[case]
+    experts = _experts(held)
+    x = jax.random.normal(jax.random.PRNGKey(5), (tokens, HIDDEN))
+    ids = (_uniform_ids(tokens, top_k, routed, 11) if make_ids is None
+           else make_ids(tokens, top_k))
+    weights = jax.random.uniform(jax.random.PRNGKey(6), (tokens, top_k),
+                                 minval=0.1, maxval=1.0)
+    valid = jnp.arange(tokens) < n_valid
+    probe = jax.random.normal(jax.random.PRNGKey(7), (tokens, HIDDEN))
+
+    def layer(x, weights, experts):
+        y, counts = expert_shard.held_experts_ffn(
+            x, weights, ids, valid, experts, first_expert=first,
+            interpret=True, tiling=(TILE, 128, 128), routed=routed,
+            reverse=GRAD_CASES[case] == "reverse")
+        return jnp.sum(y * probe), (y, counts)
+
+    def dense(x, weights, experts):
+        return jnp.sum(_dense_jax(x, weights, ids, valid, experts, first)
+                       * probe)
+
+    (_, (y, counts)), got = jax.jit(jax.value_and_grad(
+        layer, argnums=(0, 1, 2), has_aux=True))(x, weights, experts)
+    want_grads = jax.grad(dense, argnums=(0, 1, 2))(x, weights, experts)
+    np.testing.assert_allclose(
+        np.asarray(y), _dense(x, weights, ids, valid, experts, first),
+        rtol=1e-4, atol=1e-5)
+    assert int(expert_shard.pair_passes(
+        counts, tokens * top_k, routed, TILE)) == want[1]
+    for g, r in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want_grads)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=2e-4,
+                                   atol=2e-5)
+
+
+@pytest.mark.parametrize("sizes", [(16, 0, 32, 16), (5, 27, 0, 32),
+                                   (0, 0, 0, 64), (30, 30, 4, 0)],
+                         ids=str)
+def test_grouped_product_gradient_leaves_out_the_groups_held_elsewhere(sizes):
+    """Three held groups and a fourth (the last of ``sizes``) whose rows are
+    nobody's: d lhs zero there, d rhs the held groups' rows alone, an empty
+    group's zero."""
+    from deepspeed_tpu.ops.transformer.grouped_matmul import (
+        moe_grouped_matmul)
+
+    m, k, n = sum(sizes), 32, 48
+    lhs = jax.random.normal(jax.random.PRNGKey(1), (m, k))
+    rhs = jax.random.normal(jax.random.PRNGKey(2), (3, k, n))
+    probe = jax.random.normal(jax.random.PRNGKey(3), (m, n))
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    group = np.repeat(np.arange(4), sizes)
+    held = jnp.asarray(group < 3)[:, None]
+
+    def kernel(lhs, rhs):
+        return jnp.sum(moe_grouped_matmul(
+            lhs, rhs, group_sizes, tiling=(16, 128, 128), interpret=True)
+            * probe)
+
+    def plain(lhs, rhs):
+        out = jnp.einsum("mk,mkn->mn", lhs, rhs[np.minimum(group, 2)],
+                         precision="highest")
+        return jnp.sum(jnp.where(held, out, 0.0) * probe)
+
+    got = jax.grad(kernel, argnums=(0, 1))(lhs, rhs)
+    want = jax.grad(plain, argnums=(0, 1))(lhs, rhs)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=1e-4,
+                                   atol=1e-4)
+    assert not np.asarray(got[0])[group == 3].any()
+
+
+def test_plain_softmax_router_renormalises_over_all_the_chosen():
+    x = jax.random.normal(jax.random.PRNGKey(0), (12, HIDDEN))
+    kernel = jax.random.normal(jax.random.PRNGKey(1), (HIDDEN, 8))
+    scores = expert_shard.router_scores(x, kernel, "softmax")
+    weights, ids = expert_shard.route(
+        x, kernel, n_group=1, topk_group=1, top_k=3, scaling=1.0,
+        scoring="softmax", renormalise=True)
+    p = np.asarray(jax.nn.softmax(jnp.matmul(
+        x, kernel, precision="highest"), axis=-1))
+    np.testing.assert_allclose(np.asarray(scores), p, rtol=1e-5)
+    top = np.argsort(-p, axis=-1)[:, :3]
+    assert (np.asarray(ids) == top).all()
+    chosen = np.take_along_axis(p, top, axis=-1)
+    np.testing.assert_allclose(np.asarray(weights),
+                               chosen / chosen.sum(-1, keepdims=True),
+                               rtol=1e-5)
+
+
+def test_aux_load_balance_by_hand_and_its_gradient_through_the_scores():
+    # 4 tokens, 2 choices, 4 experts: loads 3, 2, 2, 1 pairs over 4 tokens
+    ids = jnp.asarray([[0, 1], [0, 2], [0, 3], [1, 2]], jnp.int32)
+    scores = jnp.asarray([[.4, .3, .2, .1]] * 4, jnp.float32)
+    want = 4 * (0.75 * .4 + 0.5 * .3 + 0.5 * .2 + 0.25 * .1)
+    assert float(expert_shard.aux_load_balance(scores, ids, 4)) == \
+        pytest.approx(want, rel=1e-6)
+    # an even load and even scores: top_k
+    even_ids = jnp.asarray([[0, 1], [2, 3]], jnp.int32)
+    assert float(expert_shard.aux_load_balance(
+        jnp.full((2, 4), 0.25), even_ids, 4)) == pytest.approx(2.0)
+    grad = jax.grad(expert_shard.aux_load_balance)(scores, ids, 4)
+    np.testing.assert_allclose(
+        np.asarray(grad), np.tile([.75, .5, .5, .25], (4, 1)), rtol=1e-6)

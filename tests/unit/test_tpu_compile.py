@@ -848,3 +848,60 @@ def test_the_seq128_step_compiles_to_a_kernel_call_a_layer_each_way(v5e):
     text = lowered.compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 46
     assert re.findall(rf"\[{b},{h},{s},{s}\]", text) == []
+
+
+# -- the training kernels of grouped, windowed heads and held experts --------
+
+@pytest.mark.parametrize("window,name", [(None, "gqa_train_attention"),
+                                         (1024, "window_train_attention")])
+def test_flash_gradient_compiles_with_grouped_heads_and_a_window(
+        v5e, window, name):
+    """Mellum's attention at its widths (32 query heads over 4 KV heads of
+    128, one row of 8192, blocks of 512): forward, dq and the dk/dv kernel
+    that sums each group in VMEM, each under its own name; dk and dv leave
+    with the KV heads' shape."""
+    def s(heads):
+        return jax.ShapeDtypeStruct((1, 8192, heads, 128), jnp.bfloat16,
+                                    sharding=v5e)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, None, None, True, 512, 512,
+                                       False, 0.0, window)
+                       .astype(jnp.float32))
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    compiled = grad.lower(s(32), s(4), s(4)).compile()
+    text = compiled.as_text()
+    for part in ("fwd", "bwd_dq", "bwd_dkv"):
+        assert f"{name}_{part}" in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert re.findall(r"\[\d*,?8192,8192\]", text) == []
+    dq, dk, dv = grad.eval_shape(s(32), s(4), s(4))
+    assert (dq.shape, dk.shape, dv.shape) == (
+        (1, 8192, 32, 128), (1, 8192, 4, 128), (1, 8192, 4, 128))
+
+
+def test_grouped_matmul_gradient_compiles_for_the_trained_expert_layer(v5e):
+    """16 held experts of 2304 x 1792 (gate and up fused) and 896 x 2304
+    over a pass of 131,072 sorted pairs, 17 groups: the forward, d lhs (the
+    same kernel, weights transposed) and d rhs (``tgmm``), by name."""
+    from deepspeed_tpu.ops.transformer.grouped_matmul import (
+        moe_grouped_matmul)
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    for k, n in ((2304, 1792), (896, 2304)):
+        text = _compile(jax.grad(
+            lambda l, r, g: jnp.sum(moe_grouped_matmul(
+                l, r, g, tiling=(512, 1024, 1024)).astype(jnp.float32) ** 2),
+            argnums=(0, 1)),
+            s((131072, k)), s((16, k, n)), s((17,), jnp.int32))
+        assert text.count('custom_call_target="tpu_custom_call"') == 3
+        calls = re.findall(
+            r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+        # a differentiated forward is ``jvp_jit_moe_grouped_matmul__``: one
+        # pattern, ``moe_grouped_matmul``, finds all three in a trace
+        assert all("moe_grouped_matmul" in name for name in calls), calls
+        assert sum("bwd_lhs" in name for name in calls) == 1
+        assert sum("bwd_rhs" in name for name in calls) == 1
