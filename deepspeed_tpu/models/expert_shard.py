@@ -41,7 +41,11 @@ has no reverse rule, so a training program asks for ``reverse=True``: the
 first pass as it is, and each further pass the routing could need under a
 ``cond`` on ``pair_passes``, recomputed on the way back, so that a pass
 that does not run costs neither time nor memory in either direction.  The
-serving programs keep the forms they had.
+program that asked for ``reverse`` — the one with a backward to pay —
+takes its two ways out (the choices' weighted sum and d x:
+:func:`_sum_of_rows`) through ``ops/transformer/gather_combine.py``, which
+moves the rows of the pairs held here alone.  The serving programs keep
+the forms they had.
 
 Readings that chose the form (on a v5e; PERF.md section 6, PR 39).  One
 DeepSeek-V2 expert layer, 8,192 tokens, 6 choices, 20 of 160 experts:
@@ -64,6 +68,25 @@ move: there a pass takes every pair, the program holds no loop, and the
 way out stays one gather of all the pairs with their weighted sum —
 ``top_k`` gathers of 64 rows cost 26 to 42 us a layer more than that
 (1.367 for 1.341 ms, 1.753 for 1.711).
+
+Readings that chose the training program's ways (PERF.md section 6, PR
+44).  XLA's row gather moves ~85 GB/s whoever owns the row: 54 ns a row
+of 2,304 bfloat16, 112 of 5,120, 128 of 6,144 — Mellum's layer (32,768
+tokens, 8 of 64 experts a token, 16 held) gathers 917,504 rows a step,
+three of four of the ways out's masked on arrival.  The way out alone,
+XLA's ``top_k`` gathers against ``moe_gather_combine`` (equal to the last
+bit): 14.26 ms for 4.56 at Mellum's geometry (70 ns a HELD row: the sums
+read single rows out of VMEM, a sublane of eight at work), 5.50 for 1.35
+at DeepSeek-V2's prefill (8,192 tokens, 6 choices, 20 of 160) and 8.43
+for 1.77 at K-EXAONE's (8 choices, 16 of 128) with the source in HBM —
+where the serving cell's program has it in XLA's faster memory a row
+cost 19 ns (PR 39), 1.25 ms, so the serving programs were left alone.
+The ways in cut at ``held_rows`` (a loop of 8,192-row gathers into a
+zeroed buffer, or ``cond`` chunks concatenated) gained nothing on the
+layer alone (57.24 and 59.43 ms forward + backward for 57.18) and cost
+0.3 GB: the carried buffer is copied.  A DMA cannot move one row of a
+tiled array, which is why the kernel copies chunks and why no kernel
+moves the ways in.
 """
 
 import functools
@@ -71,6 +94,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..ops.transformer.gather_combine import moe_gather_combine
 from ..ops.transformer.grouped_matmul import moe_grouped_matmul
 from ..utils.logging import logger
 from .layers import gated_silu
@@ -168,53 +192,88 @@ def pair_passes(counts, pairs, routed, tile):
     return jnp.maximum(-(-counts[:-1].sum() // capacity), 1)
 
 
-@jax.custom_vjp
-def _rows_of_pairs(x, token, row, inside):
+def rows_moved_share(counts, pairs, routed, tile):
+    """Rows the ways in and out of a ``reverse`` layer move for ``counts``
+    as :func:`held_experts_ffn` returns them, over what moving every row of
+    every pass would (fp32 scalar, from the counts and shapes alone).  A
+    pass moves ``capacity`` rows three ways in (``x[token]`` forward and
+    recomputed, ``g[token]`` backward) and its held pairs' rows two ways
+    out (the choices' weighted sum and d x), where every pair's would be
+    ``pairs`` rows each way out."""
+    capacity = pair_capacity(pairs, counts.size - 1, routed, tile)
+    held = counts[:-1].sum()
+    passes = jnp.maximum(-(-held // capacity), 1)
+    moved = 3 * capacity * passes + 2 * held
+    every = passes * (3 * capacity + 2 * pairs)
+    return moved.astype(jnp.float32) / every.astype(jnp.float32)
+
+
+def _sum_of_rows(way, src, row, group, c=None, start=None):
+    """``start + sum_j c[t, j] src[row[t, j]]`` (``c`` None: 1, ``start``
+    None: 0) over the pairs with ``group[t, j] >= 0``, in fp32, the choices
+    in their own order.  ``way`` is ``(held, interpret)`` for the kernel
+    that moves those pairs' rows alone
+    (``ops/transformer/gather_combine.py``), or None for ``top_k`` gathers
+    of ``[tokens, hidden]`` masked and summed in one fusion."""
+    if way is not None:
+        total = moe_gather_combine(src, row, group, c, held=way[0],
+                                   interpret=way[1])
+        return total if start is None else start + total
+    total = start
+    if total is None:
+        total = jnp.zeros((row.shape[0], src.shape[1]), jnp.float32)
+    for j in range(row.shape[1]):
+        rows = src[row[:, j]].astype(jnp.float32)
+        total = total + jnp.where(
+            group[:, j, None] >= 0,
+            rows if c is None else c[:, j, None] * rows, 0.0)
+    return total
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _rows_of_pairs(way, x, token, row, group):
     """``x[token]``: the rows of ``x [tokens, hidden]`` a pass's sorted
     pairs read, ``token [capacity]``.  ``row [tokens, top_k]`` is where each
-    (token, choice) pair stands in the pass and ``inside`` whether it does:
-    on the way back a token's gradient is the sum of its pairs' rows, top_k
-    gathers as in :func:`_weighted_rows`, not a scatter of the rows."""
+    (token, choice) pair stands in the pass and ``group`` its expert among
+    the held (negative: not in the pass): on the way back a token's
+    gradient is the sum of its pairs' rows (:func:`_sum_of_rows`), not a
+    scatter of the rows."""
     return x[token]
 
 
-def _rows_of_pairs_fwd(x, token, row, inside):
-    return x[token], (row, inside)
+def _rows_of_pairs_fwd(way, x, token, row, group):
+    return x[token], (row, group)
 
 
-def _rows_of_pairs_bwd(res, g):
-    row, inside = res
-    dx = sum(jnp.where(inside[:, j, None], g[row[:, j]].astype(jnp.float32),
-                       0.0) for j in range(row.shape[1]))
-    return dx.astype(g.dtype), None, None, None
+def _rows_of_pairs_bwd(way, res, g):
+    row, group = res
+    return _sum_of_rows(way, g, row, group).astype(g.dtype), None, None, None
 
 
 _rows_of_pairs.defvjp(_rows_of_pairs_fwd, _rows_of_pairs_bwd)
 
 
-@jax.custom_vjp
-def _weighted_rows(y, out, weights, row, inside, token, choice, held_rows):
-    """``y + sum_j w[t, j] out[row[t, j]]`` over the pairs ``inside`` the
-    pass, in fp32, the choices in their own order.  On the way back row
-    ``r`` of ``out`` belongs to one pair, ``(token[r], choice[r])``, so its
-    gradient is that pair's weight times its token's — a gather; the rows
-    past ``held_rows`` are nobody's."""
-    for j in range(row.shape[1]):
-        y = y + jnp.where(
-            inside[:, j, None],
-            weights[:, j, None] * out[row[:, j]].astype(jnp.float32), 0.0)
-    return y
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _weighted_rows(way, y, out, weights, row, group, token, choice,
+                   held_rows):
+    """``y + sum_j w[t, j] out[row[t, j]]`` over the pairs in the pass
+    (``group[t, j] >= 0``), in fp32, the choices in their own order
+    (:func:`_sum_of_rows`).  On the way back row ``r`` of ``out`` belongs to
+    one pair, ``(token[r], choice[r])``, so its gradient is that pair's
+    weight times its token's — a gather; the rows past ``held_rows`` are
+    nobody's."""
+    return _sum_of_rows(way, out, row, group, weights, y)
 
 
-def _weighted_rows_fwd(y, out, weights, row, inside, token, choice,
+def _weighted_rows_fwd(way, y, out, weights, row, group, token, choice,
                        held_rows):
-    return (_weighted_rows(y, out, weights, row, inside, token, choice,
+    return (_weighted_rows(way, y, out, weights, row, group, token, choice,
                            held_rows),
-            (out, weights, row, inside, token, choice, held_rows))
+            (out, weights, row, group, token, choice, held_rows))
 
 
-def _weighted_rows_bwd(res, g):
-    out, weights, row, inside, token, choice, held_rows = res
+def _weighted_rows_bwd(way, res, g):
+    out, weights, row, group, token, choice, held_rows = res
     mine = (jnp.arange(out.shape[0]) < held_rows)[:, None]
     g_rows = g[token]   # each sorted pair's token's gradient, once
     d_out = jnp.where(mine, weights[token, choice][:, None] * g_rows,
@@ -222,7 +281,7 @@ def _weighted_rows_bwd(res, g):
     # d w of a pair is its row's dot with its token's gradient: formed in
     # the sorted order, then one gather of scalars back to (token, choice)
     dots = jnp.sum(g_rows * out.astype(jnp.float32), axis=-1)
-    d_weights = jnp.where(inside, dots[row], 0.0).astype(weights.dtype)
+    d_weights = jnp.where(group >= 0, dots[row], 0.0).astype(weights.dtype)
     return g, d_out, d_weights, None, None, None, None, None
 
 
@@ -302,6 +361,7 @@ def held_experts_ffn(x, weights, ids, valid, experts, *, first_expert,
         return jnp.einsum("tk,tkh->th", jnp.where(here, weights, 0.0),
                           out.astype(jnp.float32)), counts
     ends = jnp.cumsum(sizes[:held])
+    way = (held, interpret) if reverse else None
 
     def one_pass(p, y, x, weights, experts):
         """``y`` with sorted pairs ``[p * capacity, (p + 1) * capacity)``
@@ -312,10 +372,11 @@ def held_experts_ffn(x, weights, ids, valid, experts, *, first_expert,
         row = place - lo
         inside = here & (row >= 0) & (row < capacity)
         row = jnp.where(inside, row, 0)
+        group = jnp.where(inside, local, -1)
         token, held_rows = pair // top_k, mine.sum()
-        out = products(_rows_of_pairs(x, token, row, inside),
+        out = products(_rows_of_pairs(way, x, token, row, group),
                        jnp.append(mine, capacity - held_rows), experts)
-        return _weighted_rows(y, out, weights, row, inside, token,
+        return _weighted_rows(way, y, out, weights, row, group, token,
                               pair % top_k, held_rows)
 
     passes = pair_passes(counts, pairs, routed, tiling[0])

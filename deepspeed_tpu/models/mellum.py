@@ -236,7 +236,8 @@ class MellumForCausalLMTPU:
     def _moe(self, lp, x):
         """The expert layer's part of the stream (fp32) and what it
         reports: ``(aux loss, pairs a held expert and elsewhere, passes,
-        share of the tokens with no expert here)``."""
+        share of the tokens with no expert here, share of the rows
+        moved)``."""
         c, moe = self.config, lp["moe"]
         b, s, hidden = x.shape
         g32 = rms_norm(lp["post_norm"], x, c.rms_norm_eps).reshape(
@@ -265,7 +266,9 @@ class MellumForCausalLMTPU:
                 counts, ids.size, c.num_experts, c.expert_tiling[0])
             nowhere = expert_shard.tokens_without_held_expert(
                 ids, valid, c.first_expert, c.experts_held)
-        return y.reshape(b, s, hidden), (aux, counts, passes, nowhere)
+            moved = expert_shard.rows_moved_share(
+                counts, ids.size, c.num_experts, c.expert_tiling[0])
+        return y.reshape(b, s, hidden), (aux, counts, passes, nowhere, moved)
 
     def _layer(self, lp, x, kind, tables):
         with jax.named_scope("attention"):
@@ -320,6 +323,8 @@ class MellumForCausalLMTPU:
                 [load[1] for load in loads])).astype(jnp.float32),
             "training/moe_tokens_without_local_expert": jnp.mean(jnp.stack(
                 [load[2] for load in loads])),
+            "training/moe_rows_moved_share": jnp.mean(jnp.stack(
+                [load[3] for load in loads])),
             "training/moe_aux_loss": aux,
         }
 

@@ -237,3 +237,81 @@ def test_aux_load_balance_by_hand_and_its_gradient_through_the_scores():
     grad = jax.grad(expert_shard.aux_load_balance)(scores, ids, 4)
     np.testing.assert_allclose(
         np.asarray(grad), np.tile([.75, .5, .5, .25], (4, 1)), rtol=1e-6)
+
+
+def _routed_pairs(tokens, top_k, held, routed, fixed):
+    """``(group, row)`` of a seeded routing sorted by expert as the layer
+    sorts it, ``fixed`` {token: its experts} written over the draw."""
+    ids = np.array(_uniform_ids(tokens, top_k, routed, 3))
+    for token, experts in fixed.items():
+        ids[token] = experts
+    group = np.where(ids < held, ids, held)
+    order = np.argsort(group.reshape(-1), kind="stable")
+    row = np.zeros(tokens * top_k, np.int32)
+    row[order] = np.arange(tokens * top_k)
+    return (np.where(group < held, group, -1).astype(np.int32),
+            row.reshape(tokens, top_k))
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("weighted", [True, False],
+                         ids=["weights", "ones"])
+@pytest.mark.parametrize("tokens,tile", [(48, 16), (45, 16), (21, 256)],
+                         ids=["whole_tiles", "last_tile_partly_padding",
+                              "one_tile_of_every_token"])
+def test_the_way_out_kernel_sums_the_rows_of_the_pairs_here_and_no_other(
+        dtype, weighted, tokens, tile):
+    """``moe_gather_combine`` alone against ``sum_j where(inside, c *
+    src[row])`` in float32: tokens with none, one and all eight of their
+    choices here, pairs of another pass (their rows here, their group not),
+    and every row of ``src`` that no pair here owns NaN — a neighbour in a
+    fetched chunk must not reach a sum."""
+    from deepspeed_tpu.ops.transformer.gather_combine import (
+        moe_gather_combine)
+    top_k, held, routed = 8, 8, 32
+    group, row = _routed_pairs(
+        tokens, top_k, held, routed,
+        {0: np.arange(8, 16), 1: [3] + list(range(20, 27)),
+         2: np.arange(8)[::-1], tokens - 1: np.arange(8)})
+    group[5:9] = -1      # another pass's pairs
+    inside = group >= 0
+    assert inside[0].sum() == 0 and inside[1].sum() == 1
+    assert inside[2].all() and inside[-1].all()
+    rows = -(-int(row[inside].max() + 1) // 16) * 16 + 16
+    src = np.array(jax.random.normal(jax.random.PRNGKey(4), (rows, HIDDEN)))
+    owned = np.zeros(rows, bool)
+    owned[row[inside]] = True
+    src[~owned] = np.nan
+    src = jnp.asarray(src, dtype)
+    c = np.asarray(jax.random.uniform(
+        jax.random.PRNGKey(9), (tokens, top_k), minval=0.1, maxval=1.0))
+    row = np.where(inside, row, 0)
+    got = moe_gather_combine(
+        src, jnp.asarray(row), jnp.asarray(group),
+        jnp.asarray(c) if weighted else None, held=held, tile=tile,
+        interpret=True)
+    want = np.zeros((tokens, HIDDEN), np.float32)
+    values = np.asarray(src.astype(jnp.float32))
+    for j in range(top_k):
+        term = (c[:, j, None] if weighted else 1.0) * values[row[:, j]]
+        want = want + np.where(inside[:, j, None], term, np.float32(0))
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    assert not np.isnan(np.asarray(got)).any()
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6, atol=1e-6)
+    assert (np.asarray(got)[0] == 0).all()      # no pair here: zeros
+
+
+@pytest.mark.parametrize("held_pairs,want", [
+    # one pass of 256 rows: 3 * 256 ways in + 2 * 64 ways out of
+    # 3 * 256 + 2 * 512
+    ([40, 24], (768 + 128) / 1792),
+    ([0, 0], 768 / 1792),
+    ([256, 0], (768 + 512) / 1792),
+    # two passes
+    ([200, 100], (2 * 768 + 600) / (2 * 1792)),
+])
+def test_rows_moved_share_is_the_hand_count(held_pairs, want):
+    counts = jnp.asarray(held_pairs + [512 - sum(held_pairs)], jnp.int32)
+    assert expert_shard.pair_capacity(512, 2, 8, 16) == 256
+    got = float(expert_shard.rows_moved_share(counts, 512, 8, 16))
+    assert got == pytest.approx(want, rel=1e-6)

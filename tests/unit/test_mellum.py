@@ -170,12 +170,21 @@ def test_the_step_reports_the_expert_layers_counters(trained):
     assert set(reports) == {
         "training/moe_expert_load_max_over_mean",
         "training/moe_local_assignment_share", "training/moe_pair_passes",
-        "training/moe_tokens_without_local_expert", "training/moe_aux_loss"}
+        "training/moe_tokens_without_local_expert", "training/moe_aux_loss",
+        "training/moe_rows_moved_share"}
     # 2 of 8 experts held, 2 choices a token
     assert 0.1 < reports["training/moe_local_assignment_share"] < 0.45
     assert reports["training/moe_pair_passes"] >= 1
     assert reports["training/moe_expert_load_max_over_mean"] >= 1
     assert 1.9 < reports["training/moe_aux_loss"] < 4   # top_k when even
+    # 256 tokens, 512 pairs, a pass of 256 rows: a layer moves the pass
+    # three ways in and its held pairs' rows two ways out, of the
+    # 3 * 256 + 2 * 512 rows of every pair's
+    assert reports["training/moe_pair_passes"] == 1
+    held = reports["training/moe_local_assignment_share"] * 512
+    moved = reports["training/moe_rows_moved_share"]
+    assert 0 < moved <= 1
+    assert moved == pytest.approx((768 + 2 * held) / 1792, rel=1e-5)
 
 
 def test_the_shares_add_up_to_the_uncut_layer():

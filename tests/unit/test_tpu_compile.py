@@ -905,3 +905,37 @@ def test_grouped_matmul_gradient_compiles_for_the_trained_expert_layer(v5e):
         assert all("moe_grouped_matmul" in name for name in calls), calls
         assert sum("bwd_lhs" in name for name in calls) == 1
         assert sum("bwd_rhs" in name for name in calls) == 1
+
+
+def test_the_trained_expert_layer_gathers_no_row_nobody_here_owns(v5e):
+    """Mellum's layer a chip (32,768 tokens of 2,304, 8 of 64 experts a
+    token, 16 held, a pass of 131,072 rows), forward and backward under
+    ``reverse``: both ways out — the choices' weighted sum and d x — are
+    ``moe_gather_combine`` through Mosaic, so no gather gives a ``[32768,
+    2304]`` array; the ways in stay XLA's gathers of ``[131072, 2304]``."""
+    from deepspeed_tpu.models import expert_shard
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def loss(x, w, experts, ids, valid):
+        y, _ = expert_shard.held_experts_ffn(
+            x, w, ids, valid, experts, first_expert=0, interpret=False,
+            tiling=(512, 1024, 1024), routed=64, reverse=True)
+        return jnp.sum(y * y)
+
+    text = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)), s((32768, 2304)),
+        s((32768, 8), jnp.float32),
+        {"gate_up": s((16, 2304, 1792)), "down": s((16, 896, 2304))},
+        s((32768, 8), jnp.int32), s((32768,), jnp.bool_))
+    calls = re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    # the first pass, and the one further pass the routing could need
+    # under its ``cond``: each its two ways out
+    assert sum(name.startswith("moe_gather_combine") for name in calls) == 4
+    gathered = re.findall(r"= \w+\[([\d,]+)\]\S* gather\(", text)
+    assert "32768,2304" not in gathered
+    # x[token] and g[token] of the first pass; of the further one x[token]
+    # forward and recomputed, and g[token]
+    assert gathered.count("131072,2304") == 5
