@@ -36,16 +36,23 @@ weights.  Its ways in and out are gathers in both directions
 (:func:`_rows_of_pairs`, :func:`_weighted_rows`: the reverse of a gather
 of sorted pairs is a gather by the inverse permutation, never XLA's row
 scatter, which the readings below put at 1.4 us a row), and the grouped
-product has a gradient rule of its own.  A ``while_loop`` over the passes
-has no reverse rule, so a training program asks for ``reverse=True``: the
-first pass as it is, and each further pass the routing could need under a
-``cond`` on ``pair_passes``, recomputed on the way back, so that a pass
-that does not run costs neither time nor memory in either direction.  The
-program that asked for ``reverse`` — the one with a backward to pay —
-takes its two ways out (the choices' weighted sum and d x:
-:func:`_sum_of_rows`) through ``ops/transformer/gather_combine.py``, which
-moves the rows of the pairs held here alone.  The serving programs keep
-the forms they had.
+product has a gradient rule of its own.  The index arithmetic around them
+— how many pairs a group got, where a pair stands among the sorted, which
+lane a chosen score's gradient belongs to — is dense wherever the shapes
+allow a compare (:func:`sorted_pairs`, :func:`_top_k`,
+:func:`aux_load_balance`): XLA's gather or scatter of SCALARS costs 4.6 to
+8.7 ns an element, so a histogram of 262,144 pairs into 17 bins took as
+long as a grouped product's quarter.  What the choice and the sort produce
+carries a name (``SAVED_NAMES``), so that a model which recomputes its layers
+on the way back keeps them and routes once a step.  A ``while_loop`` over the
+passes has no reverse rule, so a training program asks for ``reverse=True``:
+the first pass as it is, and each further pass the routing could need under a
+``cond`` on ``pair_passes``, recomputed on the way back, so that a pass that
+does not run costs neither time nor memory in either direction.  The program
+that asked for ``reverse`` — the one with a backward to pay — takes its two
+ways out (the choices' weighted sum and d x: :func:`_sum_of_rows`) through
+``ops/transformer/gather_combine.py``, which moves the rows of the pairs held
+here alone.  The serving programs keep the ways out they had.
 
 Readings that chose the form (on a v5e; PERF.md section 6, PR 39).  One
 DeepSeek-V2 expert layer, 8,192 tokens, 6 choices, 20 of 160 experts:
@@ -87,12 +94,26 @@ layer alone (57.24 and 59.43 ms forward + backward for 57.18) and cost
 0.3 GB: the carried buffer is copied.  A DMA cannot move one row of a
 tiled array, which is why the kernel copies chunks and why no kernel
 moves the ways in.
+
+Readings that chose the index arithmetic (PERF.md section 6, PR 45; one
+v5e, Mellum's geometry: 262,144 pairs, 17 groups, a pass of 131,072).
+The group sizes as a scatter-add 2.33 ms, by compare-and-sum 0.21; the aux
+loss's load (four sequences' 64 bins) 2.35 for 0.20; ``lax.top_k``'s own
+reverse rule (a scatter into ``[32768, 64]``) 1.99 for the one-hot sum's
+0.23; a pass's pairs gathered out of the order 1.16, sliced 0.19 with the
+division; each pair's place by a scatter of the order 1.25, counted 0.38;
+a pair's weight gathered out of ``[tokens, top_k]`` 1.19, carried through
+the sort as a third operand +0.13 (the sort 0.26 -> 0.40; ``top_k``
+itself, a full sort of the scores on this compiler, 0.36).  In the cell a
+layer ran the choice, the sort and every forward-side op twice (``remat``):
+kept by name they run once.
 """
 
 import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.transformer.gather_combine import moe_gather_combine
 from ..ops.transformer.grouped_matmul import moe_grouped_matmul
@@ -100,20 +121,64 @@ from ..utils.logging import logger
 from .layers import gated_silu
 
 
+# what the choice and the sort leave for a layer's backward, by name: the
+# chosen scores and ids, the sorted pairs' order and weights, each pair's
+# place among the sorted and the groups' sizes.  A model that recomputes its
+# layers keeps them (``jax.checkpoint``'s ``save_only_these_names``: 5 MB a
+# Mellum layer) and its second forward holds no ``top_k`` and no sort
+SAVED_NAMES = ("moe_chosen_scores", "moe_chosen_ids", "moe_pair_order",
+               "moe_pair_weight", "moe_pair_place", "moe_group_sizes")
+
+
+def _one_of(ids, n):
+    """``[..., k, n]`` bool: is lane ``0..n-1`` the integer ``ids[..., j]``.
+    Summed or any-ed over ``k`` it is the dense form of a histogram or a
+    scatter of small integers: a compare a lane inside one fusion, where
+    XLA's scatter pays 7-9 ns an index."""
+    return ids[..., None] == jnp.arange(n, dtype=ids.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _top_k(scores, top_k):
+    """``lax.top_k`` over the last axis, with a reverse rule that scatters
+    nothing: the chosen ids of a row are distinct, so d scores is the sum
+    over the choices of ``d value`` where the lane is the choice's — one
+    non-zero term a lane, the value ``lax.top_k``'s own rule scatters."""
+    return tuple(jax.lax.top_k(scores, top_k))
+
+
+def _top_k_fwd(scores, top_k):
+    values, ids = (checkpoint_name(x, n) for x, n in zip(
+        jax.lax.top_k(scores, top_k), SAVED_NAMES))
+    # the lanes ride along as a residual for their number alone
+    return (values, ids), (ids, jnp.arange(scores.shape[-1], dtype=ids.dtype))
+
+
+def _top_k_bwd(top_k, res, g):
+    ids, lanes = res
+    return (jnp.sum(jnp.where(_one_of(ids, lanes.size), g[0][..., None], 0.0),
+                    axis=-2),)
+
+
+_top_k.defvjp(_top_k_fwd, _top_k_bwd)
+
+
 def group_limited_topk(scores, n_group, topk_group, top_k):
     """``group_limited_greedy``: a group's score is its best expert's;
     only the experts of the ``topk_group`` best groups stay; of those the
     ``top_k`` best are chosen.  ``scores [tokens, experts]`` (fp32) ->
     ``(weights [tokens, top_k], expert ids [tokens, top_k])``, the weights
-    the chosen experts' scores themselves."""
+    the chosen experts' scores themselves.  Where every group stays
+    (``topk_group == n_group``: one group, Mellum's and K-EXAONE's) there
+    is no limit to apply."""
     tokens, experts = scores.shape
-    grouped = scores.reshape(tokens, n_group, experts // n_group)
-    _, best_groups = jax.lax.top_k(grouped.max(axis=-1), topk_group)
-    keep = jnp.zeros((tokens, n_group), bool).at[
-        jnp.arange(tokens)[:, None], best_groups].set(True)
-    masked = jnp.where(keep[:, :, None], grouped, 0.0).reshape(
-        tokens, experts)
-    return jax.lax.top_k(masked, top_k)
+    if topk_group < n_group:
+        grouped = scores.reshape(tokens, n_group, experts // n_group)
+        _, best_groups = jax.lax.top_k(grouped.max(axis=-1), topk_group)
+        keep = _one_of(best_groups, n_group).any(axis=-2)
+        scores = jnp.where(keep[:, :, None], grouped, 0.0).reshape(
+            tokens, experts)
+    return _top_k(scores, top_k)
 
 
 def router_scores(x, router_kernel, scoring="softmax"):
@@ -164,8 +229,8 @@ def aux_load_balance(scores, ids, n_experts):
     tokens and ``P_e`` the tokens' mean score of ``e``.  1 * ``top_k`` under
     an even load; the gradient flows through the scores alone."""
     tokens = ids.shape[0]
-    load = jnp.zeros((n_experts,), jnp.float32).at[ids.reshape(-1)].add(
-        1.0) / tokens
+    load = jnp.sum(_one_of(ids.reshape(-1), n_experts), axis=0,
+                   dtype=jnp.float32) / tokens
     return n_experts * jnp.sum(load * scores.astype(jnp.float32).mean(0))
 
 
@@ -254,38 +319,97 @@ _rows_of_pairs.defvjp(_rows_of_pairs_fwd, _rows_of_pairs_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _weighted_rows(way, y, out, weights, row, group, token, choice,
+def _weighted_rows(way, y, out, weights, row, group, pair, pair_weight,
                    held_rows):
     """``y + sum_j w[t, j] out[row[t, j]]`` over the pairs in the pass
     (``group[t, j] >= 0``), in fp32, the choices in their own order
     (:func:`_sum_of_rows`).  On the way back row ``r`` of ``out`` belongs to
-    one pair, ``(token[r], choice[r])``, so its gradient is that pair's
-    weight times its token's — a gather; the rows past ``held_rows`` are
-    nobody's."""
+    one pair, ``pair[r]`` (``token * top_k + choice``) of weight
+    ``pair_weight[r]``, so its gradient is that weight times its token's
+    — one gather of rows; the rows past ``held_rows`` are nobody's."""
     return _sum_of_rows(way, out, row, group, weights, y)
 
 
-def _weighted_rows_fwd(way, y, out, weights, row, group, token, choice,
+def _weighted_rows_fwd(way, y, out, weights, row, group, pair, pair_weight,
                        held_rows):
-    return (_weighted_rows(way, y, out, weights, row, group, token, choice,
-                           held_rows),
-            (out, weights, row, group, token, choice, held_rows))
+    return (_weighted_rows(way, y, out, weights, row, group, pair,
+                           pair_weight, held_rows),
+            (out, weights, pair, pair_weight, held_rows))
 
 
 def _weighted_rows_bwd(way, res, g):
-    out, weights, row, group, token, choice, held_rows = res
-    mine = (jnp.arange(out.shape[0]) < held_rows)[:, None]
-    g_rows = g[token]   # each sorted pair's token's gradient, once
-    d_out = jnp.where(mine, weights[token, choice][:, None] * g_rows,
+    out, weights, pair, pair_weight, held_rows = res
+    mine = jnp.arange(out.shape[0]) < held_rows
+    g_rows = g[pair // weights.shape[1]]   # each sorted pair's token's, once
+    d_out = jnp.where(mine[:, None], pair_weight[:, None] * g_rows,
                       0.0).astype(out.dtype)
     # d w of a pair is its row's dot with its token's gradient: formed in
-    # the sorted order, then one gather of scalars back to (token, choice)
+    # the sorted order, and the held rows' scattered back to (token,
+    # choice) — half as many scalars as a gather by ``row`` would read; a
+    # row that is nobody's lands past the pairs, each in a place of its own.
+    # The indices are unique because the held pairs sort first: a row below
+    # ``held_rows`` stands at a sorted position below the number of held
+    # pairs (``ends[-1] <= pairs`` in ``held_experts_ffn``), so it is a pair
+    # of its own and never the last pair again that fills the last pass
     dots = jnp.sum(g_rows * out.astype(jnp.float32), axis=-1)
-    d_weights = jnp.where(group >= 0, dots[row], 0.0).astype(weights.dtype)
-    return g, d_out, d_weights, None, None, None, None, None
+    rows = jnp.arange(out.shape[0])
+    d_weights = jnp.zeros((weights.size + rows.size,), weights.dtype).at[
+        jnp.where(mine, pair, weights.size + rows)].set(
+        dots.astype(weights.dtype), unique_indices=True)
+    return (g, d_out, d_weights[:weights.size].reshape(weights.shape), None,
+            None, None, None, None)
 
 
 _weighted_rows.defvjp(_weighted_rows_fwd, _weighted_rows_bwd)
+
+
+def sorted_pairs(group, weights, groups, capacity):
+    """The pairs sorted by ``group [pairs]`` (integers below ``groups``), a
+    group's pairs in their own order: ``(order, sorted weights, place,
+    sizes)`` — the pair that stands at each sorted position and its entry
+    of ``weights [pairs]`` (it rides the sort, for the backward's use
+    alone: no gradient flows through it), each pair's position among the
+    sorted, and the pairs a group got.  The first two are filled up to
+    whole passes of ``capacity`` with the last pair, so that any pass is a
+    slice of them (:func:`pairs_of_pass`).
+
+    Places and sizes are counted, not scattered: a pair stands after its
+    group's start, its group's pairs in the blocks of 128 pairs before its
+    own (a histogram a block by compare-and-sum, summed along the blocks)
+    and those before it in its block (a compare of the block with itself)
+    — dense arithmetic in three fusions, 0.37 ms where XLA's scatter of
+    262,144 places took 1.2 and its scatter-add of as many into 17 bins
+    2.3 (blocks of 512: 0.38).  Each result carries its name of
+    ``SAVED_NAMES``."""
+    pairs, block = group.size, 128
+    _, order, weights = jax.lax.sort(
+        (group, jnp.arange(pairs, dtype=jnp.int32),
+         jax.lax.stop_gradient(weights)), num_keys=1, is_stable=True)
+    # a block's filling belongs to no group and stands after its pairs
+    blocks = jnp.pad(group, (0, -pairs % block),
+                     constant_values=groups).reshape(-1, block)
+    hot = _one_of(blocks, groups)
+    per_block = jnp.sum(hot, axis=1, dtype=jnp.int32)
+    sizes = per_block.sum(axis=0)
+    before = (jnp.cumsum(sizes) - sizes
+              + jnp.cumsum(per_block, axis=0) - per_block)
+    earlier = jnp.tril(jnp.ones((block, block), bool), -1)
+    place = jnp.sum((blocks[:, :, None] == blocks[:, None, :]) & earlier,
+                    axis=2, dtype=jnp.int32) + jnp.sum(
+        jnp.where(hot, before[:, None, :], 0), axis=2)
+    order, weights = (jnp.pad(x, (0, -pairs % capacity), mode="edge")
+                      for x in (order, weights))
+    return tuple(checkpoint_name(x, n) for x, n in zip(
+        (order, weights, place.reshape(-1)[:pairs], sizes), SAVED_NAMES[2:]))
+
+
+def pairs_of_pass(filled, p, capacity):
+    """Sorted positions ``[p * capacity, (p + 1) * capacity)`` of
+    :func:`sorted_pairs`' order or sorted weights (``filled`` to whole
+    passes): a static slice where ``p`` is a Python integer (a ``reverse``
+    program's passes), a dynamic one where it is a loop's counter — never
+    a gather of ``capacity`` scalars."""
+    return jax.lax.dynamic_slice_in_dim(filled, p * capacity, capacity)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -333,12 +457,10 @@ def held_experts_ffn(x, weights, ids, valid, experts, *, first_expert,
     local = ids - first_expert
     here = (local >= 0) & (local < held) & valid[:, None]
     # group ``held`` is everything not computed here; it sorts last
-    group = jnp.where(here, local, held).reshape(-1)
-    order = jnp.argsort(group, stable=True)
-    # where each (token, choice) pair stands among the sorted
-    place = jnp.zeros_like(order).at[order].set(jnp.arange(pairs)).reshape(
-        tokens, top_k)
-    sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)
+    group = jnp.where(here, local, held)
+    order, sorted_weights, place, sizes = sorted_pairs(
+        group.reshape(-1), weights.reshape(-1), held + 1, capacity)
+    place = place.reshape(tokens, top_k)
     # the padding's pairs are rows of the last group to the product, and
     # nobody's to the counters
     counts = sizes.at[held].add(
@@ -367,17 +489,18 @@ def held_experts_ffn(x, weights, ids, valid, experts, *, first_expert,
         """``y`` with sorted pairs ``[p * capacity, (p + 1) * capacity)``
         added."""
         lo = p * capacity
-        pair = order[jnp.minimum(lo + jnp.arange(capacity), pairs - 1)]
+        pair = pairs_of_pass(order, p, capacity)
         mine = jnp.diff(jnp.clip(ends, lo, lo + capacity), prepend=lo)
         row = place - lo
         inside = here & (row >= 0) & (row < capacity)
         row = jnp.where(inside, row, 0)
         group = jnp.where(inside, local, -1)
-        token, held_rows = pair // top_k, mine.sum()
-        out = products(_rows_of_pairs(way, x, token, row, group),
+        held_rows = mine.sum()
+        out = products(_rows_of_pairs(way, x, pair // top_k, row, group),
                        jnp.append(mine, capacity - held_rows), experts)
-        return _weighted_rows(way, y, out, weights, row, group, token,
-                              pair % top_k, held_rows)
+        return _weighted_rows(
+            way, y, out, weights, row, group, pair,
+            pairs_of_pass(sorted_weights, p, capacity), held_rows)
 
     passes = pair_passes(counts, pairs, routed, tiling[0])
     zeros = jnp.zeros((tokens, x.shape[1]), jnp.float32)

@@ -29,10 +29,13 @@ no code stands in for the other chips.  Attention runs the flash kernels'
 grouped and windowed forms, forward and backward
 (``ops/transformer/flash_attention.py``); off the TPU the same kernels run
 through Pallas' interpreter.  Each layer is recomputed on the way back
-(``remat``) but for its attention kernel's output and logsumexp, which
-are kept (0.27 GB a layer at 4 rows of 8192), and the loss is taken over chunks of positions
-(``loss_chunk``), so neither an ``[s, s]`` nor a ``[tokens, vocab]`` array
-exists.
+(``remat``) but for its attention kernel's output and logsumexp (0.27 GB a
+layer at 4 rows of 8192) and the expert layer's routing — the chosen scores
+and ids, the sorted pairs' order and weights, each pair's place and the
+group sizes, 5 MB a layer — which are kept (``SAVED_NAMES``: the second
+forward holds no ``top_k`` and no sort), and the loss is taken over chunks
+of positions (``loss_chunk``), so neither an ``[s, s]`` nor a ``[tokens,
+vocab]`` array exists.
 
 Batch contract: ``{"input_ids"[, "labels"]}`` as ``GPT2LMHeadTPU``'s;
 ``eval_batch`` on ids alone returns the logits at every position.
@@ -53,6 +56,10 @@ from .deepseek_v2 import yarn_inv_freq
 from .layers import rms_norm
 
 WINDOW, FULL = "sliding_attention", "full_attention"
+
+# what a recomputed layer keeps: the attention kernels' outputs and the
+# expert layer's routing (who was chosen, who sorted where)
+SAVED_NAMES = flash_saved_names + expert_shard.SAVED_NAMES
 
 # the published ``rope_parameters``
 ROPE_PARAMETERS = {
@@ -297,10 +304,9 @@ class MellumForCausalLMTPU:
                 from ..runtime.activation_checkpointing import (
                     checkpointing as ds_ckpt)
 
-                # the attention kernels' outputs are kept, not made again
                 if ds_ckpt.should_checkpoint_layer(i, c.num_hidden_layers):
                     run = ds_ckpt.checkpoint_wrapper(
-                        run, save_names=flash_saved_names)
+                        run, save_names=SAVED_NAMES)
             with jax.named_scope(f"layer_{i}"):
                 x, load, aux = run(params["layers"][f"layer_{i}"], x)
             auxes.append(aux)

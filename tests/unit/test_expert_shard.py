@@ -94,6 +94,102 @@ def test_held_pairs_alone_are_computed_and_none_is_dropped(case):
         counts, tokens * top_k, routed, TILE)) == passes
 
 
+def _bits(a):
+    return np.asarray(a).view(np.int32)
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_the_sort_the_sizes_and_each_passs_pairs_are_the_old_forms(case):
+    """``sorted_pairs`` and ``pairs_of_pass`` against the forms they took
+    the place of, written out here: the group sizes as a scatter-add, each
+    pair's place as a scatter of the order, a sorted pair's weight as a
+    gather by the order, a pass's pairs as a gather of the order at
+    clamped positions — the same integers and bits, with the pass a Python
+    integer (``reverse``) and a traced one (the loop's counter)."""
+    tokens, top_k, held, routed, first, make_ids, n_valid, want = CASES[case]
+    capacity, _ = want
+    pairs = tokens * top_k
+    ids = (_uniform_ids(tokens, top_k, routed, 11) if make_ids is None
+           else make_ids(tokens, top_k))
+    local = ids - first
+    here = (local >= 0) & (local < held) & (jnp.arange(tokens) < n_valid)[
+        :, None]
+    group = jnp.where(here, local, held).reshape(-1)
+    weights = jax.random.uniform(jax.random.PRNGKey(6), (pairs,))
+    order, sorted_weights, place, sizes = jax.jit(
+        expert_shard.sorted_pairs, static_argnums=(2, 3))(
+        group, weights, held + 1, capacity)
+    old_order = jnp.argsort(group, stable=True)
+    passes = -(-pairs // capacity)
+    assert order.shape == sorted_weights.shape == (passes * capacity,)
+    assert (np.asarray(order[:pairs]) == np.asarray(old_order)).all()
+    assert (_bits(sorted_weights[:pairs]) == _bits(weights[old_order])).all()
+    assert (np.asarray(place) == np.asarray(
+        jnp.zeros_like(old_order).at[old_order].set(jnp.arange(pairs)))).all()
+    assert (np.asarray(sizes) == np.asarray(
+        jnp.zeros((held + 1,), jnp.int32).at[group].add(1))).all()
+    assert sizes.dtype == jnp.int32 and place.dtype == old_order.dtype
+    traced = jax.jit(expert_shard.pairs_of_pass, static_argnums=2)
+    for p in range(passes):
+        old = old_order[jnp.minimum(p * capacity + jnp.arange(capacity),
+                                    pairs - 1)]
+        assert (np.asarray(expert_shard.pairs_of_pass(order, p, capacity))
+                == np.asarray(old)).all()
+        assert (np.asarray(traced(order, jnp.int32(p), capacity))
+                == np.asarray(old)).all()
+
+
+def _old_group_limited_topk(scores, n_group, topk_group, top_k):
+    """The choice as it was: the kept groups scattered into a mask, and
+    ``lax.top_k`` with its own reverse rule (a scatter of ``d weights``)."""
+    tokens, experts = scores.shape
+    grouped = scores.reshape(tokens, n_group, experts // n_group)
+    _, best_groups = jax.lax.top_k(grouped.max(axis=-1), topk_group)
+    keep = jnp.zeros((tokens, n_group), bool).at[
+        jnp.arange(tokens)[:, None], best_groups].set(True)
+    masked = jnp.where(keep[:, :, None], grouped, 0.0).reshape(
+        tokens, experts)
+    return jax.lax.top_k(masked, top_k)
+
+
+@pytest.mark.parametrize("scoring", ["softmax", "sigmoid"])
+@pytest.mark.parametrize("n_group,topk_group", [(1, 1), (8, 3), (8, 8)],
+                         ids=["one_group", "3_of_8_groups", "8_of_8_groups"])
+def test_the_choice_and_its_gradient_are_lax_top_ks_to_the_last_bit(
+        n_group, topk_group, scoring):
+    """``group_limited_topk`` — no scatter for the kept groups, none on the
+    way back — against the old form: weights and d scores equal bit for
+    bit, ids equal, under a probe with zeros and negative entries."""
+    tokens, experts, top_k = 96, 64, 6
+    x = jax.random.normal(jax.random.PRNGKey(2), (tokens, HIDDEN))
+    kernel = jax.random.normal(jax.random.PRNGKey(3), (HIDDEN, experts))
+    scores = expert_shard.router_scores(x, kernel, scoring)
+    probe = jax.random.normal(jax.random.PRNGKey(4), (tokens, top_k))
+    probe = probe.at[::7].set(0.0)
+
+    def through(choose):
+        def f(scores):
+            weights, ids = choose(scores, n_group, topk_group, top_k)
+            return jnp.sum(weights * probe), (weights, ids)
+        return jax.jit(jax.value_and_grad(f, has_aux=True))(scores)
+
+    (_, (w, ids)), d = through(expert_shard.group_limited_topk)
+    (_, (w_old, ids_old)), d_old = through(_old_group_limited_topk)
+    assert (np.asarray(ids) == np.asarray(ids_old)).all()
+    assert (_bits(w) == _bits(w_old)).all()
+    assert (_bits(d) == _bits(d_old)).all()
+    assert np.asarray(d).any()
+
+
+def test_the_aux_losss_load_is_the_scatter_adds():
+    ids = _uniform_ids(160, 6, 64, 13)
+    scores = jax.nn.softmax(jax.random.normal(
+        jax.random.PRNGKey(8), (160, 64)), axis=-1)
+    load = jnp.zeros((64,), jnp.float32).at[ids.reshape(-1)].add(1.0) / 160
+    old = 64 * jnp.sum(load * scores.mean(0))
+    assert _bits(expert_shard.aux_load_balance(scores, ids, 64)) == _bits(old)
+
+
 def test_capacity_follows_from_shapes_alone():
     # the cells' layers: DeepSeek-V2 prefill and decode, K-EXAONE's; one
     # row tile is a decode step's, and a pass then holds every pair
@@ -167,6 +263,53 @@ def test_layer_gradients_match_the_dense_sum_and_nothing_is_dropped(case):
                     jax.tree_util.tree_leaves(want_grads)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=2e-4,
                                    atol=2e-5)
+
+
+@pytest.mark.parametrize("p,held_pairs", [
+    (3, 120), (3, 101), (3, 96), (3, 60), (2, 120), (0, 7)], ids=[
+    "filled_last_pass_every_real_pair_held", "filled_last_pass_five_held",
+    "filled_last_pass_none_held", "filled_last_pass_held_pairs_end_before_it",
+    "whole_pass_every_pair_held", "first_pass_seven_held"])
+def test_d_weights_scatter_gives_each_held_row_a_pair_of_its_own(
+        p, held_pairs):
+    """The reverse rule of ``_weighted_rows`` scatters a pass's dots to
+    their pairs with ``unique_indices``.  That holds because the held pairs
+    sort first, so a row below ``held_rows`` is never the filling of the last
+    pass (the last pair again and again): 120 pairs in passes of 32, the last
+    one of 24 pairs and 8 fillers, against the form the scatter took the
+    place of, ``dots[row]`` gathered back — equal bit for bit."""
+    tokens, top_k, capacity = 40, 3, 32
+    pairs, lo = tokens * top_k, p * capacity
+    order = jax.random.permutation(jax.random.PRNGKey(1), pairs).astype(
+        jnp.int32)
+    place = jnp.zeros_like(order).at[order].set(
+        jnp.arange(pairs, dtype=jnp.int32)).reshape(tokens, top_k)
+    weights = jax.random.uniform(jax.random.PRNGKey(2), (tokens, top_k))
+    filled = jnp.pad(order, (0, -pairs % capacity), mode="edge")
+    pair = expert_shard.pairs_of_pass(filled, p, capacity)
+    row = place - lo
+    inside = (place < held_pairs) & (row >= 0) & (row < capacity)
+    row, group = jnp.where(inside, row, 0), jnp.where(inside, 0, -1)
+    held_rows = jnp.int32(np.clip(held_pairs - lo, 0, capacity))
+    assert int(inside.sum()) == int(held_rows)
+    if p == 3:   # the filling is there, and is the last pair's
+        assert (np.asarray(pair[24:]) == int(order[-1])).all()
+    out = jax.random.normal(jax.random.PRNGKey(3), (capacity, HIDDEN))
+    g = jax.random.normal(jax.random.PRNGKey(4), (tokens, HIDDEN))
+
+    def summed(weights):
+        return expert_shard._weighted_rows(
+            None, jnp.zeros((tokens, HIDDEN)), out, weights, row, group,
+            pair, weights.reshape(-1)[pair], held_rows)
+
+    def gathered():
+        dots = jnp.sum(g[pair // top_k] * out, axis=-1)
+        return jnp.where(group >= 0, dots[row], 0.0)
+
+    got, = jax.jit(lambda w: jax.vjp(summed, w)[1](g))(weights)
+    old = jax.jit(gathered)()
+    assert (_bits(got) == _bits(old)).all()
+    assert int((np.asarray(got) != 0).sum()) == int(held_rows)
 
 
 @pytest.mark.parametrize("sizes", [(16, 0, 32, 16), (5, 27, 0, 32),

@@ -82,14 +82,30 @@ def _reference_steps(params, batches):
     return losses, first, params
 
 
+SEED = 7
+
+
 @pytest.fixture(scope="module")
-def trained():
-    """One engine through three steps, beside the reference's."""
-    seed = 7
-    params = bench_model.init_params(MODEL, seed)
-    batches = _batches(seed)
+def reference_steps():
+    return _reference_steps(bench_model.init_params(MODEL, SEED),
+                            _batches(SEED))
+
+
+@pytest.fixture(scope="module", params=[True, False],
+                ids=["remat_routing_kept", "no_remat"])
+def trained(request, reference_steps):
+    """One engine through three steps, beside the reference's: with every
+    layer recomputed on the way back but for what ``mellum.SAVED_NAMES``
+    keeps (the attention kernels' outputs and the expert layer's routing:
+    the routing kept is the routing recomputed), and with nothing
+    recomputed."""
+    assert MODEL["remat"] and set(mellum.expert_shard.SAVED_NAMES) < set(
+        mellum.SAVED_NAMES)
+    params = bench_model.init_params(MODEL, SEED)
+    batches = _batches(SEED)
     engine, *_ = deepspeed.initialize(
-        model=bench_model.build_program_model(MODEL, None),
+        model=bench_model.build_program_model(
+            dict(MODEL, remat=request.param), None),
         config={"train_batch_size": ROWS, "steps_per_print": 2,
                 "optimizer": {"type": "Adam", "params": ADAM}},
         mesh=make_mesh({"data": 1}, devices=jax.devices()[:1]),
@@ -122,9 +138,8 @@ def trained():
     engine.close()
     return {"program": (losses, first, master), "logits": logits,
             "reports": reports, "batches": batches,
-            "reference": _reference_steps(
-                bench_model.init_params(MODEL, seed), batches),
-            "start": bench_model.init_params(MODEL, seed)}
+            "reference": reference_steps,
+            "start": bench_model.init_params(MODEL, SEED)}
 
 
 def test_losses_match_the_reference(trained):

@@ -939,3 +939,68 @@ def test_the_trained_expert_layer_gathers_no_row_nobody_here_owns(v5e):
     # x[token] and g[token] of the first pass; of the further one x[token]
     # forward and recomputed, and g[token]
     assert gathered.count("131072,2304") == 5
+
+
+def test_a_recomputed_mellum_layer_keeps_its_routing(v5e, monkeypatch):
+    """The expert half of a Mellum layer at the cell's size (4 rows of
+    8,192, 8 of 64 experts a token, 16 held) under ``jax.checkpoint`` with
+    the model's ``SAVED_NAMES``, loss and gradient both taken: the choice
+    and the sort are made ONCE — one sort of the 262,144 pairs and one of
+    the scores (``top_k``), not two — and the index arithmetic scatters
+    and gathers no scalars but d weights' way back: no group-size
+    histogram (``s32[17]``), no scatter of the places, no scatter of ``d
+    weights`` into the scores' shape, no gather of a pass's 131,072 pairs
+    or of their weights, no histogram of the aux loss's load.  The ways in and
+    out are the ones ``test_the_trained_expert_layer_gathers_no_row_nobody_
+    here_owns`` counts."""
+    from benchmarks import common
+    from benchmarks.models import mellum as bench_model
+    from deepspeed_tpu.models import mellum
+
+    monkeypatch.setattr(mellum, "current_platform", lambda: "tpu")
+    spec = common.load_cell("mellum2_ep4.code8k")
+    model = bench_model.build_program_model(
+        spec["config"]["model_config"], spec["traffic"])
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def loss(lp, x):
+        # linear in y, as the residual stream is: nothing on the way back
+        # reads the recomputed forward's sum
+        y, reports = model._moe(lp, x)
+        return jnp.sum(y) + reports[0]
+
+    layer = jax.tree_util.tree_map(
+        s, model.param_shapes()["layers"]["layer_0"],
+        is_leaf=lambda x: isinstance(x, tuple))
+    text = _compile(
+        jax.value_and_grad(jax.checkpoint(
+            loss, policy=jax.checkpoint_policies.save_only_these_names(
+                *mellum.SAVED_NAMES)), argnums=(0, 1)),
+        layer, s((4, 8192, 2304), jnp.float32))
+    sorts = re.findall(r"= \(?(\w+\[[\d,]+\])\S*[^=\n]*? sort\(", text)
+    assert sorted(sorts) == ["f32[32768,64]", "s32[262144]"], sorts
+    scattered = re.findall(r"= (\w+\[[\d,]+\])\S* scatter\(", text)
+    for shape in ("s32[17]", "f32[32768,64]", "f32[2097152]", "f32[64]",
+                  "f32[4,64]", "f32[256]"):
+        assert shape not in scattered, (shape, scattered)
+    # places are counted, not scattered; the one scatter of scalars left
+    # is d weights, a pass's held rows back to (token, choice): the first
+    # pass's and the further one's under its ``cond``
+    assert "s32[262144]" not in scattered
+    assert scattered.count("f32[393216]") == 2
+    # no pass's pairs gathered out of the order, no pair's weight gathered
+    # out of ``[tokens, top_k]``, no dot gathered back by row
+    gathers = re.findall(r"= (\w+\[[\d,]+\])\S* gather\(", text)
+    for shape in ("s32[131072]", "f32[131072]", "f32[32768,8]",
+                  "f32[262144]"):
+        assert shape not in gathers, (shape, gathers)
+    gathered = [shape.split("[")[1][:-1] for shape in gathers]
+    calls = re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert sum(name.startswith("moe_gather_combine") for name in calls) == 4
+    assert "32768,2304" not in gathered
+    # a pass's x[token] forward and recomputed, and its g[token], for the
+    # first pass and for the further one under its ``cond``
+    assert gathered.count("131072,2304") == 6
