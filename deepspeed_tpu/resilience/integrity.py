@@ -623,7 +623,10 @@ class FleetHeartbeat:
         self._publish_lock = threading.Lock()
         self._stop = threading.Event()
         self._thread = None
+        # ``fired`` is raised AFTER a conviction's effects (verdict, hook,
+        # exit request); the latch stops a second one starting meanwhile
         self.fired = False
+        self._convicted = False
         self.last_verdict = None
         # the verdict function over the fleet's heartbeat map.  Default:
         # the training quorum (step-position + staleness).  A serving
@@ -684,7 +687,7 @@ class FleetHeartbeat:
     # ------------------------------------------------------------------
     def _run(self):
         while not self._stop.wait(self.poll_interval):
-            if self.fired:
+            if self._convicted:
                 continue
             if not self._armed:
                 # paused for a known-long gap (rollback restore, final
@@ -718,7 +721,7 @@ class FleetHeartbeat:
                                       self.peer_timeout_secs)
             if verdict is None:
                 continue
-            self.fired = True
+            self._convicted = True
             self.last_verdict = verdict
             detail = (
                 f"rank {verdict['suspect']} stalled "
@@ -730,9 +733,8 @@ class FleetHeartbeat:
             if self.action != "evict":
                 # integrity_action="warn" is the operator's explicit
                 # opt-out of automated eviction (documented contract:
-                # telemetry only) — no verdict file, no exit.  ``fired``
-                # latches so a long stall warns once per life, not once
-                # per poll
+                # telemetry only) — no verdict file, no exit.  The latch
+                # makes a long stall warn once per life, not once per poll
                 logger.warning(
                     "fleet heartbeat: hang quorum — %s; "
                     "integrity_action='warn': telemetry only, not "
@@ -743,6 +745,7 @@ class FleetHeartbeat:
                     except Exception as e:  # noqa: BLE001 — warn path
                         logger.error("heartbeat on_fire hook failed: %s",
                                      e)
+                self.fired = True
                 continue
             write_verdict(self.run_dir, self._verdict_kind,
                           verdict["suspect"], detail, rank=self.rank,
@@ -758,4 +761,5 @@ class FleetHeartbeat:
                 except Exception as e:  # noqa: BLE001 — exiting anyway
                     logger.error("heartbeat on_fire hook failed: %s", e)
             self._exit_fn(EXIT_INTEGRITY_EVICT)
+            self.fired = True
             return

@@ -391,27 +391,12 @@ class DeepSpeedEngine:
 
         self.zero_stage = self._config.zero_optimization_stage
         zc = self._config.zero_config
-        # uniform-chunk (O(1)-compile) streamed offload: the coordinator
-        # aligns the row layout so every chunk of every host group has
-        # ONE shape (zero/stream.py).  "auto" engages past
-        # UNIFORM_MIN_CHUNKS chunks of state; an explicit true forces
-        # alignment at any size; false keeps the round-5 layout.
-        from .zero.stream import UNIFORM_MIN_CHUNKS
+        # the offload keys' half of the layout (uniform-chunk alignment,
+        # the master's storage dtype, the host-buffer families to cap)
+        from .zero.offload import host_state_dtypes, layout_args
 
-        uniform_cfg = getattr(zc, "offload_uniform_chunks", "auto")
-        chunk_rows_cfg = (max(1, (zc.offload_chunk_mb << 20) // (LANES * 4))
-                          if zc.offload_chunk_mb else None)
-        # reduced-precision host state (zero/qstate.py): the master's
-        # storage dtype shapes the coordinator's buffers; the residual
-        # and gradient buffer FAMILIES count toward the host-buffer
-        # total the auto group layout must cap (the AOT crash mode)
-        from .zero.qstate import STATE_DTYPES
-
-        sd_cfg = zc.offload_state_dtype
         self._state_reduced = bool(
             getattr(zc, "offload_state_reduced", False))
-        host_families = (3 + (1 if zc.offload_gradients else 0)
-                         + getattr(zc, "offload_state_residual_count", 0))
         # -- bucketed gradient-collective overlap (overlap_comm, round
         # 14): decide BEFORE the coordinator builds, because the
         # overlapped exchange requires the shard-major sub-partition
@@ -438,15 +423,7 @@ class DeepSpeedEngine:
             group_bytes=(zc.offload_group_mb << 20
                          if getattr(zc, "offload_group_mb_explicit", False)
                          else None),
-            uniform_chunk_rows=(chunk_rows_cfg
-                                if zc.cpu_offload and uniform_cfg is not False
-                                else None),
-            uniform_min_chunks=(1 if uniform_cfg is True
-                                else UNIFORM_MIN_CHUNKS),
-            host_families=host_families,
-            master_dtype=(STATE_DTYPES[sd_cfg["master"]]
-                          if self._state_reduced else None),
-            bucket_plan=bucket_plan)
+            bucket_plan=bucket_plan, **layout_args(zc))
         self.segments = self.flat.segments
         if self._comm_overlap:
             what = ("JIT parameter gathers + bucketed gradient exchange"
@@ -541,11 +518,8 @@ class DeepSpeedEngine:
                 # reduced host state: flat leaves store in their
                 # configured dtype (exp_avg -> momentum, exp_avg_sq ->
                 # variance); scalars and the fp32 default are untouched
-                sd_by_name = {}
-                if self._state_reduced:
-                    sd_by_name = {
-                        "exp_avg": STATE_DTYPES[sd_cfg["momentum"]],
-                        "exp_avg_sq": STATE_DTYPES[sd_cfg["variance"]]}
+                sd_by_name = (host_state_dtypes(zc)
+                              if self._state_reduced else {})
 
                 def _mk(leaf, dtype):
                     if leaf.shape == self.segments.shape:
@@ -627,11 +601,12 @@ class DeepSpeedEngine:
                      if self._offload_grads else None)
 
         # persistent error-feedback residuals (reduced-precision offload
-        # state, zero/qstate.py): one pinned-host buffer per reduced
+        # state): one pinned-host buffer per reduced
         # state buffer, grouped like the master, zero-init (the init
         # downcast error is absorbed within the first few steps)
         qres0 = None
-        if self._state_reduced and sd_cfg["error_feedback"]:
+        if (self._state_reduced
+                and zc.offload_state_dtype["error_feedback"]):
             res_bounds = (self.flat.host_group_bounds
                           or ((0, self.segments.rows),))
 
@@ -643,12 +618,9 @@ class DeepSpeedEngine:
                 return (grps if self.flat.host_group_bounds is not None
                         else grps[0])
 
-            qres0 = {}
-            for name, field in (("master", "master"),
-                                ("exp_avg", "momentum"),
-                                ("exp_avg_sq", "variance")):
-                if sd_cfg[field] != "fp32":
-                    qres0[name] = _zeros_grouped(STATE_DTYPES[sd_cfg[field]])
+            qres0 = {name: _zeros_grouped(dtype)
+                     for name, dtype in host_state_dtypes(zc).items()
+                     if dtype != jnp.float32}
 
         self.state = {
             "master": master0,
@@ -1027,6 +999,10 @@ class DeepSpeedEngine:
         optimizer state (both directions; gradients separate).  None
         when offload is off."""
         return getattr(self, "_host_state_bytes_per_step", None)
+
+    @property
+    def _rr_disabled_logged(self):
+        return self._offload_stream.rr_disabled_logged
 
     def host_stream_schedule(self):
         """Declared issue schedule of the streamed offload update
@@ -1954,13 +1930,10 @@ class DeepSpeedEngine:
             self._segment_ids = jax.device_put(
                 segments.segment_ids(), self.flat.master_sharding)
 
-        # ZeRO-Offload: master/optimizer flat buffers live in pinned host
-        # memory; on TPU the compiled programs stream them to device
-        # explicitly (XLA requires uniform memory spaces per op) and the
+        # ZeRO-Offload (zero/offload.py): the compiled programs stream
+        # the pinned-host state to device themselves and the
         # out_shardings pin results back to host.  On backends without
         # in-jit placement the engine parks state eagerly between steps.
-        # Reference analog: CPU-resident fp32 master + DeepSpeedCPUAdam
-        # with async GPU copies (stage2.py:326-342, csrc/adam/cpu_adam.cpp).
         offload = self._offload and not self._offload_eager  # in-jit mode
         dev_sharding = self.flat.master_device_sharding
         master_out_sharding = (self.flat.master_sharding
@@ -1977,230 +1950,35 @@ class DeepSpeedEngine:
         def to_device(flat_buf):
             return jax.device_put(flat_buf, dev_sharding) if offload else flat_buf
 
-        # Chunk plan for streamed offload: the capacity fix for the in-jit
-        # path, which otherwise materializes master + m + v on device AT
-        # ONCE for the update (measured 21.8 G peak at GPT-2-large — MORE
-        # than device-resident training, defeating offload's purpose).
-        # Chunked, each program step streams one [chunk, LANES] slice of
-        # (p, m, v) host→device, updates, and streams back — measured
-        # throughput-equal to the full-buffer form (examples/
-        # exp_host_stream.py) with peak HBM of ~one chunk.  Per-tensor
-        # trust-ratio optimizers (LAMB) need whole-buffer norms, so only
-        # elementwise flat optimizers (Adam family) chunk; the reference
-        # has the same constraint (ZeRO-Offload pairs with [CPU]Adam only).
-        from .zero.coordinator import split_rows
+        # ONE object decides the update's form, builds the traced
+        # functions composed below and declares their schedule
+        from .zero.offload import OffloadStream
 
-        groups = self.flat.host_group_bounds  # tuple[(r0, rc)] or None
-        chunk_mb = int(getattr(self._config.zero_config,
-                               "offload_chunk_mb", 512) or 0)
-        rows_per_chunk = (max(1, (chunk_mb << 20) // (LANES * 4))
-                          if chunk_mb else None)
-
-        def _chunks(rows_g):
-            """Relative chunk bounds within one (group) buffer."""
-            return split_rows(rows_g, rows_per_chunk)
-
-        # Stream when the full-buffer path would not fit: below the floor
-        # the one-shot update is ~15% faster (gpt2-medium measured 738 vs
-        # 855 ms/step) because chunk chaining costs overlap.  The floor is
-        # the state size whose 3-buffer device peak (+ grads + params)
-        # still fit a 16 G chip: medium (1.42 GB/buffer) fits, large
-        # (3.09 GB/buffer) OOM'd at 21.8 G.  An explicitly non-default
-        # offload_chunk_mb overrides the floor (smaller chips / bigger
-        # co-residents); row-grouped state ALWAYS streams — the one-shot
-        # path cannot consume tuple-of-group buffers, so with
-        # offload_chunk_mb == 0 each group streams as one chunk.
-        stream_min_bytes = 1792 << 20
-        try:
-            # derive the floor from real device memory when the backend
-            # reports it (~11% of HBM ~= the 1.75G/16G calibration point,
-            # applied in BOTH directions so >16G chips keep the faster
-            # one-shot path for proportionally bigger state); a backend
-            # that reports no limit (the CPU test mesh returns None)
-            # keeps the 16G-chip calibration
-            ms = mesh.devices.flat[0].memory_stats()
-            if ms and ms.get("bytes_limit"):
-                stream_min_bytes = int(ms["bytes_limit"] * 0.11)
-        except Exception:  # dslint: disable=DSE502 -- memory_stats is an optional backend API; calibration default applies
-            pass
+        ofs = self._offload_stream = OffloadStream(
+            self._config.zero_config, self.flat, segments, optimizer,
+            mesh.devices.flat[0], offload=self._offload,
+            eager=self._offload_eager, host_grads=self._offload_grads,
+            prng_impl=self._prng_impl, skip_bad=skip_bad, clip=clip,
+            compute_dtype=self.compute_dtype,
+            param_template=self._param_template,
+            param_shardings=param_shardings)
+        offload_stream = ofs.stream
         # the floor this device gave (chip_smoke.py prints it)
-        self.offload_stream_min_bytes = stream_min_bytes
-        chunk_mb_forced = (chunk_mb > 0 and getattr(
-            self._config.zero_config, "offload_chunk_mb_explicit", False))
-        # Reduced-precision host state (zero/qstate.py): squant is None
-        # on the fp32 default path, and every insertion below is gated
-        # on it — the default-path programs stay byte-identical.
-        from .zero.qstate import (build_state_quant,
-                                  host_state_bytes_per_step)
-
-        opt_shape_flat = (jax.eval_shape(
-            optimizer.init_state,
-            jax.ShapeDtypeStruct(segments.shape, jnp.float32))
-            if offload else None)
-        squant = None
-        if self._state_reduced:
-            squant = build_state_quant(
-                self._config.zero_config.offload_state_dtype,
-                opt_shape_flat, prng_impl=self._prng_impl)
-        self._state_quant = squant
-        offload_stream = (
-            offload and getattr(optimizer, "name", "") == "adam"
-            and (self._offload_grads  # host grads ride the chunk stream
-                 or squant is not None  # compression rides the stream
-                 or groups is not None
-                 or (rows_per_chunk is not None
-                     and segments.rows > rows_per_chunk
-                     and (chunk_mb_forced
-                          or segments.rows * LANES * 4 > stream_min_bytes))))
-        if offload_stream:
-            log_dist(
-                f"ZeRO-Offload: streaming update over "
-                f"{len(groups) if groups else 1} host group(s) in chunks "
-                f"of ≤{chunk_mb} MB", ranks=[0])
-
-        # O(1)-compile uniform-chunk form (zero/stream.py): past
-        # UNIFORM_MIN_CHUNKS the unrolled form's compile time — not
-        # memory — caps capacity (~35 min at gpt2-xl's 37 chunks,
-        # >30 min un-finished at 2.7B; PERF.md "Compile time"), so the
-        # chunk loop becomes a lax.scan whose body is traced once.
-        from .zero.stream import (uniform_chunk_jobs, uniform_geometry_ok,
-                                  uniform_scan_update)
-
-        offload_uniform = False
-        if offload_stream:
-            gb_all = groups or ((0, segments.rows),)
-            n_chunks_total = sum(len(_chunks(grc)) for _, grc in gb_all)
-            uniform_cfg = getattr(self._config.zero_config,
-                                  "offload_uniform_chunks", "auto")
-            # ONE decision point: the coordinator already decided (it
-            # set uniform_chunk_rows iff the config allowed it AND the
-            # chunk-count threshold was met at layout time) — the engine
-            # follows that decision rather than re-deriving the
-            # threshold from post-padding geometry, which near the
-            # boundary could disagree with the layout actually built.
-            want_uniform = (uniform_cfg is True
-                            or (uniform_cfg == "auto"
-                                and self.flat.uniform_chunk_rows
-                                is not None))
-            geom_ok = (rows_per_chunk is not None
-                       and self.flat.uniform_chunk_rows == rows_per_chunk
-                       and uniform_geometry_ok(gb_all, rows_per_chunk))
-            offload_uniform = want_uniform and geom_ok
-            if want_uniform and not geom_ok:
-                # loud fallback — only reachable when uniform was FORCED
-                # (true) but the layout could not be chunk-aligned, e.g.
-                # offload_chunk_mb: 0 (one ragged chunk per group)
-                logger.warning(
-                    "offload_uniform_chunks: chunk geometry is not "
-                    "uniform (chunk_rows=%s over groups %s); falling "
-                    "back to the unrolled streamed update — compile "
-                    "time will scale with chunk count",
-                    rows_per_chunk, gb_all)
-            if offload_uniform:
-                log_dist(
-                    f"ZeRO-Offload: uniform-chunk scan update "
-                    f"({n_chunks_total} chunks x {chunk_mb} MB, "
-                    f"{len(gb_all)} group(s)) — compile cost is "
-                    f"O(groups), not O(chunks)", ranks=[0])
-        self._offload_uniform = offload_uniform
-
-        # Overlapped chunk streaming (round 12): double-buffer the
-        # streamed update — prefetch chunk k+1's host state while chunk
-        # k updates, overlap write-back with the next fetch (scan form:
-        # the carry-held prefetch queue in zero/stream.py; unrolled
-        # form: round-robin group interleave + depth-2 tokens).  Same
-        # per-chunk math with the same canonical SR tags, so the
-        # overlapped and serialized schedules are BIT-IDENTICAL
-        # (tests/unit/test_offload_overlap.py); only transfer issue
-        # order changes.  "auto" overlaps whenever the update streams;
-        # false keeps the serialized schedule as the measured control.
-        overlap_cfg = getattr(self._config.zero_config,
-                              "offload_overlap", "auto")
-        prefetch_cfg = int(getattr(self._config.zero_config,
-                                   "offload_prefetch_depth", 2) or 2)
-        if overlap_cfg is True and prefetch_cfg < 2:
-            raise ValueError(
-                "offload_overlap: true contradicts offload_prefetch_"
-                "depth: 1 (a one-deep pipeline IS the serialized "
-                "schedule); raise the depth or drop offload_overlap")
-        # depth 1 means serialized — an explicit offload_prefetch_depth:
-        # 1 under "auto" selects the serialized control exactly like
-        # offload_overlap: false (the documented knob contract)
-        offload_overlap = (bool(offload_stream)
-                           and overlap_cfg is not False
-                           and prefetch_cfg >= 2)
-        if overlap_cfg is True and self._offload and not offload_stream:
-            raise ValueError(
-                "offload_overlap: true but the offloaded update does not "
-                "stream (eager-offload or the full-buffer one-shot path) "
-                "— there is no chunk pipeline to overlap; drop the key "
-                "or set offload_chunk_mb to force streaming")
-        self._offload_overlap = offload_overlap
-        self._offload_prefetch_depth = (prefetch_cfg if offload_overlap
-                                        else 1)
-
-        # Declared host-stream schedule (profiling/overlap, DSO7xx): the
-        # CPU-path receipt for the pipeline above.  The offload round
-        # trips run BETWEEN dispatches, invisible in any one program's
-        # HLO, so the engine declares not just the wire BYTES
-        # (host_state_bytes_per_step) but the SCHEDULE it actually
-        # built — chunk count, pipeline depth, issue form — and the
-        # overlap analyzer prices the exposed fraction from that.  This
-        # dict describes the program structure the jits below actually
-        # trace; keep them in lockstep.
-        self._host_stream_schedule = None
-        if offload_stream:
-            gb_all = groups or ((0, segments.rows),)
-            n_chunks_total = sum(len(_chunks(grc)) for _, grc in gb_all)
-            self._host_stream_schedule = {
-                "overlap": bool(offload_overlap),
-                "prefetch_depth": int(self._offload_prefetch_depth),
-                "chunks": int(n_chunks_total),
-                "groups": int(len(gb_all)),
-                "form": "scan" if offload_uniform else "unrolled",
-            }
-            if self._offload_grads:
-                # offload_gradients wire: one spill (device->host)
-                # during bwd + one reload (host->device) in the update;
-                # the spill chunks depend only on the grad leaves they
-                # cover, so the backward hides them when overlap is on
-                self._host_stream_schedule["grad_wire_bytes"] = int(
-                    2 * segments.rows * LANES * 4)
-            if self.telemetry.enabled:
-                self.telemetry.gauge("offload/overlap_enabled").set(
-                    float(bool(offload_overlap)))
-                self.telemetry.gauge("offload/prefetch_depth").set(
-                    float(self._offload_prefetch_depth))
-            log_dist(
-                f"ZeRO-Offload: {'double-buffered' if offload_overlap else 'serialized'} "
-                f"chunk streaming ({n_chunks_total} chunks, depth "
-                f"{self._offload_prefetch_depth}, "
-                f"{'scan' if offload_uniform else 'unrolled'} form)",
-                ranks=[0])
-
-        # Wire-bytes accounting (PERF.md "ZeRO-Offload wire bytes"): the
-        # streamed update moves every host state buffer down and back up
-        # exactly once per step — a deterministic figure the bench JSON
-        # and telemetry carry so reduced-precision claims are auditable.
-        self._host_state_bytes_per_step = None
-        if offload:
-            n_flat_leaves = sum(
-                1 for l in jax.tree_util.tree_leaves(opt_shape_flat)
-                if getattr(l, "ndim", 0) == 2)
-            self._host_state_bytes_per_step = host_state_bytes_per_step(
-                segments.rows, LANES, squant, n_flat_leaves=n_flat_leaves)
-            if self.telemetry.enabled:
-                self.telemetry.gauge(
-                    "offload/host_state_bytes_per_step").set(
-                    float(self._host_state_bytes_per_step))
-            if squant is not None:
-                log_dist(
-                    f"ZeRO-Offload: reduced-precision host state "
-                    f"{self._config.zero_config.offload_state_dtype} — "
-                    f"{self._host_state_bytes_per_step / 2**30:.2f} GB "
-                    f"state wire bytes/step (fp32 layout: "
-                    f"{host_state_bytes_per_step(segments.rows, LANES, None, n_flat_leaves=n_flat_leaves) / 2**30:.2f} GB)",
-                    ranks=[0])
+        self.offload_stream_min_bytes = ofs.stream_min_bytes
+        self._state_quant = ofs.quant
+        self._offload_uniform = ofs.uniform
+        self._offload_overlap = ofs.overlap
+        self._offload_prefetch_depth = ofs.prefetch_depth
+        self._host_stream_schedule = ofs.schedule()
+        self._host_state_bytes_per_step = ofs.host_state_bytes_per_step
+        if self.telemetry.enabled and offload_stream:
+            self.telemetry.gauge("offload/overlap_enabled").set(
+                float(ofs.overlap))
+            self.telemetry.gauge("offload/prefetch_depth").set(
+                float(ofs.prefetch_depth))
+        if self.telemetry.enabled and offload:
+            self.telemetry.gauge("offload/host_state_bytes_per_step").set(
+                float(ofs.host_state_bytes_per_step))
 
         # Declared collective schedule (profiling/overlap, DSO7xx): the
         # bucketed-exchange twin of the host-stream declaration above.
@@ -2249,470 +2027,18 @@ class DeepSpeedEngine:
                 self.telemetry.gauge("comm/allgather_groups").set(
                     float(sched["ag_buckets"]))
 
-        host_big = self.flat.master_sharding
-
-        def _after(token, tree):
-            """Data-dependency fence: every producer feeding ``tree`` may
-            only be scheduled after ``token`` is computed.  Without this the
-            chunk pipelines below are mutually independent and XLA's
-            scheduler runs them ALL concurrently — every chunk's fp32 state
-            lands on device at once and the peak is the full buffers again
-            (measured: 29.3 G at GPT-2-xl, worse than unchunked)."""
-            tree, _ = jax.lax.optimization_barrier((tree, token))
-            return tree
-
-        def _is_grp(x):
-            # plain tuple only: NamedTuple optimizer states are pytree
-            # NODES, not row-group containers
-            return type(x) is tuple
-
-        def _split_group_states(opt_state, n_g):
-            """Per-group flattened optimizer-state views of a (possibly
-            row-grouped) state tree: flat row-buffer leaves differ per
-            group, scalar leaves are shared.  Returns (group_leaves,
-            is_flat mask, treedef) — the common prologue of both
-            streamed update forms."""
-            opt_defs = None
-            group_leaves, is_flat = [], None
-            for gi in range(n_g):
-                st_g = jax.tree_util.tree_map(
-                    lambda l: l[gi] if type(l) is tuple else l,
-                    opt_state, is_leaf=_is_grp)
-                leaves, opt_defs = jax.tree_util.tree_flatten(st_g)
-                group_leaves.append(leaves)
-                if is_flat is None:
-                    is_flat = [getattr(l, "ndim", 0) == 2 for l in leaves]
-            return group_leaves, is_flat, opt_defs
-
-        def _recombine_group_states(opt_state, new_sts):
-            """Inverse of :func:`_split_group_states`: per-group state
-            trees back into the original (grouped or single) layout."""
-            if groups is None:
-                return new_sts[0]
-            return jax.tree_util.tree_map(
-                lambda orig, *gs: tuple(gs) if type(orig) is tuple
-                else gs[0],
-                opt_state, *new_sts, is_leaf=_is_grp)
-
-        def carve_leaves(chunk_list):
-            """In-order device chunks tiling the flat rows → params pytree
-            in compute dtype (leaves carved with ordinary device slices;
-            see the cast_params alignment note)."""
-            tmpl_leaves, treedef = jax.tree_util.tree_flatten(
-                self._param_template)
-            offs, rcs, ns = (segments.row_offsets, segments.row_counts,
-                             segments.sizes)
-            pieces = [[] for _ in tmpl_leaves]
-            abs0 = 0
-            for chunk in chunk_list:
-                end = abs0 + chunk.shape[0]
-                for i in range(len(tmpl_leaves)):
-                    lo = max(offs[i], abs0)
-                    hi = min(offs[i] + rcs[i], end)
-                    if lo < hi:
-                        pieces[i].append(jax.lax.slice_in_dim(
-                            chunk, lo - abs0, hi - abs0))
-                abs0 = end
-            assert abs0 == segments.rows, (abs0, segments.rows)
-            out = []
-            for i, tl in enumerate(tmpl_leaves):
-                rows = (pieces[i][0] if len(pieces[i]) == 1
-                        else jnp.concatenate(pieces[i], axis=0))
-                out.append(jax.lax.slice(
-                    rows.reshape(-1), (0,), (ns[i],)).reshape(tl.shape))
-            params = jax.tree_util.tree_unflatten(treedef, out)
-            return jax.tree_util.tree_map(
-                lambda x, s: jax.lax.with_sharding_constraint(x, s),
-                params, param_shardings)
-
-        def _qres_group_bufs(qres):
-            """state["qres"] dict -> {name: per-group buffer list}; the
-            residual buffers share the master's row-group layout."""
-            return {k: (list(v) if type(v) is tuple else [v])
-                    for k, v in (qres or {}).items()}
-
-        def _qres_regroup(res_bufs, qres):
-            """Inverse: per-group lists back into the state layout."""
-            if not res_bufs:
-                return qres
-            return {k: (tuple(v) if groups is not None else v[0])
-                    for k, v in res_bufs.items()}
-
-        def chunked_offload_update(master, opt_state, g, hp, overflow,
-                                   qres=None, coef=None, g_on_host=False,
-                                   want_cast=False):
-            """Chunk-streamed offloaded update, ROUND-ROBIN over host
-            groups.
-
-            Each chunk's (p, m, v[, g]) slices load from pinned host,
-            update on device, and write back in place via
-            ``dynamic_update_slice`` (concatenated fresh outputs defeat
-            host donation aliasing).
-            Within one group the SSA chain serializes chunk k's loads
-            behind chunk k-1's write-back — that preserves in-place
-            aliasing (reading the ORIGINAL buffer instead measured
-            1.62 → 2.23 s/step from the induced host copies) but leaves
-            the wire idle during compute.  Round-robin interleaving
-            restores the overlap WITHOUT breaking aliasing: group A's
-            chunk k+1 only depends on A's chunk k, so its host→device
-            DMA streams while group B's chunk updates and writes back,
-            and the ``_after`` token (gating loads on the update two
-            jobs back) bounds in-flight chunks at two.
-
-            ``coef`` folds unscale+clip for host-resident gradients
-            (``g_on_host``); ``want_cast`` collects updated chunks cast
-            to the compute dtype so the caller assembles new params
-            without re-reading the master from host."""
-            masters = list(master) if type(master) is tuple else [master]
-            gb = groups or ((0, segments.rows),)
-            n_g = len(gb)
-            group_leaves, is_flat, opt_defs = _split_group_states(
-                opt_state, n_g)
-            scalar_out = [None] * len(is_flat)
-            nf = sum(is_flat)
-            res_bufs = _qres_group_bufs(qres)
-            # residual read/write plan: master first, then reduced flat
-            # leaves in leaf order — tags must match the scan form so
-            # stochastic-rounding draws agree across the two layouts
-            res_items = []
-            if squant is not None:
-                if "master" in res_bufs:
-                    res_items.append(("master", None))
-                fi_of_li = {}
-                fi = 0
-                for li, f in enumerate(is_flat):
-                    if f:
-                        fi_of_li[li] = fi
-                        fi += 1
-                for li in squant.res_leaf_lis:
-                    res_items.append((squant.leaf_names[li], li))
-
-            per_group = [_chunks(grc) for _, grc in gb]
-            n_chunks_total = sum(len(c) for c in per_group)
-            # Issue order: round-robin interleave overlaps group A's DMA
-            # with group B's update — but ONLY below the measured scale
-            # breakpoint (stream.ROUND_ROBIN_MAX_CHUNKS: gpt2-xl's 37
-            # chunks ran 19.5 s/step round-robin vs 5.16 sequential —
-            # interleaving spreads each group's in-place DUS chain past
-            # XLA's buffer-forwarding window and every write-back
-            # becomes a host-buffer copy).  Past the breakpoint, and
-            # always under offload_overlap: false (the serialized
-            # control schedule), chunks issue group-sequentially.
-            from .zero.stream import ROUND_ROBIN_MAX_CHUNKS, sr_chunk_tags
-
-            round_robin = (self._offload_overlap
-                           and n_chunks_total <= ROUND_ROBIN_MAX_CHUNKS)
-            if (self._offload_overlap and not round_robin
-                    and not getattr(self, "_rr_disabled_logged", False)):
-                self._rr_disabled_logged = True
-                log_dist(
-                    f"ZeRO-Offload: round-robin group interleave "
-                    f"auto-disabled at {n_chunks_total} chunks (> "
-                    f"{ROUND_ROBIN_MAX_CHUNKS}): issuing group-"
-                    f"sequentially (the measured-faster order at this "
-                    f"scale — PERF.md capacity ladder)", ranks=[0])
-            jobs = []
-            if round_robin:
-                idx = [0] * n_g
-                while any(idx[gi] < len(per_group[gi])
-                          for gi in range(n_g)):
-                    for gi in range(n_g):
-                        if idx[gi] < len(per_group[gi]):
-                            jobs.append((gi,)
-                                        + tuple(per_group[gi][idx[gi]]))
-                            idx[gi] += 1
-            else:
-                for gi in range(n_g):
-                    jobs.extend((gi,) + tuple(c) for c in per_group[gi])
-            # canonical (issue-order-invariant) SR tags, shared with the
-            # scan form: rank by absolute row start
-            sr_tags = sr_chunk_tags(
-                [(gi, r0, gb[gi][0] + r0) for gi, r0, _ in jobs])
-
-            cast_parts = {} if (want_cast and self.compute_dtype) else None
-            tok2 = tok1 = jnp.float32(0.0)
-            for jn, (gi, r0, rc) in enumerate(jobs):
-                gr0, _ = gb[gi]
-                master_g = masters[gi]
-                leaves = group_leaves[gi]
-                slices = [jax.lax.slice_in_dim(master_g, r0, r0 + rc)] + [
-                    jax.lax.slice_in_dim(l, r0, r0 + rc)
-                    for l, f in zip(leaves, is_flat) if f]
-                for name, _li in res_items:
-                    slices.append(jax.lax.slice_in_dim(
-                        res_bufs[name][gi], r0, r0 + rc))
-                if g_on_host:
-                    g_g = g[gi] if type(g) is tuple else g
-                    slices.append(jax.lax.slice_in_dim(g_g, r0, r0 + rc))
-                # depth-2 token (gate on the update two jobs back)
-                # bounds in-flight chunks at two while letting job k+1's
-                # DMA stream during job k's update; the serialized
-                # control (offload_overlap: false) gates on the
-                # IMMEDIATELY previous update — one chunk in flight,
-                # wire fully exposed by construction
-                host_slices = _after(
-                    tok2 if self._offload_overlap else tok1, slices)
-                pm_q = jax.device_put(host_slices[0], dev_sharding)
-                it = iter(host_slices[1:1 + nf])
-                chunk_leaves_q = [
-                    jax.device_put(next(it), dev_sharding) if f else l
-                    for l, f in zip(leaves, is_flat)]
-                res_dev = [jax.device_put(x, dev_sharding)
-                           for x in host_slices[1 + nf:1 + nf
-                                                + len(res_items)]]
-                if squant is None:
-                    pm, chunk_leaves = pm_q, chunk_leaves_q
-                else:
-                    res_by_li = {li: res_dev[i] for i, (_, li)
-                                 in enumerate(res_items) if li is not None}
-                    res_m = (res_dev[0] if res_items
-                             and res_items[0][0] == "master" else None)
-                    pm = squant.load(pm_q, res_m)
-                    chunk_leaves = [
-                        squant.load(cq, res_by_li.get(li))
-                        if is_flat[li] else cq
-                        for li, cq in enumerate(chunk_leaves_q)]
-                st = jax.tree_util.tree_unflatten(opt_defs, chunk_leaves)
-                if g_on_host:
-                    gc_ = jax.device_put(host_slices[-1],
-                                         dev_sharding) * coef
-                else:
-                    gc_ = jax.lax.slice_in_dim(g, gr0 + r0, gr0 + r0 + rc)
-                new_p, new_st = optimizer.update(st, pm, gc_, hp)
-                new_leaves = jax.tree_util.tree_leaves(new_st)
-                tok2, tok1 = tok1, new_p[0, 0]
-                key_base = None
-                if squant is not None and squant._key0 is not None:
-                    scal = [new_leaves[li] for li, f in enumerate(is_flat)
-                            if not f]
-                    key_base = squant.chunk_key(
-                        scal[squant.step_scalar_idx],
-                        jnp.uint32(sr_tags[jn]))
-                if squant is None:
-                    if skip_bad:
-                        new_p = jnp.where(overflow, pm, new_p)
-                    write_p = new_p
-                else:
-                    q_p, r_p = squant.store(
-                        new_p, squant.master_dtype,
-                        key=(jax.random.fold_in(key_base, 0)
-                             if key_base is not None and squant.master_dtype
-                             != jnp.float32 else None))
-                    if skip_bad:
-                        q_p = jnp.where(overflow, pm_q, q_p)
-                        if r_p is not None:
-                            r_p = jnp.where(overflow, res_m, r_p)
-                    write_p = q_p
-                    if r_p is not None:
-                        res_bufs["master"][gi] = jax.lax.dynamic_update_slice(
-                            res_bufs["master"][gi],
-                            jax.device_put(r_p, host_big), (r0, 0))
-                if cast_parts is not None:
-                    # fold the compute-dtype param cast into the update:
-                    # the new-param chunk is already on device, so the
-                    # post-update streamed cast's re-download of the
-                    # whole master disappears.  Under reduced storage the
-                    # cast derives from the QUANTIZED value, so forward
-                    # params equal the stored master exactly in both
-                    # streamed forms
-                    cast_parts[(gi, r0)] = write_p.astype(self.compute_dtype)
-                masters[gi] = jax.lax.dynamic_update_slice(
-                    master_g, jax.device_put(write_p, host_big), (r0, 0))
-                for li, (old_q, new_l) in enumerate(zip(
-                        chunk_leaves_q, new_leaves)):
-                    if is_flat[li]:
-                        if squant is None:
-                            if skip_bad:
-                                new_l = jnp.where(overflow, old_q, new_l)
-                        else:
-                            q_l, r_l = squant.store(
-                                new_l, squant.leaf_dtypes[li],
-                                key=(jax.random.fold_in(
-                                    key_base, 1 + fi_of_li[li])
-                                    if key_base is not None
-                                    and squant.leaf_dtypes[li]
-                                    != jnp.float32 else None))
-                            if skip_bad:
-                                q_l = jnp.where(overflow, old_q, q_l)
-                            if li in res_by_li and r_l is not None:
-                                if skip_bad:
-                                    r_l = jnp.where(overflow,
-                                                    res_by_li[li], r_l)
-                                nm = squant.leaf_names[li]
-                                res_bufs[nm][gi] = \
-                                    jax.lax.dynamic_update_slice(
-                                        res_bufs[nm][gi],
-                                        jax.device_put(r_l, host_big),
-                                        (r0, 0))
-                            new_l = q_l
-                        leaves[li] = jax.lax.dynamic_update_slice(
-                            leaves[li], jax.device_put(new_l, host_big),
-                            (r0, 0))
-                    elif scalar_out[li] is None:
-                        # non-flat state (the step counter): identical per
-                        # chunk; the overflow pick applies as in the full
-                        # path
-                        scalar_out[li] = (jnp.where(overflow, leaves[li],
-                                                    new_l)
-                                          if skip_bad else new_l)
-
-            cast_list = None
-            if cast_parts is not None:
-                cast_list = [cast_parts[k] for k in sorted(cast_parts)]
-            new_sts = []
-            for gi in range(n_g):
-                out_leaves = [group_leaves[gi][li] if is_flat[li]
-                              else scalar_out[li]
-                              for li in range(len(is_flat))]
-                new_sts.append(jax.tree_util.tree_unflatten(opt_defs,
-                                                            out_leaves))
-            new_opt = _recombine_group_states(opt_state, new_sts)
-            new_qres = _qres_regroup(res_bufs, qres)
-            if groups is None:
-                return masters[0], new_opt, new_qres, cast_list
-            return tuple(masters), new_opt, new_qres, cast_list
-
-        def uniform_offload_update(master, opt_state, g, hp, overflow,
-                                   qres=None, coef=None, g_on_host=False):
-            """The O(1)-compile streamed update: same per-chunk math and
-            group structure as :func:`chunked_offload_update`, but the
-            chunk loop is a ``lax.scan`` over (group, row) index data
-            (zero/stream.py) instead of an unrolled trace.  No folded
-            cast (``want_cast``): a scan can only stack per-chunk
-            outputs into a full flat compute-dtype array — the exact
-            ~2 bytes/param capacity ceiling the round-4 post-mortem
-            documented — so callers re-read params via the leaf-direct
-            streamed ``cast_params`` (2 HLO ops per chunk) instead."""
-            masters = list(master) if type(master) is tuple else [master]
-            gb = groups or ((0, segments.rows),)
-            n_g = len(gb)
-            group_leaves, is_flat, opt_defs = _split_group_states(
-                opt_state, n_g)
-            g_groups = gg = None
-            if g_on_host:
-                g_groups = list(g) if type(g) is tuple else [g]
-            else:
-                gg = g
-            res_bufs = _qres_group_bufs(qres)
-            res_masters = res_bufs.get("master")
-            res_names = ([squant.leaf_names[li]
-                          for li in squant.res_leaf_lis]
-                         if squant is not None else [])
-            res_group_leaves = ([[res_bufs[nm][gi] for nm in res_names]
-                                 for gi in range(n_g)]
-                                if res_names else None)
-            out = uniform_scan_update(
-                masters=masters, group_leaves=group_leaves,
-                is_flat=is_flat, opt_treedef=opt_defs,
-                update_fn=optimizer.update, hp=hp, overflow=overflow,
-                skip_bad=skip_bad,
-                jobs=uniform_chunk_jobs(gb, rows_per_chunk),
-                chunk_rows=rows_per_chunk, lanes=LANES,
-                g=gg, g_groups=g_groups, coef=coef,
-                to_dev=lambda x: jax.device_put(x, dev_sharding),
-                to_host=lambda x: jax.device_put(x, host_big),
-                quant=squant, res_masters=res_masters,
-                res_group_leaves=res_group_leaves,
-                prefetch_depth=self._offload_prefetch_depth)
-            if len(out) == 5:
-                (new_masters, new_group_leaves, _, new_resm,
-                 new_resf) = out
-                if new_resm is not None:
-                    res_bufs["master"] = list(new_resm)
-                for k, nm in enumerate(res_names):
-                    res_bufs[nm] = [new_resf[gi][k] for gi in range(n_g)]
-            else:
-                new_masters, new_group_leaves, _ = out
-            new_qres = _qres_regroup(res_bufs, qres)
-            new_sts = [jax.tree_util.tree_unflatten(opt_defs, gl)
-                       for gl in new_group_leaves]
-            new_opt = _recombine_group_states(opt_state, new_sts)
-            if groups is None:
-                return new_masters[0], new_opt, new_qres, None
-            return tuple(new_masters), new_opt, new_qres, None
-
-        host_grad_big = self.flat.grad_host_sharding
-        offload_grads_mode = self._offload_grads and offload_stream
-
-        def grads_tree_to_host(grads, hostg):
-            """Write the flat fp32 gradient into the donated pinned-host
-            buffer chunk-by-chunk, iterating chunks in REVERSE row order
-            (≈ the backward's production order: later tree leaves — later
-            layers and the LM head — produce their gradients first), so
-            each grad leaf's device lifetime ends at its host write and
-            the full 4 bytes/param gradient never sits in HBM (reference
-            analog: ZeRO-Offload moves averaged gradients to CPU as the
-            backward frees them, stage2.py:622-668).  Squared norm and
-            finiteness accumulate on device during the pass — clipping
-            and fp16 overflow detection would otherwise cost a second
-            streamed read of the host buffer."""
-            leaves = jax.tree_util.tree_leaves(grads)
-            hostgs = list(hostg) if type(hostg) is tuple else [hostg]
-            bounds = groups or ((0, segments.rows),)
-            offs, rcs, ns = (segments.row_offsets, segments.row_counts,
-                             segments.sizes)
-            sq = jnp.float32(0.0)
-            finite = jnp.asarray(True)
-            # Spill token chains: depth-2 PER GROUP under overlap — each
-            # group's host gradient buffer then depends only on its own
-            # spill writes (plus the grad leaves it covers), so the
-            # streamed update's reads of group g can be scheduled as
-            # soon as g's spill drains, while other groups are still
-            # spilling mid-backward: the optimizer stream starts hot.
-            # (When clipping or fp16 overflow detection is on, the
-            # global sq/finite reductions below re-impose the full
-            # drain — a mathematical barrier, not a scheduling one.)
-            # The serialized control keeps ONE global depth-2 chain.
-            toks = {gi: (jnp.float32(0.0), jnp.float32(0.0))
-                    for gi in range(len(bounds))}
-            glob = (jnp.float32(0.0), jnp.float32(0.0))
-            for gi in reversed(range(len(bounds))):
-                gr0, grc = bounds[gi]
-                for r0, rc in reversed(_chunks(grc)):
-                    abs0 = gr0 + r0
-                    end = abs0 + rc
-                    parts, cursor = [], abs0
-                    for i in range(len(leaves)):
-                        lo = max(offs[i], abs0)
-                        hi = min(offs[i] + rcs[i], end)
-                        if lo >= hi:
-                            continue
-                        if lo > cursor:  # inter-leaf padding rows
-                            parts.append(jnp.zeros(
-                                ((lo - cursor) * LANES,), jnp.float32))
-                        el_lo = (lo - offs[i]) * LANES
-                        el_hi = (hi - offs[i]) * LANES
-                        flat_leaf = leaves[i].reshape(-1).astype(jnp.float32)
-                        take_hi = min(el_hi, ns[i])
-                        if el_lo < take_hi:
-                            parts.append(jax.lax.slice(
-                                flat_leaf, (el_lo,), (take_hi,)))
-                        if take_hi < el_hi:  # leaf's own row-tail padding
-                            parts.append(jnp.zeros(
-                                (el_hi - take_hi,), jnp.float32))
-                        cursor = hi
-                    if cursor < end:  # trailing dp-padding rows
-                        parts.append(jnp.zeros(
-                            ((end - cursor) * LANES,), jnp.float32))
-                    tok2, tok1 = (toks[gi] if self._offload_overlap
-                                  else glob)
-                    parts = _after(tok2, parts)
-                    chunk = (parts[0] if len(parts) == 1
-                             else jnp.concatenate(parts)).reshape(rc, LANES)
-                    if clip > 0.0:
-                        sq = sq + jnp.sum(chunk ** 2)
-                    if skip_bad:
-                        finite = jnp.logical_and(
-                            finite, jnp.all(jnp.isfinite(chunk)))
-                    if self._offload_overlap:
-                        toks[gi] = (tok1, chunk[0, 0])
-                    else:
-                        glob = (tok1, chunk[0, 0])
-                    hostgs[gi] = jax.lax.dynamic_update_slice(
-                        hostgs[gi], jax.device_put(chunk, host_grad_big),
-                        (r0, 0))
-            out = tuple(hostgs) if type(hostg) is tuple else hostgs[0]
-            return out, sq, finite
+        def scale_tail(scale_state, skipped, overflow):
+            """(loss-scale state, skipped count) after a step that
+            overflowed or not."""
+            if fp16 and dynamic:
+                scale_state = update_scale_state(
+                    scale_state, overflow,
+                    scale_window=scale_args.get("scale_window", 1000),
+                    min_scale=scale_args.get("min_scale", 1.0),
+                    delayed_shift=scale_args.get("delayed_shift", 1))
+            if skip_bad:
+                skipped = skipped + overflow.astype(jnp.int32)
+            return scale_state, skipped
 
         @jax.named_scope("optimizer")
         def apply_update_hostg(master, opt_state, scale_state, skipped,
@@ -2729,24 +2055,10 @@ class DeepSpeedEngine:
             else:
                 gnorm = jnp.asarray(0.0, jnp.float32)
                 coef = jnp.asarray(inv, jnp.float32)
-            if offload_uniform:
-                new_master, new_opt, qres, cast_list = \
-                    uniform_offload_update(
-                        master, opt_state, hostg, hp, overflow, qres=qres,
-                        coef=coef, g_on_host=True)
-            else:
-                new_master, new_opt, qres, cast_list = \
-                    chunked_offload_update(
-                        master, opt_state, hostg, hp, overflow, qres=qres,
-                        coef=coef, g_on_host=True, want_cast=True)
-            if fp16 and dynamic:
-                scale_state = update_scale_state(
-                    scale_state, overflow,
-                    scale_window=scale_args.get("scale_window", 1000),
-                    min_scale=scale_args.get("min_scale", 1.0),
-                    delayed_shift=scale_args.get("delayed_shift", 1))
-            if skip_bad:
-                skipped = skipped + overflow.astype(jnp.int32)
+            new_master, new_opt, qres, cast_list = ofs.update(
+                master, opt_state, hostg, hp, overflow, qres=qres,
+                coef=coef, g_on_host=True, want_cast=True)
+            scale_state, skipped = scale_tail(scale_state, skipped, overflow)
             return (new_master, new_opt, scale_state, skipped, overflow,
                     gnorm, qres, cast_list)
 
@@ -2773,32 +2085,7 @@ class DeepSpeedEngine:
             # instead of materializing a replicated copy of every
             # parameter for the whole step (stage-3's memory win)
             if offload_stream and self.compute_dtype:
-                # leaf-direct streamed cast: parameter leaves materialize
-                # from chunk-aligned host reads — the full flat
-                # compute-dtype buffer never exists on device, so cast
-                # peak is the bf16 leaves plus ~two fp32 chunks.  (The
-                # round-4 parts+concat+unflatten form peaked at
-                # ~4 bytes/param — flat bf16 AND the leaves — re-imposing
-                # a ~2B capacity ceiling the update stream had removed.)
-                # Load-bearing detail: host-space slice offsets must stay
-                # CHUNK-ALIGNED — per-leaf (unaligned) host reads
-                # silently corrupted the whole fused step in round 4
-                # (master write-back lost, cast returned
-                # zeros), so each aligned chunk loads to device whole and
-                # leaves are carved out with ordinary device slices.
-                masters = master if type(master) is tuple else (master,)
-                bounds = groups or ((0, segments.rows),)
-                tok2 = tok1 = jnp.float32(0.0)  # depth-2: see update loop
-                chunk_list = []
-                for gi, (gr0, grc) in enumerate(bounds):
-                    for r0, rc in _chunks(grc):
-                        src = _after(tok2, jax.lax.slice_in_dim(
-                            masters[gi], r0, r0 + rc))
-                        chunk = jax.device_put(src, dev_sharding).astype(
-                            self.compute_dtype)
-                        tok2, tok1 = tok1, chunk[0, 0].astype(jnp.float32)
-                        chunk_list.append(chunk)
-                return carve_leaves(chunk_list)
+                return ofs.cast(master)
             elif type(master) is tuple:
                 # grouped state but fp32 compute: the full fp32 buffer is
                 # needed on device regardless — assemble it
@@ -3192,7 +2479,7 @@ class DeepSpeedEngine:
         @jax.named_scope("loss_and_grads")
         def loss_and_grads_tree(params, batch, rng, cur_scale, extra):
             """offload_gradients path: returns the raw gradient TREE (no
-            device flatten — grads_tree_to_host streams it out leaf-wise)."""
+            device flatten — ofs.grads_to_host streams it out leaf-wise)."""
 
             def scaled_loss(p):
                 loss = self._loss_fn(p, batch, rng=rng, train=True, **extra)
@@ -3260,37 +2547,19 @@ class DeepSpeedEngine:
                 # same caveat as the offload pipeline's clip note.
                 new_master, new_opt, cast_tree = bucketed_update_and_cast(
                     master, opt_state, g, hp, overflow, want_cast)
-                if fp16 and dynamic:
-                    scale_state = update_scale_state(
-                        scale_state, overflow,
-                        scale_window=scale_args.get("scale_window", 1000),
-                        min_scale=scale_args.get("min_scale", 1.0),
-                        delayed_shift=scale_args.get("delayed_shift", 1))
-                if skip_bad:
-                    skipped = skipped + overflow.astype(jnp.int32)
+                scale_state, skipped = scale_tail(scale_state, skipped,
+                                                  overflow)
                 base = (new_master, new_opt, scale_state, skipped,
                         overflow, gnorm, qres)
                 return base + ((cast_tree,) if want_cast else ())
 
             if offload_stream:
                 # streamed offload: per-chunk fp16 pick happens inside
-                if offload_uniform:
-                    new_master, new_opt, qres, cast_list = \
-                        uniform_offload_update(
-                            master, opt_state, g, hp, overflow, qres=qres)
-                else:
-                    new_master, new_opt, qres, cast_list = \
-                        chunked_offload_update(
-                            master, opt_state, g, hp, overflow, qres=qres,
-                            want_cast=want_cast)
-                if fp16 and dynamic:
-                    scale_state = update_scale_state(
-                        scale_state, overflow,
-                        scale_window=scale_args.get("scale_window", 1000),
-                        min_scale=scale_args.get("min_scale", 1.0),
-                        delayed_shift=scale_args.get("delayed_shift", 1))
-                if skip_bad:
-                    skipped = skipped + overflow.astype(jnp.int32)
+                new_master, new_opt, qres, cast_list = ofs.update(
+                    master, opt_state, g, hp, overflow, qres=qres,
+                    want_cast=want_cast)
+                scale_state, skipped = scale_tail(scale_state, skipped,
+                                                  overflow)
                 base = (new_master, new_opt, scale_state, skipped, overflow,
                         gnorm, qres)
                 return base + (cast_list,) if want_cast else base
@@ -3308,13 +2577,7 @@ class DeepSpeedEngine:
                 pick = lambda new, old: jnp.where(overflow, old, new)
                 new_master = pick(new_master, master)
                 new_opt = jax.tree_util.tree_map(pick, new_opt, opt_state)
-                if fp16 and dynamic:
-                    scale_state = update_scale_state(
-                        scale_state, overflow,
-                        scale_window=scale_args.get("scale_window", 1000),
-                        min_scale=scale_args.get("min_scale", 1.0),
-                        delayed_shift=scale_args.get("delayed_shift", 1))
-                skipped = skipped + overflow.astype(jnp.int32)
+            scale_state, skipped = scale_tail(scale_state, skipped, overflow)
             return (new_master, new_opt, scale_state, skipped, overflow,
                     gnorm, qres)
 
@@ -3322,8 +2585,8 @@ class DeepSpeedEngine:
         qres_sharding = None
         if self.state.get("qres"):
             qres_sharding = {
-                k: (tuple(host_big for _ in v) if type(v) is tuple
-                    else host_big)
+                k: (tuple(master_sharding for _ in v) if type(v) is tuple
+                    else master_sharding)
                 for k, v in self.state["qres"].items()}
         apply_donate = (0, 1, 4) + ((7,) if self.state.get("qres")
                                     else ())
@@ -3377,7 +2640,7 @@ class DeepSpeedEngine:
                 rng = jax.random.fold_in(base_rng,
                                          ustep * jnp.uint32(acc_steps))
 
-            if offload_grads_mode:
+            if ofs.grads_on_host:
                 # capacity path: grads stream to pinned host as the
                 # backward frees them; the update streams them back per
                 # chunk.  acc_steps == 1 enforced at init.
@@ -3387,8 +2650,8 @@ class DeepSpeedEngine:
                                                   cur_scale, extra)
                 with jax.named_scope("loss_and_grads"), \
                         jax.named_scope("grad_flatten"):
-                    hostgrad, sq, finite = grads_tree_to_host(grads,
-                                                              hostgrad)
+                    hostgrad, sq, finite = ofs.grads_to_host(grads,
+                                                         hostgrad)
                 del grads
                 (master, opt_state, scale_state, skipped, overflow,
                  gnorm, qres, cast_list) = apply_update_hostg(
@@ -3397,7 +2660,7 @@ class DeepSpeedEngine:
                 if stage3:
                     new_params = None
                 elif cast_list is not None:
-                    new_params = carve_leaves(cast_list)
+                    new_params = ofs.carve_leaves(cast_list)
                 else:
                     new_params = cast_params(master)
                 drops = {k: jnp.asarray(0, jnp.int32) for k in sparse_paths}
@@ -3452,7 +2715,7 @@ class DeepSpeedEngine:
             elif offload_stream and upd[7] is not None:
                 # params assembled from the update's own device chunks —
                 # no post-update re-read of the host master
-                new_params = carve_leaves(upd[7])
+                new_params = ofs.carve_leaves(upd[7])
             else:
                 new_params = cast_params(master)
             return (jnp.mean(losses), master, opt_state, scale_state, skipped,
@@ -3460,12 +2723,13 @@ class DeepSpeedEngine:
                     hostgrad, qres, reports)
 
         hostgrad_sharding = None
-        if offload_grads_mode:
+        if ofs.grads_on_host:
+            host_grad_big = self.flat.grad_host_sharding
             hostgrad_sharding = (
-                tuple(host_grad_big for _ in groups) if groups is not None
-                else host_grad_big)
+                tuple(host_grad_big for _ in ofs.groups)
+                if ofs.groups is not None else host_grad_big)
         donate = (0, 1, 5)
-        if offload_grads_mode:
+        if ofs.grads_on_host:
             donate = donate + (11,)
         if self.state.get("qres"):
             donate = donate + (12,)

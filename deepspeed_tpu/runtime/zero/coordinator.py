@@ -55,7 +55,7 @@ from .stream import UNIFORM_MIN_CHUNKS
 # The limit is 1.75 GB rather than the 3.5 GB the SIGABRT bound allows:
 # the engine's round-robin chunk pipeline overlaps host↔device transfer
 # with update compute ACROSS groups (within a group the in-place DUS
-# write-back chain serializes chunks — see chunked_offload_update), so
+# write-back chain serializes chunks — offload.py, _unrolled_update), so
 # any state big enough to stream should split into at least two groups.
 HOST_GROUP_BYTES = 1792 << 20
 

@@ -1,7 +1,7 @@
 """O(1)-compile streamed-offload update: one chunk program, scanned.
 
-The round-5 streamed ZeRO-Offload update (``engine.py``,
-``chunked_offload_update``) unrolls one full update pipeline — host
+The round-5 streamed ZeRO-Offload update (``offload.py``,
+``OffloadStream._unrolled_update``) unrolls one full update pipeline — host
 load, optimizer math, overflow select, host write-back — per chunk into
 the fused step.  XLA program size therefore grows linearly with chunk
 count (= state bytes / ``offload_chunk_mb``) and compile time grows

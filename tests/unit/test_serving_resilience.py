@@ -235,9 +235,7 @@ class TestHangEviction:
         engine.attach_health(health)
         deadline = time.monotonic() + 5.0
         step = 0
-        # wait for the exit request, the monitor thread's LAST act: it
-        # sets ``fired`` first and writes the verdict in between
-        while not codes and time.monotonic() < deadline:
+        while not health.heartbeat.fired and time.monotonic() < deadline:
             step += 1
             health.beat(step)                     # this rank stays live...
             integ.publish_rank_heartbeat(tmp_path, 2, step)  # ...peer 2 too
