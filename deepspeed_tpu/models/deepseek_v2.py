@@ -119,6 +119,23 @@ class DeepseekV2Config:
         return i >= self.first_k_dense_replace
 
 
+# -- the latent attention, as functions of a config --------------------------
+# What DeepSeek-V2's programs and Xing-4.0's (``models/xing.py``) both call:
+# other ranks, another head count, another YaRN factor, the same equations.
+# ``c`` is a model's config (the published keys ``num_attention_heads``,
+# ``q_lora_rank``, ``kv_lora_rank``, ``qk_nope_head_dim``,
+# ``qk_rope_head_dim``, ``v_head_dim``, ``rms_norm_eps``, ``rope_theta``,
+# ``rope_scaling``), ``lp`` one layer's parameters with ``kv_b`` already
+# split (:func:`split_kv_b`).  The two attention bodies take the layer's
+# input AFTER its norm, in the compute dtype, and return the output
+# projection's result in fp32 with the cache written: what a model does with
+# it — add it to one stream, or mix it into four — is the model's.
+
+def cache_row(c):
+    """The latent row as the cache stores it: whole 128-lane tiles."""
+    return padded_row_width(c.latent_row)
+
+
 def _yarn_mscale(scale, mscale):
     return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
 
@@ -173,6 +190,109 @@ def rotate(x, positions, config):
     even, odd = pairs[..., 0], pairs[..., 1]
     return jnp.concatenate([even * cos - odd * sin, odd * cos + even * sin],
                            axis=-1).astype(x.dtype)
+
+
+def queries(c, lp, h, positions):
+    """``(q_nope, q_rope)`` ``[tokens, heads, .]``, the rotary part
+    rotated."""
+    c_q = rms_norm(lp["q_a_norm"], h @ lp["q_a"]["kernel"], c.rms_norm_eps)
+    q = (c_q @ lp["q_b"]["kernel"]).reshape(
+        h.shape[0], c.num_attention_heads, -1)
+    return (q[..., :c.qk_nope_head_dim],
+            rotate(q[..., c.qk_nope_head_dim:], positions, c))
+
+
+def latent_rows(c, lp, h, positions):
+    """``(c_kv, k_r, row)``: the normalised latent, the rotated shared
+    key, and the cache row ``[c_kv ; k_r ; 0 pad]``."""
+    ckv = h @ lp["kv_a"]["kernel"]
+    c_kv = rms_norm(lp["kv_a_norm"], ckv[:, :c.kv_lora_rank],
+                    c.rms_norm_eps)
+    k_r = rotate(ckv[:, c.kv_lora_rank:], positions, c)
+    pad = jnp.zeros((h.shape[0], cache_row(c) - c.latent_row), h.dtype)
+    return c_kv, k_r, jnp.concatenate([c_kv, k_r, pad], axis=-1)
+
+
+def split_kv_b(c, layers):
+    """``layers`` (``{name: layer parameters}``) with each layer's
+    ``kv_b/kernel [latent, heads * (nope + value)]`` split into ``w_uk
+    [heads, nope, latent]`` and ``w_uv [heads, latent, value]``, batched
+    over the leading axis as decode's two absorbed products take them.
+    Inside the program the split was a transpose of the whole kernel for
+    each product, in every step, of a weight that does not change between
+    steps.  ``kv_b`` is not kept: the served tree holds the same bytes."""
+    @jax.jit
+    def split(kernel):
+        w = kernel.reshape(c.kv_lora_rank, c.num_attention_heads, -1)
+        return (w[..., :c.qk_nope_head_dim].transpose(1, 2, 0),
+                w[..., c.qk_nope_head_dim:].transpose(1, 0, 2))
+
+    out = {}
+    for name, lp in layers.items():
+        lp = dict(lp)
+        lp["w_uk"], lp["w_uv"] = split(lp.pop("kv_b")["kernel"])
+        out[name] = lp
+    return out
+
+
+def prefill_attention(c, lp, h, cache, i, block_table, positions, *,
+                      kv_block_size, block, interpret):
+    """The expanded path over one request's bucket: ``h [s, hidden]`` (the
+    layer's normed input in the compute dtype) -> ``(the output
+    projection's result [s, hidden] in fp32, cache)`` with the bucket's
+    latent rows written into plane ``i`` as whole pages through
+    ``block_table``; causal flash attention at key width nope + rope, value
+    width ``v_head_dim``, blocks of ``block`` positions."""
+    s = h.shape[0]
+    n_pages = s // kv_block_size
+    q_nope, q_rope = queries(c, lp, h, positions)
+    c_kv, k_r, rows = latent_rows(c, lp, h, positions)
+    # the bucket's latent rows as whole pages, one scatter
+    cache = cache.at[i, block_table[:n_pages]].set(
+        rows.reshape(n_pages, kv_block_size, -1).astype(cache.dtype),
+        unique_indices=True)
+    k_nope = jnp.einsum("sc,hdc->shd", c_kv, lp["w_uk"])
+    v = jnp.einsum("sc,hcd->shd", c_kv, lp["w_uv"])
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(
+        k_r[:, None], q_rope.shape)], axis=-1)
+    # the kernel scales by (nope + rope)^-1/2; YaRN's m^2 rides on the
+    # query
+    m2 = yarn_softmax_scale(c) * math.sqrt(k.shape[-1])
+    q = (jnp.concatenate([q_nope, q_rope], axis=-1).astype(
+        jnp.float32) * m2).astype(h.dtype)
+    # causality alone hides the bucket's padding from the positions that
+    # are tokens
+    ctx = flash_attention_forward(
+        q[None], k[None], v[None], causal=True, block_q=block,
+        block_k=block, interpret=interpret,
+        name="mla_prefill_attention")[0]
+    return jnp.matmul(ctx.reshape(s, -1), lp["o"]["kernel"],
+                      preferred_element_type=jnp.float32), cache
+
+
+def decode_attention(c, lp, h, cache, i, block_tables, ctx_lens, target, *,
+                     pages_per_step, interpret):
+    """The absorbed path over the batch's slots: ``h [slots, hidden]`` ->
+    ``(the output projection's result [slots, hidden] in fp32, cache)``
+    with every slot's new latent row appended at ``target`` (block ids,
+    offsets) of plane ``i``; scores straight against the cached rows by
+    the paged latent kernel."""
+    n_slots = h.shape[0]
+    q_nope, q_rope = queries(c, lp, h, ctx_lens)
+    _, _, rows = latent_rows(c, lp, h, ctx_lens)
+    # the append: every slot's new row in one scatter
+    cache = cache.at[(i, *target)].set(rows.astype(cache.dtype))
+    q_abs = jnp.einsum("bhd,hdc->bhc", q_nope, lp["w_uk"])
+    pad = jnp.zeros(q_abs.shape[:2] + (cache_row(c) - c.latent_row,),
+                    q_abs.dtype)
+    u = mla_paged_decode_attention(
+        jnp.concatenate([q_abs, q_rope, pad], axis=-1), cache,
+        block_tables, ctx_lens, layer=i, value_width=c.kv_lora_rank,
+        scale=yarn_softmax_scale(c), pages_per_step=pages_per_step,
+        interpret=interpret)
+    o = jnp.einsum("bhc,hcd->bhd", u, lp["w_uv"])
+    return jnp.matmul(o.reshape(n_slots, -1), lp["o"]["kernel"],
+                      preferred_element_type=jnp.float32), cache
 
 
 class DeepseekV2ForServing:
@@ -242,8 +362,7 @@ class DeepseekV2Serving:
     def __init__(self, config):
         self.config = config
         self.num_layers = config.num_hidden_layers
-        self.row = padded_row_width(config.latent_row)
-        self.scale = yarn_softmax_scale(config)
+        self.row = cache_row(config)
         self.interpret = current_platform() != "tpu"
 
     def cache_buffers(self, icfg):
@@ -260,50 +379,11 @@ class DeepseekV2Serving:
                            icfg.kv_block_size)
 
     # -- pieces shared by the two programs --------------------------------
-    def _queries(self, lp, h, positions):
-        """``(q_nope, q_rope)`` ``[tokens, heads, .]``, the rotary part
-        rotated."""
-        c = self.config
-        c_q = rms_norm(lp["q_a_norm"], h @ lp["q_a"]["kernel"],
-                       c.rms_norm_eps)
-        q = (c_q @ lp["q_b"]["kernel"]).reshape(
-            h.shape[0], c.num_attention_heads, -1)
-        return (q[..., :c.qk_nope_head_dim],
-                rotate(q[..., c.qk_nope_head_dim:], positions, c))
-
-    def _latent_rows(self, lp, h, positions):
-        """``(c_kv, k_r, row)``: the normalised latent, the rotated shared
-        key, and the cache row ``[c_kv ; k_r ; 0 pad]``."""
-        c = self.config
-        ckv = h @ lp["kv_a"]["kernel"]
-        c_kv = rms_norm(lp["kv_a_norm"], ckv[:, :c.kv_lora_rank],
-                        c.rms_norm_eps)
-        k_r = rotate(ckv[:, c.kv_lora_rank:], positions, c)
-        pad = jnp.zeros((h.shape[0], self.row - c.latent_row), h.dtype)
-        return c_kv, k_r, jnp.concatenate([c_kv, k_r, pad], axis=-1)
-
     def prepare_params(self, params):
-        """The tree the programs take: each layer's ``kv_b/kernel [latent,
-        heads * (nope + value)]`` split into ``w_uk [heads, nope, latent]``
-        and ``w_uv [heads, latent, value]``, batched over the leading axis
-        as decode's two absorbed products take them.  Inside the program
-        the split was a transpose of the whole kernel for each product, in
-        every step, of a weight that does not change between steps.
-        ``kv_b`` is not kept: the served tree holds the same bytes."""
-        c = self.config
-
-        @jax.jit
-        def split(kernel):
-            w = kernel.reshape(c.kv_lora_rank, c.num_attention_heads, -1)
-            return (w[..., :c.qk_nope_head_dim].transpose(1, 2, 0),
-                    w[..., c.qk_nope_head_dim:].transpose(1, 0, 2))
-
-        layers = {}
-        for name, lp in params["layers"].items():
-            lp = dict(lp)
-            lp["w_uk"], lp["w_uv"] = split(lp.pop("kv_b")["kernel"])
-            layers[name] = lp
-        return {**params, "layers": layers}
+        """The tree the programs take: each layer's ``kv_b`` split into
+        ``w_uk`` and ``w_uv`` (:func:`split_kv_b`), once."""
+        return {**params,
+                "layers": split_kv_b(self.config, params["layers"])}
 
     def _mlp(self, lp, z32, dtype, valid, tiling):
         """The layer's MLP of the normed stream ``z32`` (fp32), computed in
@@ -347,36 +427,15 @@ class DeepseekV2Serving:
         bs = icfg.kv_block_size
         assert bucket_len % bs == 0
         block = math.gcd(bucket_len, self.PREFILL_BLOCK)
-        n_pages = bucket_len // bs
 
         def layer(lp, x, cache, i, block_table, positions, valid, dtype):
-            s = x.shape[0]
             with jax.named_scope("attention"):
                 h = rms_norm(lp["input_norm"], x, c.rms_norm_eps).astype(
                     dtype)
-                q_nope, q_rope = self._queries(lp, h, positions)
-                c_kv, k_r, rows = self._latent_rows(lp, h, positions)
-                # the bucket's latent rows as whole pages, one scatter
-                cache = cache.at[i, block_table[:n_pages]].set(
-                    rows.reshape(n_pages, bs, self.row).astype(cache.dtype),
-                    unique_indices=True)
-                k_nope = jnp.einsum("sc,hdc->shd", c_kv, lp["w_uk"])
-                v = jnp.einsum("sc,hcd->shd", c_kv, lp["w_uv"])
-                k = jnp.concatenate([k_nope, jnp.broadcast_to(
-                    k_r[:, None], q_rope.shape)], axis=-1)
-                # the kernel scales by (nope + rope)^-1/2; YaRN's m^2 rides
-                # on the query
-                m2 = self.scale * math.sqrt(k.shape[-1])
-                q = (jnp.concatenate([q_nope, q_rope], axis=-1).astype(
-                    jnp.float32) * m2).astype(dtype)
-                # causality alone hides the bucket's padding from the
-                # positions that are tokens
-                ctx = flash_attention_forward(
-                    q[None], k[None], v[None], causal=True, block_q=block,
-                    block_k=block, interpret=self.interpret,
-                    name="mla_prefill_attention")[0]
-                x = x + jnp.matmul(ctx.reshape(s, -1), lp["o"]["kernel"],
-                                   preferred_element_type=jnp.float32)
+                y, cache = prefill_attention(
+                    c, lp, h, cache, i, block_table, positions,
+                    kv_block_size=bs, block=block, interpret=self.interpret)
+                x = x + y
             with jax.named_scope("mlp" if "mlp" in lp else "moe"):
                 z = rms_norm(lp["post_norm"], x, c.rms_norm_eps)
                 y, _ = self._mlp(lp, z, dtype, valid, self.PREFILL_TILING)
@@ -422,23 +481,12 @@ class DeepseekV2Serving:
             with jax.named_scope("attention"):
                 h = rms_norm(lp["input_norm"], x, c.rms_norm_eps).astype(
                     dtype)
-                q_nope, q_rope = self._queries(lp, h, ctx_lens)
-                _, _, rows = self._latent_rows(lp, h, ctx_lens)
-                # the append: every slot's new row in one scatter
-                cache = cache.at[(i, *target)].set(rows.astype(cache.dtype))
-                q_abs = jnp.einsum("bhd,hdc->bhc", q_nope, lp["w_uk"])
-                pad = jnp.zeros(q_abs.shape[:2] + (self.row - c.latent_row,),
-                                q_abs.dtype)
-                u = mla_paged_decode_attention(
-                    jnp.concatenate([q_abs, q_rope, pad], axis=-1), cache,
-                    block_tables, ctx_lens, layer=i,
-                    value_width=c.kv_lora_rank, scale=self.scale,
+                y, cache = decode_attention(
+                    c, lp, h, cache, i, block_tables, ctx_lens, target,
                     pages_per_step=min(self.DECODE_PAGES,
                                        icfg.max_blocks_per_seq),
                     interpret=self.interpret)
-                o = jnp.einsum("bhc,hcd->bhd", u, lp["w_uv"])
-                x = x + jnp.matmul(o.reshape(n_slots, -1), lp["o"]["kernel"],
-                                   preferred_element_type=jnp.float32)
+                x = x + y
             with jax.named_scope("mlp" if "mlp" in lp else "moe"):
                 z = rms_norm(lp["post_norm"], x, c.rms_norm_eps)
                 y, counts = self._mlp(lp, z, dtype, valid,

@@ -17,7 +17,8 @@ from benchmarks import generators, models, serve
 from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
 from deepspeed_tpu.parallel import make_mesh
 from deepspeed_tpu.telemetry import scopes
-from tests.benchmarks import _tiny, _tiny_deepseek, _tiny_exaone, _tiny_ouro
+from tests.benchmarks import (_tiny, _tiny_deepseek, _tiny_exaone, _tiny_ouro,
+                              _tiny_xing)
 
 LEDGER = {"profiling": {"memory_ledger": True}}
 
@@ -38,7 +39,7 @@ def _no_persistent_cache():
 
 SERVED = {"gpt2": _tiny.serve_spec, "deepseek_v2": _tiny_deepseek.serve_spec,
           "exaone_moe": _tiny_exaone.serve_spec,
-          "ouro": _tiny_ouro.serve_spec}
+          "ouro": _tiny_ouro.serve_spec, "xing": _tiny_xing.serve_spec}
 
 
 # -- an op_name read as a scope ---------------------------------------------
@@ -219,7 +220,13 @@ def test_a_served_models_decode_stands_under_the_vocabulary(model):
     names = _names(decode)
     assert {"embed", "layer_0", "attention", "final_norm", "lm_head",
             "sample"} <= names
-    assert ("moe" in names) == (model in ("deepseek_v2", "exaone_moe"))
+    assert ("moe" in names) == (model in ("deepseek_v2", "exaone_moe",
+                                          "xing"))
+    if model == "xing":      # the four streams' mixes, beside the sublayers
+        assert {"hc_pre", "hc_post", "hc_merge"} <= names
+        assert {"layer_0/hc_pre", "layer_0/attention", "layer_0/hc_post",
+                "layer_0/mlp", "layer_2/moe/router",
+                "hc_merge"} <= {scope for scope, _ in decode.values()}
     if "moe" in names:
         assert {"router", "experts", "shared_experts", "mlp"} <= names
     else:

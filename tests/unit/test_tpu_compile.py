@@ -631,10 +631,10 @@ def _serving_shapes(cell, sharding, monkeypatch):
     from deepspeed_tpu.inference import model as gpt2_serving
     from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
     from deepspeed_tpu.models import (deepseek_v2, exaone_moe, minicpm_sala,
-                                      ouro)
+                                      ouro, xing)
 
     for module in (gpt2_serving, deepseek_v2, exaone_moe, ouro,
-                   minicpm_sala):
+                   minicpm_sala, xing):
         monkeypatch.setattr(module, "current_platform", lambda: "tpu")
     spec = common.load_cell(cell)
     config = spec["config"]
@@ -664,7 +664,8 @@ def _serving_shapes(cell, sharding, monkeypatch):
                                   "gpt2_large.backlog",
                                   "k_exaone_ep8.reason_backlog",
                                   "ouro_2_6b.think_backlog",
-                                  "minicpm_sala_pp8.longdoc_backlog"])
+                                  "minicpm_sala_pp8.longdoc_backlog",
+                                  "xing4_29b_6l.docqa_backlog"])
 def test_decode_makes_no_copy_of_a_weight(v5e, monkeypatch, cell):
     """A weight does not change between decode steps, so a step re-lays
     none out: no top-level ``copy`` or ``transpose`` of 1 MB or more whose
@@ -756,6 +757,55 @@ def test_the_largest_sparse_and_lightning_prefill_fits_beside_the_caches(
     header = text.split("\n", 1)[0]
     assert header.count("may-alias") + header.count("must-alias") == 4
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
+def test_the_four_stream_decode_and_prefill_fit_beside_their_cache(
+        v5e, monkeypatch):
+    """Xing-4.0's programs at the published widths.  A decode step of 14
+    kernel calls (6 latent attentions, 8 grouped products; its 24 mixes of
+    32 rows are XLA's: they fill no 128-row tile), the 3.27 GB cache aliased
+    and temporaries of tens of megabytes.  The 12,288-token prefill: 38
+    calls (6 flash attentions, 8 grouped products, 12 + 12 stream mixes),
+    under 2.5 GB of temporaries beside 11.65 GB of weights and cache, and no
+    copy of the stream — it is held [tokens, 4 x 3584]: a [tokens, 4, 3584]
+    float32 array is tiled over its last two dimensions and copied whole on
+    the way into every mix."""
+    cell = "xing4_29b_6l.docqa_backlog"
+    compiled = _compiled_serve_decode(cell, v5e, monkeypatch)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 14
+    assert not re.findall(r'%mhc_[\w.]* = [^\n]*custom_call_target', text)
+    assert len(re.findall(
+        r'%mla_paged_decode_attention[\w.]* = [^\n]*'
+        r'custom_call_target="tpu_custom_call"', text)) == 6
+    header = text.split("\n", 1)[0]
+    assert header.count("may-alias") + header.count("must-alias") == 1
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 6 * 6657 * 64 * 640 * 2
+    assert memory.temp_size_in_bytes < 64 * 2 ** 20
+    serving, icfg, s, params, caches = _serving_shapes(cell, v5e,
+                                                       monkeypatch)
+    bucket = max(icfg.prefill_buckets)
+    tables = tuple(s((g.table_width(icfg),), jnp.int32)
+                   for g in serving.cache_groups(icfg))
+    prefill = jax.jit(serving.build_prefill(icfg, bucket),
+                      donate_argnums=(1,)).lower(
+        params, caches, s((1, bucket), jnp.int32), s((), jnp.int32), tables,
+        s((icfg.max_batch_slots,), jnp.int32), s((), jnp.int32)).compile()
+    text = prefill.as_text()
+    assert text.count("tpu_custom_call") == 38
+    assert "mla_prefill_attention" in text
+    for name in ("mhc_pre_mix", "mhc_post_res_mix"):
+        assert len(re.findall(
+            rf'%{name}[\w.]* = [^\n]*custom_call_target="tpu_custom_call"',
+            text)) == 12, name
+    memory = prefill.memory_analysis()
+    assert memory.alias_size_in_bytes == 6 * 6657 * 64 * 640 * 2
+    assert memory.temp_size_in_bytes < 2.5e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14.5e9
+    # no copy of the stream: nothing of [12288, 4 x 3584] float32 is re-laid
+    assert not [line for op, size, _, line in _top_level(
+        text, ("copy", "transpose")) if size >= 12288 * 14336 * 4]
 
 
 # -- a training cell's whole step, as the chip gets it ----------------------
