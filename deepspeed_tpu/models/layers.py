@@ -19,6 +19,7 @@ CUDA ``DeepSpeedTransformerLayer`` plays in the reference
   ``ops/transformer/transformer.py:39-154``).
 """
 
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -31,6 +32,7 @@ from ..ops.transformer.attention import (dot_product_attention,
                                          self_attention,
                                          shard_kernel_over_mesh)
 from ..parallel.mesh import current_platform
+from ..utils.logging import logger
 
 
 def _dense_init(rng, in_dim, out_dim, initializer_range=0.02):
@@ -367,3 +369,127 @@ def cross_entropy_with_logits(logits, labels, ignore_index=-100):
     nll = (lse - gold) * mask
     denom = jnp.maximum(jnp.sum(mask), 1)
     return jnp.sum(nll) / denom
+
+
+def _chunks_of(x, labels, chunk):
+    """``x`` and ``labels`` as ``[chunks, rows, chunk, ...]``."""
+    b, s, h = x.shape
+    n = s // chunk
+    return (x.reshape(b, n, chunk, h).swapaxes(0, 1),
+            labels.reshape(b, n, chunk).swapaxes(0, 1))
+
+
+def _chunk_nll(logits, labels):
+    """One chunk's summed cross-entropy and what its gradient needs:
+    ``(sum, logsumexp, labelled, labels with 0 where unlabelled)``."""
+    mask = labels != -100
+    safe = jnp.where(mask, labels, 0)
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    gold = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0]
+    return jnp.sum((lse - gold) * mask), lse, mask, safe
+
+
+def _log_chunked_geometry(head, head_params, x, chunk, products, what):
+    """One line a traced geometry, as the kernels': which form of the loop
+    a program holds."""
+    b, s, h = x.shape
+    vocab = jax.eval_shape(head, head_params, jax.ShapeDtypeStruct(
+        (b, chunk, h), x.dtype)).shape[-1]
+    logger.info(
+        "chunked_lm_loss geometry: rows=%d seq=%d chunk=%d chunks=%d "
+        "vocab=%d head_products_per_chunk=%d (%s)", b, s, chunk, s // chunk,
+        vocab, products, what)
+
+
+def _plain_chunked_loss(head, head_params, x, labels, chunk, recompute=False):
+    """The loop as autodiff sees it: a chunk a trip of a ``lax.map``, its
+    logits recomputed on the way back under ``recompute``."""
+
+    def one(args):
+        xc, lc = args
+        total, _, mask, _ = _chunk_nll(head(head_params, xc), lc)
+        return total, jnp.sum(mask)
+
+    with jax.named_scope("loss"):
+        sums, counts = jax.lax.map(jax.checkpoint(one) if recompute else one,
+                                   _chunks_of(x, labels, chunk))
+        return jnp.sum(sums) / jnp.maximum(jnp.sum(counts), 1)
+
+
+def chunked_lm_loss(head, head_params, x, labels, chunk):
+    """Mean cross-entropy of ``head(head_params, x)`` over the positions
+    labelled other than ``-100``, the head and the loss taken over chunks
+    of ``chunk`` positions a row (``chunk`` divides the sequence), so no
+    ``[tokens, vocab]`` array exists: a chunk's ``[rows, chunk, vocab]``
+    logits live inside one step of a loop.
+
+    ``head`` is a static callable ``(head_params, [rows, chunk, hidden])
+    -> logits`` whose products keep their own dtypes; logsumexp and
+    softmax run on the logits as it returns them (float32 wherever the
+    head accumulates so).
+
+    Differentiated, the gradient is made in the forward, while a chunk's
+    logits are live: ``d logits = (softmax - one-hot) * labelled / count``
+    goes through the head's own transposes in the same step of ONE loop,
+    the head's gradient adds up over the chunks in float32 and is rounded
+    once, and what is kept for the way back is ``d x`` and the head's
+    gradient — not one logit — which the backward rule only multiplies by
+    the loss's cotangent.  So a chunk costs three products where a loop
+    recomputed on the way back costs four.  Undifferentiated (an eval
+    loss) it is the plain loop and no gradient work is done.
+
+    A float16 head keeps the recomputed loop: its gradients leave their
+    products as float16 and need the loss scale — which arrives only with
+    the cotangent — inside ``d logits`` to stay out of the subnormals.
+    """
+    if x.dtype == jnp.float16:
+        _log_chunked_geometry(
+            head, head_params, x, chunk, 1,
+            "float16: 4 where differentiated, recomputed on the way back")
+        return _plain_chunked_loss(head, head_params, x, labels, chunk,
+                                   recompute=True)
+    return _one_pass_lm_loss(head, head_params, x, labels, chunk)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 4))
+def _one_pass_lm_loss(head, head_params, x, labels, chunk):
+    _log_chunked_geometry(head, head_params, x, chunk, 1, "primal")
+    return _plain_chunked_loss(head, head_params, x, labels, chunk)
+
+
+def _one_pass_lm_loss_fwd(head, head_params, x, labels, chunk):
+    _log_chunked_geometry(head, head_params, x, chunk, 3,
+                          "gradient in the forward")
+    with jax.named_scope("loss"):
+        count = jnp.maximum(jnp.sum(labels != -100), 1)
+
+        def step(d_head, args):
+            xc, lc = args
+            logits, pull = jax.vjp(head, head_params, xc)
+            total, lse, mask, safe = _chunk_nll(logits, lc)
+            d_logits = (jnp.exp(logits - lse[..., None]) - jax.nn.one_hot(
+                safe, logits.shape[-1], dtype=logits.dtype)) * (
+                    mask / count)[..., None].astype(logits.dtype)
+            d_head_c, d_xc = pull(d_logits)
+            return jax.tree_util.tree_map(
+                lambda acc, g: acc + g.astype(jnp.float32), d_head,
+                d_head_c), (total, d_xc)
+
+        d_head, (sums, d_xs) = jax.lax.scan(
+            step, jax.tree_util.tree_map(
+                lambda p: jnp.zeros(p.shape, jnp.float32), head_params),
+            _chunks_of(x, labels, chunk))
+        d_head = jax.tree_util.tree_map(
+            lambda g, p: g.astype(p.dtype), d_head, head_params)
+        return jnp.sum(sums) / count, (d_head, d_xs.swapaxes(0, 1).reshape(
+            x.shape))
+
+
+def _one_pass_lm_loss_bwd(head, chunk, kept, g):
+    with jax.named_scope("loss"):
+        d_head, d_x = jax.tree_util.tree_map(
+            lambda d: (d * g).astype(d.dtype), kept)
+    return d_head, d_x, None
+
+
+_one_pass_lm_loss.defvjp(_one_pass_lm_loss_fwd, _one_pass_lm_loss_bwd)

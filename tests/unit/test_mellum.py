@@ -10,6 +10,8 @@ product's tiles, XLA's reductions): a few 1e-6 of a leaf.  The limit of
 planted ones of ``tests/benchmarks/test_bench_mellum.py`` read 0.08 and
 more by their worst leaf."""
 
+import logging
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,7 +22,9 @@ from benchmarks.models import mellum as bench_model
 from benchmarks.reference import mellum as reference
 from benchmarks.reference import ops
 from deepspeed_tpu.models import mellum
+from deepspeed_tpu.models.layers import cross_entropy_with_logits
 from deepspeed_tpu.parallel import make_mesh
+from deepspeed_tpu.utils.logging import logger
 from tests.benchmarks import _tiny_mellum
 
 LIMIT = 1e-4
@@ -82,6 +86,30 @@ def _reference_steps(params, batches):
     return losses, first, params
 
 
+def _next_ids(ids):
+    """The labels ``Mellum`` makes of ids alone: the next id, none for the
+    last position."""
+    return np.concatenate(
+        [ids[:, 1:], np.full((ids.shape[0], 1), -100, ids.dtype)], axis=1)
+
+
+class _HeadLines(logging.Handler):
+    """The ``chunked_lm_loss geometry:`` lines logged since the last
+    :meth:`take`."""
+
+    def __init__(self):
+        super().__init__(logging.INFO)
+        self.lines = []
+
+    def emit(self, record):
+        if "chunked_lm_loss geometry:" in record.getMessage():
+            self.lines.append(record.getMessage().split("geometry: ")[1])
+
+    def take(self):
+        lines, self.lines = self.lines, []
+        return lines
+
+
 SEED = 7
 
 
@@ -112,6 +140,12 @@ def trained(request, reference_steps):
         model_parameters=params)
     logits = np.asarray(engine.eval_batch(
         {"input_ids": batches[0]["input_ids"]}))
+    head_lines = _HeadLines()
+    logger.addHandler(head_lines)
+    eval_loss = float(engine.eval_batch(
+        {"input_ids": batches[0]["input_ids"],
+         "labels": _next_ids(batches[0]["input_ids"])}))
+    evaluated = head_lines.take()
     losses, unflatten = [], None
     shapes = jax.tree_util.tree_map(lambda x: x.shape, params)
     leaves, treedef = jax.tree_util.tree_flatten(
@@ -133,10 +167,13 @@ def trained(request, reference_steps):
                 engine.state["opt"].exp_avg) / (1 - ADAM["betas"][0]))
     master = tree_of(engine.flat.gather_master_unpadded(
         engine.state["master"]))
+    stepped = head_lines.take()
+    logger.removeHandler(head_lines)
     reports = {k: float(v) for k, v in jax.device_get(
         engine._last_reports).items()}
     engine.close()
     return {"program": (losses, first, master), "logits": logits,
+            "head_lines": (stepped, evaluated), "eval_loss": eval_loss,
             "reports": reports, "batches": batches,
             "reference": reference_steps,
             "start": bench_model.init_params(MODEL, SEED)}
@@ -178,6 +215,22 @@ def test_eval_batch_returns_the_references_logits(trained):
         trained["start"], {"input_ids": ids})).reshape(ROWS, SEQ, -1)
     assert trained["logits"].shape == want.shape
     assert np.abs(trained["logits"] - want).max() < LIMIT * np.abs(want).max()
+
+
+def test_the_head_makes_its_gradient_in_the_forward(trained):
+    """The step's program holds the head's three products a chunk in the
+    forward's one loop and no other form of it; a labelled ``eval_batch``
+    does no gradient work, and returns the loss of the logits an
+    unlabelled one returns."""
+    geometry = (f"rows={ROWS} seq={SEQ} chunk={MODEL['loss_chunk']} "
+                f"chunks={SEQ // MODEL['loss_chunk']} "
+                f"vocab={MODEL['vocab_size']} head_products_per_chunk=")
+    stepped, evaluated = trained["head_lines"]
+    assert stepped == [geometry + "3 (gradient in the forward)"]
+    assert evaluated == [geometry + "1 (primal)"]
+    want = float(cross_entropy_with_logits(
+        trained["logits"], _next_ids(trained["batches"][0]["input_ids"])))
+    assert trained["eval_loss"] == pytest.approx(want, rel=1e-5)
 
 
 def test_the_step_reports_the_expert_layers_counters(trained):
